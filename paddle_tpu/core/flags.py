@@ -113,13 +113,9 @@ define_flag("xla_compile_cache", True,
             "Enable the persistent on-disk XLA compilation cache at import "
             "(core.compile_cache.initialize). Warm-starts every compiled "
             "entry point: eager dispatch, to_static, TrainStep, benches.")
-define_flag("xla_compile_cache_dir", "",
-            "Persistent compile cache directory. Empty = "
-            "JAX_COMPILATION_CACHE_DIR env, else ~/.cache/paddle_tpu/xla.")
 define_flag("xla_compile_cache_min_compile_secs", 1.0,
             "Only persist compiles that took at least this many seconds "
-            "(keeps thousands of tiny eager-op entries off disk). Benches "
-            "set 0.0 to persist everything.")
+            "(keeps thousands of tiny eager-op entries off disk).")
 define_flag("trainstep_donate", True,
             "Donate params + optimizer slots into the compiled TrainStep "
             "update (XLA reuses their HBM in place; halves update peak). "

@@ -1,8 +1,7 @@
 """Framework-level resilience: retry, fault injection, preemption, rollback.
 
-The north-star runs on *preemptible* TPUs behind a flaky remote-compile
-tunnel (docs/compile_cache.md): IO can fail transiently, pods get SIGTERMed
-mid-step, and one nonfinite step can silently poison a run. The reference
+The north-star runs on *preemptible* TPUs: IO can fail transiently, pods
+get SIGTERMed mid-step, and one nonfinite step can silently poison a run. The reference
 framework scatters its answers — etcd-leased elastic restarts
 (ref:python/paddle/distributed/fleet/elastic/manager.py), AutoCheckpointChecker
 epoch checkpoints (ref:python/paddle/fluid/incubate/checkpoint/
@@ -194,7 +193,7 @@ class DeadlineExceededError(TimeoutError):
 
 class ServingDeviceError(RuntimeError):
     """Transient accelerator/runtime failure inside a compiled serving call
-    (dead device tunnel, evicted backend). The serving supervisor treats it
+    (lost device, evicted backend). The serving supervisor treats it
     as recoverable: rebuild the KV arena and replay in-flight requests from
     their journals (``serving.supervisor``)."""
 
@@ -613,7 +612,7 @@ class PreemptionGuard:
     def _on_signal(self, signum, frame) -> None:
         if self._event.is_set():
             # SECOND signal: the step-boundary poll is clearly not being
-            # reached (hung collective, dead tunnel) and the operator
+            # reached (hung collective, lost device) and the operator
             # insists — restore the previous handler and re-deliver, so
             # repeated SIGTERM/Ctrl-C escalates instead of being swallowed
             # forever (SIGKILL would skip the final checkpoint anyway)

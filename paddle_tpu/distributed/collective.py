@@ -205,11 +205,9 @@ def _shard_map_collective(mesh, axis, kind, op, shape, dtype, spec):
     reduced_spec = _drop_axis(spec, axis)
 
     def _wrap(f, out_spec):
-        from .sharding_util import shard_map_compat
-
         return jax.jit(
-            shard_map_compat(f, mesh=mesh, in_specs=(P(*spec),),
-                             out_specs=P(*out_spec), check_vma=False)
+            jax.shard_map(f, mesh=mesh, in_specs=(P(*spec),),
+                          out_specs=P(*out_spec), check_vma=False)
         )
 
     if kind == "all_reduce":

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
 from . import mesh as mesh_mod
-from .sharding_util import pcast, shard_map_compat
 
 SEP_AXIS = "sep"
 _NEG_INF = -1e30  # finite: keeps exp(m_old - m_new) well-defined for empty rows
@@ -53,7 +52,7 @@ def _ring_body(q, k0, v0, *, scale, causal, R, s_local):
     rank = jax.lax.axis_index(SEP_AXIS)
     b, h, sq, d = q.shape
     def pvary(x):
-        return pcast(x, (SEP_AXIS,), to="varying")
+        return jax.lax.pcast(x, (SEP_AXIS,), to="varying")
     m = pvary(jnp.full((b, h, sq), _NEG_INF, jnp.float32))
     l = pvary(jnp.zeros((b, h, sq), jnp.float32))
     o = pvary(jnp.zeros((b, h, sq, d), jnp.float32))
@@ -113,7 +112,7 @@ def ring_attention(
         return jnp.swapaxes(out, 1, 2)
 
     spec = PartitionSpec(None, SEP_AXIS, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         f, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names={SEP_AXIS}, check_vma=True,
     )
@@ -154,7 +153,7 @@ def ulysses_attention(
         return rev(out)
 
     spec = PartitionSpec(None, SEP_AXIS, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         f, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names={SEP_AXIS}, check_vma=True,
     )

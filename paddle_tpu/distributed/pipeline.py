@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import mesh as mesh_mod
-from .sharding_util import pcast, shard_map_compat
 
 PIPE_AXIS = "pipe"
 
@@ -109,8 +108,10 @@ def pipeline_apply(
         x_mb = xb.reshape((M, mb_sz) + xb.shape[1:])
 
         # initial carries become stage-varying after the first tick; mark them
-        state = pcast(jnp.zeros_like(x_mb[0]), (PIPE_AXIS,), to="varying")
-        outputs = pcast(jnp.zeros_like(x_mb), (PIPE_AXIS,), to="varying")
+        state = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (PIPE_AXIS,),
+                              to="varying")
+        outputs = jax.lax.pcast(jnp.zeros_like(x_mb), (PIPE_AXIS,),
+                                to="varying")
         fwd_perm = [(i, (i + 1) % S) for i in range(S)]
 
         def tick(carry, t):
@@ -141,7 +142,7 @@ def pipeline_apply(
         jax.tree.map(lambda _: PartitionSpec(PIPE_AXIS), stage_params),
         PartitionSpec(),
     )
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         _pipelined,
         mesh=mesh,
         in_specs=in_specs,
@@ -264,10 +265,11 @@ def pipeline_apply_interleaved(
         mb_sz = xb.shape[0] // M
         x_mb = xb.reshape((M, mb_sz) + xb.shape[1:])
 
-        state = pcast(jnp.zeros_like(x_mb[0]), (PIPE_AXIS,), to="varying")
+        state = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (PIPE_AXIS,),
+                              to="varying")
         out_shape = (M_pad,) + x_mb.shape[1:]
-        outputs = pcast(jnp.zeros(out_shape, x_mb.dtype),
-                        (PIPE_AXIS,), to="varying")
+        outputs = jax.lax.pcast(jnp.zeros(out_shape, x_mb.dtype),
+                                (PIPE_AXIS,), to="varying")
         fwd_perm = [(i, (i + 1) % S) for i in range(S)]
 
         def tick(carry, t):
@@ -308,7 +310,7 @@ def pipeline_apply_interleaved(
         jax.tree.map(lambda _: PartitionSpec(None, PIPE_AXIS), chunk_params),
         PartitionSpec(),
     )
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         _pipelined,
         mesh=mesh,
         in_specs=in_specs,

@@ -8,10 +8,9 @@ the ICI collectives (SURVEY.md §7: "GSPMD sharding annotations give DP/TP/
 sharding for free").
 
 ISSUE 14 makes this module the ONE sharding home for the compiled
-execution core: :func:`shard_map_compat` now emulates partial-manual maps
-on old jax (instead of refusing), :func:`pcast` shims the vma-marking API,
-:func:`shard_kv_entry` states the KV-arena pool placement rule (payload
-heads-sharded over "model", per-block scale pools replicated), and
+execution core: :func:`shard_kv_entry` states the KV-arena pool placement
+rule (payload heads-sharded over "model", per-block scale pools
+replicated), and
 :func:`mesh_axes_key` is the hashable mesh fingerprint that joins every
 compiled program key (engine builds, ``generate()``'s runner cache)
 exactly like the quant/donation flags already do.
@@ -20,10 +19,12 @@ ISSUE 16 adds :func:`headwise_shard_map` — the manual-partitioning rule
 that runs the Pallas paged-attention kernels per model-shard over the
 pools :func:`shard_kv_entry` committed (local head counts in, replicated
 block tables through, heads-sharded output back to GSPMD).
+:func:`flash_shard_map` is the same rule for the training flash kernel
+(batch over the data-like axes, heads over "model").
 """
 from __future__ import annotations
 
-import threading
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -37,85 +38,6 @@ def _mesh() -> Mesh:
     return mesh_mod.ensure_mesh()
 
 
-# --------------------------------------------------- manual-region tracking
-
-_manual_tls = threading.local()
-
-
-def in_manual_region() -> bool:
-    """True while tracing the body of an emulated partial-manual shard_map
-    (see :func:`shard_map_compat`): every mesh axis is manual there, so a
-    full-mesh ``with_sharding_constraint`` would be ill-typed —
-    :func:`constraint` consults this and lets GSPMD propagate instead (the
-    vma-based check covers the same case on a jax with the public API)."""
-    return getattr(_manual_tls, "depth", 0) > 0
-
-
-def manual_emulation_active() -> bool:
-    """True when this jax lacks the public ``jax.shard_map`` API, i.e.
-    partial-manual maps run through the full-manual EMULATION below.
-    Callers use this to steer around old-jaxlib sharp edges — e.g.
-    TrainStep declines buffer donation for pipe/sep-axis programs here,
-    because donated params read back through an emulated manual region
-    hit a CPU aliasing bug (nondeterministic NaN / heap corruption on
-    0.4.x; the copying build is bit-correct)."""
-    return getattr(jax, "shard_map", None) is None
-
-
-def pcast(x, axes, to: str = "varying"):
-    """``jax.lax.pcast`` across jax versions: marks a value as
-    manual-axis-varying where the API exists; identity on a jax without it
-    (the emulated full-manual path needs no vma marking — replication is
-    unchecked there, see :func:`shard_map_compat`)."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, tuple(axes), to=to)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=False,
-                     axis_names=None):
-    """``jax.shard_map`` across jax versions (degraded-environment
-    robustness): the public API when this jax has it, else
-    ``jax.experimental.shard_map`` with the old kwarg name (``check_rep``
-    for ``check_vma``).
-
-    Partial-manual callers (``axis_names=...`` — the pipeline and
-    context-parallel bodies, manual only over their own axis) get the
-    public API's native mode when available. On an old jax the native
-    ``auto=`` partial-manual mode is unsound (XLA SPMD-partitioner CHECK
-    failures that abort the process on 0.4.x), so the fallback EMULATES it
-    with a full-manual map instead: the body's collectives only ever name
-    the manual axes, and the in/out specs replicate over every other axis,
-    so full-manual is numerically identical — the only cost is that
-    non-manual-axis GSPMD sharding inside the body degrades to
-    replication (a perf, never a correctness, difference). The body is
-    traced inside a manual-region marker so :func:`constraint` calls
-    within it no-op (the vma check does this on new jax), and replication
-    checking is off — the emulation has no vma tracking to satisfy it."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-
-    if axis_names is None:
-        return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
-    def manual_body(*args, **kwargs):
-        _manual_tls.depth = getattr(_manual_tls, "depth", 0) + 1
-        try:
-            return f(*args, **kwargs)
-        finally:
-            _manual_tls.depth -= 1
-
-    return esm(manual_body, mesh=mesh, in_specs=in_specs,
-               out_specs=out_specs, check_rep=False)
-
-
 def headwise_shard_map(fn, mesh, in_head_dims, out_head_dim: int,
                        num_heads: int):
     """Manual-partitioning wrapper for a head-parallel Pallas kernel
@@ -126,7 +48,7 @@ def headwise_shard_map(fn, mesh, in_head_dims, out_head_dim: int,
     ``None`` for replicated runtime data (block tables, positions,
     per-block scale pools — exactly the operands
     :func:`shard_kv_entry` keeps replicated). The returned callable maps
-    ``fn`` over the WHOLE mesh via :func:`shard_map_compat`: head-carrying
+    ``fn`` over the WHOLE mesh via ``jax.shard_map``: head-carrying
     operands split over the "model" axis (so ``fn`` sees the LOCAL head
     count, ``num_heads // mp``, and reads only its own K/V shard — zero
     cross-chip traffic), everything else replicates, and the single output
@@ -138,9 +60,9 @@ def headwise_shard_map(fn, mesh, in_head_dims, out_head_dim: int,
     committed replicated (:func:`shard_kv_entry`'s divisibility guard), so
     every spec replicates and each device runs the full-head kernel —
     correct, just not compute-scaled; a data-only mesh degenerates the
-    same way. Replicated operands are passed through :func:`pcast` inside
-    the body (identity on a jax without the vma API) so a vma-checking
-    shard_map types them against the sharded ones."""
+    same way. Replicated operands are passed through ``jax.lax.pcast``
+    inside the body so a vma-checking shard_map types them against the
+    sharded ones."""
     mp = mesh.shape.get(MODEL_AXIS, 1)
     split = mp > 1 and num_heads % mp == 0
 
@@ -153,12 +75,38 @@ def headwise_shard_map(fn, mesh, in_head_dims, out_head_dim: int,
 
     def body(*local):
         if split:
-            local = [pcast(a, (MODEL_AXIS,)) if d is None else a
+            local = [jax.lax.pcast(a, (MODEL_AXIS,), to="varying")
+                     if d is None else a
                      for a, d in zip(local, in_head_dims)]
         return fn(*local)
 
-    return shard_map_compat(body, mesh, in_specs, spec(out_head_dim),
-                            check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec(out_head_dim), check_vma=False)
+
+
+#: mesh axes a training batch is split over (``shard_batch`` / the hybrid
+#: mesh's data-like axes), outermost first
+BATCH_AXES = ("data", "sharding")
+
+
+def flash_shard_map(fn, mesh, batch: int, num_heads: int):
+    """Manual-partitioning wrapper for the flash-attention kernel on
+    ``[batch, seq, heads, head_dim]`` operands. GSPMD cannot partition a
+    Mosaic kernel ("Mosaic kernels cannot be automatically partitioned"),
+    so on a multi-device mesh the compiled step would be refused; attention
+    is independent per sequence and per head, so each device runs ``fn`` on
+    its own block: batch over the data-like axes, heads over "model" —
+    the placement :func:`constraint` already gives q/k/v in the GPT block,
+    hence no resharding. An axis that does not divide its dimension
+    replicates instead (correct, just not scaled)."""
+    batch_axes = tuple(a for a in BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if batch % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    mp = mesh.shape.get(MODEL_AXIS, 1)
+    heads = MODEL_AXIS if mp > 1 and num_heads % mp == 0 else None
+    spec = PartitionSpec(batch_axes or None, None, heads, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def _prune_spec(mesh: Mesh, spec):
@@ -197,12 +145,6 @@ def constraint(x, *spec, mesh: Optional[Mesh] = None):
     mesh = mesh or mesh_mod.get_mesh()
     if mesh is None:
         return x
-    if in_manual_region():
-        # inside an emulated partial-manual shard_map body every mesh axis
-        # is manual: a full-mesh constraint is ill-typed — let GSPMD
-        # propagate from the operands (the vma check below covers this on
-        # a jax with the public shard_map API)
-        return x
     spec = _prune_spec(mesh, spec)
     t = isinstance(x, Tensor)
     arr = x._data if t else x
@@ -224,6 +166,20 @@ def constraint(x, *spec, mesh: Optional[Mesh] = None):
 
 def replicate(x, mesh: Optional[Mesh] = None):
     return constraint(x, mesh=mesh)
+
+
+def replicate_unplaced(x, mesh: Mesh):
+    """Commit ``x`` (a Tensor, in place; or an array, returned) replicated
+    over ``mesh`` unless it already spans the mesh's devices. What no layer
+    placed — norm weights, position embeddings, fresh optimizer state, a
+    host array — sits uncommitted on one device; a compiled call would
+    re-broadcast it every time, and a training step whose outputs come
+    back on the mesh would compile a second time for them."""
+    arr = x._data if isinstance(x, Tensor) else x
+    sh = getattr(arr, "sharding", None)  # None: a host (numpy) array
+    if sh is not None and len(sh.device_set) == mesh.devices.size:
+        return x
+    return replicate(x, mesh)
 
 
 # ------------------------------------------------ mesh-aware program keys

@@ -25,9 +25,8 @@ def _worker(func, rank, nprocs, endpoints, backend, args, queue):
     os.environ["PADDLE_TRAINER_ENDPOINTS"] = ",".join(endpoints)
     os.environ["PADDLE_CURRENT_ENDPOINT"] = endpoints[rank]
     if backend == "cpu":
-        # force, not setdefault: the inherited env (and any sitecustomize
-        # jax.config pin) may point at a TPU plugin the workers must not
-        # fight over
+        # force, not setdefault: the inherited env may point at a TPU the
+        # workers must not fight over (a chip belongs to one process)
         os.environ["JAX_PLATFORMS"] = "cpu"
         try:
             import jax

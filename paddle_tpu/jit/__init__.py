@@ -671,28 +671,8 @@ class TrainStep:
         them in place — halves the peak HBM of the update; old arrays are
         invalidated, but __call__ rebinds every Tensor._data to the new
         buffers. FLAGS_trainstep_donate=0 (read at build time) keeps the
-        copying build for A/B verification.
-
-        Declined (regardless of the flag) when the step will trace an
-        EMULATED partial-manual shard_map region — a multi-device mesh
-        with an active pipe/sep axis on a jax without the public
-        shard_map API: donated params read back through the emulated
-        manual region hit a 0.4.x CPU aliasing bug (nondeterministic
-        NaN / heap corruption in the SECOND step; reproduced via the
-        interleaved GPT pipe). The copying build is bit-correct, so the
-        old environment trades the HBM win for determinism; GSPMD-only
-        mesh programs (dp/mp, serving) keep donating."""
-        if not flags.flag("trainstep_donate"):
-            return ()
-        from ..distributed import mesh as mesh_mod
-        from ..distributed.sharding_util import manual_emulation_active
-
-        m = mesh_mod.get_mesh()
-        if (m is not None and m.devices.size > 1
-                and manual_emulation_active()
-                and any(m.shape.get(a, 1) > 1 for a in ("pipe", "sep"))):
-            return ()
-        return (0, 2)
+        copying build for A/B verification."""
+        return (0, 2) if flags.flag("trainstep_donate") else ()
 
     def _guarded_update(self, param_arrays, grads, loss, opt_state, lr):
         """NaN/Inf step sentinel: ONE fused finiteness reduction over
@@ -717,8 +697,27 @@ class TrainStep:
         new_params, new_state = jax.lax.cond(finite, _apply, _skip, None)
         return new_params, new_state, finite
 
+    @staticmethod
+    def _commit_to_mesh(tree):
+        """Under an installed multi-device mesh, commit what no layer placed
+        as replicated over it (``sharding_util.replicate_unplaced``):
+        Tensors in place, arrays in the returned pytree. Left uncommitted
+        on one device, the first call compiles for one-device inputs, its
+        outputs come back on the mesh, and the second call compiles the
+        whole step again for those."""
+        from ..distributed import mesh as mesh_mod
+        from ..distributed.sharding_util import replicate_unplaced
+
+        m = mesh_mod.get_mesh()
+        if m is None or m.devices.size <= 1:
+            return tree
+        return jax.tree_util.tree_map(
+            lambda x: replicate_unplaced(x, m), tree,
+            is_leaf=lambda x: isinstance(x, Tensor))
+
     def _build(self):
         compile_cache.bump("train_step.builds")
+        self._commit_to_mesh(self._train_params + self._buffers)
         self._sentinel = bool(flags.flag("trainstep_sentinel"))
         if self._accumulate_steps > 1:
             self._build_accum(self._accumulate_steps, self._accumulate_avg)
@@ -827,7 +826,10 @@ class TrainStep:
 
         self._jit_fn = _step
 
-    def __call__(self, *args):
+    def _call_args(self, args, inject_fault: bool = True) -> tuple:
+        """The compiled step's full argument tuple for user inputs
+        ``args`` at the current training state (builds the step and seeds
+        the optimizer state on first use)."""
         if self._jit_fn is None:
             self._build()
         if self._accumulate_steps > 1:
@@ -860,27 +862,43 @@ class TrainStep:
             # live in Optimizer._overlay_slot
             slots = [self._opt._overlay_slot(self._opt._init_slot(p._data), p)
                      for p in self._train_params]
-            self._opt_state = {
+            self._opt_state = self._commit_to_mesh({
                 "slots": slots,
                 "step": jnp.asarray(self._opt._step_count, jnp.int32),
-            }
-        compile_cache.bump("train_step.steps")
+            })
         param_arrays = tuple(p._data for p in self._train_params)
         buffer_arrays = tuple(b._data for b in self._buffers)
         lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
+        head = (param_arrays, buffer_arrays, self._opt_state, lr,
+                rng.next_key())
+        if not self._sentinel:
+            return head + (args,)
+        # nonfinite_grads injection rides a runtime scalar (no recompile)
+        bad = inject_fault and resilience.maybe_fault("nonfinite_grads")
+        return head + (jnp.asarray(float("nan") if bad else 1.0,
+                                   jnp.float32), args)
+
+    def lower(self, *args):
+        """``jax.stages.Lowered`` of the compiled step for inputs ``args``
+        at the current training state — for reading what the step contains
+        (``.compile().as_text()``: a ``tpu_custom_call`` when flash
+        attention is in it, collectives on a mesh) and what it needs
+        (``.compile().memory_analysis()``). Runs nothing; like a call it
+        builds the step on first use and draws one key from the framework
+        rng."""
+        call_args = self._call_args(args, inject_fault=False)
+        return self._jit_fn.lower(*call_args)
+
+    def __call__(self, *args):
+        call_args = self._call_args(args)
+        compile_cache.bump("train_step.steps")
         finite = None
         if self._sentinel:
-            # nonfinite_grads injection rides a runtime scalar (no recompile)
-            scale = jnp.asarray(
-                float("nan") if resilience.maybe_fault("nonfinite_grads")
-                else 1.0, jnp.float32)
-            loss, new_params, self._opt_state, mutated, finite = self._jit_fn(
-                param_arrays, buffer_arrays, self._opt_state, lr,
-                rng.next_key(), scale, args)
+            loss, new_params, self._opt_state, mutated, finite = \
+                self._jit_fn(*call_args)
         else:
-            loss, new_params, self._opt_state, mutated = self._jit_fn(
-                param_arrays, buffer_arrays, self._opt_state, lr,
-                rng.next_key(), args)
+            loss, new_params, self._opt_state, mutated = \
+                self._jit_fn(*call_args)
         # params/opt state MUST rebind even on a skipped step (donation
         # invalidated the old arrays; the skip branch returned them through)
         for p, np_ in zip(self._train_params, new_params):

@@ -55,8 +55,7 @@ class GPTConfig:
     recompute_policy: str = "full"
     # lax.scan one decoder block over stacked per-layer params: XLA compiles
     # the block ONCE instead of inlining num_layers copies, so compile time
-    # (and HLO size) stop growing with depth — the lever that makes a deep
-    # config compile inside a short remote-compile window. Runtime cost is
+    # (and HLO size) stop growing with depth. Runtime cost is
     # one stack/unstack copy of the layer params per step (~2*P bytes of
     # HBM traffic, <1% of a training step). Training-path only (the KV-cache
     # decode path keeps per-layer buffers); requires dropout == 0 while

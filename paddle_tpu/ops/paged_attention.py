@@ -9,9 +9,10 @@ K/V **directly through the block tables instead**: the table rides as a
 scalar-prefetch operand and each grid step's BlockSpec ``index_map``
 resolves one logical block to its physical pool row, so HBM traffic is
 the pool blocks themselves — no contiguous copy, no f32 materialization
-of an int8 arena (per-block scales stream alongside the payload and
-dequantize in VMEM via the one
-:func:`paddle_tpu.quantization.dequantize_kv` home).
+of an int8 arena (per-block scales stream alongside the payload and fold
+into the scores and probabilities in VMEM —
+:func:`paddle_tpu.quantization.dequantize_kv`'s math, see
+:func:`_attend_block`).
 
 Two kernels, same online-softmax core as the training flash kernel
 (:mod:`paddle_tpu.ops.pallas_ops`):
@@ -64,8 +65,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .pallas_ops import (NEG_INF, _HAS_PALLAS, _LANES, _compiler_params,
-                         _use_interpret)
+from .pallas_ops import NEG_INF, _HAS_PALLAS, _LANES, _use_interpret
 
 if _HAS_PALLAS:
     from jax.experimental import pallas as pl
@@ -78,8 +78,8 @@ __all__ = ["available", "paged_decode_attention", "paged_prefill_attention",
 def available() -> bool:
     """Whether the paged kernels can run here (Pallas importable with
     scalar-prefetch support). The engine checks ONCE at construction and
-    falls back to the XLA gather path with a warning — never a traced
-    branch."""
+    refuses to build a kernel engine without them — never a traced
+    branch, never a silent gather path."""
     return _HAS_PALLAS and hasattr(pltpu, "PrefetchScalarGridSpec")
 
 
@@ -124,15 +124,71 @@ def _local_heads(num_heads: int, mesh) -> int:
         else num_heads
 
 
-def _deq(block, scale_row, dtype):
-    """In-VMEM dequant of one pool block ``[bs, ...,]`` through its
-    per-row scales — the same
-    :func:`paddle_tpu.quantization.dequantize_kv` math the XLA fallback
-    uses (f32 multiply, one cast), applied to one block instead of the
-    whole gathered context."""
-    from ..quantization import dequantize_kv
+#: query rows per decode tile. Mosaic lowers the heads-batched contraction
+#: only when the lhs has a free (row) dimension, so the slot's single
+#: query is broadcast IN VMEM to one f32 sublane tile; row 0 is written
+#: back. Decode attention is bound by the K/V block reads, not these rows.
+_DECODE_ROWS = 8
 
-    return dequantize_kv(block, scale_row, dtype)
+#: int8 scale pools stream in whole f32 sublane tiles: a ``(1, bs)`` block
+#: of a ``[num_blocks, bs]`` array is not a legal TPU block shape, an
+#: ``(8, bs)`` one is. The kernel picks its row out of the tile.
+_SCALE_ROWS = 8
+
+
+def _scale_spec(bs, block_of):
+    """BlockSpec of one scale pool: the sublane tile holding the physical
+    block that ``block_of(*grid_and_prefetch_args)`` names."""
+    return pl.BlockSpec(
+        (_SCALE_ROWS, bs), lambda *a: (block_of(*a) // _SCALE_ROWS, 0))
+
+
+def _scale_row(ref, block):
+    """``[1, bs]`` per-token scales of physical ``block`` out of its
+    tile (keys on lanes — the layout of a score row)."""
+    return ref[pl.ds(block % _SCALE_ROWS, 1), :]
+
+
+def _attend_block(q, k, v, k_scale, v_scale, visible, scale,
+                  m_scr, l_scr, acc_scr):
+    """Online-softmax update of ``[blk_h, rows, ...]`` scratch with one
+    KV block: ``q`` ``[blk_h, rows, D]`` (head-major — the batch dim leads
+    the lhs and the lhs keeps a free dim, the one form of this contraction
+    Mosaic lowers), ``k``/``v`` ``[bs, blk_h, D]``, ``visible`` a mask
+    broadcastable to ``[blk_h, rows, bs]``.
+
+    An int8 block is NOT dequantized element-wise: its per-token scales
+    (``[1, bs]``, keys on lanes) fold into the score columns and the
+    probabilities — ``q.(k*s) == (q.k)*s`` and ``p@(v*s) == (p*s)@v`` —
+    which is :func:`paddle_tpu.quantization.dequantize_kv`'s math without
+    a relayout of the scale row across the block's leading axis (int8
+    values are exact in every compute dtype)."""
+    if k_scale is not None:
+        k, v = k.astype(q.dtype), v.astype(q.dtype)
+    sc = jax.lax.dot_general(  # [blk_h, rows, bs]
+        q, k, (((2,), (2,)), ((0,), (1,))),
+        preferred_element_type=jnp.float32) * scale
+    if k_scale is not None:
+        sc = sc * k_scale
+    sc = jnp.where(visible, sc, NEG_INF)
+    m_prev = m_scr[:, :, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+    p = jnp.exp(sc - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = corr * l_scr[:, :, 0:1] + jnp.sum(p, axis=2, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
+    pv = jax.lax.dot_general(  # [blk_h, rows, D]
+        p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
+        preferred_element_type=jnp.float32)
+    acc_scr[:] = acc_scr[:] * corr + pv
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _normalized(l_scr, acc_scr):
+    denom = l_scr[:, :, 0:1]
+    return acc_scr[:] / jnp.where(denom == 0.0, 1.0, denom)
 
 
 # ---------------------------------------------------------------- decode
@@ -164,34 +220,19 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, blk_h,
     # output: key 0 is always <= pos, so the denominator never zeroes)
     @pl.when(j * bs <= pos)
     def _step():
-        q = q_ref[0]  # [blk_h, D]
-        k = k_ref[0]  # [bs, blk_h, D]
-        v = v_ref[0]
-        if quantized:
-            k = _deq(k, ks_ref[0], q.dtype)
-            v = _deq(v, vs_ref[0], q.dtype)
-        sc = jax.lax.dot_general(  # [blk_h, bs], heads batched
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        gk = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        sc = jnp.where(gk <= pos, sc, NEG_INF)
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(  # [blk_h, D]
-            p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * corr + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        q = q_ref[0]  # [blk_h, 1, D]
+        q = jnp.broadcast_to(q, (blk_h, _DECODE_ROWS, q.shape[-1]))
+        block = bt_ref[s, j]
+        gk = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
+        _attend_block(
+            q, k_ref[0], v_ref[0],
+            _scale_row(ks_ref, block) if quantized else None,
+            _scale_row(vs_ref, block) if quantized else None,
+            gk <= pos, scale, m_scr, l_scr, acc_scr)
 
     @pl.when(j == nj - 1)
     def _fin():
-        denom = l_scr[:, 0:1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        o_ref[0] = _normalized(l_scr, acc_scr)[:, 0:1, :].astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, entry, block_tables, positions,
@@ -229,40 +270,39 @@ def paged_decode_attention(q, entry, block_tables, positions,
     grid = (S, H // blk_h, MB)
     kern = functools.partial(_decode_kernel, bs=bs, blk_h=blk_h,
                              scale=1.0 / math.sqrt(D), quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, blk_h, D), lambda s, g, j, bt, pos: (s, g, 0)),
-        pl.BlockSpec((1, bs, blk_h, D),
-                     lambda s, g, j, bt, pos: (bt[s, j], 0, g, 0)),
-        pl.BlockSpec((1, bs, blk_h, D),
-                     lambda s, g, j, bt, pos: (bt[s, j], 0, g, 0)),
-    ]
-    args = [block_tables, positions, q, kp, vp]
+    # [S, H, 1, D] view: the (1, D) trailing block dims equal the array's,
+    # so any head grouping is a legal block, and the kernel reads its
+    # query head-major without a transpose
+    q_spec = pl.BlockSpec((1, blk_h, 1, D),
+                          lambda s, g, j, bt, pos: (s, g, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, blk_h, D),
+                           lambda s, g, j, bt, pos: (bt[s, j], 0, g, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    args = [block_tables, positions, q[:, :, None, :], kp, vp]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs), lambda s, g, j, bt, pos: (bt[s, j], 0)),
-            pl.BlockSpec((1, bs), lambda s, g, j, bt, pos: (bt[s, j], 0)),
-        ]
+        in_specs += [_scale_spec(
+            bs, lambda s, g, j, bt, pos: bt[s, j])] * 2
         args += [entry[2], entry[3]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, blk_h, D),
-                               lambda s, g, j, bt, pos: (s, g, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((blk_h, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((blk_h, _LANES), jnp.float32),  # running denom
-            pltpu.VMEM((blk_h, D), jnp.float32),       # out accumulator
+            pltpu.VMEM((blk_h, _DECODE_ROWS, _LANES), jnp.float32),  # max
+            pltpu.VMEM((blk_h, _DECODE_ROWS, _LANES), jnp.float32),  # denom
+            pltpu.VMEM((blk_h, _DECODE_ROWS, D), jnp.float32),  # out acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        compiler_params=_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
     )(*args)
+    return out[:, :, 0, :]
 
 
 def _sharded_decode(q, entry, block_tables, positions, block_h, mesh):
@@ -327,37 +367,20 @@ def _prefill_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, *rest, bs,
     # a block strictly past this tile's last global row is fully masked
     @pl.when(j * bs <= prefix + (qi + 1) * blk_q - 1)
     def _step():
-        q = q_ref[:]  # [blk_h, blk_q, D] (head-major — see the wrapper)
-        k = k_ref[0]  # [bs, blk_h, D]
-        v = v_ref[0]
-        if quantized:
-            k = _deq(k, ks_ref[0], q.dtype)
-            v = _deq(v, vs_ref[0], q.dtype)
-        sc = jax.lax.dot_general(  # [blk_h, blk_q, bs]
-            q, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
+        block = bt_ref[j]
         rows = prefix + qi * blk_q + jax.lax.broadcasted_iota(
             jnp.int32, (1, blk_q, bs), 1)
         cols = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (1, blk_q, bs), 2)
-        sc = jnp.where(cols <= rows, sc, NEG_INF)
-        m_prev = m_scr[:, :, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_scr[:, :, 0:1] + jnp.sum(p, axis=2, keepdims=True)
-        pv = jax.lax.dot_general(  # [blk_h, blk_q, D]
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * corr + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _attend_block(  # q is [blk_h, blk_q, D], head-major (the wrapper)
+            q_ref[:], k_ref[0], v_ref[0],
+            _scale_row(ks_ref, block) if quantized else None,
+            _scale_row(vs_ref, block) if quantized else None,
+            cols <= rows, scale, m_scr, l_scr, acc_scr)
 
     @pl.when(j == nj - 1)
     def _fin():
-        denom = l_scr[:, :, 0:1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[:] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        o_ref[:] = _normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def paged_prefill_attention(q, entry, bt_row, prefix_len,
@@ -409,10 +432,8 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
     args = [bt_row, jnp.reshape(jnp.asarray(prefix_len, jnp.int32), (1,)),
             q_hm, kp, vp]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs), lambda g, qi, j, bt, meta: (bt[j], 0)),
-            pl.BlockSpec((1, bs), lambda g, qi, j, bt, meta: (bt[j], 0)),
-        ]
+        in_specs += [_scale_spec(
+            bs, lambda g, qi, j, bt, meta: bt[j])] * 2
         args += [entry[2], entry[3]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -430,7 +451,7 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, sq, D), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
     )(*args)

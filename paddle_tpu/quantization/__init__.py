@@ -113,15 +113,14 @@ def dequantize_kv(q, scale, dtype):
     multiply happens before the cast so a bf16 compute dtype rounds once,
     not twice.
 
-    This is the ONE home of the dequant math: the XLA gather path calls
-    it over gathered context (per block when the compute dtype is
-    narrower than f32 — ``engine._gather_ctx``), and the Pallas paged
-    kernels (:mod:`paddle_tpu.ops.paged_attention`) call it inside the
-    kernel body on one VMEM-resident block at a time with its ``[bs]``
-    scale rows — the broadcast over the trailing ``(heads, dim)`` axes is
-    the same either way, so the fused path can never drift from the
-    fallback's numbers by more than the documented softmax-association
-    tolerance (docs/performance.md)."""
+    This is the home of the dequant math: the XLA gather path calls it
+    over gathered context (per block when the compute dtype is narrower
+    than f32 — ``engine._gather_ctx``). The Pallas paged kernels
+    (:mod:`paddle_tpu.ops.paged_attention`) apply the same per-token
+    scales to the score columns and the probabilities instead
+    (``q.(k*s) == (q.k)*s``): a ``[1, bs]`` lane row of scales cannot be
+    relaid across a VMEM block's leading axis on the chip. The two agree
+    within the documented tolerance (docs/performance.md)."""
     return (q.astype(jnp.float32) * scale[..., None, None]).astype(dtype)
 
 

@@ -529,6 +529,13 @@ class ServingEngine:
             self.lora = None
         params, buffers = model.functional_state()
         self._objs = list(params.values()) + list(buffers.values())
+        if self._mesh_devices > 1:
+            # norms and position embeddings no layer placed: every compiled
+            # call reads them in place on each chip, not from device 0
+            from ..distributed.sharding_util import replicate_unplaced
+
+            for p in self._objs:
+                replicate_unplaced(p, self.mesh)
         self._arrays = [p._data for p in self._objs]
 
         mcfg = model.cfg
@@ -570,14 +577,15 @@ class ServingEngine:
             from ..ops import paged_attention
 
             if not paged_attention.available():
-                # resolved ONCE here, never a traced branch: without
-                # Pallas scalar-prefetch support the engine serves the
-                # (numerically equivalent) XLA gather path instead
-                warnings.warn("FLAGS_serving_paged_kernel requested but "
-                              "Pallas scalar-prefetch is unavailable; "
-                              "falling back to the XLA gather path")
-                self.paged_kernel = False
-            elif self._mesh_devices > 1:
+                # the kernel was asked for (config or flag): serving the
+                # gather path instead would hide that from every counter
+                # and every measurement made of this engine
+                raise RuntimeError(
+                    "paged_kernel requested but the Pallas paged-attention "
+                    "kernels are unavailable here (no scalar-prefetch "
+                    "support); the engine does not fall back to the XLA "
+                    "gather path")
+            if self._mesh_devices > 1:
                 self._kernel_mesh = self.mesh
         self._retry = cfg.retry_policy
         if self._retry is None and not self.donate:
@@ -1883,6 +1891,26 @@ class ServingEngine:
                else self._adapter[slot:slot + 1])
         return (self.lora.device_pools(), jnp.asarray(ids))
 
+    def _step_args(self, act) -> tuple:
+        """The decode step's arguments at the current slot state."""
+        import jax.numpy as jnp
+
+        if self._bt_dev is None:
+            self._bt_dev = jnp.asarray(self._bt_host)
+        return (self._arrays, self.arena.pools, self._bt_dev,
+                jnp.asarray(self._positions), jnp.asarray(self._last_tok),
+                jnp.asarray(act), self._samp_args(), *self._lora_args())
+
+    def lower_decode_step(self):
+        """``jax.stages.Lowered`` of the one compiled decode step at this
+        engine's shapes and placements — for reading what the step
+        contains (a ``tpu_custom_call`` when the paged kernel is in it,
+        collectives on a mesh; ``.compile()`` gives the memory analysis).
+        Lowering re-traces the step, so it counts in ``decode_traces`` /
+        ``serving.decode_compiles`` like any other trace: read those
+        counters first."""
+        return self._get_step().lower(*self._step_args(self._active))
+
     def decode_step(self, active=None) -> np.ndarray:
         """One iteration: every active slot's last token is forwarded at
         its own position, its k/v lands in its current block, and one new
@@ -1891,21 +1919,13 @@ class ServingEngine:
         lane mask (runtime data — same program): the speculative decoder
         drives the sampled/constrained/adapter lanes it must not cover
         through here, see :meth:`spec_ineligible`."""
-        import jax.numpy as jnp
-
         t0 = time.perf_counter()
         act = self._active if active is None else np.asarray(active, bool)
         # grow block tables whose write position crossed a block boundary
         for slot in np.flatnonzero(act):
             self._grow_slot_to(slot, int(self._positions[slot]))
-        if self._bt_dev is None:
-            self._bt_dev = jnp.asarray(self._bt_host)
-        fn = self._get_step()
         nxt, new_pools = self._call(
-            fn, self._arrays, self.arena.pools, self._bt_dev,
-            jnp.asarray(self._positions), jnp.asarray(self._last_tok),
-            jnp.asarray(act), self._samp_args(), *self._lora_args(),
-            name="serving.step")
+            self._get_step(), *self._step_args(act), name="serving.step")
         self.arena.set_pools(new_pools)
         out = np.asarray(nxt)
         self._positions[act] += 1

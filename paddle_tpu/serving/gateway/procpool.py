@@ -719,6 +719,8 @@ class ProcessReplicaPool(ReplicaPool):
             self._payload = worker.encode_payload(
                 model, dict(config=config, max_queue=max_queue,
                             **engine_kw), self._hb_interval)
+        except worker.ChipHeldError:
+            raise
         except Exception as e:
             # analysis: allow(broad-except) — pickle failures surface as
             # anything (PicklingError, TypeError, recursion); all of them

@@ -2,8 +2,8 @@
 
 The PR 4 engine treated every step exception as fatal: ``Scheduler.fail_all``
 failed each in-flight request and the caller resubmitted from scratch. On
-preemptible TPUs behind a flaky tunnel that is the wrong default — a dead
-device tunnel or an evicted backend is *transient*, and each request already
+preemptible TPUs that is the wrong default — a lost device or an evicted
+backend is *transient*, and each request already
 journals everything needed to resume (``Request.prompt`` + the emitted
 ``Request.tokens``). The supervisor turns those failures into a bounded
 recovery loop:
@@ -69,7 +69,7 @@ TRANSIENT_ERRORS = (resilience.ServingDeviceError,
 def is_transient_serving_error(exc: BaseException) -> bool:
     """True when a serving-step/prefill failure is worth a rebuild+replay:
     the registry's ``serving_device``/``arena_corrupt`` fault classes, or a
-    real ``jaxlib`` runtime error (dead PJRT tunnel, evicted backend).
+    real ``jaxlib`` runtime error (lost PJRT device, evicted backend).
     IO-class errors are NOT claimed here — they belong to the engine's
     (donation-off) retry policy; and plain bugs/validation errors must keep
     failing fast."""

@@ -16,16 +16,19 @@ from paddle_tpu.core.tensor import Tensor
 
 
 @pytest.fixture
-def tmp_cache():
+def tmp_cache(monkeypatch):
     """Point the persistent cache at a fresh tmp dir (persist-everything
-    thresholds) for one test; restore the previous dir after."""
+    thresholds) for one test; restore the previous dir after. The
+    explicit ``cache_dir=`` exists for exactly this and yields to
+    JAX_COMPILATION_CACHE_DIR, so the variable is unset for the test."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev = cc.cache_dir()
     d = tempfile.mkdtemp(prefix="pt_cc_test_")
     cc.initialize(cache_dir=d, force=True, min_compile_secs=0.0)
     try:
         yield d
     finally:
-        cc.initialize(cache_dir=prev or cc.default_cache_dir(), force=True)
+        cc.initialize(cache_dir=prev, force=True)
 
 
 @pytest.fixture
@@ -87,6 +90,84 @@ def test_initialize_idempotent_and_clear(tmp_cache):
     removed = cc.clear(tmp_cache)
     assert removed >= 1
     assert os.path.isdir(tmp_cache)  # dir itself survives
+
+
+@pytest.fixture
+def restore_cache_dir(monkeypatch):
+    """Re-point the cache where it was once the test is over. Depends on
+    ``monkeypatch`` so that this teardown runs AFTER a test's own
+    ``monkeypatch.setenv`` — but it must not trust the order: the variable
+    is dropped here before the cache is re-pointed."""
+    prev = cc.cache_dir()
+    yield
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cc.initialize(cache_dir=prev, force=True)
+    assert cc.cache_dir() == prev
+
+
+def test_env_var_places_the_cache_and_nothing_repoints_it(
+        tmp_path, monkeypatch, restore_cache_dir):
+    """JAX_COMPILATION_CACHE_DIR set -> the cache is there: neither the
+    explicit argument, nor a plain re-initialize, nor importing the bench
+    helpers moves it."""
+    import importlib
+
+    import jax
+
+    env_dir, other = str(tmp_path / "from_env"), str(tmp_path / "other")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    assert cc.resolve_cache_dir() == env_dir
+    assert cc.resolve_cache_dir(other) == env_dir
+    assert cc.initialize(cache_dir=other, force=True) == env_dir
+    assert cc.initialize(force=True) == env_dir
+    import benches._common as bench_common
+
+    importlib.reload(bench_common)  # import-time side effects, if any
+    assert cc.cache_dir() == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert os.path.isdir(env_dir) and not os.path.exists(other)
+    assert not hasattr(bench_common, "enable_compile_cache")
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout(monkeypatch):
+    """Unset -> one fixed directory in the checkout, the same from two
+    fresh interpreters: never under the home directory, a temp name, a pid
+    or a time (the path is part of the cache key)."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(cc.__file__)))
+    root = os.path.dirname(root)
+    want = os.path.join(root, ".jax_cache")
+    assert cc.default_cache_dir() == want
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    code = ("import jax, paddle_tpu;"
+            "from paddle_tpu.core import compile_cache as cc;"
+            "print(cc.cache_dir());"
+            "print(jax.config.jax_compilation_cache_dir)")
+    seen = [subprocess.run([sys.executable, "-c", code], env=env, cwd=cwd,
+                           capture_output=True, text=True, timeout=300,
+                           check=True).stdout.split()
+            for cwd in (root, os.path.join(root, "tests"))]
+    assert seen == [[want, want], [want, want]]
+    assert not want.startswith(os.path.expanduser("~") + os.sep + ".cache")
+
+
+def test_cache_stats_tool_reads_the_one_rule(tmp_path, monkeypatch):
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "cache_stats.py")
+    spec = importlib.util.spec_from_file_location("cache_stats_tool", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    args = type("A", (), {"dir": None})()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert tool._resolve_dir(args) == cc.default_cache_dir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert tool._resolve_dir(args) == str(tmp_path)
 
 
 def test_eager_jit_counters():

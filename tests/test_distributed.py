@@ -34,9 +34,7 @@ def test_all_reduce_traced_psum():
         t = paddle.Tensor(x)
         return dist.all_reduce(t, group=g)._data
 
-    from paddle_tpu.distributed.sharding_util import shard_map_compat
-
-    fn = jax.jit(shard_map_compat(f, mesh=m, in_specs=(P("data"),), out_specs=P(), check_vma=False))
+    fn = jax.jit(jax.shard_map(f, mesh=m, in_specs=(P("data"),), out_specs=P(), check_vma=False))
     x = jnp.arange(8.0)
     out = fn(x)
     assert np.allclose(np.asarray(out), 28.0)
@@ -71,9 +69,7 @@ def test_all_gather_traced():
         dist.all_gather(outs, paddle.Tensor(x), group=g)
         return jnp.concatenate([o._data for o in outs])
 
-    from paddle_tpu.distributed.sharding_util import shard_map_compat
-
-    fn = jax.jit(shard_map_compat(f, mesh=m, in_specs=(P(("data", "model")),), out_specs=P("data"), check_vma=False))
+    fn = jax.jit(jax.shard_map(f, mesh=m, in_specs=(P(("data", "model")),), out_specs=P("data"), check_vma=False))
     out = fn(jnp.arange(8.0))
     # each model-pair gathers its two shards; stitched over data -> identity
     assert out.shape == (8,) and np.allclose(np.asarray(out), np.arange(8.0))

@@ -180,3 +180,40 @@ def test_effective_min_seqlen_auto(tmp_path, monkeypatch):
         assert _effective_min_seqlen(2048) == 0
     finally:
         flags.set_flags({"flash_attention_min_seqlen": old})
+
+
+@pytest.mark.parametrize("data,model", [(4, 1), (1, 4), (2, 2)],
+                         ids=["dp4", "mp4", "dp2xmp2"])
+def test_flash_under_a_mesh_matches_one_device(data, model):
+    """On a multi-device mesh the attention path maps the flash kernel per
+    device (``sharding_util.flash_shard_map`` — GSPMD cannot partition a
+    Mosaic kernel): batch over the data axis, heads over "model". Outputs
+    and gradients equal the unmapped kernel's (each device runs whole
+    sequences of whole heads, so nothing is reassociated); a batch the
+    data axis does not divide replicates instead."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.nn.functional.attention import _sdpa
+
+    kw = dict(scale=1.0 / np.sqrt(64), causal=True, use_flash=True)
+
+    def loss_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: (_sdpa(q, k, v, **kw) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    # 3: a batch the data axis does not divide (replicates instead)
+    for batch in ((4, 3) if data == 4 else (4,)):
+        q, k, v = _qkv(batch, 128, 4, 64)
+        want = jax.jit(loss_and_grads)(q, k, v)  # no mesh: the bare kernel
+        mesh = mesh_mod.serving_mesh(model, data=data)
+        placed = [jax.device_put(t, NamedSharding(mesh, PartitionSpec(
+            "data" if batch % data == 0 and data > 1 else None, None,
+            "model" if model > 1 else None, None))) for t in (q, k, v)]
+        got = jax.jit(loss_and_grads)(*placed)
+        mesh_mod.clear_mesh()
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5)

@@ -612,7 +612,7 @@ def test_transient_serving_error_classifier():
     class XlaRuntimeError(Exception):  # jaxlib's class, matched by name
         pass
 
-    assert is_transient_serving_error(XlaRuntimeError("dead tunnel"))
+    assert is_transient_serving_error(XlaRuntimeError("device lost"))
     # bugs / IO / validation / interrupts keep the fail-fast (or retry) path
     assert not is_transient_serving_error(OSError("io"))
     assert not is_transient_serving_error(ValueError("bad request"))

@@ -1,0 +1,191 @@
+"""Every Pallas entry point of the main path compiles for a v5e chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (``/opt/skills/guides/on-chip-measurement`` §2.3),
+so what Mosaic refuses — a contraction it cannot lower, an illegal block
+shape, too much VMEM — fails in tier-1 instead of on the chip. Interpret
+mode cannot show any of that: before this file existed the paged decode
+kernel passed every interpreter parity test and did not lower.
+
+Shapes are the smoke's (``chip_smoke.py``: 32 slots, block 16, 128 blocks
+per slot, sq 512) at the 124M widths (H12/D64), the gpt_1p3b widths
+(H16/D128) and the H/4 local-head shapes each device runs on the 4-way
+mesh route. A compile that passes is not a chip run: numerics are held by
+the interpret-mode parity tests (``tests/test_paged_kernel.py``).
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
+                          SingleDeviceSharding)
+
+from paddle_tpu.ops import paged_attention as pa  # noqa: E402
+from paddle_tpu.ops import pallas_ops  # noqa: E402
+
+_DEVICES = []  # the four described v5e devices, filled by `_described`
+
+
+@pytest.fixture(scope="module")
+def _described():
+    """Describe the chip once per process, at RUN time: a skip decided at
+    import would make pytest-xdist workers collect different tests. The
+    compiler library guards against two processes holding a chip; nothing
+    here attaches one, so several workers may load it side by side."""
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    _DEVICES[:] = topo.devices
+
+
+#: (heads, head_dim): 124M, gpt_1p3b, and their H/4 mesh-local shapes
+WIDTHS = [(12, 64), (16, 128), (3, 64), (4, 128)]
+_IDS = [f"H{h}D{d}" for h, d in WIDTHS]
+S, BS, MB, NB, SQ = 32, 16, 128, 1024, 512
+
+
+@pytest.fixture(autouse=True)
+def _for_the_chip(monkeypatch, _described):
+    """Steer the kernels off the interpreter (the program gets no option
+    for this), compile at the chip's matmul precision (conftest pins
+    "highest" for the float64 goldens; Mosaic refuses that on bf16
+    operands), and keep the persistent cache out of it: an entry compiled
+    for a described chip cannot be read back without one."""
+    monkeypatch.setattr(pallas_ops, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with jax.default_matmul_precision("default"):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(_DEVICES[0]))
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text  # the kernel itself, not a fallback
+    return text
+
+
+def _entry(h, d, quantized):
+    dt = jnp.int8 if quantized else jnp.bfloat16
+    pools = (_sds((NB, BS, h, d), dt),) * 2
+    return pools + ((_sds((NB, BS), jnp.float32),) * 2 if quantized else ())
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
+def test_paged_decode_compiles(h, d, quantized):
+    _compile(lambda q, bt, pos, *entry:
+             pa.paged_decode_attention(q, entry, bt, pos),
+             _sds((S, h, d), jnp.bfloat16), _sds((S, MB), jnp.int32),
+             _sds((S,), jnp.int32), *_entry(h, d, quantized))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
+def test_paged_prefill_compiles(h, d, quantized):
+    _compile(lambda q, bt, prefix, *entry:
+             pa.paged_prefill_attention(q, entry, bt, prefix),
+             _sds((SQ, h, d), jnp.bfloat16), _sds((MB,), jnp.int32),
+             _sds((), jnp.int32), *_entry(h, d, quantized))
+
+
+@pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
+def test_paged_full_prefill_compiles(h, d):
+    x = _sds((SQ, h, d), jnp.bfloat16)
+    _compile(lambda q, k, v: pa.paged_full_prefill_attention(q, k, v, BS),
+             x, x, x)
+
+
+@pytest.mark.parametrize("blk", [128, 512], ids=["untuned", "tuned"])
+@pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
+def test_flash_fwd_bwd_compiles(h, d, blk):
+    """Forward and both backward kernels, at the untuned tiling and the
+    (512, 512) that benches/FLASH_TUNED.json records for "TPU v5 lite"."""
+    x = _sds((2, 1024, h, d), jnp.bfloat16)
+
+    def loss_and_grads(q, k, v):
+        def loss(q, k, v):
+            o = pallas_ops.flash_attention(q, k, v, causal=True,
+                                           blk_q=blk, blk_k=blk)
+            return o.astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(loss_and_grads, x, x, x)
+    assert text.count("tpu_custom_call") >= 3  # fwd, dK/dV, dQ
+
+
+# ------------------------------------------------ four described devices
+
+
+def _mesh(data, model):
+    return Mesh(np.array(_DEVICES[:4]).reshape(data, model),
+                ("data", "model"))
+
+
+def _on(mesh, shape, dtype, *spec):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*spec)))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_sharded_paged_kernels_compile(quantized):
+    """The ``headwise_shard_map`` route at gpt_1p3b widths on a 4-way
+    model mesh: pools and queries split over heads as ``shard_kv_entry``
+    commits them, tables / positions / scale pools replicated."""
+    mesh, (h, d) = _mesh(1, 4), (16, 128)
+    dt = jnp.int8 if quantized else jnp.bfloat16
+    entry = (_on(mesh, (NB, BS, h, d), dt, None, None, "model", None),) * 2
+    if quantized:
+        entry += (_on(mesh, (NB, BS), jnp.float32),) * 2
+    _compile(lambda q, bt, pos, *entry:
+             pa.paged_decode_attention(q, entry, bt, pos, mesh=mesh),
+             _on(mesh, (S, h, d), jnp.bfloat16, None, "model", None),
+             _on(mesh, (S, MB), jnp.int32), _on(mesh, (S,), jnp.int32),
+             *entry)
+    _compile(lambda q, bt, prefix, *entry:
+             pa.paged_prefill_attention(q, entry, bt, prefix, mesh=mesh),
+             _on(mesh, (SQ, h, d), jnp.bfloat16, None, "model", None),
+             _on(mesh, (MB,), jnp.int32), _on(mesh, (), jnp.int32), *entry)
+
+
+@pytest.mark.parametrize("data,model", [(4, 1), (1, 4), (2, 2)],
+                         ids=["dp4", "mp4", "dp2xmp2"])
+def test_flash_under_a_mesh_compiles(data, model):
+    """GSPMD refuses to partition a Mosaic kernel, so under an installed
+    multi-device mesh the attention path maps the flash kernel per device
+    (``flash_shard_map``); without that the dp=4 training step does not
+    compile for the chip at all."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.nn.functional.attention import _sdpa
+
+    mesh = _mesh(data, model)
+    mesh_mod.set_mesh(mesh)  # conftest uninstalls it after the test
+    x = _on(mesh, (4, 1024, 16, 128), jnp.bfloat16,
+            "data", None, "model", None)
+
+    def loss_and_grads(q, k, v):
+        def loss(q, k, v):
+            o = _sdpa(q, k, v, scale=128 ** -0.5, causal=True,
+                      use_flash=True)
+            return o.astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(loss_and_grads, x, x, x)
+    assert text.count("tpu_custom_call") >= 3

@@ -103,7 +103,8 @@ def main():
         "for bf16 layouts; TPU layout assignment, fusion, and remat choices",
         "all differ, so a projected 0.41 could plausibly measure 0.3 on",
         "hardware. These projections ORDER the sweep candidates; only",
-        "`benches/sweep.py` rows with `platform: tpu` in SWEEP_RESULTS.jsonl",
+        "`benches/sweep.py` rows with `platform: tpu` (an earlier chip "
+        "record, since removed; not measured on current code)",
         "are evidence.",
         "",
         "| config | params | HLO FLOPs | est bytes | bound | proj ms/step "
