@@ -694,7 +694,8 @@ class TrainStep:
         def _skip(_):
             return list(param_arrays), opt_state
 
-        new_params, new_state = jax.lax.cond(finite, _apply, _skip, None)
+        with jax.named_scope("optimizer_update"):
+            new_params, new_state = jax.lax.cond(finite, _apply, _skip, None)
         return new_params, new_state, finite
 
     @staticmethod

@@ -356,10 +356,14 @@ class GPTDecoderLayer(nn.Layer):
 
     def forward(self, x, cache=None, start_pos=0):
         if cache is not None:
-            attn_out, new_cache = self.attn(self.ln1(x), cache=cache,
-                                            start_pos=start_pos)
+            # serving's compiled steps: a scope is metadata on the device's
+            # operations (a trace reader's handle) and changes no program
+            with jax.named_scope("attention"):
+                attn_out, new_cache = self.attn(self.ln1(x), cache=cache,
+                                                start_pos=start_pos)
             x = x + self.drop(attn_out)
-            x = x + self.drop(self.mlp(self.ln2(x)))
+            with jax.named_scope("mlp"):
+                x = x + self.drop(self.mlp(self.ln2(x)))
             return x, new_cache
         x = x + self.drop(self.attn(self.ln1(x)))
         x = x + self.drop(self.mlp(self.ln2(x)))

@@ -301,6 +301,7 @@ def paged_decode_attention(q, entry, block_tables, positions,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
+        name="paged_decode",
     )(*args)
     return out[:, :, 0, :]
 
@@ -454,6 +455,7 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
+        name="paged_prefill",
     )(*args)
     return jnp.swapaxes(out, 0, 1)
 

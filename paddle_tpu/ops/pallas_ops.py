@@ -183,6 +183,7 @@ def _flash_forward(q, k, v, scale, causal, blk_q=128, blk_k=128,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return (res[0], res[1]) if with_lse else res[0]
 
@@ -313,6 +314,7 @@ def _flash_backward(q, k, v, o, lse, do, scale, causal, blk_q=128, blk_k=128):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, di)
 
     q_spec_q = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
@@ -331,6 +333,7 @@ def _flash_backward(q, k, v, o, lse, do, scale, causal, blk_q=128, blk_k=128):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, di)
     return dq, dk, dv
 

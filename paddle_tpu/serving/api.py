@@ -108,6 +108,7 @@ class ServingAPI:
         self.drain_count = 0  # this API's lifetime drains
         self._guard = None
         self._guard_grace: Optional[float] = None
+        self._turn = 0  # locked loop turns so far (the sched.step phase's arg)
         self._thread = None
         _live_apis.add(self)
         if background:
@@ -160,7 +161,10 @@ class ServingAPI:
         re-route continues ONE timeline); empty mints a fresh one and
         emits its SUBMITTED span here — exactly one site ever emits
         SUBMITTED per trace (docs/observability.md)."""
-        with self._lock:
+        t_submit = time.perf_counter()  # ttft/e2e start here, lock wait in
+        with telemetry.phase("submit.lock_wait",
+                             self.engine.hists) as wait, self._lock:
+            wait.stop()  # the lock is held: the wait is over
             # checked under the lock: a submit racing drain()/close() must
             # never enqueue after the straggler sweep (its request would
             # sit unpumped forever)
@@ -183,7 +187,8 @@ class ServingAPI:
                           request_id=request_id, priority=priority,
                           sampling=sampling, constraint=constraint,
                           adapter_id=int(adapter), trace_id=trace_id,
-                          deadline=resilience.Deadline.after(timeout))
+                          deadline=resilience.Deadline.after(timeout),
+                          _submit_ts=t_submit)
             if minted:
                 telemetry.span(req.trace_id, telemetry.SUBMITTED,
                                request_id=req.request_id,
@@ -451,55 +456,67 @@ class ServingAPI:
         # just continues; anything else fails every in-flight request
         # (error + stream sentinel + done_event) before propagating, so a
         # pumping caller can never strand RUNNING requests holding slots
-        # and arena blocks.
-        try:
-            self.scheduler.step()
-            self.supervisor.note_step()
-        # analysis: allow(broad-except) — THE classification point:
-        # the supervisor decides transient-vs-fatal for every step error
-        except Exception as e:
+        # and arena blocks. The phase is the whole locked turn, a
+        # recovery included (its seconds show beside supervisor.rebuilds).
+        self._turn += 1
+        with telemetry.phase("sched.step", self.engine.hists,
+                             turn=self._turn):
             try:
-                recovered = self.supervisor.handle(e)
-            # analysis: allow(broad-except) — recovery failure of any
-            # kind must fail staged requests, never strand them RUNNING
-            except Exception as e2:
-                # recovery itself died (e.g. the rebuilt arena's allocation
-                # failed on a still-dead device): the supervisor already
-                # failed the requests it had staged for replay; fail_all
-                # sweeps whatever is left registered, so nothing is ever
-                # stranded RUNNING with its done_event unset
-                self.scheduler.fail_all(e2)
-                raise e2 from e
-            if recovered:
-                metrics.bump("api.recoveries")
-                return
-            err = self.supervisor.wrap(e)
-            self.scheduler.fail_all(err)
-            if err is e:
-                raise
-            raise err
+                self.scheduler.step()
+                self.supervisor.note_step()
+            # analysis: allow(broad-except) — THE classification point:
+            # the supervisor decides transient-vs-fatal for every step error
+            except Exception as e:
+                try:
+                    recovered = self.supervisor.handle(e)
+                # analysis: allow(broad-except) — recovery failure of any
+                # kind must fail staged requests, never strand them RUNNING
+                except Exception as e2:
+                    # recovery itself died (e.g. the rebuilt arena's
+                    # allocation failed on a still-dead device): the
+                    # supervisor already failed the requests it had staged
+                    # for replay; fail_all sweeps whatever is left
+                    # registered, so nothing is ever stranded RUNNING with
+                    # its done_event unset
+                    self.scheduler.fail_all(e2)
+                    raise e2 from e
+                if recovered:
+                    metrics.bump("api.recoveries")
+                    return
+                err = self.supervisor.wrap(e)
+                self.scheduler.fail_all(err)
+                if err is e:
+                    raise
+                raise err
 
     def _pump_loop(self) -> None:
+        # the pump thread's wall time is two phases end to end:
+        # pump.unlocked (the guard poll, the wait to take the lock back
+        # from submitting handler threads, has_work, the idle sleep) and
+        # sched.step (_step_guarded: the locked turn)
         while not self._closed:
-            self._check_guard()
-            with self._lock:
-                busy = self.scheduler.has_work()
-                if busy:
-                    try:
-                        # analysis: allow(blocking-call-in-lock) — the API
-                        # lock is the engine serialization point
-                        # (background pump thread)
-                        self._step_guarded()
-                    except Exception:
-                        # analysis: allow(broad-except) — the pump thread
-                        # must never die silently with
-                        # requests in flight: _step_guarded already failed
-                        # them all (done_event + sentinel) — keep serving;
-                        # new submissions surface errors through their own
-                        # results
-                        pass
-            if not busy:
-                time.sleep(0.001)
+            with telemetry.phase("pump.unlocked",
+                                 self.engine.hists) as unlocked:
+                self._check_guard()
+                with self._lock:
+                    busy = self.scheduler.has_work()
+                    if busy:
+                        unlocked.stop()
+                        try:
+                            # analysis: allow(blocking-call-in-lock) — the
+                            # API lock is the engine serialization point
+                            # (background pump thread)
+                            self._step_guarded()
+                        except Exception:
+                            # analysis: allow(broad-except) — the pump
+                            # thread must never die silently with
+                            # requests in flight: _step_guarded already
+                            # failed them all (done_event + sentinel) —
+                            # keep serving; new submissions surface errors
+                            # through their own results
+                            pass
+                if not busy:
+                    time.sleep(0.001)
 
 
 class EnginePredictor:

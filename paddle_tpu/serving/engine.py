@@ -95,12 +95,12 @@ reproducing the plain engine exactly.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from contextlib import nullcontext as _null_ctx
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ..core import compile_cache, flags, resilience
@@ -136,6 +136,7 @@ def _scatter_rows(entry, row, off, kc, vc):
             ks.at[row, off].set(sk), vs.at[row, off].set(sv))
 
 
+@jax.named_scope("kv_gather")  # metadata on the device's operations
 def _gather_ctx(entry, table, dtype):
     """Gather a block table's logical context from one pool entry:
     ``table`` is ``[..., max_blocks]`` int32; returns ``(k_all, v_all)``
@@ -151,7 +152,6 @@ def _gather_ctx(entry, table, dtype):
     multiply, one cast), so the output is bitwise unchanged."""
     kp, vp = entry[0], entry[1]
     if len(entry) == 4:
-        import jax
         import jax.numpy as jnp
 
         from ..quantization import dequantize_kv
@@ -900,7 +900,6 @@ class ServingEngine:
         fn = self._prefill_jits.get(p_bucket)
         if fn is not None:
             return fn
-        import jax
         import jax.numpy as jnp
 
         from ..core import rng as prng
@@ -931,9 +930,10 @@ class ServingEngine:
                           else _null_ctx()):
                         h, chunks = model.gpt(Tensor(ids), caches=views,
                                               start_pos=0)
-                h_last = jax.lax.dynamic_index_in_dim(
-                    h._data, true_len - 1, axis=1, keepdims=False)
-                logits = model._head_logits(h_last)
+                with jax.named_scope("head_sample"):
+                    h_last = jax.lax.dynamic_index_in_dim(
+                        h._data, true_len - 1, axis=1, keepdims=False)
+                    logits = model._head_logits(h_last)
             p_idx = jnp.arange(p_bucket)
             row = rows[p_idx // bs]
             # padded positions (>= the true prompt length) scatter into the
@@ -951,8 +951,9 @@ class ServingEngine:
             # bit-identical per row); greedy/unmasked slots reproduce
             # the classic argmax exactly
             temp, k, p, seed, spos, vmask = samp
-            nxt = sample_tokens(logits, temp, k, p, seed, spos,
-                                allowed=vmask)
+            with jax.named_scope("head_sample"):
+                nxt = sample_tokens(logits, temp, k, p, seed, spos,
+                                    allowed=vmask)
             return nxt[0], new_pools
 
         fn = (jax.jit(prefill, donate_argnums=(3,)) if self.donate
@@ -969,7 +970,6 @@ class ServingEngine:
         fn = self._prefix_jits.get(p_bucket)
         if fn is not None:
             return fn
-        import jax
         import jax.numpy as jnp
 
         from ..core import rng as prng
@@ -1001,12 +1001,14 @@ class ServingEngine:
                           else _null_ctx()):
                         h, new_views = model.gpt(Tensor(ids), caches=views,
                                                  start_pos=prefix_len)
-                h_last = jax.lax.dynamic_index_in_dim(
-                    h._data, true_len - 1, axis=1, keepdims=False)
-                logits = model._head_logits(h_last)
+                with jax.named_scope("head_sample"):
+                    h_last = jax.lax.dynamic_index_in_dim(
+                        h._data, true_len - 1, axis=1, keepdims=False)
+                    logits = model._head_logits(h_last)
             temp, k, p, seed, spos, vmask = samp
-            nxt = sample_tokens(logits, temp, k, p, seed, spos,
-                                allowed=vmask)
+            with jax.named_scope("head_sample"):
+                nxt = sample_tokens(logits, temp, k, p, seed, spos,
+                                    allowed=vmask)
             new_pools = [v.entry for v in new_views]
             return nxt[0], new_pools
 
@@ -1082,7 +1084,13 @@ class ServingEngine:
         garbage) or when the arena has no headroom for another restore
         target. Returns how many leading nodes of ``nodes`` were
         restored."""
-        t0 = time.perf_counter()
+        with telemetry.phase("restore", self.hists) as ph:
+            restored = self._restore_chain(nodes)
+            if not restored:
+                ph.discard()  # nothing restored is no restore sample
+        return restored
+
+    def _restore_chain(self, nodes) -> int:
         cache = self.prefix_cache
         payloads, live = [], []
         for node in nodes:
@@ -1134,8 +1142,6 @@ class ServingEngine:
         for node, blk in zip(live, blks):
             cache.mark_restored(node, blk)
         self.tier.note_restored(payloads)
-        telemetry.observe("latency.restore", time.perf_counter() - t0,
-                          self.hists)
         # the restore ran inside an admission's radix walk: its span lands
         # on the admitting request's timeline (the engine is serialized
         # under the api lock, so _trace_ctx is exactly that admission's)
@@ -1146,8 +1152,6 @@ class ServingEngine:
     def _get_step(self):
         if self._step_jit is not None:
             return self._step_jit
-        import jax
-
         from ..core import rng as prng
         from ..jit import _swap_data
         from .sampling import sample_tokens
@@ -1177,15 +1181,17 @@ class ServingEngine:
                         h, new_views = model.gpt(Tensor(last_tok[:, None]),
                                                  caches=views,
                                                  start_pos=positions)
-                logits = model._head_logits(h._data[:, 0])
+                with jax.named_scope("head_sample"):
+                    logits = model._head_logits(h._data[:, 0])
             # per-slot sampling over the constrained logits: temperature /
             # top-k / top-p / seed / mask are all runtime data (greedy
             # lanes reproduce the classic argmax bit-for-bit); the
             # emitted token sits at context index positions+1 — its
             # positional PRNG key (see serving.sampling)
             temp, k, p, seed, vmask = samp
-            nxt = sample_tokens(logits, temp, k, p, seed, positions + 1,
-                                allowed=vmask)
+            with jax.named_scope("head_sample"):
+                nxt = sample_tokens(logits, temp, k, p, seed, positions + 1,
+                                    allowed=vmask)
             new_pools = [v.entry for v in new_views]
             return nxt, new_pools
 
@@ -1247,14 +1253,11 @@ class ServingEngine:
 
         Raises if no capacity; callers gate on :meth:`can_admit`."""
         self._trace_ctx = trace_id
-        t0 = time.perf_counter()
-        st = self._admit_setup(prompt, max_new_tokens, tokens,
-                               sampling=sampling, adapter=adapter,
-                               mask=mask, spec_exclude=spec_exclude)
-        out = st.slot, self._admit_prefill_all(st)
-        telemetry.observe("latency.prefill", time.perf_counter() - t0,
-                          self.hists)
-        return out
+        with telemetry.phase("prefill", self.hists, trace_id=trace_id):
+            st = self._admit_setup(prompt, max_new_tokens, tokens,
+                                   sampling=sampling, adapter=adapter,
+                                   mask=mask, spec_exclude=spec_exclude)
+            return st.slot, self._admit_prefill_all(st)
 
     def admit_begin(self, prompt: np.ndarray, max_new_tokens: int,
                     tokens=None, sampling=None, adapter: int = 0,
@@ -1269,16 +1272,15 @@ class ServingEngine:
         held) but not *active* (its lane stays masked out of the decode
         step), so running streams keep decoding between chunks."""
         self._trace_ctx = trace_id
-        t0 = time.perf_counter()
-        st = self._admit_setup(prompt, max_new_tokens, tokens,
-                               sampling=sampling, adapter=adapter,
-                               mask=mask, spec_exclude=spec_exclude)
-        chunk = self.chunk_size
-        if chunk <= 0 or st.clen - st.prefix_len <= chunk:
-            out = st.slot, self._admit_prefill_all(st)
-            telemetry.observe("latency.prefill", time.perf_counter() - t0,
-                              self.hists)
-            return out
+        with telemetry.phase("prefill", self.hists,
+                             trace_id=trace_id) as ph:
+            st = self._admit_setup(prompt, max_new_tokens, tokens,
+                                   sampling=sampling, adapter=adapter,
+                                   mask=mask, spec_exclude=spec_exclude)
+            chunk = self.chunk_size
+            if chunk <= 0 or st.clen - st.prefix_len <= chunk:
+                return st.slot, self._admit_prefill_all(st)
+            ph.discard()  # the claim alone: admit_chunk times each chunk
         st.trace_id = trace_id  # admit_chunk restores the trace context
         st.done = st.prefix_len
         self._chunk[st.slot] = st
@@ -1298,42 +1300,40 @@ class ServingEngine:
             raise RuntimeError(f"slot {slot} has no chunked prefill "
                                "in progress")
         self._trace_ctx = st.trace_id
-        t0 = time.perf_counter()
-        take = min(self.chunk_size, st.clen - st.done)
-        try:
-            nxt, new_pools = self._suffix_prefill_call(
-                st.ctx, st.done + take, st.done, slot, chunked=True)
-            self.arena.set_pools(new_pools)
-            st.done += take
-            metrics.bump("chunk.chunks")
-            metrics.bump("chunk.tokens", take)
-            # incremental publish (FLAGS_serving_publish_chunks): every
-            # prompt block this chunk finished scattering becomes a radix
-            # node NOW — and, via the insert path's write_through (+
-            # FLAGS_serving_tier_publish), tier/disk-resident — so a
-            # disagg prefill worker's partial chain is restorable the
-            # moment it exists. insert() is idempotent over the already-
-            # inserted prefix (resident nodes are skipped), and the new
-            # nodes' blocks are marked cached, so even an abort of the
-            # remaining chunks leaves them valid (cached blocks survive
-            # the reservation release).
-            if (self.prefix_cache is not None
-                    and flags.flag("serving_publish_chunks")):
-                full = min(st.done, st.plen) // self.block_size
-                if full > 0:
-                    self.prefix_cache.insert(st.prompt, self._bt_host[slot],
-                                             full)
-            if (st.done >= st.clen and self.spec is not None
-                    and not st.skip_draft):
-                self.spec.prefill(slot, st.ctx)
-        # analysis: allow(broad-except) — cleanup-and-reraise: a failed
-        # chunk must not leak the admission's blocks/refs/slot
-        except Exception:
-            self._chunk.pop(slot, None)
-            self._admit_abort(st)
-            raise
-        telemetry.observe("latency.prefill", time.perf_counter() - t0,
-                          self.hists)
+        with telemetry.phase("prefill", self.hists, trace_id=st.trace_id):
+            take = min(self.chunk_size, st.clen - st.done)
+            try:
+                nxt, new_pools = self._suffix_prefill_call(
+                    st.ctx, st.done + take, st.done, slot, chunked=True)
+                self.arena.set_pools(new_pools)
+                st.done += take
+                metrics.bump("chunk.chunks")
+                metrics.bump("chunk.tokens", take)
+                # incremental publish (FLAGS_serving_publish_chunks):
+                # every prompt block this chunk finished scattering becomes
+                # a radix node NOW — and, via the insert path's
+                # write_through (+ FLAGS_serving_tier_publish), tier/disk-
+                # resident — so a disagg prefill worker's partial chain is
+                # restorable the moment it exists. insert() is idempotent
+                # over the already-inserted prefix (resident nodes are
+                # skipped), and the new nodes' blocks are marked cached, so
+                # even an abort of the remaining chunks leaves them valid
+                # (cached blocks survive the reservation release).
+                if (self.prefix_cache is not None
+                        and flags.flag("serving_publish_chunks")):
+                    full = min(st.done, st.plen) // self.block_size
+                    if full > 0:
+                        self.prefix_cache.insert(
+                            st.prompt, self._bt_host[slot], full)
+                if (st.done >= st.clen and self.spec is not None
+                        and not st.skip_draft):
+                    self.spec.prefill(slot, st.ctx)
+            # analysis: allow(broad-except) — cleanup-and-reraise: a
+            # failed chunk must not leak the admission's blocks/refs/slot
+            except Exception:
+                self._chunk.pop(slot, None)
+                self._admit_abort(st)
+                raise
         if st.done < st.clen:
             return None
         self._chunk.pop(slot, None)
@@ -1837,11 +1837,8 @@ class ServingEngine:
         up to k accepted tokens per active slot from one compiled call —
         see :class:`~.spec_decode.SpecDecoder.step`. Returns
         ``{slot: [tokens]}``."""
-        t0 = time.perf_counter()
-        out = self.spec.step()
-        telemetry.observe("latency.spec_step", time.perf_counter() - t0,
-                          self.hists)
-        return out
+        with telemetry.phase("spec_step", self.hists):
+            return self.spec.step()
 
     def _samp_args(self):
         """The decode step's per-slot sampling pytree: (temp, top_k,
@@ -1919,23 +1916,39 @@ class ServingEngine:
         lane mask (runtime data — same program): the speculative decoder
         drives the sampled/constrained/adapter lanes it must not cover
         through here, see :meth:`spec_ineligible`."""
-        t0 = time.perf_counter()
-        act = self._active if active is None else np.asarray(active, bool)
-        # grow block tables whose write position crossed a block boundary
-        for slot in np.flatnonzero(act):
-            self._grow_slot_to(slot, int(self._positions[slot]))
-        nxt, new_pools = self._call(
-            self._get_step(), *self._step_args(act), name="serving.step")
-        self.arena.set_pools(new_pools)
-        out = np.asarray(nxt)
-        self._positions[act] += 1
-        self._last_tok[act] = out[act]
-        metrics.bump("engine.steps")
-        metrics.bump("tokens.generated", int(act.sum()))
-        self._meter.tick(int(act.sum()))
-        metrics.set_gauge("tokens_per_sec", round(self._meter.rate(), 1))
-        telemetry.observe("latency.decode_step",
-                          time.perf_counter() - t0, self.hists)
+        hists = self.hists
+        with telemetry.phase("decode_step", hists):
+            act = (self._active if active is None
+                   else np.asarray(active, bool))
+            with telemetry.phase("decode.prepare", hists):
+                # grow block tables whose write position crossed a block
+                # boundary, then the host's slot state goes to the device
+                for slot in np.flatnonzero(act):
+                    self._grow_slot_to(slot, int(self._positions[slot]))
+                args = self._step_args(act)
+            with telemetry.phase("decode.dispatch", hists):
+                nxt, new_pools = self._call(self._get_step(), *args,
+                                            name="serving.step")
+            with telemetry.phase("decode.wait", hists):
+                with telemetry.phase("decode.release", hists):
+                    # the step's argument arrays and the donated pools
+                    # must die HERE, while the device runs: each device
+                    # array's destructor hands the GIL over and queues
+                    # for it again, tens of ms a step under load. Kept
+                    # alive to the function's end, that time came after
+                    # the device's step instead of under it (PERF.md,
+                    # PR 24: a third fewer tokens a second)
+                    del args
+                    self.arena.set_pools(new_pools)
+                # blocks until the device is done, the token vector is
+                # back AND this thread has the GIL again
+                out = np.asarray(nxt)
+            self._positions[act] += 1
+            self._last_tok[act] = out[act]
+            metrics.bump("engine.steps")
+            metrics.bump("tokens.generated", int(act.sum()))
+            self._meter.tick(int(act.sum()))
+            metrics.set_gauge("tokens_per_sec", round(self._meter.rate(), 1))
         return out
 
     # -------------------------------------------------------------- stats

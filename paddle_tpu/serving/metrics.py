@@ -87,6 +87,18 @@ Counter namespaces:
   ``kernel.paged`` (0/1 mode) and ``kernel.tuned_entries`` (tuning-store
   records for this chip — ``ops.tuning`` / benches/TUNED_KERNELS.json)
 
+* ``time_us.*``    — wall time of the serving loop by phase, in whole
+  microseconds (``serving.telemetry.phase``): ``time_us.<phase>`` grows
+  by every use's elapsed time, so a window's delta over the window is
+  that phase's share of wall time and over the delta of ``engine.steps``
+  its mean per decode step. ``pump.unlocked`` and ``sched.step``
+  partition the pump thread's time; ``sched.admit`` (parent of
+  ``prefill``), ``decode_step`` (parent of ``decode.prepare`` /
+  ``decode.dispatch`` / ``decode.wait``, the last the parent of
+  ``decode.release``) and ``sched.emit`` lie inside ``sched.step``;
+  ``submit.lock_wait`` is handler threads' time (docs/observability.md
+  "Phases of the serving loop")
+
 Gauges: ``queue.depth``, ``queue.prefilling`` (chunked prefills in
 progress), ``spec.acceptance_rate``, ``slots.active``, ``slots.total``,
 ``arena.blocks_free``, ``arena.blocks_total``, ``arena.blocks_cached``
@@ -165,6 +177,11 @@ DOCUMENTED_NAMESPACES = (
     # counters and segments / bytes gauges (serving.gateway.wal,
     # docs/robustness.md "Gateway crash recovery")
     "wal",
+    # time_us.* (ISSUE 24): microseconds spent in each phase of the
+    # serving loop, summed — one counter per serving.telemetry.phase
+    # name, the exact sums beside the latency.* buckets
+    # (docs/observability.md "Phases of the serving loop")
+    "time_us",
     "queue", "slots", "tokens_per_sec",
 )
 

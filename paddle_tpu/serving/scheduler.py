@@ -116,7 +116,7 @@ class Request:        # compare numpy prompt payloads
     # so preemption re-queue / replay / re-route all land their spans on
     # the same timeline (docs/observability.md)
     trace_id: str = ""
-    _submit_ts: float = 0.0     # perf_counter at construction (ttft/e2e)
+    _submit_ts: float = 0.0     # perf_counter at submit's entry (ttft/e2e)
     _queued_ts: float = 0.0     # perf_counter at enqueue (queue_wait)
     _last_emit_ts: float = 0.0  # perf_counter of the last emitted token
 
@@ -129,7 +129,10 @@ class Request:        # compare numpy prompt payloads
             # request then replays/preempts/re-routes token-identically
             self.sampling = self.sampling.materialized()
         self._arrival = next(_seq_counter)
-        self._submit_ts = time.perf_counter()
+        if not self._submit_ts:
+            # built directly; ServingAPI.submit passes the time of its
+            # own entry, so ttft/e2e hold its wait for the API lock
+            self._submit_ts = time.perf_counter()
         if not self.request_id:
             self.request_id = f"req-{next(_req_counter)}"
         if not self.trace_id:
@@ -269,7 +272,7 @@ class Scheduler:
             # Deadline.check() bumps)
             resilience.bump("deadline.exceeded")
         if state == RequestState.FINISHED:
-            # e2e = construction -> complete output (only for requests
+            # e2e = submit -> complete output (only for requests
             # that delivered one — failures/cancels would skew the tail)
             telemetry.observe("latency.e2e",
                               time.perf_counter() - req._submit_ts,
@@ -505,11 +508,11 @@ class Scheduler:
             self._check_boundary(req)  # may retire at once (stop/budget)
         return True
 
-    def step(self) -> bool:
-        """One scheduler iteration: cull dead queue entries, advance one
-        chunked prefill, admit while capacity allows (preempting under
-        starvation), run one engine decode step, retire finished. Returns
-        True if any request made progress."""
+    def _admit_pass(self) -> bool:
+        """The pass before the decode call: cull dead queue entries,
+        advance one chunked prefill, admit while capacity allows
+        (preempting under starvation). True if any request made
+        progress."""
         progress = False
         # cull queued requests that died before costing a prefill
         for req in list(self.waiting):
@@ -602,6 +605,18 @@ class Scheduler:
             self.running.append(req)
             self._emit(req, first)
             self._check_boundary(req)  # may retire immediately (stop/budget)
+        return progress
+
+    def step(self) -> bool:
+        """One scheduler iteration: the admission pass
+        (:meth:`_admit_pass`), one engine decode step, retire finished.
+        Returns True if any request made progress. Three phases
+        (``telemetry.phase``): ``sched.admit`` around the pass (parent of
+        the engine's ``prefill``), the engine's own ``decode_step``, and
+        ``sched.emit`` around the emit loop as a whole."""
+        hists = getattr(self.engine, "hists", None)
+        with telemetry.phase("sched.admit", hists):
+            progress = self._admit_pass()
         # one decode iteration over every occupied slot
         if self.running:
             if getattr(self.engine, "spec", None) is not None:
@@ -611,16 +626,18 @@ class Scheduler:
                 # (tokens past a stop are dropped, exactly like the
                 # sequential path that would never have generated them)
                 accepted = self.engine.spec_decode_step()
-                for req in list(self.running):
-                    for tok in accepted.get(req.slot, ()):
-                        self._emit(req, int(tok))
-                        if self._check_boundary(req):
-                            break
+                with telemetry.phase("sched.emit", hists):
+                    for req in list(self.running):
+                        for tok in accepted.get(req.slot, ()):
+                            self._emit(req, int(tok))
+                            if self._check_boundary(req):
+                                break
             else:
                 toks = self.engine.decode_step()
-                for req in list(self.running):
-                    self._emit(req, int(toks[req.slot]))
-                    self._check_boundary(req)
+                with telemetry.phase("sched.emit", hists):
+                    for req in list(self.running):
+                        self._emit(req, int(toks[req.slot]))
+                        self._check_boundary(req)
             progress = True
         self._gauges()
         return progress

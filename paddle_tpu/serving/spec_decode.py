@@ -60,8 +60,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-import time
-
 from ..core import compile_cache, flags
 from ..core.tensor import Tensor
 from . import metrics, telemetry
@@ -453,10 +451,8 @@ class SpecDecoder:
         if act_spec.any():
             # the fused propose+verify dispatch alone (the plain-decode
             # fallback lanes below are latency.decode_step samples)
-            t0 = time.perf_counter()
-            out.update(self._spec_step(act_spec))
-            telemetry.observe("latency.spec_verify",
-                              time.perf_counter() - t0, engine.hists)
+            with telemetry.phase("spec_verify", engine.hists):
+                out.update(self._spec_step(act_spec))
         if act_plain.any():
             # per-slot fallback: sampled/constrained/adapter lanes decode
             # one plain (sampling-core) token through the classic step

@@ -18,12 +18,21 @@ answers "how long" and "what happened to THIS request":
   ``GET /v1/metrics``) and Chrome trace-event conversion
   (:func:`chrome_events`, ``tools/trace_dump.py``).
 
+* :class:`phase` — THE way a duration is taken on the serving step path:
+  a context manager around one layer boundary of the serving loop that
+  records a ``latency.<name>`` sample, adds the elapsed whole microseconds
+  to the ``time_us.<name>`` counter, and is a ``pt.<name>`` interval on
+  the calling thread's line of any running ``jax.profiler`` session — the
+  same clock as the device's events. Always on, no flag
+  (docs/observability.md, "Phases of the serving loop").
+
 Everything here is host-side and OUTSIDE compiled regions: a timestamp is
 taken around a compiled call, never inside one (a ``time.*`` read under
 ``jax.jit`` would be a traced-cast — the ``compiled_telemetry`` lint
-fixture pins that down). The step hot path pays one ``perf_counter`` pair
-and one histogram record per boundary; span emission short-circuits on the
-flag before touching the ring.
+fixture pins that down). The step hot path pays one :class:`phase` per
+boundary (two clock reads, one histogram record, one counter add, one
+annotation that tests a flag when no profiler runs); span emission
+short-circuits on the flag before touching the ring.
 
 Histogram key namespaces (``tools/analyze.py``'s ``unknown-metric-key``
 rule checks literal :func:`observe` keys against this registry, exactly
@@ -33,11 +42,18 @@ like ``metrics.bump`` keys):
   ``ttft`` (submit -> first emitted token), ``inter_token`` (gap between
   consecutive emitted tokens of one stream), ``queue_wait`` (enqueue ->
   admission), ``prefill`` (one admission / chunk prefill call),
-  ``decode_step`` (one compiled decode iteration wall-time),
+  ``decode_step`` (one ``engine.decode_step`` call: host preparation,
+  dispatch, the device step and the host read that ends it),
   ``spec_step`` (one speculative iteration), ``spec_verify`` (the fused
   propose+verify dispatch alone), ``restore`` (tier-restore scatter of one
   spilled chain), ``spill`` (tiering one evicted device block), ``e2e``
-  (submit -> FINISHED).
+  (submit -> FINISHED); ``ttft`` and ``e2e`` start at the entry of
+  ``ServingAPI.submit``, so the wait for the API lock is inside them.
+  Every :class:`phase` name is a ``latency.*`` histogram too
+  (``pump.unlocked``, ``sched.step``, ``sched.admit``, ``sched.emit``,
+  ``decode.prepare``, ``decode.dispatch``, ``decode.wait``,
+  ``decode.release``, ``submit.lock_wait``), with its exact sum in
+  ``time_us.<name>`` (``serving.metrics``).
 * ``telemetry.*`` — the plane's own meta-counters (mirrored into
   ``serving.metrics``): ``spans`` recorded / ``spans_dropped`` (ring
   overflow, oldest-first).
@@ -51,6 +67,8 @@ import time
 import uuid
 from collections import deque
 from typing import Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..core import flags
 from . import metrics
@@ -238,6 +256,64 @@ def observe(name: str, seconds: float, *sets: Optional[HistogramSet]) -> None:
     for s in sets:
         if s is not None:
             s.get(name).record(v)
+
+
+class phase:
+    """Time one boundary of the serving loop, on the profiler's clock.
+
+    ``with phase("decode.wait", engine.hists): ...`` does three things on
+    every use, with no flag:
+
+    * opens a ``jax.profiler.TraceAnnotation("pt.<name>", **args)``. With
+      no profiler session that is a flag test; inside one the phase is an
+      interval on the calling thread's line of ``/host:CPU``, on the same
+      clock as the device's events, nested in its parent phase by
+      containment. ``args`` (a ``trace_id``, a loop turn's number) link
+      the interval to its cause and are only formatted while a session
+      runs;
+    * on exit records the elapsed seconds into ``latency.<name>``
+      (:func:`observe`: the global set and every extra ``sets``);
+    * and adds them, in whole microseconds, to the counter
+      ``time_us.<name>``. The counter is what makes shares and per-step
+      means exact (the histogram keeps 25%-wide buckets); a phase's self
+      time is its counter minus its children's.
+
+    Host side only, never under ``jit``. :meth:`stop` ends the phase
+    before the block does (a lock wait ends when the lock is held);
+    :meth:`discard` ends it unrecorded."""
+
+    __slots__ = ("name", "_sets", "_ann", "_t0")
+
+    def __init__(self, name: str, *sets: Optional[HistogramSet], **args):
+        self.name = name
+        self._sets = sets
+        self._ann = TraceAnnotation("pt." + name, **args)
+        self._t0: Optional[float] = None
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def _end(self) -> Optional[float]:
+        t0, self._t0 = self._t0, None
+        if t0 is None:
+            return None  # already stopped or discarded
+        dt = time.perf_counter() - t0
+        self._ann.__exit__(None, None, None)
+        return dt
+
+    def stop(self) -> None:
+        dt = self._end()
+        if dt is not None:
+            observe(f"latency.{self.name}", dt, *self._sets)
+            metrics.bump(f"time_us.{self.name}", round(dt * 1e6))
+
+    def discard(self) -> None:
+        self._end()
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
 
 
 def histograms() -> Dict[str, Histogram]:

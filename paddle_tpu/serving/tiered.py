@@ -64,8 +64,6 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
-import time
-
 from ..core import flags, resilience
 from . import metrics, telemetry
 
@@ -459,9 +457,8 @@ class TierView:
     def spill(self, key: bytes, reader: Callable[[], Payload]) -> None:
         """A device block is being evicted: make its bytes tier-resident
         (``reader`` runs only when the write-through copy is gone)."""
-        t0 = time.perf_counter()
-        written = self.store.ensure(self._k(key), reader)
-        telemetry.observe("latency.spill", time.perf_counter() - t0)
+        with telemetry.phase("spill"):
+            written = self.store.ensure(self._k(key), reader)
         self.spilled_blocks += 1
         self.spilled_bytes += written
         metrics.bump("tier.spilled_blocks")

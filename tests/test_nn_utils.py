@@ -89,8 +89,11 @@ def test_weight_norm_compiled_step():
     lin = nn.Linear(4, 2)
     nn.utils.weight_norm(lin, "weight")
     opt = SGD(learning_rate=0.05, parameters=lin.parameters())
-    x = np.random.randn(8, 4).astype(np.float32)
-    y = np.random.randn(8, 2).astype(np.float32)
+    # seeded: of data drawn from the global generator, one set in twenty
+    # does not halve the loss in 20 steps (seed 0 ends at 0.31 of it)
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((8, 4)).astype(np.float32)
+    y = rng.standard_normal((8, 2)).astype(np.float32)
     step = TrainStep(lambda a, b: ((lin(a) - b) ** 2).mean(), opt, layers=lin)
     l0 = float(step(Tensor(x), Tensor(y))._data)
     for _ in range(20):

@@ -3,9 +3,16 @@ log buckets, merge/minus, percentile interpolation), the request-lifecycle
 trace ring and its ``FLAGS_serving_telemetry`` gate, trace_id propagation
 through a real ServingAPI run, Prometheus text rendering, Chrome
 trace-event conversion, the windowed ``metrics.Meter`` decay regression,
-and the profiler's per-run latency delta."""
+and the profiler's per-run latency delta. ISSUE 24: ``telemetry.phase``
+(histogram, ``time_us.*`` counter, profiler annotation), the phases of the
+serving loop (closure, nesting, the profiler's clock), and first-token
+time that holds the wait for the API lock."""
+import glob
 import json
+import os
 import re
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -311,3 +318,228 @@ def test_profiler_reports_latency_delta(model):
     assert prof.latency_stats["latency.e2e.count"] == 1
     assert prof.latency_stats["latency.e2e.p99_ms"] > 0
     assert prof.latency_stats["latency.ttft.count"] == 1  # noise excluded
+
+
+# ------------------------------------------------------- phases (ISSUE 24)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: parent phase -> the phases that lie inside it (docs/observability.md)
+PHASE_TREE = {
+    "sched.step": ("sched.admit", "decode_step", "sched.emit"),
+    "sched.admit": ("prefill",),
+    "decode_step": ("decode.prepare", "decode.dispatch", "decode.wait"),
+    "decode.wait": ("decode.release",),
+}
+
+
+def _time_us():
+    return {k[len("time_us."):]: v for k, v in serving_metrics.stats().items()
+            if k.startswith("time_us.")}
+
+
+def _prompts(rng, n, lo=5, hi=12):
+    return [rng.integers(0, 1024, (int(rng.integers(lo, hi)),),
+                         dtype=np.int32) for _ in range(n)]
+
+
+def test_phase_records_histogram_counter_and_self_time():
+    telemetry.reset_histograms()
+    before = _time_us()
+    extra = telemetry.HistogramSet()
+    with telemetry.phase("test.outer", extra):
+        time.sleep(0.02)
+        with telemetry.phase("test.inner", extra, turn=1):
+            time.sleep(0.03)
+    after = _time_us()
+    outer = after["test.outer"] - before.get("test.outer", 0)
+    inner = after["test.inner"] - before.get("test.inner", 0)
+    assert isinstance(outer, int) and isinstance(inner, int)
+    assert 30_000 <= inner <= outer and outer >= 50_000
+    assert outer - inner >= 20_000  # the parent's self time
+    for name, us in (("latency.test.outer", outer),
+                     ("latency.test.inner", inner)):
+        h = telemetry.histogram(name)
+        assert h.n == 1 and extra.peek(name).n == 1
+        assert abs(h.total * 1e6 - us) <= 1.0  # whole microseconds
+
+
+def test_phase_stop_ends_early_and_discard_records_nothing():
+    telemetry.reset_histograms()
+    with telemetry.phase("test.stopped") as ph:
+        time.sleep(0.01)
+        ph.stop()
+        time.sleep(0.03)  # after the stop: not the phase's time
+    h = telemetry.histogram("latency.test.stopped")
+    assert h.n == 1 and 0.01 <= h.total < 0.03
+    with telemetry.phase("test.dropped") as ph:
+        ph.discard()
+    assert telemetry.histogram("latency.test.dropped").n == 0
+    assert "time_us.test.dropped" not in serving_metrics.stats()
+
+
+def test_pump_phases_cover_the_wall_time_and_children_fit_parents(model):
+    """A background run of some tens of steps: ``pump.unlocked`` and
+    ``sched.step`` partition the pump thread's time (within 5% of the wall
+    time measured around the run), and no phase's children exceed it."""
+    api = ServingAPI(model, background=True, **API_KW)
+    try:
+        rng = np.random.default_rng(24)
+        api.result(api.submit(_prompts(rng, 1)[0], max_new_tokens=3),
+                   timeout=300)  # compiled: the window below is steady
+        time.sleep(0.05)
+        uses = {k: h.n for k, h in telemetry.histograms().items()}
+        c0, steps0 = _time_us(), serving_metrics.stats()["engine.steps"]
+        t0 = time.perf_counter()
+        reqs = [api.submit(p, max_new_tokens=40) for p in _prompts(rng, 6)]
+        for r in reqs:
+            api.result(r, timeout=300)
+        time.sleep(0.05)
+        wall_us = (time.perf_counter() - t0) * 1e6
+        c1, steps1 = _time_us(), serving_metrics.stats()["engine.steps"]
+    finally:
+        api.close()
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    assert steps1 - steps0 >= 40
+    d = {k: c1[k] - c0.get(k, 0) for k in c1}
+    assert abs(d["pump.unlocked"] + d["sched.step"] - wall_us) \
+        <= 0.05 * wall_us, (d, wall_us)
+    n = {k: h.n - uses.get(k, 0) for k, h in telemetry.histograms().items()}
+    for parent, children in PHASE_TREE.items():
+        # each use rounds to a whole microsecond: half a one of slack
+        slack = sum(n["latency." + c] for c in children) / 2 + 1
+        assert sum(d[c] for c in children) <= d[parent] + slack, (parent, d)
+    # what the three decode phases leave of the call is bookkeeping
+    assert d["decode_step"] - sum(d[c] for c in PHASE_TREE["decode_step"]) \
+        <= 0.25 * d["decode_step"]
+
+
+def test_phases_nest_on_the_profilers_host_line(model, tmp_path):
+    """Inside a ``jax.profiler`` session the phases are ``pt.*`` intervals
+    on the pump thread's line of ``/host:CPU``, nested as the table in
+    docs/observability.md says."""
+    import jax
+    from jax.profiler import ProfileData
+
+    api = ServingAPI(model, background=True, **API_KW)
+    try:
+        rng = np.random.default_rng(25)
+        api.result(api.submit(_prompts(rng, 1)[0], max_new_tokens=3),
+                   timeout=300)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            reqs = [api.submit(p, max_new_tokens=6)
+                    for p in _prompts(rng, 3)]
+            for r in reqs:
+                api.result(r, timeout=300)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        api.close()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events if e.name.startswith("pt.")]
+             for line in host.lines]
+    pump = max(lines, key=lambda evs: sum(
+        n == "pt.sched.step" for n, _, _ in evs))
+    # a session cuts the turns at its edges: look at whole turns only
+    whole = [(a, b) for n, a, b in pump if n == "pt.sched.step"]
+    lo, hi = min(a for a, _ in whole), max(b for _, b in whole)
+    by = {}
+    for name, a, b in pump:
+        if lo <= a and b <= hi:
+            by.setdefault(name[len("pt."):], []).append((a, b))
+    assert len(by["decode_step"]) >= 5 and len(by["prefill"]) >= 2
+
+    def inside(child, parents):
+        return any(a <= child[0] and child[1] <= b for a, b in parents)
+
+    for parent, children in PHASE_TREE.items():
+        for c in children:
+            assert by[c] and all(inside(ev, by[parent]) for ev in by[c]), c
+    for step in by["decode_step"]:
+        mine = [next(ev for ev in by["decode." + k] if inside(ev, [step]))
+                for k in ("prepare", "dispatch", "wait")]
+        assert mine[0][1] <= mine[1][0] and mine[1][1] <= mine[2][0]
+    # the two halves of the pump's time never overlap
+    turns = sorted(by["pump.unlocked"] + by["sched.step"])
+    assert all(a[1] <= b[0] for a, b in zip(turns, turns[1:]))
+    # the handler threads' lock waits are on lines of their own
+    assert any(n == "pt.submit.lock_wait" for evs in lines if evs is not pump
+               for n, _, _ in evs)
+
+
+def test_ttft_and_e2e_include_the_wait_for_the_api_lock(model):
+    """A submit made while another thread holds ``ServingAPI._lock``: the
+    wait is a ``submit.lock_wait`` sample AND inside that request's
+    ``latency.ttft`` and ``latency.e2e`` (it used to start them after)."""
+    telemetry.reset_histograms()
+    api = ServingAPI(model, **API_KW)
+    out, at_the_door = [], threading.Event()
+
+    def client():
+        at_the_door.set()
+        out.append(api.submit(np.arange(6, dtype=np.int32),
+                              max_new_tokens=3))
+
+    try:
+        t = threading.Thread(target=client)
+        with api._lock:
+            t.start()
+            assert at_the_door.wait(10)
+            time.sleep(0.06)
+        t.join(30)
+        assert not t.is_alive() and len(out) == 1
+        api.run_until_idle()
+    finally:
+        api.close()
+    assert out[0].state == RequestState.FINISHED
+    waited = telemetry.histogram("latency.submit.lock_wait")
+    assert waited.n == 1 and waited.total >= 0.05
+    assert api.engine.hists.peek("latency.submit.lock_wait").n == 1
+    for name in ("latency.ttft", "latency.e2e"):
+        h = telemetry.histogram(name)
+        assert h.n == 1 and h.total >= 0.05, name
+    assert serving_metrics.stats()["time_us.submit.lock_wait"] >= 50_000
+
+
+def test_a_request_built_directly_still_stamps_its_own_submit_time():
+    from paddle_tpu.serving.scheduler import Request
+
+    t0 = time.perf_counter()
+    req = Request(np.arange(4), max_new_tokens=2)
+    assert t0 <= req._submit_ts <= time.perf_counter()
+    assert Request(np.arange(4), _submit_ts=12.5)._submit_ts == 12.5
+
+
+def test_metric_key_lint_knows_every_phase_key(model):
+    """``tools/analyze.py``'s ``unknown-metric-key`` rule over the files
+    that emit phases: nothing unknown; and every key a run leaves in the
+    registries sits in a documented namespace."""
+    from paddle_tpu import analysis
+
+    report = analysis.run_analysis(
+        ["paddle_tpu/serving/telemetry.py", "paddle_tpu/serving/metrics.py",
+         "paddle_tpu/serving/api.py", "paddle_tpu/serving/scheduler.py",
+         "paddle_tpu/serving/engine.py", "paddle_tpu/serving/tiered.py",
+         "paddle_tpu/serving/spec_decode.py"],
+        root=REPO, rules=["unknown-metric-key"], full_corpus=False)
+    assert not report.findings, report.findings
+    api = ServingAPI(model, **API_KW)
+    try:
+        api.submit(np.arange(5, dtype=np.int32), max_new_tokens=2)
+        api.run_until_idle()
+    finally:
+        api.close()
+    keys = list(serving_metrics.stats())
+    assert any(k.startswith("time_us.decode.") for k in keys)
+    for key in keys:
+        assert key.split(".", 1)[0] in \
+            serving_metrics.DOCUMENTED_NAMESPACES, key
+    for key in telemetry.histograms():
+        assert key.split(".", 1)[0] in telemetry.DOCUMENTED_NAMESPACES, key
