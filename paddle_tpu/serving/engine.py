@@ -115,6 +115,14 @@ def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
 
 
+# rows of the decode step's packed ``[8, num_slots]`` int32 slot state (the
+# two float rows travel bit-cast; the adapter row is read only when the
+# LoRA arena is on)
+_ST_ROWS = 8
+(_ST_POS, _ST_TOK, _ST_ACT, _ST_TEMP, _ST_TOP_K, _ST_TOP_P, _ST_SEED,
+ _ST_ADAPTER) = range(_ST_ROWS)
+
+
 def _scatter_rows(entry, row, off, kc, vc):
     """Scatter one chunk's k/v rows at ``(row, off)`` into a pool entry.
     A full-precision ``(k, v)`` entry writes the rows as-is (op-for-op
@@ -638,6 +646,12 @@ class ServingEngine:
         self._positions = np.zeros(s, np.int32)
         self._last_tok = np.zeros(s, np.int32)
         self._active = np.zeros(s, np.bool_)
+        # the decode step's device copy of the per-slot vectors (positions,
+        # last token, active mask, sampling params, adapter ids: one packed
+        # array, see _pack_slot_state). The step hands the next step's
+        # state back, so the host mirrors above and below are uploaded only
+        # after a host write: None = stale, set through _touch_slot_state
+        self._state_dev = None
         # occupied ⊇ active: a slot mid-chunked-prefill holds blocks and
         # must not be re-picked, but its lane stays masked out of the
         # decode step until its first token exists
@@ -671,7 +685,7 @@ class ServingEngine:
         self._mask_host = np.ones((s, self.vocab), np.bool_)
         self._mask_dev = None
         self._mask_dirty: set = set()  # rows stale on device (see
-        #                                _samp_args: one batched row
+        #                                _mask_arg: one batched row
         #                                scatter per step, not per update)
         # lifetime per-engine admission counters (EnginePredictor.close()
         # summaries must not read the process-global metrics)
@@ -1152,7 +1166,10 @@ class ServingEngine:
     def _get_step(self):
         if self._step_jit is not None:
             return self._step_jit
+        import jax.numpy as jnp
+
         from ..core import rng as prng
+        from ..distributed.sharding_util import replicate
         from ..jit import _swap_data
         from .sampling import sample_tokens
 
@@ -1161,23 +1178,31 @@ class ServingEngine:
         bs = self.block_size
         use_kernel = self.paged_kernel
         kmesh = self._kernel_mesh
+        mesh = self.mesh
 
-        def step(arrays, pools, block_tables, positions, last_tok, active,
-                 samp, *lora_args):
+        def step(arrays, pools, block_tables, state, vmask, *lora_pools):
             self.decode_traces += 1  # trace-time: the no-recompile counter
             compile_cache.bump("serving.decode_compiles")
             if use_kernel:
                 # trace-time: the paged-kernel twin of decode_traces —
                 # asserts admit/retire churn never re-lowers the kernel
                 metrics.bump("kernel.decode_traces")
+            # the packed slot state (see _pack_slot_state): whether it was
+            # uploaded this step or is the last step's output is invisible
+            # here — one program either way
+            positions, last_tok = state[_ST_POS], state[_ST_TOK]
+            active = state[_ST_ACT] != 0
+            temp = jax.lax.bitcast_convert_type(state[_ST_TEMP], jnp.float32)
+            top_p = jax.lax.bitcast_convert_type(state[_ST_TOP_P],
+                                                 jnp.float32)
             views = [_PagedCacheView(entry, block_tables, positions,
                                      active, bs, kernel=use_kernel,
                                      mesh=kmesh)
                      for entry in pools]
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
-                    with (lora.bind(*lora_args) if lora is not None
-                          else _null_ctx()):
+                    with (lora.bind(*lora_pools, state[_ST_ADAPTER])
+                          if lora is not None else _null_ctx()):
                         h, new_views = model.gpt(Tensor(last_tok[:, None]),
                                                  caches=views,
                                                  start_pos=positions)
@@ -1188,12 +1213,21 @@ class ServingEngine:
             # lanes reproduce the classic argmax bit-for-bit); the
             # emitted token sits at context index positions+1 — its
             # positional PRNG key (see serving.sampling)
-            temp, k, p, seed, vmask = samp
             with jax.named_scope("head_sample"):
-                nxt = sample_tokens(logits, temp, k, p, seed, positions + 1,
+                nxt = sample_tokens(logits, temp, state[_ST_TOP_K], top_p,
+                                    state[_ST_SEED], positions + 1,
                                     allowed=vmask)
             new_pools = [v.entry for v in new_views]
-            return nxt, new_pools
+            # the next step's state, as the host's mirrors will read after
+            # this one: active lanes advance a position and hold `nxt`
+            new_state = state.at[_ST_POS].set(
+                jnp.where(active, positions + 1, positions)
+            ).at[_ST_TOK].set(jnp.where(active, nxt, last_tok))
+            # on a mesh the state goes round replicated, the placement
+            # _step_args uploads it in: one signature, one executable
+            if mesh is not None:
+                new_state = replicate(new_state, mesh=mesh)
+            return nxt, new_pools, new_state
 
         self._step_jit = (jax.jit(step, donate_argnums=(1,)) if self.donate
                           else jax.jit(step))
@@ -1497,6 +1531,7 @@ class ServingEngine:
         self._seed[slot] = 0 if sp is None else int(sp.seed)
         self._sampled[slot] = not greedy
         self._adapter[slot] = adapter
+        self._touch_slot_state()
         if mask is not None:
             row = np.asarray(mask, bool).reshape(-1)
             if row.shape[0] != self.vocab:
@@ -1530,6 +1565,7 @@ class ServingEngine:
         self._sampled[slot] = False
         self._adapter[slot] = 0
         self._scenario_once[slot] = False
+        self._touch_slot_state()
         if self._constrained[slot]:
             self._mask_host[slot, :] = True
             self._constrained[slot] = False
@@ -1537,7 +1573,7 @@ class ServingEngine:
 
     def _update_mask_row(self, slot: int) -> None:
         """Mark one mask row stale on device. The refresh is DEFERRED and
-        batched: ``_samp_args`` applies every dirty row in one scatter
+        batched: ``_mask_arg`` applies every dirty row in one scatter
         per decode step — neither a full [S, vocab] re-upload per step
         (the walker advances every token) nor one dispatch per update."""
         if self._mask_dev is not None:
@@ -1640,6 +1676,7 @@ class ServingEngine:
         self._last_tok[slot] = first
         self._slot_limit[slot] = st.plen + st.max_new
         self._active[slot] = True
+        self._touch_slot_state()
         metrics.bump("engine.admits")
         metrics.bump("tokens.prefill", st.clen - st.prefix_len)
         metrics.bump("tokens.generated")  # the next token, out of prefill
@@ -1724,7 +1761,7 @@ class ServingEngine:
         self._positions[slot] = 0
         self._last_tok[slot] = 0
         self._slot_limit[slot] = 0
-        self._clear_slot_scenario(slot)
+        self._clear_slot_scenario(slot)  # marks the device's copy stale
         metrics.bump("engine.retires")
         if flags.flag("serving_arena_invariants"):
             self.check_invariants()
@@ -1784,6 +1821,7 @@ class ServingEngine:
                     self.prefix_cache.bind_index(old._index, old._replica)
         self._bt_host[:] = 0
         self._bt_dev = None
+        self._touch_slot_state()
         self._positions[:] = 0
         self._last_tok[:] = 0
         self._active[:] = False
@@ -1840,27 +1878,48 @@ class ServingEngine:
         with telemetry.phase("spec_step", self.hists):
             return self.spec.step()
 
-    def _samp_args(self):
-        """The decode step's per-slot sampling pytree: (temp, top_k,
-        top_p, seed, mask) — [S] arrays plus the [S, vocab] constraint
-        mask. The device mask is memoized; rows the walkers changed
-        since the last step refresh in ONE batched scatter here
-        (unconstrained steady state re-passes the cached array with
-        zero transfer; constrained slots cost one small dispatch/step)."""
+    def _touch_slot_state(self) -> None:
+        """A host write to any per-slot vector the decode step carries
+        (positions, last token, active mask, sampling params, adapter
+        ids): the device copy is stale and the next step re-sends the
+        mirrors, all in one upload."""
+        self._state_dev = None
+
+    def _pack_slot_state(self, act) -> np.ndarray:
+        """The host mirrors as the decode step's one ``[8, S]`` int32
+        argument (rows ``_ST_*``; float rows bit-cast, unpacked again
+        inside the compiled step)."""
+        st = np.empty((_ST_ROWS, self.num_slots), np.int32)
+        st[_ST_POS] = self._positions
+        st[_ST_TOK] = self._last_tok
+        st[_ST_ACT] = act
+        st[_ST_TEMP] = self._temp.view(np.int32)
+        st[_ST_TOP_K] = self._top_k
+        st[_ST_TOP_P] = self._top_p.view(np.int32)
+        st[_ST_SEED] = self._seed
+        st[_ST_ADAPTER] = self._adapter
+        return st
+
+    def _mask_arg(self):
+        """The decode step's [S, vocab] constraint mask. The device mask
+        is memoized; rows the walkers changed since the last step refresh
+        in ONE batched scatter here (unconstrained steady state re-passes
+        the cached array with zero transfer; constrained slots cost one
+        small dispatch/step)."""
         import jax.numpy as jnp
 
         if self._mask_dev is None:
             self._mask_dev = jnp.asarray(self._mask_host)
             self._mask_dirty.clear()
+            metrics.bump("engine.step_uploads")
         elif self._mask_dirty:
             rows = np.fromiter(self._mask_dirty, np.int32,
                                len(self._mask_dirty))
             self._mask_dev = self._mask_dev.at[jnp.asarray(rows)].set(
                 jnp.asarray(self._mask_host[rows]))
             self._mask_dirty.clear()
-        return (jnp.asarray(self._temp), jnp.asarray(self._top_k),
-                jnp.asarray(self._top_p), jnp.asarray(self._seed),
-                self._mask_dev)
+            metrics.bump("engine.step_uploads", 2)  # row ids and rows
+        return self._mask_dev
 
     def _samp_row(self, slot: int, pos: int):
         """One slot's sampling pytree for a prefill call ([1] shapes;
@@ -1875,28 +1934,45 @@ class ServingEngine:
                 jnp.full((1,), pos, jnp.int32),
                 jnp.asarray(self._mask_host[slot:slot + 1]))
 
-    def _lora_args(self, slot: Optional[int] = None) -> tuple:
-        """The adapter-arena args of a compiled call — ``()`` when the
+    def _lora_args(self, slot: int) -> tuple:
+        """The adapter-arena args of a prefill call — ``()`` when the
         arena is off (the programs are built without the parameters), else
         ``(pools, adapter_ids)``: the memoized device pools plus the
-        per-lane (or single-slot) adapter index vector."""
+        slot's adapter index. (The decode step reads its per-lane ids from
+        the packed slot state.)"""
         if self.lora is None:
             return ()
         import jax.numpy as jnp
 
-        ids = (self._adapter if slot is None
-               else self._adapter[slot:slot + 1])
-        return (self.lora.device_pools(), jnp.asarray(ids))
+        return (self.lora.device_pools(),
+                jnp.asarray(self._adapter[slot:slot + 1]))
 
     def _step_args(self, act) -> tuple:
-        """The decode step's arguments at the current slot state."""
+        """The decode step's arguments at the current slot state. Only what
+        the host changed since the last step is sent again (the block
+        table after a lane grew, the packed slot state after a host
+        write, stale mask rows); ``engine.step_uploads`` counts the
+        transfers."""
         import jax.numpy as jnp
 
         if self._bt_dev is None:
             self._bt_dev = jnp.asarray(self._bt_host)
+            metrics.bump("engine.step_uploads")
+        if self._state_dev is None:
+            # placed as the step hands it back (replicated on a mesh,
+            # uncommitted without one), so that an uploaded and a carried
+            # state are one call signature
+            state = self._pack_slot_state(act)
+            if self.mesh is None:
+                self._state_dev = jnp.asarray(state)
+            else:
+                from ..distributed.sharding_util import replicate
+
+                self._state_dev = replicate(state, mesh=self.mesh)
+            metrics.bump("engine.step_uploads")
+        lora = () if self.lora is None else (self.lora.device_pools(),)
         return (self._arrays, self.arena.pools, self._bt_dev,
-                jnp.asarray(self._positions), jnp.asarray(self._last_tok),
-                jnp.asarray(act), self._samp_args(), *self._lora_args())
+                self._state_dev, self._mask_arg(), *lora)
 
     def lower_decode_step(self):
         """``jax.stages.Lowered`` of the one compiled decode step at this
@@ -1920,15 +1996,25 @@ class ServingEngine:
         with telemetry.phase("decode_step", hists):
             act = (self._active if active is None
                    else np.asarray(active, bool))
+            if active is not None:
+                # the device's copy holds the engine's own mask: this step
+                # sends the mirrors with the caller's, the next one again
+                self._touch_slot_state()
             with telemetry.phase("decode.prepare", hists):
                 # grow block tables whose write position crossed a block
-                # boundary, then the host's slot state goes to the device
+                # boundary, then whatever the host changed since the last
+                # step goes to the device
                 for slot in np.flatnonzero(act):
                     self._grow_slot_to(slot, int(self._positions[slot]))
                 args = self._step_args(act)
+                # stale until this step's tokens are back: a call that
+                # raises produced no next state (and may have consumed the
+                # donated pools), so the step after it starts from the
+                # mirrors
+                self._touch_slot_state()
             with telemetry.phase("decode.dispatch", hists):
-                nxt, new_pools = self._call(self._get_step(), *args,
-                                            name="serving.step")
+                nxt, new_pools, state = self._call(self._get_step(), *args,
+                                                   name="serving.step")
             with telemetry.phase("decode.wait", hists):
                 with telemetry.phase("decode.release", hists):
                     # the step's argument arrays and the donated pools
@@ -1943,6 +2029,8 @@ class ServingEngine:
                 # blocks until the device is done, the token vector is
                 # back AND this thread has the GIL again
                 out = np.asarray(nxt)
+            if active is None:
+                self._state_dev = state  # the mirrors below, advanced
             self._positions[act] += 1
             self._last_tok[act] = out[act]
             metrics.bump("engine.steps")

@@ -12,7 +12,8 @@ Counter namespaces:
 
 * ``requests.*``   — submitted / finished / cancelled / expired / failed
 * ``tokens.*``     — ``generated`` (decode) and ``prefill`` (prompt) tokens
-* ``engine.*``     — steps, admits, retires, rebuilds, trace counts
+* ``engine.*``     — steps, admits, retires, rebuilds, trace counts,
+  ``step_uploads`` (host-to-device transfers made preparing decode steps)
 * ``arena.*``      — block allocs / frees / reuse / alloc failures
 * ``scheduler.*``  — ``preemptions`` (starvation-triggered victim
   evictions), ``cache_skips`` (cache-affinity admissions past a cold head)

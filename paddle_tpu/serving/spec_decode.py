@@ -547,6 +547,7 @@ class SpecDecoder:
             engine._last_tok[slot] = accepted[-1]
             out[slot] = accepted
             n_emitted += len(accepted)
+        engine._touch_slot_state()  # the plain step's device copy is stale
         self.iterations += 1
         self.proposed += n_proposed
         self.accepted += n_accepted

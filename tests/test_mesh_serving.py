@@ -146,6 +146,37 @@ def test_zero_recompile_churn_on_live_mesh():
     assert stats["arena.mesh"] == (("data", 2), ("model", 4))
 
 
+def test_carried_slot_state_keeps_the_placement_it_is_uploaded_in():
+    """The decode step hands the next step's slot state back (ISSUE 25).
+    On a mesh an upload and a carried state must be ONE call signature:
+    replicated both ways, one decode trace, and no backend compile after
+    the first step whichever way the state arrived."""
+    from paddle_tpu.serving import ServingEngine
+
+    serving_mesh(4, data=2)
+    engine = ServingEngine(_model(), ServingConfig(
+        num_slots=4, kv_block_size=16, max_model_len=MAX_LEN))
+    rng = np.random.default_rng(7)
+    engine.admit(rng.integers(0, 1024, (9,), dtype=np.int32), 20)
+    engine.decode_step()  # state uploaded; what comes back is carried
+    placed = engine._state_dev.sharding
+    assert placed.is_fully_replicated
+    assert len(placed.device_set) == 8
+    compiled = compile_cache.stats().get("compile.backend", 0)
+    engine.decode_step()  # carried
+    assert engine._state_dev.sharding == placed
+    engine.admit(rng.integers(0, 1024, (9,), dtype=np.int32), 20)
+    assert engine._state_dev is None
+    engine.decode_step()  # uploaded again, beside a carried lane
+    engine.decode_step()
+    assert engine._state_dev.sharding == placed
+    np.testing.assert_array_equal(
+        np.asarray(engine._state_dev),
+        engine._pack_slot_state(engine._active))
+    assert engine.decode_traces == 1
+    assert compile_cache.stats().get("compile.backend", 0) == compiled
+
+
 def test_prefix_hit_parity_on_mesh():
     """Radix-cache hits attach host-side block ids — layout-agnostic by
     construction: hit-path tokens equal the no-mesh hit-path tokens."""

@@ -430,7 +430,9 @@ def test_phases_nest_on_the_profilers_host_line(model, tmp_path):
         opts.host_tracer_level = 2
         jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
         try:
-            reqs = [api.submit(p, max_new_tokens=6)
+            # 7 decode steps each: stop_trace may cut the last turn (its
+            # pt.sched.step still open), and 5 whole ones must remain
+            reqs = [api.submit(p, max_new_tokens=8)
                     for p in _prompts(rng, 3)]
             for r in reqs:
                 api.result(r, timeout=300)
