@@ -1060,7 +1060,7 @@ def run_paged_attention(model, platform):
     import jax.numpy as jnp
 
     from paddle_tpu.core import compile_cache
-    from paddle_tpu.models.gpt import masked_attention
+    from paddle_tpu.models.serving_seam import masked_attention
     from paddle_tpu.ops import paged_attention as pk
     from paddle_tpu.ops import tuning
     from paddle_tpu.serving import ServingConfig, ServingEngine, telemetry

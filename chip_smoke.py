@@ -482,7 +482,7 @@ def kernel_parity(plan: Plan, seed: int, quantized: bool) -> dict:
     the served widths, on random pools."""
     import jax.numpy as jnp
 
-    from paddle_tpu.models.gpt import masked_attention
+    from paddle_tpu.models.serving_seam import masked_attention
     from paddle_tpu.ops import paged_attention as pk
     from paddle_tpu.quantization import quantize_kv
     from paddle_tpu.serving.engine import _gather_ctx

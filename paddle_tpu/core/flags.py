@@ -227,7 +227,7 @@ define_flag("serving_spec_k", 0,
 define_flag("serving_quant_weights", False,
             "Weight-only int8 serving: quantize every GPT attention/MLP "
             "matmul per output channel at engine construction "
-            "(models.gpt.quantize_serving_weights — the single "
+            "(models.serving_seam.quantize_serving_weights — the single "
             "quantization.quantize_weight path) and dequantize in-kernel "
             "inside the compiled decode/prefill/verify programs, so "
             "weight HBM traffic is 1 byte/param. Greedy output is gated "
@@ -246,7 +246,7 @@ define_flag("serving_quant_kv", False,
             "(default) keeps full-precision pools.")
 define_flag("serving_quant_draft", False,
             "Quantize the speculative-decoding draft model's weights to "
-            "int8 (models.gpt.quantize_serving_weights on "
+            "int8 (models.serving_seam.quantize_serving_weights on "
             "ServingConfig.draft_model). Never changes emitted tokens — "
             "verification keeps target-greedy semantics; a quantized "
             "draft only moves spec.acceptance_rate (per-mode telemetry: "

@@ -13,4 +13,10 @@ from .gpt import (  # noqa: F401
     gpt_base,
     gpt_tiny,
 )
+from .olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig,
+    OlmoHybridForCausalLM,
+    OlmoHybridModel,
+    olmo_hybrid_tiny,
+)
 from .widedeep import DeepFM, DistributedEmbedding, WideDeep  # noqa: F401

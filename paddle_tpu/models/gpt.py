@@ -18,17 +18,14 @@ All shapes static; whole model jits into one XLA program via TrainStep/pjit.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..core.tensor import Tensor
 from ..distributed.fleet.meta_parallel.mp_layers import (
-    MODEL_AXIS,
     ColumnParallelLinear,
     RowParallelLinear,
     VocabParallelEmbedding,
@@ -36,6 +33,13 @@ from ..distributed.fleet.meta_parallel.mp_layers import (
 from ..distributed.sharding_util import constraint
 from ..nn import functional as F
 from ..ops import creation, manipulation as M
+from .serving_seam import (
+    KVLayerState,
+    ServingSpec,
+    masked_attention,
+    serving_compute_dtype,
+    serving_linear as _serving_linear,
+)
 
 
 @dataclass
@@ -91,171 +95,6 @@ def _filter_logits(scaled, top_k: int, top_p: float, vocab: int):
                          keepdims=True)
         scaled = jnp.where(scaled < thresh, -jnp.inf, scaled)
     return scaled
-
-
-def masked_attention(qa, ka, va, mask):
-    """Core cached-decode attention: q against an (already updated) K/V
-    buffer under an explicit boolean mask. ``qa`` is [b, s, heads, dim];
-    ``ka``/``va`` are [b, kv_len, heads, dim]; ``mask`` broadcasts against
-    [b, heads, s, kv_len]. Returns [b, s, heads, dim].
-
-    This one function is the numerics contract shared by ``generate()``'s
-    contiguous KV path and the serving engine's paged-arena path — both
-    must produce token-for-token identical greedy decodes, so they must
-    run the exact same ops (same dtypes, same -1e30 masking, same fp32
-    softmax)."""
-    qt = jnp.swapaxes(qa, 1, 2)  # [b, h, s, d]
-    kt = jnp.swapaxes(ka, 1, 2)
-    vt = jnp.swapaxes(va, 1, 2)
-    scale = 1.0 / math.sqrt(qa.shape[-1])
-    logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * scale
-    logits = jnp.where(mask, logits, -1e30)
-    p = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(qa.dtype)
-    return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vt), 1, 2)
-
-
-#: the attention/MLP matmul weights quantize_serving_weights targets — the
-#: serving decode hot path's HBM traffic, in model order
-_SERVING_QUANT_LINEARS = ("attn.qkv", "attn.proj", "mlp.up", "mlp.down")
-
-#: multi-LoRA hook (serving.adapters): called as hook(layer, x, y) inside
-#: _serving_linear to add the per-lane low-rank update when an adapter
-#: trace context is bound; inert (returns y) without one. Process-global
-#: and None until an AdapterArena exists, so the training/generate paths
-#: never pay for it.
-_lora_hook = None
-
-
-def set_lora_hook(fn) -> None:
-    """Install the serving-adapter hook (``serving.adapters`` calls this
-    once, at the first :class:`~paddle_tpu.serving.adapters.AdapterArena`
-    construction). Idempotent."""
-    global _lora_hook
-    _lora_hook = fn
-
-
-def quantize_serving_weights(model, mesh=None) -> int:
-    """Per-channel int8 weight-only quantization of every attention/MLP
-    matmul of a :class:`GPTForCausalLM`, in place (``FLAGS_serving_quant_weights``
-    — the serving engine calls this at model load).
-
-    Each targeted linear's weight payload becomes int8 (``[in, out]``,
-    quantized per OUTPUT channel via
-    :func:`paddle_tpu.quantization.quantize_weight` — the framework's one
-    weight quantizer, no absmax math duplicated here) and the ``[1, out]``
-    float32 scale is registered as a ``weight_scale`` buffer, so
-    ``functional_state()`` carries both into every compiled program: the
-    decode/prefill/verify programs then stream int8 weights from HBM and
-    dequantize in-kernel (:func:`_serving_linear`). Embeddings, the (tied)
-    LM head and LayerNorms stay in the compute dtype — they are a small
-    fraction of decode traffic and the head's argmax is tolerance-critical.
-
-    Idempotent (a gateway's replicas share one model instance): already
-    quantized layers are skipped. Returns the number of layers quantized
-    by THIS call. ``mesh`` pins the re-placement below to a specific mesh
-    (the serving engine passes its captured one so an explicit
-    ``ServingConfig.mesh`` stays coherent); None defers to the installed
-    global. Training a quantized model is not supported — serving
-    quantization is a load-time conversion, not QAT (see
-    :mod:`paddle_tpu.quantization` for fake-quant training)."""
-    from .. import quantization
-    from ..distributed.sharding_util import shard_parameter
-
-    n = 0
-    for blk in model.gpt.layers:
-        for lin in (blk.attn.qkv, blk.attn.proj, blk.mlp.up, blk.mlp.down):
-            if getattr(lin, "weight_scale", None) is not None:
-                continue
-            qw, scale = quantization.quantize_weight(
-                np.asarray(lin.weight._data), channel_axis=1)
-            lin.weight._data = jnp.asarray(qw)
-            lin.weight.stop_gradient = True
-            lin.register_buffer("weight_scale",
-                                Tensor(jnp.asarray(scale)))
-            # re-place on the mesh: the payload swap above replaced the
-            # committed (sharded) array with a default-placed one, and jit
-            # infers in_shardings from committed arrays — without this a
-            # TP mesh would hold the FULL int8 weight per chip. Column
-            # linears (qkv/up) shard out_features on the model axis (the
-            # per-out-channel scale shards with them); row linears
-            # (proj/down) shard in_features, their out-channel scale is
-            # replicated. No-op off-mesh (single chip).
-            if isinstance(lin, ColumnParallelLinear):
-                shard_parameter(lin.weight, None, MODEL_AXIS, mesh=mesh)
-                shard_parameter(lin.weight_scale, None, MODEL_AXIS,
-                                mesh=mesh)
-            else:
-                shard_parameter(lin.weight, MODEL_AXIS, None, mesh=mesh)
-                shard_parameter(lin.weight_scale, None, None, mesh=mesh)
-            n += 1
-    if n:
-        # generate()'s memoized runner is keyed per decode configuration;
-        # the quant tag joins that key (like the donation flag) so a
-        # pre-quantization runner is never reused on int8 weights
-        model._serving_quant = getattr(model, "_serving_quant", 0) + 1
-    return n
-
-
-def _serving_linear(layer, x):
-    """The attention/MLP matmul entry point shared by the quantized and
-    plain paths. An unquantized layer runs its normal forward (op-for-op
-    identical to calling it directly — the flag-off serving path stays
-    bit-identical). A layer carrying a ``weight_scale`` buffer (int8
-    payload from :func:`quantize_serving_weights`) dequantizes IN the
-    kernel: the int8 weight is read from HBM, multiplied by its per-channel
-    scale and cast to the activation dtype right before the matmul, so XLA
-    fuses the dequant into the matmul's operand pipeline — weight traffic
-    is 1 byte/param instead of 2-4.
-
-    This is also the multi-LoRA attach point (``serving.adapters``): when
-    an adapter trace context is bound, the per-lane low-rank update
-    ``(x @ A[ids]) @ B[ids]`` is added to the base matmul's output —
-    int8 base + f32 adapters compose here. No context ⇒ identical trace."""
-    scale = getattr(layer, "weight_scale", None)
-    if scale is None:
-        y = layer(x)
-        if _lora_hook is not None:
-            y = _lora_hook(layer, x, y)
-        return y
-    from ..core.dispatch import apply
-
-    if isinstance(layer, RowParallelLinear) and layer.input_is_parallel:
-        # mirror RowParallelLinear.forward's input hint: the contraction
-        # over the model-sharded in_features must stay a partial matmul +
-        # psum, not an all-gather of the activations
-        x = constraint(x, "data", None, MODEL_AXIS)
-
-    def deq_matmul(xa, qwa, sa, ba=None):
-        w = (qwa.astype(jnp.float32) * sa).astype(xa.dtype)
-        y = xa @ w
-        if ba is not None:
-            y = y + ba.astype(y.dtype)
-        return y
-
-    args = (x, layer.weight, scale) + (
-        () if layer.bias is None else (layer.bias,))
-    y = apply(deq_matmul, args, {}, name="serving_qlinear")
-    if _lora_hook is not None:
-        y = _lora_hook(layer, x, y)
-    # mirror the parallel linears' output shardings (the quantized matmul
-    # must shard exactly like the one it replaces)
-    if isinstance(layer, ColumnParallelLinear) and not layer.gather_output:
-        return constraint(y, "data", None, MODEL_AXIS)
-    return constraint(y, "data", None, None)
-
-
-def serving_compute_dtype(model) -> str:
-    """The model's activation/KV compute dtype. Normally the attention
-    weights' dtype; with int8-quantized serving weights those read "int8",
-    so fall back to the (never-quantized) token embedding — KV caches and
-    activation buffers must be allocated in the compute dtype, not the
-    storage dtype. Accepts a :class:`GPTForCausalLM` or a bare
-    :class:`GPTModel`; this is the ONE home of the fallback rule
-    (``gen_kv_caches`` derives from it too), and the dict lookup keeps it
-    branch-free — generate()'s compiled copying build traces through it."""
-    gpt = getattr(model, "gpt", model)
-    d = str(gpt.layers[0].attn.qkv.weight._data.dtype)
-    return {"int8": str(gpt.wte.weight._data.dtype)}.get(d, d)
 
 
 def gpt_tiny(**kw) -> "GPTConfig":
@@ -397,9 +236,12 @@ class GPTModel(nn.Layer):
                  creation.zeros(shape, dtype=dtype))
                 for _ in self.layers]
 
-    def forward(self, input_ids, caches=None, start_pos=0):
-        b, s = input_ids.shape
-        if caches is not None:
+    def embed(self, input_ids, start_pos=None):
+        """Token plus position embeddings; ``start_pos`` None is a whole
+        sequence from position 0, a scalar or a per-sequence ``[b]`` vector
+        the cached paths' offset."""
+        s = input_ids.shape[1]
+        if start_pos is not None:
             off = (start_pos._data if isinstance(start_pos, Tensor)
                    else start_pos)
             off = jnp.asarray(off)
@@ -412,7 +254,23 @@ class GPTModel(nn.Layer):
         else:
             pos = creation.arange(0, s, dtype="int32")
         x = self.wte(input_ids) + self.wpe(pos)
-        x = constraint(self.drop(x), "data", "sep", None)
+        return constraint(self.drop(x), "data", "sep", None)
+
+    def serving_linears(self):
+        """``(site, linear)`` of every attention/MLP matmul, in model order:
+        what the int8 quantizer and the LoRA arena target."""
+        return [(f"{li}.{name}", lin)
+                for li, blk in enumerate(self.layers)
+                for name, lin in (("attn.qkv", blk.attn.qkv),
+                                  ("attn.proj", blk.attn.proj),
+                                  ("mlp.up", blk.mlp.up),
+                                  ("mlp.down", blk.mlp.down))]
+
+    def serving_embedding(self):
+        return self.wte
+
+    def forward(self, input_ids, caches=None, start_pos=0):
+        x = self.embed(input_ids, start_pos if caches is not None else None)
         if caches is not None:
             new_caches = []
             for layer, cache in zip(self.layers, caches):
@@ -604,6 +462,35 @@ class GPTForCausalLM(nn.Layer):
             return total / jnp.maximum(count, 1.0)
 
         return apply(_loss, (h, labels, w), {}, name="chunked_lm_loss")
+
+    # ---- the engine<->model seam (models/serving_seam.py)
+
+    def serving_spec(self) -> ServingSpec:
+        c = self.cfg
+        return ServingSpec(
+            vocab_size=int(c.vocab_size),
+            max_positions=int(c.max_position_embeddings),
+            layers=(KVLayerState(int(c.num_heads),
+                                 int(c.hidden_size // c.num_heads)),)
+            * int(c.num_layers))
+
+    def serving_embed(self, ids, positions):
+        return self.gpt.embed(ids, positions)
+
+    def serving_layers(self):
+        return self.gpt.layers
+
+    def serving_final(self, x):
+        return self.gpt.ln_f(x)
+
+    def serving_head(self, h_last):
+        return self._head_logits(h_last)
+
+    def serving_linears(self):
+        return self.gpt.serving_linears()
+
+    def serving_embedding(self):
+        return self.gpt.wte
 
     def _head_logits(self, h_last):
         """Next-token logits [b, vocab] from last hidden states [b, hidden]

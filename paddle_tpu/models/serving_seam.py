@@ -1,0 +1,256 @@
+"""The engine<->model seam: what a model declares so that
+``paddle_tpu.serving`` can run it, and the serving machinery every served
+model shares (docs/serving_model_seam.md).
+
+**What a model declares** (methods on the ``nn.Layer`` it hands the engine;
+:class:`~paddle_tpu.models.gpt.GPTForCausalLM` and
+:class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM` both do):
+
+* ``serving_spec() -> ServingSpec``: vocabulary, longest context, and one
+  :class:`KVLayerState` or :class:`RecurrentLayerState` per layer, in order:
+  the KIND of per-request state that layer keeps and its shape;
+* ``serving_embed(ids, positions)``: ``[lanes, s]`` token ids at per-lane
+  start positions -> hidden states;
+* ``serving_layers()``: the layers, each called
+  ``layer(x, cache=view, start_pos=positions) -> (x, successor view)`` with a
+  cache view of ITS kind, built by the engine;
+* ``serving_final(x)``: the final norm; ``serving_head(h_last)``:
+  ``[b, hidden]`` arrays -> ``[b, vocab]`` logits;
+* ``serving_linears()``: ``(site, linear)`` for every matmul the int8
+  quantizer and the LoRA arena may touch, in model order;
+* ``serving_embedding()``: the token table (never quantized: its dtype is
+  the compute dtype).
+
+**The two cache-view protocols.** A ``"kv"`` layer drives
+``view.update_and_attend(q, k, v) -> (attention output, successor)`` with
+``[b, s, heads, head_dim]`` arrays; the view owns the paged layout. A
+``"recurrent"`` layer drives ``view.read() -> state arrays`` (the order of
+its :class:`RecurrentLayerState`), ``view.valid_len`` (None, or the traced
+true length of a padded prefill) and ``view.write(new arrays) -> successor``;
+the view owns the per-lane store and which lanes a write reaches.
+
+The engine never names a model class or a model's attribute beyond these.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..core.tensor import Tensor
+from ..distributed.fleet.meta_parallel.mp_layers import (
+    MODEL_AXIS,
+    ColumnParallelLinear,
+    RowParallelLinear,
+)
+from ..distributed.sharding_util import constraint
+
+
+@dataclass(frozen=True)
+class KVLayerState:
+    """A layer whose per-request state is keys and values, one row a token:
+    it lives in the paged arena's block pools."""
+
+    num_heads: int
+    head_dim: int
+    kind: str = "kv"
+
+
+@dataclass(frozen=True)
+class RecurrentLayerState:
+    """A layer whose per-request state has a fixed size whatever the
+    context: ``arrays`` is ``((name, per-lane shape, dtype), ...)``; the
+    engine keeps one ``[lanes, *shape]`` array each, indexed by slot."""
+
+    arrays: Tuple[Tuple[str, Tuple[int, ...], str], ...]
+    kind: str = "recurrent"
+
+
+@dataclass(frozen=True)
+class ServingSpec:
+    vocab_size: int
+    max_positions: int
+    layers: Tuple[object, ...]
+
+    def kv_layers(self):
+        return [s for s in self.layers if s.kind == "kv"]
+
+    def recurrent_layers(self):
+        return [s for s in self.layers if s.kind == "recurrent"]
+
+
+def forward_cached(model, ids, views, positions):
+    """Embed -> layers (each handed its cache view) -> final norm: the one
+    way a compiled serving program runs a model. Returns ``(hidden
+    [lanes, s, hidden] Tensor, successor views)``."""
+    x = model.serving_embed(ids, positions)
+    new_views = []
+    for layer, view in zip(model.serving_layers(), views):
+        x, nv = layer(x, cache=view, start_pos=positions)
+        new_views.append(nv)
+    return model.serving_final(x), new_views
+
+
+def masked_attention(qa, ka, va, mask):
+    """Core cached-decode attention: q against an (already updated) K/V
+    buffer under an explicit boolean mask. ``qa`` is [b, s, heads, dim];
+    ``ka``/``va`` are [b, kv_len, heads, dim]; ``mask`` broadcasts against
+    [b, heads, s, kv_len]. Returns [b, s, heads, dim].
+
+    This one function is the numerics contract shared by ``generate()``'s
+    contiguous KV path and the serving engine's paged-arena path — both
+    must produce token-for-token identical greedy decodes, so they must
+    run the exact same ops (same dtypes, same -1e30 masking, same fp32
+    softmax)."""
+    qt = jnp.swapaxes(qa, 1, 2)  # [b, h, s, d]
+    kt = jnp.swapaxes(ka, 1, 2)
+    vt = jnp.swapaxes(va, 1, 2)
+    scale = 1.0 / math.sqrt(qa.shape[-1])
+    logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * scale
+    logits = jnp.where(mask, logits, -1e30)
+    p = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(qa.dtype)
+    return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vt), 1, 2)
+
+
+#: multi-LoRA hook (serving.adapters): called as hook(layer, x, y) inside
+#: serving_linear to add the per-lane low-rank update when an adapter
+#: trace context is bound; inert (returns y) without one. Process-global
+#: and None until an AdapterArena exists, so the training/generate paths
+#: never pay for it.
+_lora_hook = None
+
+
+def set_lora_hook(fn) -> None:
+    """Install the serving-adapter hook (``serving.adapters`` calls this
+    once, at the first :class:`~paddle_tpu.serving.adapters.AdapterArena`
+    construction). Idempotent."""
+    global _lora_hook
+    _lora_hook = fn
+
+
+def quantize_serving_weights(model, mesh=None) -> int:
+    """Per-channel int8 weight-only quantization of every matmul the model
+    declares (``serving_linears()``), in place
+    (``FLAGS_serving_quant_weights`` — the serving engine calls this at
+    model load).
+
+    Each targeted linear's weight payload becomes int8 (``[in, out]``,
+    quantized per OUTPUT channel via
+    :func:`paddle_tpu.quantization.quantize_weight` — the framework's one
+    weight quantizer, no absmax math duplicated here) and the ``[1, out]``
+    float32 scale is registered as a ``weight_scale`` buffer, so
+    ``functional_state()`` carries both into every compiled program: the
+    decode/prefill/verify programs then stream int8 weights from HBM and
+    dequantize in-kernel (:func:`serving_linear`). Embeddings, the LM head
+    and the norms stay in the compute dtype — they are a small fraction of
+    decode traffic and the head's argmax is tolerance-critical.
+
+    Idempotent (a gateway's replicas share one model instance): already
+    quantized layers are skipped. Returns the number of layers quantized
+    by THIS call. ``mesh`` pins the re-placement below to a specific mesh
+    (the serving engine passes its captured one so an explicit
+    ``ServingConfig.mesh`` stays coherent); None defers to the installed
+    global. Training a quantized model is not supported — serving
+    quantization is a load-time conversion, not QAT (see
+    :mod:`paddle_tpu.quantization` for fake-quant training)."""
+    from .. import quantization
+    from ..distributed.sharding_util import shard_parameter
+
+    n = 0
+    for _, lin in model.serving_linears():
+        if getattr(lin, "weight_scale", None) is not None:
+            continue
+        qw, scale = quantization.quantize_weight(
+            np.asarray(lin.weight._data), channel_axis=1)
+        lin.weight._data = jnp.asarray(qw)
+        lin.weight.stop_gradient = True
+        lin.register_buffer("weight_scale", Tensor(jnp.asarray(scale)))
+        # re-place on the mesh: the payload swap above replaced the
+        # committed (sharded) array with a default-placed one, and jit
+        # infers in_shardings from committed arrays — without this a
+        # TP mesh would hold the FULL int8 weight per chip. Column
+        # linears shard out_features on the model axis (the
+        # per-out-channel scale shards with them); row linears shard
+        # in_features, their out-channel scale is replicated; a plain
+        # linear is replicated. No-op off-mesh (single chip).
+        if isinstance(lin, ColumnParallelLinear):
+            shard_parameter(lin.weight, None, MODEL_AXIS, mesh=mesh)
+            shard_parameter(lin.weight_scale, None, MODEL_AXIS, mesh=mesh)
+        elif isinstance(lin, RowParallelLinear):
+            shard_parameter(lin.weight, MODEL_AXIS, None, mesh=mesh)
+            shard_parameter(lin.weight_scale, None, None, mesh=mesh)
+        else:
+            shard_parameter(lin.weight, None, None, mesh=mesh)
+            shard_parameter(lin.weight_scale, None, None, mesh=mesh)
+        n += 1
+    if n:
+        # generate()'s memoized runner is keyed per decode configuration;
+        # the quant tag joins that key (like the donation flag) so a
+        # pre-quantization runner is never reused on int8 weights
+        model._serving_quant = getattr(model, "_serving_quant", 0) + 1
+    return n
+
+
+def serving_linear(layer, x):
+    """The matmul entry point shared by the quantized and plain paths. An
+    unquantized layer runs its normal forward (op-for-op identical to
+    calling it directly — the flag-off serving path stays bit-identical).
+    A layer carrying a ``weight_scale`` buffer (int8 payload from
+    :func:`quantize_serving_weights`) dequantizes IN the kernel: the int8
+    weight is read from HBM, multiplied by its per-channel scale and cast
+    to the activation dtype right before the matmul, so XLA fuses the
+    dequant into the matmul's operand pipeline — weight traffic is
+    1 byte/param instead of 2-4.
+
+    This is also the multi-LoRA attach point (``serving.adapters``): when
+    an adapter trace context is bound, the per-lane low-rank update
+    ``(x @ A[ids]) @ B[ids]`` is added to the base matmul's output —
+    int8 base + f32 adapters compose here. No context ⇒ identical trace."""
+    scale = getattr(layer, "weight_scale", None)
+    if scale is None:
+        y = layer(x)
+        if _lora_hook is not None:
+            y = _lora_hook(layer, x, y)
+        return y
+    from ..core.dispatch import apply
+
+    if isinstance(layer, RowParallelLinear) and layer.input_is_parallel:
+        # mirror RowParallelLinear.forward's input hint: the contraction
+        # over the model-sharded in_features must stay a partial matmul +
+        # psum, not an all-gather of the activations
+        x = constraint(x, "data", None, MODEL_AXIS)
+
+    def deq_matmul(xa, qwa, sa, ba=None):
+        w = (qwa.astype(jnp.float32) * sa).astype(xa.dtype)
+        y = xa @ w
+        if ba is not None:
+            y = y + ba.astype(y.dtype)
+        return y
+
+    args = (x, layer.weight, scale) + (
+        () if layer.bias is None else (layer.bias,))
+    y = apply(deq_matmul, args, {}, name="serving_qlinear")
+    if _lora_hook is not None:
+        y = _lora_hook(layer, x, y)
+    # mirror the parallel linears' output shardings (the quantized matmul
+    # must shard exactly like the one it replaces)
+    if isinstance(layer, ColumnParallelLinear) and not layer.gather_output:
+        return constraint(y, "data", None, MODEL_AXIS)
+    return constraint(y, "data", None, None)
+
+
+def serving_compute_dtype(model) -> str:
+    """The model's activation/KV compute dtype. Normally its first declared
+    linear's weight dtype; with int8-quantized serving weights those read
+    "int8", so fall back to the (never-quantized) token embedding — KV
+    caches and activation buffers must be allocated in the compute dtype,
+    not the storage dtype. This is the ONE home of the fallback rule
+    (``gen_kv_caches`` derives from it too), and the dict lookup keeps it
+    branch-free — generate()'s compiled copying build traces through it."""
+    d = str(model.serving_linears()[0][1].weight._data.dtype)
+    return {"int8": str(model.serving_embedding().weight._data.dtype)}.get(
+        d, d)

@@ -71,7 +71,7 @@ def quantize_weight(w: np.ndarray, channel_axis: Optional[int] = None):
 
     This is THE weight quantizer of the framework: both the PTQ/QAT
     ``convert()`` path and the serving engine's weight-only int8 mode
-    (:func:`paddle_tpu.models.gpt.quantize_serving_weights`) call it, so
+    (:func:`paddle_tpu.models.serving_seam.quantize_serving_weights`) call it, so
     the absmax math exists exactly once. ``channel_axis`` selects the
     per-channel axis (negative values count from the end, numpy-style);
     the returned scale keeps that axis (``keepdims``) so dequantization

@@ -5,9 +5,10 @@ adapter over the SHARED (possibly int8) base weights, and a single batch
 mixes adapters freely. The design mirrors :mod:`~.kv_arena` — a fixed
 paged arena addressed by per-slot indices that are pure runtime data:
 
-* Per targeted linear (the same four matmuls
-  ``models.gpt._SERVING_QUANT_LINEARS`` quantizes — ``attn.qkv`` /
-  ``attn.proj`` / ``mlp.up`` / ``mlp.down``, per layer) the arena holds
+* Per targeted linear (the matmuls the model declares through
+  ``serving_linears()``, the same ones the int8 quantizer targets — for
+  GPT ``attn.qkv`` / ``attn.proj`` / ``mlp.up`` / ``mlp.down``, per layer)
+  the arena holds
   stacked pools ``A [cap+1, in, r]`` / ``B [cap+1, r, out]`` float32.
   **Row 0 is the identity adapter** (all zeros — the LoRA scratch block):
   a slot with ``adapter_id = 0`` runs the base model, token-identical to
@@ -19,7 +20,7 @@ paged arena addressed by per-slot indices that are pure runtime data:
   zero recompiles — like admit/retire.
 * Inside the compiled step every slot gathers its adapter by index:
   ``delta = (x @ A[ids]) @ B[ids]`` in float32, added to the base
-  matmul's output inside :func:`models.gpt._serving_linear` (the one
+  matmul's output inside :func:`models.serving_seam.serving_linear` (the one
   attention/MLP matmul entry point — with ``FLAGS_serving_quant_weights``
   the base matmul streams int8 and the adapter stays f32: int8 base +
   f32 adapters, see docs/quantization.md). The pools ride into every
@@ -28,7 +29,7 @@ paged arena addressed by per-slot indices that are pure runtime data:
 
 The binding between the traced pools and the model's linears is a
 trace-time context (:meth:`AdapterArena.bind`): the engine's compiled
-bodies enter it around ``model.gpt(...)``, ``_serving_linear`` consults
+bodies enter it around the model's forward, ``serving_linear`` consults
 it per layer. No context (training, plain ``generate()``, the spec-decode
 verify program) ⇒ the hook is inert and the trace is unchanged.
 
@@ -48,10 +49,6 @@ from . import metrics
 
 __all__ = ["LoraAdapter", "AdapterArena"]
 
-#: the targeted linears, in model order per layer (shared with the int8
-#: weight quantizer — the decode hot path's matmuls)
-TARGETS = ("attn.qkv", "attn.proj", "mlp.up", "mlp.down")
-
 _tls = threading.local()  # .ctx — the active trace-time binding
 
 
@@ -62,7 +59,8 @@ class AdapterExhaustedError(RuntimeError):
 
 class LoraAdapter:
     """One adapter's weights: ``{"<layer>.<target>": (A [in, r],
-    B [r, out])}`` with ``target`` in :data:`TARGETS`. Missing sites stay
+    B [r, out])}``, a site being one the model's ``serving_linears()``
+    names (GPT: ``attn.qkv``, ``attn.proj``, ``mlp.up``, ``mlp.down``). Missing sites stay
     identity (zeros). ``alpha`` is the usual LoRA scaling — folded into
     ``B`` as ``alpha / rank`` at registration time."""
 
@@ -106,7 +104,7 @@ class _TraceCtx:
 
 
 def _lora_hook(layer, x, y):
-    """``models.gpt._serving_linear``'s adapter hook: add the per-lane
+    """``models.serving_seam.serving_linear``'s adapter hook: add the per-lane
     low-rank update when a trace context is bound, identity otherwise.
     The gather (``A[ids]`` / ``B[ids]``) and both matmuls are all-array
     math over static shapes — the adapter mix is runtime data."""
@@ -154,18 +152,12 @@ class AdapterArena:
         self._b: List[np.ndarray] = []
         self._site_names: List[str] = []
         self._site_by_layer: Dict[int, int] = {}
-        for li, blk in enumerate(model.gpt.layers):
-            for tgt, lin in (("attn.qkv", blk.attn.qkv),
-                             ("attn.proj", blk.attn.proj),
-                             ("mlp.up", blk.mlp.up),
-                             ("mlp.down", blk.mlp.down)):
-                fi, fo = (int(d) for d in lin.weight.shape)
-                self._site_by_layer[id(lin)] = len(self._site_names)
-                self._site_names.append(f"{li}.{tgt}")
-                self._a.append(np.zeros((capacity + 1, fi, rank),
-                                        np.float32))
-                self._b.append(np.zeros((capacity + 1, rank, fo),
-                                        np.float32))
+        for site, lin in model.serving_linears():
+            fi, fo = (int(d) for d in lin.weight.shape)
+            self._site_by_layer[id(lin)] = len(self._site_names)
+            self._site_names.append(site)
+            self._a.append(np.zeros((capacity + 1, fi, rank), np.float32))
+            self._b.append(np.zeros((capacity + 1, rank, fo), np.float32))
         # LIFO free list over rows 1..capacity (row 0 = identity, never
         # allocatable — the kv_arena scratch-block discipline). Seeded
         # descending so pop() hands out 1, 2, ... in registration order:
@@ -177,9 +169,9 @@ class AdapterArena:
         self._dev = None  # memoized device pools
         self._engine = None  # bound by ServingEngine: the liveness guard
         # the hook is process-global and inert without a bound context
-        from ..models import gpt as _gpt
+        from ..models.serving_seam import set_lora_hook
 
-        _gpt.set_lora_hook(_lora_hook)
+        set_lora_hook(_lora_hook)
         metrics.set_gauge("lora.slots", self.capacity)
         metrics.set_gauge("lora.live", 0)
         metrics.set_gauge("lora.arena_bytes", self.bytes_total())
@@ -208,7 +200,8 @@ class AdapterArena:
                 self._free.append(idx)
                 raise ValueError(
                     f"adapter site {key!r} does not exist in this model "
-                    f"(sites are '<layer>.<target>', targets {TARGETS})")
+                    f"(sites are '<layer>.<target>', such as "
+                    f"{self._site_names[0]!r})")
         for si, site in enumerate(self._site_names):
             ab = adapter.weights.get(site)
             if ab is None:

@@ -56,6 +56,15 @@ class DisaggReplicaPool(ProcessReplicaPool):
     def __init__(self, model, prefill_replicas: Optional[int] = None,
                  decode_replicas: Optional[int] = None,
                  disk_dir: Optional[str] = None, **pool_kw):
+        spec = getattr(model, "serving_spec", None)
+        if spec is not None and spec().recurrent_layers():
+            # a request is handed over as its published BLOCK chain; a
+            # recurrent layer's state is not blocks (a model factory is
+            # refused by its workers' engines: the prefix cache names it)
+            raise ValueError(
+                "disaggregated prefill/decode handoff is not supported for "
+                "a model with recurrent-state layers: it hands a request "
+                "over as paged blocks")
         p, d = role_counts(prefill_replicas, decode_replicas)
         if p < 1 or d < 1:
             raise ValueError(

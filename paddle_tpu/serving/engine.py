@@ -24,10 +24,26 @@ a fixed slot arena**:
   discarded by the scheduler — the standard masked-lane trick that keeps
   one executable serving every occupancy pattern.
 
-Decode numerics deliberately share ``models.gpt.masked_attention`` and
-``GPTForCausalLM._head_logits`` with ``generate()``, so a greedy request
-served through the engine reproduces ``generate(stop_token_id=...)``
-token-for-token.
+**The engine<->model seam** (``models/serving_seam.py``,
+docs/serving_model_seam.md): the engine names no model. A model declares
+its vocabulary, its longest context and, per layer, the KIND of per-request
+state that layer keeps (``serving_spec()``): ``"kv"`` (heads, head_dim:
+rows in the paged arena) or ``"recurrent"`` (fixed-size arrays per lane, in
+the arena's slot-indexed store). Every compiled program here runs the model
+one way, :func:`~paddle_tpu.models.serving_seam.forward_cached`: embed ->
+layers, each handed a cache view of ITS kind built here (the three paged
+views below for ``"kv"`` layers, the two slot-state views for
+``"recurrent"`` ones) -> final norm; then ``serving_head``. A recurrent
+layer's lane is started from zeros and written by the prefill that admits a
+request, advanced in place by the decode step, and untouched while
+inactive. The options whose bookkeeping assumes every layer's state is
+blocks (prefix cache, KV tiering, speculative decoding, chunked prefill)
+refuse a model with a recurrent layer at construction.
+
+Decode numerics deliberately share
+``models.serving_seam.masked_attention`` and the model's ``serving_head``
+with ``GPTForCausalLM.generate()``, so a greedy GPT request served through
+the engine reproduces ``generate(stop_token_id=...)`` token-for-token.
 
 Under ``FLAGS_decode_donate`` the KV pools are donated into every compiled
 prefill/decode call: XLA updates the arena in place instead of
@@ -36,7 +52,7 @@ double-buffering what is by far the engine's largest allocation.
 **Quantized serving** (``FLAGS_serving_quant_weights`` /
 ``FLAGS_serving_quant_kv`` / ``FLAGS_serving_quant_draft`` — see
 docs/quantization.md) rides the same data path: weights stream int8 and
-dequantize in-kernel (:func:`paddle_tpu.models.gpt._serving_linear`),
+dequantize in-kernel (:func:`paddle_tpu.models.serving_seam.serving_linear`),
 the KV arena stores int8 with per-block scale pools carried inside every
 pool entry (quantize-on-scatter in :func:`_scatter_rows`,
 dequant-on-attend in :func:`_gather_ctx`), and each mode is captured at
@@ -49,7 +65,7 @@ per-slot sampling params + positional PRNG seeds
 vocab mask (:mod:`paddle_tpu.serving.constrain`), and the per-slot LoRA
 adapter index into a paged adapter arena
 (:mod:`paddle_tpu.serving.adapters`, gathered inside
-``gpt._serving_linear``) all thread through the one compiled step like
+``serving_seam.serving_linear``) all thread through the one compiled step like
 ``start_pos`` — a batch mixing greedy, sampled, constrained, and
 N-adapter slots never recompiles, and the greedy/mask-off/adapter-0
 paths are token-identical to the classic engine.
@@ -188,8 +204,8 @@ def _gather_ctx(entry, table, dtype):
 
 
 class _PagedCacheView:
-    """One layer's decode-step view of the paged arena (the ``cache``
-    protocol object ``GPTAttention.forward`` drives): write the new token's
+    """One ``"kv"`` layer's decode-step view of the paged arena (the ``cache``
+    protocol object an attention layer drives): write the new token's
     k/v at each lane's (block, offset), gather the lane's block table, and
     attend under the per-lane position mask. ``entry`` is the layer's
     whole arena pool entry — ``(k, v)`` or, with ``FLAGS_serving_quant_kv``,
@@ -222,7 +238,7 @@ class _PagedCacheView:
     def update_and_attend(self, q, k, v):
         import jax.numpy as jnp
 
-        from ..models.gpt import masked_attention
+        from ..models.serving_seam import masked_attention
 
         qa, ka, va = (t._data if isinstance(t, Tensor) else t
                       for t in (q, k, v))
@@ -276,7 +292,7 @@ class _CapturePrefillView:
     def update_and_attend(self, q, k, v):
         import jax.numpy as jnp
 
-        from ..models.gpt import masked_attention
+        from ..models.serving_seam import masked_attention
 
         qa, ka, va = (t._data if isinstance(t, Tensor) else t
                       for t in (q, k, v))
@@ -324,7 +340,7 @@ class _PrefixPrefillView:
     def update_and_attend(self, q, k, v):
         import jax.numpy as jnp
 
-        from ..models.gpt import masked_attention
+        from ..models.serving_seam import masked_attention
 
         qa, ka, va = (t._data if isinstance(t, Tensor) else t
                       for t in (q, k, v))
@@ -354,6 +370,65 @@ class _PrefixPrefillView:
                                  self.prefix_len, self.true_len, bs,
                                  kernel=self.kernel, mesh=self.mesh)
         return o, new
+
+
+class _SlotStateDecodeView:
+    """One ``"recurrent"`` layer's decode-step view of the slot-indexed
+    store: ``entry`` is that layer's ``[S, ...]`` state arrays. The layer
+    reads every lane's state, advances it one token and writes it back;
+    an inactive lane keeps what it had."""
+
+    valid_len = None  # one real token a lane: nothing is padded
+
+    def __init__(self, entry, active):
+        self.entry = entry
+        self.active = active  # [S] bool
+
+    def read(self):
+        return self.entry
+
+    def write(self, new):
+        import jax.numpy as jnp
+
+        def keep(n, old):
+            act = self.active.reshape((-1,) + (1,) * (old.ndim - 1))
+            return jnp.where(act, n.astype(old.dtype), old)
+
+        return _SlotStateDecodeView(
+            tuple(keep(n, o) for n, o in zip(new, self.entry)), self.active)
+
+
+class _SlotStatePrefillView:
+    """One ``"recurrent"`` layer's prefill view: the admitted request
+    starts from a ZERO state (the lane is reset, whatever its last tenant
+    left), runs over the true length of the padded prompt (``valid_len``),
+    and its final state is written into lane ``slot``."""
+
+    def __init__(self, entry, slot, true_len):
+        self.entry = entry
+        self.slot = slot          # scalar int32: the lane being admitted
+        self.valid_len = true_len  # scalar int32: real (unpadded) length
+
+    def read(self):
+        import jax.numpy as jnp
+
+        return tuple(jnp.zeros((1,) + a.shape[1:], a.dtype)
+                     for a in self.entry)
+
+    def write(self, new):
+        entry = tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                old, n.astype(old.dtype), self.slot, axis=0)
+            for n, old in zip(new, self.entry))
+        return _SlotStatePrefillView(entry, self.slot, self.valid_len)
+
+
+def _split_views(views, kinds):
+    """The successor views' storage by kind, in layer order: ``(entries of
+    the "kv" layers, entries of the "recurrent" layers)``."""
+    kv = [v.entry for v, k in zip(views, kinds) if k == "kv"]
+    rec = [v.entry for v, k in zip(views, kinds) if k == "recurrent"]
+    return kv, rec
 
 
 @dataclass
@@ -515,7 +590,7 @@ class ServingEngine:
             # The captured mesh is threaded through so an explicit
             # ServingConfig.mesh re-places the int8 payloads on THIS
             # engine's mesh, not whatever global happens to be installed
-            from ..models.gpt import quantize_serving_weights
+            from ..models.serving_seam import quantize_serving_weights
 
             n = quantize_serving_weights(model, mesh=self.mesh)
             if n:
@@ -546,12 +621,20 @@ class ServingEngine:
                 replicate_unplaced(p, self.mesh)
         self._arrays = [p._data for p in self._objs]
 
-        mcfg = model.cfg
+        # what the model declares (the seam): sizes, and per layer the kind
+        # of state it keeps. The paged pools cover the "kv" layers, the
+        # arena's slot-indexed store the "recurrent" ones
+        spec = model.serving_spec()
+        self._layer_kinds = tuple(st.kind for st in spec.layers)
+        kv_layers, rec_layers = spec.kv_layers(), spec.recurrent_layers()
+        if len({(st.num_heads, st.head_dim) for st in kv_layers}) > 1:
+            raise ValueError("the paged arena holds one (heads, head_dim) "
+                             "for all of a model's kv layers")
+        self.recurrent = bool(rec_layers)
         self.num_slots = int(cfg.num_slots or flags.flag("serving_slots"))
         self.block_size = int(cfg.kv_block_size or flags.flag("kv_block_size"))
-        self.max_model_len = int(cfg.max_model_len
-                                 or mcfg.max_position_embeddings)
-        if self.max_model_len > mcfg.max_position_embeddings:
+        self.max_model_len = int(cfg.max_model_len or spec.max_positions)
+        if self.max_model_len > spec.max_positions:
             raise ValueError("max_model_len exceeds the model's "
                              "max_position_embeddings")
         self.blocks_per_slot = _ceil_div(self.max_model_len, self.block_size)
@@ -599,7 +682,7 @@ class ServingEngine:
         if self._retry is None and not self.donate:
             self._retry = resilience.io_policy()
 
-        from ..models.gpt import serving_compute_dtype
+        from ..models.serving_seam import serving_compute_dtype
 
         kv_dtype = serving_compute_dtype(model)
         # kept so the supervisor can rebuild an identically-shaped arena
@@ -609,10 +692,12 @@ class ServingEngine:
         # the mesh rides along so the rebuilt arena re-commits the SAME
         # pool shardings (identical shapes AND placements => the
         # supervisor's rebuild/replay path stays zero-recompile on a mesh)
-        self._arena_args = (mcfg.num_layers, mcfg.num_heads,
-                            mcfg.hidden_size // mcfg.num_heads,
+        kv_heads, kv_dim = ((kv_layers[0].num_heads, kv_layers[0].head_dim)
+                            if kv_layers else (1, 1))
+        self._arena_args = (len(kv_layers), kv_heads, kv_dim,
                             num_blocks, self.block_size, kv_dtype,
-                            self.quant_kv, self.mesh)
+                            self.quant_kv, self.mesh, self.num_slots,
+                            tuple(st.arrays for st in rec_layers))
         self.arena = KVArena(*self._arena_args)
         self.use_prefix_cache = (bool(flags.flag("serving_prefix_cache"))
                                  if cfg.prefix_cache is None
@@ -626,6 +711,21 @@ class ServingEngine:
         self.kv_tiering = (bool(flags.flag("serving_kv_tiering"))
                            if cfg.kv_tiering is None
                            else bool(cfg.kv_tiering))
+        if self.recurrent:
+            # each of these keeps, shares or rewinds a request's state as
+            # BLOCKS; a recurrent layer's state is not blocks (it would
+            # need a snapshot per cached prefix, per chunk, per rollback).
+            # Refuse by name instead of serving a silently wrong answer
+            for on, option in (
+                    (self.use_prefix_cache, "prefix_cache"),
+                    (self.kv_tiering, "kv_tiering"),
+                    (spec_k > 0, "spec_k (speculative decoding)"),
+                    (self.chunk_size > 0, "chunked_prefill")):
+                if on:
+                    raise ValueError(
+                        f"{option} is not supported for a model with "
+                        "recurrent-state layers: it assumes every layer's "
+                        "state is paged blocks")
         self.tier = None
         if self.kv_tiering and self.use_prefix_cache:
             from .tiered import TierView, get_tier_store
@@ -633,8 +733,7 @@ class ServingEngine:
             store = (cfg.tier_store if cfg.tier_store is not None
                      else get_tier_store())
             self.tier = TierView(store, signature=(
-                mcfg.num_layers, mcfg.num_heads,
-                mcfg.hidden_size // mcfg.num_heads, self.block_size,
+                len(kv_layers), kv_heads, kv_dim, self.block_size,
                 kv_dtype, self.quant_kv, self.mesh_key))
         self.prefix_cache = (PrefixCache(self.arena, self.block_size,
                                          tier=self.tier)
@@ -667,7 +766,7 @@ class ServingEngine:
         # (mask-off identity), adapter 0 = base weights. The mask's
         # device copy is memoized and invalidated only on change, so
         # unconstrained workloads re-pass one cached array per step.
-        self.vocab = int(mcfg.vocab_size)
+        self.vocab = int(spec.vocab_size)
         self._temp = np.zeros(s, np.float32)
         self._top_k = np.zeros(s, np.int32)
         self._top_p = np.ones(s, np.float32)
@@ -918,16 +1017,18 @@ class ServingEngine:
 
         from ..core import rng as prng
         from ..jit import _swap_data
+        from ..models.serving_seam import forward_cached
         from .sampling import sample_tokens
 
         model = self._model
         lora = self.lora
-        n_layers = model.cfg.num_layers
+        kinds = self._layer_kinds
         bs = self.block_size
         use_kernel = self.paged_kernel
         kmesh = self._kernel_mesh
 
-        def prefill(arrays, ids, true_len, pools, rows, samp, *lora_args):
+        def prefill(arrays, ids, true_len, pools, rows, samp, rec, slot,
+                    *lora_args):
             # trace-time bookkeeping (runs once per bucket, not per call)
             self.prefill_traces[p_bucket] = \
                 self.prefill_traces.get(p_bucket, 0) + 1
@@ -936,18 +1037,27 @@ class ServingEngine:
                 # trace-time: the full-prefill (pseudo-table) kernel twin
                 # of prefill_traces — admission churn never re-lowers it
                 metrics.bump("kernel.prefill_traces")
+            # a "kv" layer hands back its chunk's k/v to scatter below; a
+            # "recurrent" layer starts lane `slot` from zeros and writes
+            # its final state there itself (through its view)
+            it_rec = iter(rec)
             views = [_CapturePrefillView(bs, kernel=use_kernel, mesh=kmesh)
-                     for _ in range(n_layers)]
+                     if kind == "kv"
+                     else _SlotStatePrefillView(next(it_rec), slot, true_len)
+                     for kind in kinds]
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_args) if lora is not None
                           else _null_ctx()):
-                        h, chunks = model.gpt(Tensor(ids), caches=views,
-                                              start_pos=0)
+                        h, new_views = forward_cached(
+                            model, Tensor(ids), views, 0)
                 with jax.named_scope("head_sample"):
                     h_last = jax.lax.dynamic_index_in_dim(
                         h._data, true_len - 1, axis=1, keepdims=False)
-                    logits = model._head_logits(h_last)
+                    logits = model.serving_head(h_last)
+            chunks = [v for v, k in zip(new_views, kinds) if k == "kv"]
+            new_rec = [v.entry for v, k in zip(new_views, kinds)
+                       if k == "recurrent"]
             p_idx = jnp.arange(p_bucket)
             row = rows[p_idx // bs]
             # padded positions (>= the true prompt length) scatter into the
@@ -968,9 +1078,9 @@ class ServingEngine:
             with jax.named_scope("head_sample"):
                 nxt = sample_tokens(logits, temp, k, p, seed, spos,
                                     allowed=vmask)
-            return nxt[0], new_pools
+            return nxt[0], new_pools, new_rec
 
-        fn = (jax.jit(prefill, donate_argnums=(3,)) if self.donate
+        fn = (jax.jit(prefill, donate_argnums=(3, 6)) if self.donate
               else jax.jit(prefill))
         self._prefill_jits[p_bucket] = fn
         return fn
@@ -980,7 +1090,9 @@ class ServingEngine:
         model over the unmatched suffix (padded to ``p_bucket``) while
         attending to — not recomputing — the resident prefix blocks.
         One program per suffix-length bucket; prefix length and the block
-        table are runtime data, so hits of any depth share it."""
+        table are runtime data, so hits of any depth share it. Every
+        layer is a ``"kv"`` layer here: the prefix cache and chunked
+        prefill, which alone reach this program, refuse any other kind."""
         fn = self._prefix_jits.get(p_bucket)
         if fn is not None:
             return fn
@@ -988,6 +1100,7 @@ class ServingEngine:
 
         from ..core import rng as prng
         from ..jit import _swap_data
+        from ..models.serving_seam import forward_cached
         from .sampling import sample_tokens
 
         model = self._model
@@ -1013,12 +1126,12 @@ class ServingEngine:
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_args) if lora is not None
                           else _null_ctx()):
-                        h, new_views = model.gpt(Tensor(ids), caches=views,
-                                                 start_pos=prefix_len)
+                        h, new_views = forward_cached(
+                            model, Tensor(ids), views, prefix_len)
                 with jax.named_scope("head_sample"):
                     h_last = jax.lax.dynamic_index_in_dim(
                         h._data, true_len - 1, axis=1, keepdims=False)
-                    logits = model._head_logits(h_last)
+                    logits = model.serving_head(h_last)
             temp, k, p, seed, spos, vmask = samp
             with jax.named_scope("head_sample"):
                 nxt = sample_tokens(logits, temp, k, p, seed, spos,
@@ -1171,16 +1284,19 @@ class ServingEngine:
         from ..core import rng as prng
         from ..distributed.sharding_util import replicate
         from ..jit import _swap_data
+        from ..models.serving_seam import forward_cached
         from .sampling import sample_tokens
 
         model = self._model
         lora = self.lora
+        kinds = self._layer_kinds
         bs = self.block_size
         use_kernel = self.paged_kernel
         kmesh = self._kernel_mesh
         mesh = self.mesh
 
-        def step(arrays, pools, block_tables, state, vmask, *lora_pools):
+        def step(arrays, pools, block_tables, state, vmask, rec,
+                 *lora_pools):
             self.decode_traces += 1  # trace-time: the no-recompile counter
             compile_cache.bump("serving.decode_compiles")
             if use_kernel:
@@ -1195,19 +1311,22 @@ class ServingEngine:
             temp = jax.lax.bitcast_convert_type(state[_ST_TEMP], jnp.float32)
             top_p = jax.lax.bitcast_convert_type(state[_ST_TOP_P],
                                                  jnp.float32)
-            views = [_PagedCacheView(entry, block_tables, positions,
+            it_kv, it_rec = iter(pools), iter(rec)
+            views = [_PagedCacheView(next(it_kv), block_tables, positions,
                                      active, bs, kernel=use_kernel,
                                      mesh=kmesh)
-                     for entry in pools]
+                     if kind == "kv"
+                     else _SlotStateDecodeView(next(it_rec), active)
+                     for kind in kinds]
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_pools, state[_ST_ADAPTER])
                           if lora is not None else _null_ctx()):
-                        h, new_views = model.gpt(Tensor(last_tok[:, None]),
-                                                 caches=views,
-                                                 start_pos=positions)
+                        h, new_views = forward_cached(
+                            model, Tensor(last_tok[:, None]), views,
+                            positions)
                 with jax.named_scope("head_sample"):
-                    logits = model._head_logits(h._data[:, 0])
+                    logits = model.serving_head(h._data[:, 0])
             # per-slot sampling over the constrained logits: temperature /
             # top-k / top-p / seed / mask are all runtime data (greedy
             # lanes reproduce the classic argmax bit-for-bit); the
@@ -1217,7 +1336,7 @@ class ServingEngine:
                 nxt = sample_tokens(logits, temp, state[_ST_TOP_K], top_p,
                                     state[_ST_SEED], positions + 1,
                                     allowed=vmask)
-            new_pools = [v.entry for v in new_views]
+            new_pools, new_rec = _split_views(new_views, kinds)
             # the next step's state, as the host's mirrors will read after
             # this one: active lanes advance a position and hold `nxt`
             new_state = state.at[_ST_POS].set(
@@ -1227,10 +1346,10 @@ class ServingEngine:
             # _step_args uploads it in: one signature, one executable
             if mesh is not None:
                 new_state = replicate(new_state, mesh=mesh)
-            return nxt, new_pools, new_state
+            return nxt, new_pools, new_rec, new_state
 
-        self._step_jit = (jax.jit(step, donate_argnums=(1,)) if self.donate
-                          else jax.jit(step))
+        self._step_jit = (jax.jit(step, donate_argnums=(1, 5))
+                          if self.donate else jax.jit(step))
         return self._step_jit
 
     def _call(self, fn, *args, name: str):
@@ -1644,8 +1763,9 @@ class ServingEngine:
                 nxt, new_pools = self._suffix_prefill_call(
                     st.ctx, st.clen, st.prefix_len, st.slot)
             else:
-                nxt, new_pools = self._full_prefill_call(st.ctx, st.clen,
-                                                         st.res, st.slot)
+                nxt, new_pools, new_rec = self._full_prefill_call(
+                    st.ctx, st.clen, st.res, st.slot)
+                self.arena.set_slot_state(new_rec)
             self.arena.set_pools(new_pools)
             if self.spec is not None and not st.skip_draft:
                 self.spec.prefill(st.slot, st.ctx)
@@ -1678,6 +1798,10 @@ class ServingEngine:
         self._active[slot] = True
         self._touch_slot_state()
         metrics.bump("engine.admits")
+        if self.recurrent:
+            # the prefill started the lane from zeros (whatever its last
+            # tenant left) and wrote this request's state into it
+            metrics.bump("state.resets")
         metrics.bump("tokens.prefill", st.clen - st.prefix_len)
         metrics.bump("tokens.generated")  # the next token, out of prefill
         self._refresh_gauges()
@@ -1701,9 +1825,9 @@ class ServingEngine:
         fn = self._get_prefill(p_bucket)
         return self._call(
             fn, self._arrays, jnp.asarray(ids), jnp.int32(clen),
-            self.arena.pools, jnp.asarray(rows),
-            self._samp_row(slot, clen), *self._lora_args(slot),
-            name="serving.prefill")
+            self.arena.pools, jnp.asarray(rows), self._samp_row(slot, clen),
+            self.arena.slot_state, jnp.int32(slot),
+            *self._lora_args(slot), name="serving.prefill")
 
     def _suffix_prefill_call(self, ctx: np.ndarray, clen: int,
                              prefix_len: int, slot: int,
@@ -1972,7 +2096,8 @@ class ServingEngine:
             metrics.bump("engine.step_uploads")
         lora = () if self.lora is None else (self.lora.device_pools(),)
         return (self._arrays, self.arena.pools, self._bt_dev,
-                self._state_dev, self._mask_arg(), *lora)
+                self._state_dev, self._mask_arg(), self.arena.slot_state,
+                *lora)
 
     def lower_decode_step(self):
         """``jax.stages.Lowered`` of the one compiled decode step at this
@@ -2013,8 +2138,8 @@ class ServingEngine:
                 # mirrors
                 self._touch_slot_state()
             with telemetry.phase("decode.dispatch", hists):
-                nxt, new_pools, state = self._call(self._get_step(), *args,
-                                                   name="serving.step")
+                nxt, new_pools, new_rec, state = self._call(
+                    self._get_step(), *args, name="serving.step")
             with telemetry.phase("decode.wait", hists):
                 with telemetry.phase("decode.release", hists):
                     # the step's argument arrays and the donated pools
@@ -2026,6 +2151,7 @@ class ServingEngine:
                     # PR 24: a third fewer tokens a second)
                     del args
                     self.arena.set_pools(new_pools)
+                    self.arena.set_slot_state(new_rec)
                 # blocks until the device is done, the token vector is
                 # back AND this thread has the GIL again
                 out = np.asarray(nxt)
@@ -2061,6 +2187,9 @@ class ServingEngine:
         ``tools/serving_stats.py --run`` and ``EnginePredictor.close()``
         both read these."""
         metrics.set_gauge("arena.kv_bytes", self.arena.bytes_total())
+        if self.recurrent:
+            metrics.set_gauge("state.bytes_total",
+                              self.arena.state_bytes_total())
         by_ns = self.arena.bytes_by_namespace()
         metrics.set_gauge("arena.scale_bytes",
                           sum(d["scale_bytes"] for d in by_ns.values()))
@@ -2070,6 +2199,9 @@ class ServingEngine:
 
     def _refresh_gauges(self) -> None:
         metrics.set_gauge("slots.active", self.active_slots())
+        if self.recurrent:
+            metrics.set_gauge("state.lanes_in_use",
+                              int(self._occupied.sum()))
         a = self.arena.stats()
         metrics.set_gauge("arena.blocks_free", a["blocks_free"])
         metrics.set_gauge("arena.blocks_total", a["blocks_total"])
@@ -2114,7 +2246,8 @@ class ServingEngine:
                # model quantizes nothing (matches the quant.draft gauge)
                "quant.draft": int(self.quant_draft
                                   and self.spec is not None
-                                  and self.spec.draft_mode)}
+                                  and self.spec.draft_mode),
+               "state.bytes_total": self.arena.state_bytes_total()}
         out.update({
             "sampling.admits": self.sampled_admits,
             "constrain.admits": self.constrained_admits,

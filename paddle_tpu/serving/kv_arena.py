@@ -37,6 +37,19 @@ only when :meth:`reserve` would otherwise fail. Shared blocks are
 read-only by contract; a slot that must write into one copies it first
 (copy-on-write, in the engine).
 
+**Two kinds of state, one manager** (the engine<->model seam,
+``models/serving_seam.py``): the block pools above hold the ``"kv"``
+layers' state, which grows a row a token. A ``"recurrent"`` layer's state
+has a fixed size whatever the context, so it lives in a second,
+**slot-indexed** store: per such layer a tuple of ``[num_slots, *shape]``
+arrays (:attr:`KVArena.slot_state`), the lane a request decodes in being its
+index. Admission still counts blocks only: a lane IS its state's
+allocation. A lane's state is started from zeros and written by the prefill
+that admits a request to it, advanced in place by the decode step, left as
+it is while the lane is inactive, and never read by another lane.
+:meth:`bytes_total` stays the paged pools alone; :meth:`state_bytes_total`
+is the store's.
+
 Counters (``arena.*`` in ``serving.metrics``): allocs, frees, reuse (a taken
 block that had been used before — the free list working), alloc failures,
 high-water blocks in use.
@@ -111,7 +124,9 @@ class KVArena:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_blocks: int, block_size: Optional[int] = None,
                  dtype: str = "float32", quantized: bool = False,
-                 mesh=None):
+                 mesh=None, num_slots: int = 0, slot_state=()):
+        """``slot_state``: per recurrent layer, its
+        ``((name, per-lane shape, dtype), ...)``; ``num_slots`` lanes each."""
         import jax.numpy as jnp
 
         # mesh-sharded pools (ISSUE 14): every pool entry — primary and
@@ -164,6 +179,11 @@ class KVArena:
         # primary's (a draft model has its own layers/heads/head_dim).
         self._ns_pools: dict = {}
         self._ns_shapes: dict = {}
+        # the slot-indexed store of the recurrent layers' state
+        self._slot_state: List[Tuple] = [
+            tuple(jnp.zeros((int(num_slots),) + tuple(shape), dtype)
+                  for _, shape, dtype in arrays)
+            for arrays in slot_state]
 
     # ------------------------------------------------------------- pools
 
@@ -224,6 +244,22 @@ class KVArena:
         """Adopt the pool arrays returned by a compiled step (the old ones
         were donated into it and are no longer valid)."""
         self._pools = list(pools)
+
+    @property
+    def slot_state(self) -> List[Tuple]:
+        """Per recurrent layer, its ``[num_slots, ...]`` state arrays."""
+        return self._slot_state
+
+    def set_slot_state(self, state) -> None:
+        """Adopt the store a compiled call handed back (donation contract
+        identical to :meth:`set_pools`)."""
+        self._slot_state = [tuple(entry) for entry in state]
+
+    def state_bytes_total(self) -> int:
+        """Bytes of the slot-indexed store (not part of
+        :meth:`bytes_total`, which is the paged pools')."""
+        return sum(int(a.size) * a.dtype.itemsize
+                   for entry in self._slot_state for a in entry)
 
     def add_namespace(self, name: str, num_layers: int, num_heads: int,
                       head_dim: int, dtype: Optional[str] = None,

@@ -88,6 +88,13 @@ Counter namespaces:
   ``kernel.paged`` (0/1 mode) and ``kernel.tuned_entries`` (tuning-store
   records for this chip — ``ops.tuning`` / benches/TUNED_KERNELS.json)
 
+* ``state.*``      — the slot-indexed store of recurrent-layer state
+  (``kv_arena.KVArena.slot_state``; engines of a model that declares a
+  ``"recurrent"`` layer only): counter ``resets`` (an admission: the
+  prefill started the lane from zeros and wrote the request's state into
+  it); gauges ``state.bytes_total`` (the store's bytes, beside
+  ``arena.kv_bytes`` for the paged pools) and ``state.lanes_in_use``
+
 * ``time_us.*``    — wall time of the serving loop by phase, in whole
   microseconds (``serving.telemetry.phase``): ``time_us.<phase>`` grows
   by every use's elapsed time, so a window's delta over the window is
@@ -183,6 +190,10 @@ DOCUMENTED_NAMESPACES = (
     # name, the exact sums beside the latency.* buckets
     # (docs/observability.md "Phases of the serving loop")
     "time_us",
+    # state.* (ISSUE 26): the slot-indexed store of recurrent-layer state
+    # beside the paged KV arena — the resets counter,
+    # bytes_total / lanes_in_use gauges (docs/serving_model_seam.md)
+    "state",
     "queue", "slots", "tokens_per_sec",
 )
 

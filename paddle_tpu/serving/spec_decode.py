@@ -113,19 +113,21 @@ class SpecDecoder:
         self.quant_draft = bool(getattr(engine, "quant_draft", False))
         if self.draft is not None:
             self.draft.eval()
-            dcfg = self.draft.cfg
-            tcfg = engine._model.cfg
-            if dcfg.vocab_size != tcfg.vocab_size:
+            dspec = self.draft.serving_spec()
+            if dspec.recurrent_layers():
+                raise ValueError("speculative decoding needs a draft model "
+                                 "whose layers all keep paged KV state")
+            if dspec.vocab_size != engine.vocab:
                 raise ValueError(
-                    f"draft vocab {dcfg.vocab_size} != target vocab "
-                    f"{tcfg.vocab_size}: proposals would be meaningless ids")
+                    f"draft vocab {dspec.vocab_size} != target vocab "
+                    f"{engine.vocab}: proposals would be meaningless ids")
             if self.quant_draft:
                 # int8-quantize the draft's weights in place (idempotent)
                 # BEFORE the functional-state snapshot: the fused
                 # propose+verify program then streams the int8 payload.
                 # Verification keeps target-greedy semantics, so this only
                 # moves acceptance/speed — never the emitted tokens.
-                from ..models.gpt import quantize_serving_weights
+                from ..models.serving_seam import quantize_serving_weights
 
                 n = quantize_serving_weights(self.draft)
                 if n:
@@ -142,16 +144,16 @@ class SpecDecoder:
         return self.draft is not None
 
     def _bind_namespace(self) -> None:
-        from ..models.gpt import serving_compute_dtype
+        from ..models.serving_seam import serving_compute_dtype
 
-        dcfg = self.draft.cfg
+        kv = self.draft.serving_spec().kv_layers()
         # compute dtype, not storage dtype: an int8-quantized draft still
         # produces (and attends over) float k/v; with FLAGS_serving_quant_kv
         # the namespace inherits the arena's int8+scale-pool layout
         kv_dtype = serving_compute_dtype(self.draft)
         self.engine.arena.add_namespace(
-            self.NAMESPACE, dcfg.num_layers, dcfg.num_heads,
-            dcfg.hidden_size // dcfg.num_heads, kv_dtype)
+            self.NAMESPACE, len(kv), kv[0].num_heads, kv[0].head_dim,
+            kv_dtype)
 
     def rebuild(self) -> None:
         """Re-bind to the engine's freshly rebuilt arena (supervisor
@@ -267,10 +269,11 @@ class SpecDecoder:
 
         from ..core import rng as prng
         from ..jit import _swap_data
+        from ..models.serving_seam import forward_cached
         from .engine import _CapturePrefillView, _scatter_rows
 
         draft = self.draft
-        n_layers = draft.cfg.num_layers
+        n_layers = len(draft.serving_spec().layers)
         bs = self.engine.block_size
 
         # the draft prefill only needs the chunk k/v scattered — no head
@@ -282,8 +285,7 @@ class SpecDecoder:
             views = [_CapturePrefillView() for _ in range(n_layers)]
             with _swap_data(self._d_objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
-                    _, chunks = draft.gpt(Tensor(ids), caches=views,
-                                          start_pos=0)
+                    _, chunks = forward_cached(draft, Tensor(ids), views, 0)
             p_idx = jnp.arange(p_bucket)
             row = rows[p_idx // bs]
             row = jnp.where(p_idx < true_len, row, 0)
@@ -317,6 +319,7 @@ class SpecDecoder:
 
         from ..core import rng as prng
         from ..jit import _swap_data
+        from ..models.serving_seam import forward_cached
         from .engine import _PagedCacheView
 
         engine = self.engine
@@ -342,8 +345,8 @@ class SpecDecoder:
                      for entry in pools]
             with _swap_data(objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
-                    h, new_views = m.gpt(Tensor(toks[:, None]),
-                                         caches=views, start_pos=positions)
+                    h, new_views = forward_cached(
+                        m, Tensor(toks[:, None]), views, positions)
             return h._data[:, 0], [v.entry for v in new_views]
 
         def _sub_step(m, objs, arrays, pools, bt, positions, toks, act):
@@ -351,7 +354,7 @@ class SpecDecoder:
             h, new_pools = _fwd(m, objs, arrays, pools, bt, positions,
                                 toks, act)
             with _swap_data(objs, list(arrays)):
-                logits = m._head_logits(h)
+                logits = m.serving_head(h)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return nxt, new_pools
 
@@ -378,8 +381,10 @@ class SpecDecoder:
                 proposals = jnp.stack(props, 1)  # [S, k]
                 # ---- target verifies k+1 positions: sub-step j feeds the
                 # j-th proposal (j=0: the real last token); the verify-k
-                # head (GPTForCausalLM.verify_logits, itself per-position
-                # unrolled for bit parity) then scores every position
+                # head (the model's own head once per position: each runs
+                # the exact [S, hidden] shape of the plain decode step, so
+                # verifying is bit-identical to k single-token steps)
+                # then scores every position
                 toks = last_tok
                 hs = []
                 for j in range(k + 1):
@@ -391,7 +396,8 @@ class SpecDecoder:
                     if j < k:
                         toks = proposals[:, j]
                 with _swap_data(engine._objs, list(t_arrays)):
-                    logits = model.verify_logits(jnp.stack(hs, 1))
+                    logits = jnp.stack(
+                        [model.serving_head(h_j) for h_j in hs], 1)
                 tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return tgt, proposals, t_pools, d_pools
 

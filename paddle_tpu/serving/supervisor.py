@@ -28,7 +28,7 @@ recovery loop:
    ``prompt + tokens`` and emits the journal's next token, leaving the slot
    exactly where an uninterrupted decode would be. Output is
    token-for-token identical (prefill and decode share one numerics
-   contract — ``models.gpt.masked_attention`` / ``_head_logits``). With
+   contract — ``models.serving_seam.masked_attention`` / ``serving_head``). With
    the prefix cache on, each replayed admission re-inserts its prompt's
    full blocks, so replays that share a prefix re-attach the SAME fresh
    blocks by reference — the tree re-populates as a side effect of

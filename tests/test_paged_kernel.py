@@ -1,6 +1,6 @@
 """Pallas paged-attention serving kernels (ISSUE 13): interpreter-mode
 parity of :mod:`paddle_tpu.ops.paged_attention` against the XLA gather
-baseline (``engine._gather_ctx`` + ``gpt.masked_attention``), the shared
+baseline (``engine._gather_ctx`` + ``serving_seam.masked_attention``), the shared
 kernel-tuning store (:mod:`paddle_tpu.ops.tuning`), and the engine
 integration behind ``FLAGS_serving_paged_kernel``.
 
@@ -37,7 +37,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from paddle_tpu.quantization import quantize_kv  # noqa: E402
 from paddle_tpu.serving.engine import _gather_ctx  # noqa: E402
-from paddle_tpu.models.gpt import masked_attention  # noqa: E402
+from paddle_tpu.models.serving_seam import masked_attention  # noqa: E402
 
 
 # ------------------------------------------------------------- references

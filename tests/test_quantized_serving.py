@@ -29,9 +29,8 @@ import paddle_tpu as paddle
 from paddle_tpu import quantization
 from paddle_tpu.core import resilience
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.models.gpt import (
-    GPTForCausalLM,
-    gpt_tiny,
+from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+from paddle_tpu.models.serving_seam import (
     quantize_serving_weights,
     serving_compute_dtype,
 )
