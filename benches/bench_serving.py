@@ -137,7 +137,7 @@ in both timed windows. Persisted under ``"quantized"``.
 Env: QUANT_REQUESTS, QUANT_PROMPTS, QUANT_SYS.
 
 ``--paged-attention`` times the Pallas paged-attention decode kernel
-(ISSUE 13, ``FLAGS_serving_paged_kernel`` / ``ops.paged_attention``)
+(ISSUE 13, ``ServingConfig.paged_kernel`` / ``ops.paged_attention``)
 against the XLA gather baseline: four engine builds (gather/kernel x
 full-precision/int8-arena) admit the same 8-slot workload and time a
 fixed decode-step window with zero serving compiles and token-for-token
@@ -1160,7 +1160,7 @@ def run_paged_attention(model, platform):
 
     def time_candidate(g):
         fn = jax.jit(lambda q, e, bt, pos: pk.paged_decode_attention(
-            q, e, bt, pos, block_h=g))
+            q, e, bt, pos, pages=g))
         out = fn(q, entry, bt, pos)
         err = float(jnp.max(jnp.abs(out - ref)))
         if err > 5e-5:  # wrong launch params, not noise — never adopt
@@ -1172,8 +1172,8 @@ def run_paged_attention(model, platform):
         _common.sync(out)
         return (time.perf_counter() - t0) / tune_reps * 1e6
 
-    cands = sorted({1, 2, H} & set(
-        g for g in range(1, H + 1) if H % g == 0))
+    # the decode kernel's launch parameter: pages per tile
+    cands = sorted({g for g in (4, 8, 16, 32) if g <= mb} or {1})
     tuned = {g: time_candidate(g) for g in cands}
     tuned = {g: t for g, t in tuned.items() if t is not None}
     key = tuning.bucket_key(h=H, d=D, bs=block, mb=mb)
@@ -1218,11 +1218,11 @@ def run_paged_attention(model, platform):
     try:
         if tuned:
             best_g = min(tuned, key=tuned.get)
-            ok = tuning.adopt("paged_decode", key, {"block_h": best_g},
+            ok = tuning.adopt("paged_decode", key, {"pages": best_g},
                               tuned[best_g])
-            print(f"# paged tune: block_h candidates {tuned} -> "
+            print(f"# paged tune: pages-per-tile candidates {tuned} -> "
                   f"{'adopted' if ok else 'FAILED TO PERSIST'} "
-                  f"block_h={best_g} under {tuning.device_kind()!r} at "
+                  f"pages={best_g} under {tuning.device_kind()!r} at "
                   f"{tuning.store_path()}", flush=True)
         else:
             # every candidate failed the numerics check: never adopt a
@@ -1276,7 +1276,7 @@ def run_paged_attention(model, platform):
         "tuned": {"device_kind": tuning.device_kind(),
                   "published": platform == "tpu",
                   "paged_decode": {
-                      "bucket": key, "block_h": best_g,
+                      "bucket": key, "pages": best_g,
                       "candidates_us": {str(g): round(t, 1)
                                         for g, t in tuned.items()}},
                   "paged_prefill": {

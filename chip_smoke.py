@@ -463,7 +463,9 @@ def phase_serve(model, plan: Plan, prompts, parity: Parity, chip: bool):
     """Gather-path engine over HTTP; tokens against ``generate()``."""
     from paddle_tpu.core.tensor import Tensor
 
-    toks, rec = serve_pass("serve", model, plan, prompts, chip)
+    # the XLA gather route, asked for: on the chip the default is the kernel
+    toks, rec = serve_pass("serve", model, plan, prompts, chip,
+                           paged_kernel=False)
     release()
     ref = {}
     for i in plan.parity:
@@ -651,7 +653,7 @@ def phase_mesh_serve(plan: Plan, prompts, seed: int, chip: bool):
     mesh_mod.clear_mesh()
     model = serving_model(plan, seed)
     ref, rec = serve_pass("mesh-serve/one-device", model, plan, prompts,
-                          chip)
+                          chip, paged_kernel=False)
     emit(rec)
     del model
     release()
@@ -659,7 +661,7 @@ def phase_mesh_serve(plan: Plan, prompts, seed: int, chip: bool):
     mesh_mod.serving_mesh(4)
     model = serving_model(plan, seed)
     parity = Parity()
-    for name, kw in (("mesh-serve/gather", {}),
+    for name, kw in (("mesh-serve/gather", {"paged_kernel": False}),
                      ("mesh-serve/kernel", {"paged_kernel": True})):
         toks, rec = serve_pass(name, model, plan, prompts, chip, **kw)
         # every weight on all four devices; shard_kv_entry's rule for the

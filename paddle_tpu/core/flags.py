@@ -269,20 +269,6 @@ define_flag("serving_lora_rank", 0,
             "slot wears which are runtime data — registration and "
             "per-slot adapter churn never recompile. Adapter id 0 is "
             "the identity (base weights, token-identical).")
-define_flag("serving_paged_kernel", False,
-            "Pallas paged-attention serving kernels "
-            "(paddle_tpu.ops.paged_attention): the decode step and the "
-            "suffix/chunked prefill programs read K/V directly through "
-            "each slot's block table (scalar-prefetch index maps, online "
-            "softmax, int8 scale pools dequantized in-kernel) instead of "
-            "gathering every lane's full logical context into contiguous "
-            "buffers first. Launch params come from the shared "
-            "per-(kernel, chip, shape-bucket) tuning store "
-            "(benches/TUNED_KERNELS.json). Off-TPU the kernels run in "
-            "the Pallas interpreter. Part of the engine's program key "
-            "like donation/quant flags; 0 (default) keeps the XLA "
-            "gather path bit-identical to PR 12. Parity vs the gather "
-            "path is tolerance-gated — see docs/performance.md.")
 define_flag("serving_lora_adapters", 4,
             "Capacity of the serving LoRA adapter arena: how many "
             "adapters can be registered (live) at once. Row 0 is the "

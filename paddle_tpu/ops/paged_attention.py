@@ -17,11 +17,12 @@ into the scores and probabilities in VMEM —
 Two kernels, same online-softmax core as the training flash kernel
 (:mod:`paddle_tpu.ops.pallas_ops`):
 
-* :func:`paged_decode_attention` — one new token per slot. Grid
-  ``(slots, head-groups, logical blocks)``; each lane's ``positions``
-  entry masks keys past its own context (``start_pos`` semantics of
-  ``engine._PagedCacheView``), and whole blocks past the position are
-  predicated off with ``pl.when``.
+* :func:`paged_decode_attention` — one new token per slot. A lane's
+  context is read in tiles of several pages, double buffered, only the
+  pages that hold a live token (``positions`` is the ``start_pos`` of
+  ``engine._PagedCacheView``; a lane that is not ``active`` reads
+  nothing); all heads of a tile in one pair of matmuls (see "decode"
+  below).
 * :func:`paged_prefill_attention` — a suffix/chunk of queries for ONE
   slot against its table (the ``engine._PrefixPrefillView`` contract):
   query ``i`` sits at global position ``prefix_len + i`` and attends
@@ -32,7 +33,8 @@ Two kernels, same online-softmax core as the training flash kernel
 Block tables, positions and prefix lengths are *runtime data*
 (scalar-prefetch operands): admit/retire/accept/reject churn never
 recompiles — the same invariant the XLA path holds. Launch parameters
-(``block_h`` head grouping, ``block_q`` query tiling) come from the
+(decode's ``pages`` per tile, prefill's ``block_q`` query tiling and
+``block_h`` head grouping) come from the
 shared per-(kernel, chip, shape-bucket) tuning store
 (:mod:`paddle_tpu.ops.tuning`); absent a record the safe defaults run.
 
@@ -42,14 +44,14 @@ path's full-width softmax but associates differently, so parity is
 kernels") for the documented bound and the greedy token-parity gate.
 Off-TPU the kernels run in the Pallas interpreter
 (:func:`~paddle_tpu.ops.pallas_ops._use_interpret`), so tier-1 exercises
-this exact code path on the CPU mesh.
+these exact kernel bodies on the CPU mesh.
 
 SPMD partitioning (ISSUE 16): every public entry takes ``mesh=``. On a
 multi-device mesh the call routes through
 :func:`~paddle_tpu.distributed.sharding_util.headwise_shard_map` —
 ``shard_kv_entry`` already committed the K/V payload pools heads-sharded
 over the "model" axis, so each device runs this SAME kernel on its local
-head shard (the grid's head-group math sees the local ``H``) through the
+head shard (the launch sees the local ``H``) through the
 replicated per-slot block tables, with zero cross-chip K/V traffic; the
 heads-sharded output hands straight to the row-parallel output
 projection's psum. Launch params resolve from the tuning store under the
@@ -71,8 +73,14 @@ if _HAS_PALLAS:
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["available", "paged_decode_attention", "paged_prefill_attention",
-           "paged_full_prefill_attention"]
+__all__ = ["available", "decode_in_place", "paged_decode_attention",
+           "paged_prefill_attention", "paged_full_prefill_attention",
+           "write_token"]
+
+
+#: scoped VMEM the kernels may use (the compiler's default, 16 MiB of the
+#: chip's 128, is 260 KB short of the prefill kernel's tiles at 30 heads)
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def available() -> bool:
@@ -123,12 +131,6 @@ def _local_heads(num_heads: int, mesh) -> int:
     return num_heads // mp if (mp > 1 and num_heads % mp == 0) \
         else num_heads
 
-
-#: query rows per decode tile. Mosaic lowers the heads-batched contraction
-#: only when the lhs has a free (row) dimension, so the slot's single
-#: query is broadcast IN VMEM to one f32 sublane tile; row 0 is written
-#: back. Decode attention is bound by the K/V block reads, not these rows.
-_DECODE_ROWS = 8
 
 #: int8 scale pools stream in whole f32 sublane tiles: a ``(1, bs)`` block
 #: of a ``[num_blocks, bs]`` array is not a legal TPU block shape, an
@@ -192,51 +194,294 @@ def _normalized(l_scr, acc_scr):
 
 
 # ---------------------------------------------------------------- decode
+#
+# Grid ``(lanes,)``; inside a grid step a loop over the lane's LIVE tiles.
+# A tile is ``pages`` pages of K and of V (``_TILE_BYTES`` each), each page
+# an explicit ``make_async_copy`` through the block table into one of two
+# VMEM buffers while the other is computed on: the next tile, or the next
+# live lane's first, is always in flight. Pages past a lane's length are
+# neither copied nor computed, and a lane of length 0 (not active) costs
+# nothing.
+#
+# The kernel sees a pool as page SLABS ``[num_blocks, block_size*H, D]``,
+# a view of the ``[num_blocks, block_size, H, D]`` pool that moves no
+# byte: the chip keeps a pool whose head count fills whole sublane groups
+# token-major (a slab's rows are ``(token, head)``), and one whose head
+# count does not (30 heads: Olmo-Hybrid) head-major (``(head, token)``:
+# no padding) — :func:`_head_major` mirrors that choice, and a wrong guess
+# costs a copy, not a wrong answer. A slab's rows always fill whole
+# sublane tiles, so any head count is sliced and copied the same way.
+#
+# A tile in VMEM is ``[pages*block_size*H, D]``: ONE matmul operand for
+# all heads. ``q [H, D] . K^T`` gives every head's query against every
+# head's keys, ``[H, rows]``, of which row h wants only the columns of
+# head h. The rest is masked off with a constant bias and the masked
+# probabilities multiply ``V [rows, D]`` directly: the zeros pick each
+# head's own values. The MXU streams every K and V byte once either way;
+# nothing is transposed and no head is handled alone.
+
+#: bytes of K (and of V) a decode tile aims for: large enough that the
+#: per-tile and per-copy costs are small beside the HBM time, small
+#: enough that two buffers of each, the scores and the bias fit VMEM
+_TILE_BYTES = 1 << 20
 
 
-def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, blk_h,
-                   scale, quantized):
-    """One (slot, head-group, logical-block) step: online softmax of the
-    slot's single query against one physical KV block, masked to keys at
-    global index ``<= positions[slot]``."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        (o_ref, m_scr, l_scr, acc_scr), ks_ref, vs_ref = rest, None, None
+def _tile_pages(max_blocks, page_bytes, pages=None) -> int:
+    """Pages per decode tile: ``pages`` if given (the tuning store, a
+    test), else what fills ``_TILE_BYTES``; a power of two of at least 8
+    (so a tile's rows fill whole lane tiles of the scores at every head
+    count) unless the table itself is shorter."""
+    n = int(pages) if pages else max(8, _TILE_BYTES // max(page_bytes, 1))
+    n = 1 << (max(n, 1).bit_length() - 1)
+    return max(1, min(n, 1 << (max(max_blocks, 1) - 1).bit_length()))
+
+
+def _head_major(num_heads: int) -> bool:
+    """Whether the chip keeps a ``[blocks, block_size, H, D]`` pool with
+    the heads outside the tokens (what XLA's TPU layout does when H does
+    not fill whole 8-row sublane groups and would have to be padded:
+    ``{3,1,2,0}`` for 30 heads, ``{3,2,1,0}`` for 8, 16, 24, 32; 4 heads
+    get a 4-row tile and stay token-major)."""
+    return num_heads % 4 != 0
+
+
+def decode_in_place(head_dim: int) -> bool:
+    """Whether the decode kernel reads a pool of this head dim where it
+    lies. A page is sliced out of the pool in whole 128-lane tiles; a
+    narrower head dim (64: GPT 124M) is served from a lane-padded COPY of
+    the pool, made every call: correct, and not what a default should
+    choose."""
+    return head_dim % _LANES == 0
+
+
+def _page_slabs(pool):
+    """``[NB, bs, H, D]`` -> ``[NB, bs*H, D']``, rows ``(head, token)`` if
+    the pool lies head-major (:func:`_head_major`) else ``(token, head)``;
+    ``D'`` is D padded with zeros to whole lane tiles
+    (:func:`decode_in_place`)."""
+    nb, bs, h, d = pool.shape
+    if _head_major(h):
+        pool = jnp.swapaxes(pool, 1, 2)
+    pool = pool.reshape(nb, bs * h, d)
+    return jnp.pad(pool, ((0, 0), (0, 0), (0, -d % _LANES)))
+
+
+def write_token(pool, blocks, offsets, rows):
+    """One new token a lane into a full-precision pool, where the pool
+    lies: ``rows`` ``[S, H, D]`` go to token ``offsets[s]`` of block
+    ``blocks[s]`` (``pool.at[blocks, offsets].set(rows)``). XLA's scatter
+    wants its ``(H, D)`` window on the minor dims: in a token-major pool
+    it is, and the plain scatter runs in place; a head-major pool it
+    copies to a token-major layout and back to make it so (24 ms a decode
+    step at Olmo-Hybrid's shapes), so there each head's row is scattered
+    on its own into the :func:`_page_slabs` view, where one row is
+    minor-most. Returns the pool, same shape."""
+    nb, bs, h, d = pool.shape
+    if not _head_major(h):  # the window is minor already: 4 us a pool
+        return pool.at[blocks, offsets].set(rows)
+    at = jnp.arange(h, dtype=offsets.dtype)[None, :] * bs + offsets[:, None]
+    slab = jnp.swapaxes(pool, 1, 2).reshape(nb, h * bs, d)
+    slab = slab.at[blocks[:, None], at].set(rows)
+    return jnp.swapaxes(slab.reshape(nb, h, bs, d), 1, 2)
+
+
+def _tile_columns(pages, bs, heads, hq, head_major):
+    """What every tile's mask is made of (host constants): the bias
+    ``[hq, cols]`` that leaves query row h only the columns of head h, and
+    the token within the tile ``[1, cols]`` of each column, for the row
+    order of :func:`_page_slabs`."""
+    import numpy as np
+
+    r = np.arange(bs * heads)
+    head, tok = (r // bs, r % bs) if head_major else (r % heads, r // heads)
+    head = np.tile(head, pages)
+    tok = (np.arange(pages)[:, None] * bs + tok[None, :]).reshape(-1)
+    bias = np.where(head[None, :] == np.arange(hq)[:, None], 0.0, NEG_INF)
+    return bias.astype(np.float32), tok.astype(np.int32)[None, :]
+
+
+def _decode_kernel(bt_ref, len_ref, nxt_ref, q_ref, bias_ref, tok_ref,
+                   k_hbm, v_hbm, *rest, bs, pages, scale, quantized):
+    """One lane (grid step): its single query against its live tiles, the
+    pages copied one tile ahead, across lanes too."""
+    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    o_ref, kbuf, vbuf, sem, slot_ref = rest[-5:]
     s = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    n_lanes = pl.num_programs(0)
+    t_tile = pages * bs
+    hq, d = q_ref.shape[1], q_ref.shape[2]
+    rows = kbuf.shape[2]
+    cols = pages * rows
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def each_live_page(lane, j, slot, act):
+        """``act(K copy, V copy)`` for every page of tile ``j`` of ``lane``
+        that holds a live token."""
+        for i in range(pages):
+            page = j * pages + i
 
-    pos = pos_ref[s]
+            @pl.when(page * bs < len_ref[lane])
+            def _():
+                block = bt_ref[lane, page]
+                act(pltpu.make_async_copy(k_hbm.at[block], kbuf.at[slot, i],
+                                          sem.at[0, slot]),
+                    pltpu.make_async_copy(v_hbm.at[block], vbuf.at[slot, i],
+                                          sem.at[1, slot]))
 
-    # whole blocks past the lane's position contribute nothing — skip the
-    # math (the masked-lane/garbage-query cases still produce finite
-    # output: key 0 is always <= pos, so the denominator never zeroes)
-    @pl.when(j * bs <= pos)
-    def _step():
-        q = q_ref[0]  # [blk_h, 1, D]
-        q = jnp.broadcast_to(q, (blk_h, _DECODE_ROWS, q.shape[-1]))
-        block = bt_ref[s, j]
-        gk = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
-        _attend_block(
-            q, k_ref[0], v_ref[0],
-            _scale_row(ks_ref, block) if quantized else None,
-            _scale_row(vs_ref, block) if quantized else None,
-            gk <= pos, scale, m_scr, l_scr, acc_scr)
+    def start(lane, j, slot):
+        each_live_page(lane, j, slot, lambda ck, cv: (ck.start(), cv.start()))
 
-    @pl.when(j == nj - 1)
-    def _fin():
-        o_ref[0] = _normalized(l_scr, acc_scr)[:, 0:1, :].astype(o_ref.dtype)
+    def wait(lane, j, slot):
+        each_live_page(lane, j, slot, lambda ck, cv: (ck.wait(), cv.wait()))
+
+    @pl.when(s == 0)
+    def _first():
+        # rows of a half-filled tile that were never copied are multiplied
+        # by zero probabilities: they must be finite, so start from zeros
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+
+        @pl.when(nxt_ref[0] < n_lanes)
+        def _():
+            start(nxt_ref[0], 0, 0)
+
+    length = len_ref[s]
+    n_tiles = (length + t_tile - 1) // t_tile
+
+    @pl.when(n_tiles == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tiles > 0)
+    def _live():
+        q = q_ref[0]
+        slot0 = slot_ref[0]
+
+        def tile(j, carry):
+            m_prev, l_prev, acc = carry
+            slot = (slot0 + j) % 2
+
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                start(s, j + 1, 1 - slot)
+
+            @pl.when(j + 1 == n_tiles)
+            def _():
+                nxt = nxt_ref[s + 1]
+
+                @pl.when(nxt < n_lanes)
+                def _():
+                    start(nxt, 0, 1 - slot)
+
+            wait(s, j, slot)
+            k = kbuf[slot].reshape(cols, d)
+            v = vbuf[slot].reshape(cols, d)
+            if quantized:  # int8 values are exact in every compute dtype
+                k, v = k.astype(q.dtype), v.astype(q.dtype)
+                at = (pl.multiple_of(j * cols, _LANES) if cols % _LANES == 0
+                      else j * cols)
+            sc = jax.lax.dot_general(  # [hq, cols]: all heads x all heads
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quantized:
+                # per-token scales fold into the score columns and the
+                # probabilities: q.(k*s) == (q.k)*s, p@(v*s) == (p*s)@v
+                sc = sc * ks_ref[0, :, pl.ds(at, cols)]
+            sc = jnp.where(tok_ref[...] < length - j * t_tile,
+                           sc + bias_ref[...], NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            if quantized:
+                p = p * vs_ref[0, :, pl.ds(at, cols)]
+            pv = jax.lax.dot_general(  # [hq, d]: the zeros pick own head
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc * corr + pv
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_tiles, tile,
+            (jnp.full((hq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((hq, 1), jnp.float32),
+             jnp.zeros((hq, d), jnp.float32)))
+        slot_ref[0] = (slot0 + n_tiles) % 2
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, entry, block_tables, positions,
-                           block_h=None, mesh=None):
+@functools.partial(jax.jit, static_argnames=("pages",))
+def _decode_call(q, entry, block_tables, lengths, pages):
+    """The kernel launch on one device's heads (``lengths`` ``[S]``: live
+    tokens per lane, 0 for a lane that is not active). Jitted so that a
+    model's layers, which call it with the same shapes, share ONE traced
+    and lowered kernel inside the step program (24 tracings of the kernel
+    cost the serving cell a minute of set-up). ``pages``: the tuned tile
+    size, or a falsy value for the default."""
+    S, H, D = q.shape
+    quantized = len(entry) == 4
+    NB, bs = entry[0].shape[:2]
+    MB = block_tables.shape[1]
+    head_major = _head_major(H)
+    kp, vp = (_page_slabs(pool) for pool in entry[:2])
+    rows, Dp = bs * H, kp.shape[2]
+    hq = -(-H // 8) * 8  # query rows: whole f32 sublane tiles
+    pages = _tile_pages(MB, rows * Dp * kp.dtype.itemsize, pages)
+    cols = pages * rows
+    # the copies run one tile ahead, across lanes: nxt[0] is the first live
+    # lane, nxt[s + 1] the next after lane s, S where there is none
+    lengths = lengths.astype(jnp.int32)
+    lane = jnp.arange(S, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(lengths > 0, lane, S), axis=0,
+                         reverse=True)
+    nxt = jnp.concatenate([nxt, jnp.full((1,), S, jnp.int32)])
+    bias, tok = _tile_columns(pages, bs, H, hq, head_major)
+    q_spec = pl.BlockSpec((1, hq, Dp), lambda s, *_: (s, 0, 0))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda s, *_: (0,) * a.ndim)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, whole(bias), whole(tok), any_spec, any_spec]
+    args = [block_tables, lengths, nxt,
+            jnp.pad(q, ((0, 0), (0, hq - H), (0, Dp - D))), bias, tok, kp, vp]
+    if quantized:
+        # per-token scales of each lane's table, laid out like a tile's
+        # columns so they multiply score columns as they lie (a
+        # [S, MB*bs] gather: 1/(H*D) of the payload)
+        n_tiles = -(-MB // pages)
+
+        def expand(pool):
+            page = jnp.pad(pool[block_tables],
+                           ((0, 0), (0, n_tiles * pages - MB), (0, 0)))
+            page = (jnp.broadcast_to(page[:, :, None, :],
+                                     page.shape[:2] + (H, bs))
+                    if head_major else jnp.repeat(page, H, axis=2))
+            return page.reshape(S, 1, n_tiles * cols)
+
+        in_specs += [pl.BlockSpec((1, 1, n_tiles * cols),
+                                  lambda s, *_: (s, 0, 0))] * 2
+        args += [expand(entry[2]), expand(entry[3])]
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, bs=bs, pages=pages,
+                          scale=1.0 / math.sqrt(D), quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(S,), in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, rows, Dp), kp.dtype),
+                pltpu.VMEM((2, pages, rows, Dp), vp.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),  # buffer of the next tile
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, hq, Dp), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_use_interpret(),
+        name="paged_decode",
+    )(*args)
+    return out[:, :H, :D]
+
+
+def paged_decode_attention(q, entry, block_tables, positions, active=None,
+                           pages=None, mesh=None):
     """Decode attention straight through the block tables.
 
     ``q`` is ``[S, H, D]`` (each slot's new token, heads unflattened);
@@ -247,98 +492,61 @@ def paged_decode_attention(q, entry, block_tables, positions,
     gather path is never materialized). ``block_tables`` is ``[S, MB]``
     int32, ``positions`` ``[S]`` int32 (the new token's write position —
     keys at global index ``<= positions[s]`` are attended, matching
-    ``masked_attention``'s mask in ``_PagedCacheView``). Returns
-    ``[S, H, D]`` in ``q.dtype``. All table/position operands are
-    runtime data: one compiled program serves every churn pattern.
+    ``masked_attention``'s mask in ``_PagedCacheView``). ``active``
+    (``[S]`` bool, default all) marks the lanes that hold a request: a
+    lane that does not reads no page and returns zeros. Returns
+    ``[S, H, D]`` in ``q.dtype``. Tables, positions and ``active`` are
+    runtime data: one compiled program serves every churn pattern, and a
+    lane costs the pages it has live. ``pages`` (pages per tile) is a
+    launch parameter; None asks the tuning store, then ``_TILE_BYTES``.
     On a multi-device ``mesh`` the call runs per model-shard (module
     docstring, "SPMD partitioning")."""
+    lengths = positions.astype(jnp.int32) + 1
+    if active is not None:
+        lengths = jnp.where(active, lengths, 0)
     if _mesh_routes(mesh):
-        return _sharded_decode(q, entry, block_tables, positions,
-                               block_h, mesh)
-    S, H, D = q.shape
-    quantized = len(entry) == 4
-    kp, vp = entry[0], entry[1]
-    bs = kp.shape[1]
-    MB = block_tables.shape[1]
-    if block_h is None:
-        from . import tuning
-
-        rec = tuning.lookup("paged_decode",
-                            tuning.bucket_key(h=H, d=D, bs=bs, mb=MB))
-        block_h = rec.get("block_h") if rec else None
-    blk_h = _head_group(H, block_h)
-    grid = (S, H // blk_h, MB)
-    kern = functools.partial(_decode_kernel, bs=bs, blk_h=blk_h,
-                             scale=1.0 / math.sqrt(D), quantized=quantized)
-    # [S, H, 1, D] view: the (1, D) trailing block dims equal the array's,
-    # so any head grouping is a legal block, and the kernel reads its
-    # query head-major without a transpose
-    q_spec = pl.BlockSpec((1, blk_h, 1, D),
-                          lambda s, g, j, bt, pos: (s, g, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, blk_h, D),
-                           lambda s, g, j, bt, pos: (bt[s, j], 0, g, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [block_tables, positions, q[:, :, None, :], kp, vp]
-    if quantized:
-        in_specs += [_scale_spec(
-            bs, lambda s, g, j, bt, pos: bt[s, j])] * 2
-        args += [entry[2], entry[3]]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((blk_h, _DECODE_ROWS, _LANES), jnp.float32),  # max
-            pltpu.VMEM((blk_h, _DECODE_ROWS, _LANES), jnp.float32),  # denom
-            pltpu.VMEM((blk_h, _DECODE_ROWS, D), jnp.float32),  # out acc
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_use_interpret(),
-        name="paged_decode",
-    )(*args)
-    return out[:, :, 0, :]
+        return _sharded_decode(q, entry, block_tables, lengths, pages, mesh)
+    if pages is None:
+        pages = _tuned_pages(q.shape[1], q.shape[2], entry[0].shape[1],
+                             block_tables.shape[1])
+    return _decode_call(q, entry, block_tables, lengths, pages)
 
 
-def _sharded_decode(q, entry, block_tables, positions, block_h, mesh):
+def _tuned_pages(heads, dim, block_size, max_blocks, mesh_key=None):
+    """The tuning store's tile size for this launch (``heads``: what one
+    device launches with), or 0: the default."""
+    from . import tuning
+
+    rec = tuning.lookup(
+        "paged_decode",
+        tuning.bucket_key(h=heads, d=dim, bs=block_size, mb=max_blocks),
+        mesh=mesh_key)
+    return (rec or {}).get("pages") or 0
+
+
+def _sharded_decode(q, entry, block_tables, lengths, pages, mesh):
     """Per-shard decode: resolve launch params OUTSIDE the manual region
     under the mesh-topology tuning key (against the LOCAL head count each
     device actually launches with), then map the plain kernel over the
-    mesh — heads-sharded q/K/V in, replicated tables/positions/scales
+    mesh — heads-sharded q/K/V in, replicated tables/lengths/scales
     through, heads-sharded output back."""
     from ..distributed.sharding_util import (headwise_shard_map,
                                              mesh_axes_key)
 
     S, H, D = q.shape
-    if block_h is None:
-        from . import tuning
-
-        rec = tuning.lookup(
-            "paged_decode",
-            tuning.bucket_key(h=_local_heads(H, mesh), d=D,
-                              bs=entry[0].shape[1],
-                              mb=block_tables.shape[1]),
-            mesh=mesh_axes_key(mesh))
-        block_h = (rec or {}).get("block_h") or 0
+    if pages is None:
+        pages = _tuned_pages(_local_heads(H, mesh), D, entry[0].shape[1],
+                             block_tables.shape[1], mesh_axes_key(mesh))
     n = len(entry)
 
     def kernel(q, *rest):
-        # block_h=0 means "safe default, no store lookup" to the plain
-        # entry point — the mesh-keyed lookup above already ran
-        return paged_decode_attention(q, rest[:n], rest[n], rest[n + 1],
-                                      block_h=block_h or 0)
+        return _decode_call(q, rest[:n], rest[n], rest[n + 1], pages)
 
     mapped = headwise_shard_map(
         kernel, mesh,
         in_head_dims=(1, 2, 2) + (None,) * (n - 2) + (None, None),
         out_head_dim=1, num_heads=H)
-    return mapped(q, *entry, block_tables, positions)
+    return mapped(q, *entry, block_tables, lengths)
 
 
 # --------------------------------------------------------------- prefill
@@ -453,7 +661,8 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, sq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="paged_prefill",
     )(*args)

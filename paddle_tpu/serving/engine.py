@@ -212,13 +212,15 @@ class _PagedCacheView:
     ``(k, v, k_scale, v_scale)`` (quantize-on-scatter / dequant-on-attend
     via :func:`_scatter_rows` / :func:`_gather_ctx`).
 
-    With ``kernel=True`` (``FLAGS_serving_paged_kernel``, captured at
-    engine construction like the quant/donation flags) the attend side
+    With ``kernel=True`` (the engine's ``decode_kernel``: asked for, or
+    the default on a device where the kernel compiles natively; captured
+    at engine construction like the quant/donation flags) the attend side
     routes through the Pallas paged-decode kernel
     (:func:`paddle_tpu.ops.paged_attention.paged_decode_attention`):
     K/V are read directly through the block table — no gather into a
     contiguous ``[S, max_blocks*bs, H, D]`` buffer, int8 dequant fused
-    in-kernel. The scatter of the new token stays in XLA either way
+    in-kernel, a lane costing the pages it has live and a lane that is
+    not ``active`` none. The scatter of the new token stays in XLA either way
     (one row per lane — there is no gather to kill there). ``kernel`` is
     trace-time *structure*: toggling it is a different engine build,
     never a mid-run branch. ``mesh`` rides the same way (ISSUE 16): on a
@@ -250,12 +252,23 @@ class _PagedCacheView:
         row = self.block_tables[jnp.arange(s_lanes), pos // bs]
         row = jnp.where(self.active, row, 0)
         off = pos % bs
-        entry = _scatter_rows(self.entry, row, off, ka[:, 0], va[:, 0])
+        if self.kernel and len(self.entry) == 2 and self.mesh is None:
+            from ..ops.paged_attention import write_token
+
+            # the same write as _scatter_rows, made in place whichever
+            # way the chip lays the pool out, as the kernel reads it (an
+            # int8 entry quantizes on scatter, and a pool sharded over
+            # heads keeps its head axis, below)
+            entry = tuple(write_token(pool, row, off, new[:, 0])
+                          for pool, new in zip(self.entry, (ka, va)))
+        else:
+            entry = _scatter_rows(self.entry, row, off, ka[:, 0], va[:, 0])
         if self.kernel:
             from ..ops.paged_attention import paged_decode_attention
 
             o = paged_decode_attention(qa[:, 0], entry,
                                        self.block_tables, pos,
+                                       active=self.active,
                                        mesh=self.mesh)[:, None]
         else:
             # gather each lane's logical context [S, max_blocks*bs, H, D]
@@ -498,13 +511,16 @@ class ServingConfig:
     # identity (base weights, token-identical to an arena-less engine).
     lora_rank: Optional[int] = None
     lora_adapters: Optional[int] = None
-    # Pallas paged-attention kernels (None defers to
-    # FLAGS_serving_paged_kernel; default off = the XLA gather path,
-    # bit-preserved). Captured at construction like the quant trio —
-    # part of the engine's program key: toggling builds fresh
-    # executables whose decode/suffix-prefill attention reads K/V
-    # directly through the block tables (ops.paged_attention) instead
-    # of gathering the context into contiguous buffers.
+    # Pallas paged-attention kernels. None (the default) is resolved at
+    # construction from the device: the decode step's "kv" layers read
+    # K/V through the block tables with the paged decode kernel where it
+    # compiles natively (a TPU backend) and reads the pools where they
+    # lie (head_dim a multiple of 128), and take the XLA gather where it
+    # would run interpreted (CPU); prefill keeps the XLA path. True: every
+    # kernel route (decode, prefill, suffix/chunked prefill), raising when
+    # Pallas is missing. False: XLA everywhere. Captured at construction
+    # like the quant trio — part of the engine's program key; the route
+    # the decode step was built with is `kernel.paged` / kernel_route().
     paged_kernel: Optional[bool] = None
     # device mesh (ISSUE 14): None defers to the globally installed mesh
     # (distributed.mesh.get_mesh() — e.g. serving_mesh(mp, dp)). Captured
@@ -653,9 +669,19 @@ class ServingEngine:
                                       or flags.flag("serving_prefill_bucket_min"))
         self.donate = (bool(flags.flag("decode_donate"))
                        if cfg.donate is None else bool(cfg.donate))
-        self.paged_kernel = (bool(flags.flag("serving_paged_kernel"))
-                             if cfg.paged_kernel is None
-                             else bool(cfg.paged_kernel))
+        # `paged_kernel`: the prefill routes (asked for outright only);
+        # `decode_kernel`: the decode step's route, which the default
+        # takes from the device (see ServingConfig.paged_kernel)
+        self.paged_kernel = bool(cfg.paged_kernel)
+        if cfg.paged_kernel is None:
+            from ..ops import paged_attention, pallas_ops
+
+            # natively compiled, and reading the pools where they lie
+            self.decode_kernel = not pallas_ops._use_interpret() and all(
+                paged_attention.decode_in_place(st.head_dim)
+                for st in kv_layers)
+        else:
+            self.decode_kernel = self.paged_kernel
         # the mesh the kernel calls route through (ISSUE 16): on a
         # multi-device mesh every kernel call runs per model-shard via
         # paged_attention's headwise_shard_map wrapper — the pools are
@@ -664,18 +690,20 @@ class ServingEngine:
         # pallas path there is bit-identical to PR 13 by construction.
         # Trace-time STRUCTURE like `kernel` itself, never a traced branch.
         self._kernel_mesh = None
-        if self.paged_kernel:
+        if self.decode_kernel:
             from ..ops import paged_attention
 
             if not paged_attention.available():
-                # the kernel was asked for (config or flag): serving the
-                # gather path instead would hide that from every counter
-                # and every measurement made of this engine
+                # the kernel is this engine's route (asked for, or the
+                # default on this device): serving the gather path
+                # instead would hide that from every counter and every
+                # measurement made of this engine
                 raise RuntimeError(
-                    "paged_kernel requested but the Pallas paged-attention "
-                    "kernels are unavailable here (no scalar-prefetch "
-                    "support); the engine does not fall back to the XLA "
-                    "gather path")
+                    "the paged-attention kernel route (paged_kernel="
+                    f"{cfg.paged_kernel!r} on this device) needs the "
+                    "Pallas paged-attention kernels, which are "
+                    "unavailable here (no scalar-prefetch support); the "
+                    "engine does not fall back to the XLA gather path")
             if self._mesh_devices > 1:
                 self._kernel_mesh = self.mesh
         self._retry = cfg.retry_policy
@@ -829,7 +857,7 @@ class ServingEngine:
         metrics.set_gauge("mesh.devices", self._mesh_devices)
         metrics.set_gauge("mesh.model_axis", self._mesh_model)
         metrics.set_gauge("mesh.data_axis", self._mesh_data)
-        metrics.set_gauge("kernel.paged", int(self.paged_kernel))
+        metrics.set_gauge("kernel.paged", int(self.decode_kernel))
         # the EFFECTIVE attention route x mesh topology (ISSUE 16), per
         # arena namespace: "kernel@data1.model4", "gather@single", ... A
         # fallback (Pallas unavailable, flag off) is observable here
@@ -838,7 +866,7 @@ class ServingEngine:
         metrics.set_gauge("kernel.mesh", self.kernel_route())
         for ns in ["primary"] + self.arena.namespaces():
             metrics.set_gauge(f"kernel.mesh.{ns}", self.kernel_route())
-        if self.paged_kernel:
+        if self.decode_kernel:
             from ..ops import tuning as kernel_tuning
 
             # the tuning store's coverage for this chip, next to the mode
@@ -1291,7 +1319,7 @@ class ServingEngine:
         lora = self.lora
         kinds = self._layer_kinds
         bs = self.block_size
-        use_kernel = self.paged_kernel
+        use_kernel = self.decode_kernel
         kmesh = self._kernel_mesh
         mesh = self.mesh
 
@@ -2170,12 +2198,13 @@ class ServingEngine:
     def kernel_route(self) -> str:
         """The effective attention route x mesh topology this engine was
         BUILT with — ``"kernel@data1.model4"``, ``"gather@single"``, ...
-        (the ``kernel.mesh`` gauge). "kernel" means every decode /
-        prefill / spec sub-step reads K/V through the Pallas paged
-        kernels (per model-shard on a multi-device mesh); "gather" is the
-        XLA fallback. Construction-time structure, so a silent fallback
-        shows up here, not as a mystery step-time regression."""
-        route = "kernel" if self.paged_kernel else "gather"
+        (the ``kernel.mesh`` gauge). "kernel" means the decode step and
+        the spec sub-steps read K/V through the Pallas paged decode
+        kernel (per model-shard on a multi-device mesh), whether that was
+        asked for or is the default on this device; "gather" is the XLA
+        path. Construction-time structure, so the route a run took is
+        in its record, not inferred from step times."""
+        route = "kernel" if self.decode_kernel else "gather"
         topo = ("single" if self.mesh is None else
                 ".".join(f"{a}{int(self.mesh.shape[a])}"
                          for a in self.mesh.axis_names))
@@ -2238,7 +2267,7 @@ class ServingEngine:
                "mesh.key": self.mesh_key,
                "mesh.model_axis": self._mesh_model,
                "mesh.data_axis": self._mesh_data,
-               "kernel.paged": int(self.paged_kernel),
+               "kernel.paged": int(self.decode_kernel),
                "kernel.mesh": self.kernel_route(),
                "quant.weights": int(self.quant_weights),
                "quant.kv": int(self.quant_kv),

@@ -81,11 +81,11 @@ Counter namespaces:
   (0/1 mode), ``host_bytes`` / ``host_entries`` / ``disk_bytes`` /
   ``disk_entries`` (occupancy)
 * ``kernel.*``     — the Pallas paged-attention serving kernels
-  (``FLAGS_serving_paged_kernel``, ``ops.paged_attention``):
+  (``ServingConfig.paged_kernel``, ``ops.paged_attention``):
   trace-time counters ``decode_traces`` / ``prefill_traces`` /
   ``verify_traces`` (the kernel twins of the engine's no-recompile
   counters — churn must never re-lower a kernel), plus the gauges
-  ``kernel.paged`` (0/1 mode) and ``kernel.tuned_entries`` (tuning-store
+  ``kernel.paged`` (0/1: the route the decode step was built with) and ``kernel.tuned_entries`` (tuning-store
   records for this chip — ``ops.tuning`` / benches/TUNED_KERNELS.json)
 
 * ``state.*``      — the slot-indexed store of recurrent-layer state

@@ -328,13 +328,13 @@ class SpecDecoder:
         k = self.k
         bs = engine.block_size
 
-        use_kernel = engine.paged_kernel
+        use_kernel = engine.decode_kernel
         kmesh = engine._kernel_mesh
 
         def _fwd(m, objs, arrays, pools, bt, positions, toks, act):
             """One single-token model forward — same ops, shapes and view
             class as ``ServingEngine._get_step``'s body, head excluded
-            (``kernel=`` rides along: under FLAGS_serving_paged_kernel
+            (``kernel=`` rides along: on the engine's kernel route
             every draft/verify sub-step reads K/V through the block
             tables via the Pallas paged-decode kernel too, and ``mesh=``
             with it — on a multi-device mesh the sub-steps run the

@@ -78,28 +78,111 @@ def test_chip_backend_never_interprets(monkeypatch):
         paddle.set_flags(keep)
 
 
-@pytest.mark.parametrize("how", ["config", "flag"])
-def test_kernel_asked_for_but_unavailable_raises(model, monkeypatch, how):
+def test_kernel_asked_for_but_unavailable_raises(model, monkeypatch):
     from paddle_tpu.ops import paged_attention
     from paddle_tpu.serving import ServingConfig
     from paddle_tpu.serving.engine import ServingEngine
 
     monkeypatch.setattr(paged_attention, "available", lambda: False)
-    kw = dict(num_slots=2, max_model_len=64)
-    if how == "flag":
-        keep = paddle.get_flags("serving_paged_kernel")
-        paddle.set_flags({"serving_paged_kernel": True})
-    else:
-        keep, kw = None, dict(kw, paged_kernel=True)
-    try:
-        with pytest.raises(RuntimeError, match="does not fall back"):
-            ServingEngine(model, ServingConfig(**kw))
-    finally:
-        if keep:
-            paddle.set_flags(keep)
-    # the default (gather) engine is untouched by a missing kernel
+    with pytest.raises(RuntimeError, match="does not fall back"):
+        ServingEngine(model, ServingConfig(num_slots=2, max_model_len=64,
+                                           paged_kernel=True))
+    # the default engine, which takes the gather here, is untouched by a
+    # missing kernel
     assert ServingEngine(model, ServingConfig(
         num_slots=2, max_model_len=64)).stats()["kernel.paged"] == 0
+
+
+@pytest.fixture(scope="module")
+def wide_head_model():
+    """One head of 128: the decode kernel reads such a pool where it
+    lies (``paged_attention.decode_in_place``), as at the served widths."""
+    from paddle_tpu.models.gpt import GPTConfig
+
+    paddle.seed(0)
+    m = GPTForCausalLM(GPTConfig(vocab_size=1024, hidden_size=128,
+                                 num_layers=2, num_heads=1,
+                                 max_position_embeddings=256))
+    m.eval()
+    return m
+
+
+def _built_route(model, **kw):
+    """(kernel.paged, kernel_route()) of an engine as constructed: no step
+    is traced (Mosaic has no CPU lowering to trace one with)."""
+    from paddle_tpu.serving import ServingConfig
+    from paddle_tpu.serving.engine import ServingEngine
+
+    eng = ServingEngine(model, ServingConfig(num_slots=2, max_model_len=64,
+                                             **kw))
+    return eng.stats()["kernel.paged"], eng.kernel_route()
+
+
+@pytest.mark.parametrize("asked,backend,paged,route", [
+    (None, "cpu", 0, "gather@"), (None, "tpu", 1, "kernel@"),
+    (False, "cpu", 0, "gather@"), (False, "tpu", 0, "gather@"),
+    (True, "cpu", 1, "kernel@"), (True, "tpu", 1, "kernel@"),
+], ids=lambda v: str(v))
+def test_decode_route_is_chosen_from_the_device(wide_head_model, monkeypatch,
+                                                asked, backend, paged,
+                                                route):
+    """The default takes the kernel where it compiles natively and the
+    gather where it would run interpreted; True and False do not look at
+    the device. Asserted at construction from the engine's own record."""
+    if backend == "tpu":
+        _live_tpu(monkeypatch)
+    got = _built_route(wide_head_model, paged_kernel=asked)
+    assert got[0] == paged and got[1].startswith(route)
+
+
+def test_default_route_wants_the_pools_read_in_place(model, monkeypatch):
+    """Heads of 32 (``gpt_tiny``): the kernel would serve them from a
+    lane-padded copy of the pools, so the default stays on the gather even
+    on a chip; asked for outright it is built."""
+    _live_tpu(monkeypatch)
+    assert _built_route(model) == (0, "gather@single")
+    assert _built_route(model, paged_kernel=True) == (1, "kernel@single")
+
+
+@pytest.mark.parametrize("quant_kv", [False, True], ids=["bf16", "int8"])
+def test_default_route_on_a_chip_covers_both_arenas(wide_head_model,
+                                                    monkeypatch, quant_kv):
+    """An int8 arena takes the default route too (the kernel streams the
+    scales): the output check's control engine runs what it measures."""
+    _live_tpu(monkeypatch)
+    got = _built_route(wide_head_model, quant_kv=quant_kv)
+    assert got[0] == 1 and got[1].startswith("kernel@")
+
+
+@pytest.mark.parametrize("asked", [None, True], ids=["default", "asked"])
+def test_kernel_route_on_a_chip_never_falls_back(wide_head_model,
+                                                 monkeypatch, asked):
+    """On a chip the kernel route, default or asked for, raises when the
+    kernels are missing; only False gives the gather there."""
+    from paddle_tpu.ops import paged_attention
+
+    _live_tpu(monkeypatch)
+    monkeypatch.setattr(paged_attention, "available", lambda: False)
+    with pytest.raises(RuntimeError, match="does not fall back"):
+        _built_route(wide_head_model, paged_kernel=asked)
+    assert _built_route(wide_head_model,
+                        paged_kernel=False) == (0, "gather@single")
+
+
+def test_default_route_keeps_prefill_on_the_xla_path(wide_head_model,
+                                                     monkeypatch):
+    """The default moves the decode step alone: the prefill and
+    suffix-prefill views take the kernels only when asked outright."""
+    from paddle_tpu.serving import ServingConfig
+    from paddle_tpu.serving.engine import ServingEngine
+
+    _live_tpu(monkeypatch)
+    model = wide_head_model
+    eng = ServingEngine(model, ServingConfig(num_slots=2, max_model_len=64))
+    assert eng.decode_kernel and not eng.paged_kernel
+    eng = ServingEngine(model, ServingConfig(num_slots=2, max_model_len=64,
+                                             paged_kernel=True))
+    assert eng.decode_kernel and eng.paged_kernel
 
 
 def test_live_model_payload_from_a_chip_parent_raises(model, monkeypatch):

@@ -2,7 +2,7 @@
 parity of :mod:`paddle_tpu.ops.paged_attention` against the XLA gather
 baseline (``engine._gather_ctx`` + ``serving_seam.masked_attention``), the shared
 kernel-tuning store (:mod:`paddle_tpu.ops.tuning`), and the engine
-integration behind ``FLAGS_serving_paged_kernel``.
+integration behind ``ServingConfig.paged_kernel``.
 
 Parity policy (docs/performance.md "Paged attention kernels"): the
 kernels' online softmax associates differently from the gather path's
@@ -103,20 +103,82 @@ def test_decode_parity_permuted_partial_tables(dtype, quantized):
         **_tol(dtype))
 
 
-def test_decode_parity_every_head_grouping():
-    """block_h is a pure launch parameter: every legal grouping computes
-    the same attention (the autotuner can never change results)."""
+def test_decode_parity_every_tile_size():
+    """The tile's page count is a pure launch parameter: every size
+    computes the same attention (the autotuner can never change
+    results), a table that is not a whole number of tiles included."""
     rng = np.random.default_rng(1)
-    S, H, D, NB, bs, MB = 3, 4, 16, 11, 4, 3
+    S, H, D, NB, bs, MB = 3, 4, 16, 23, 4, 5
     entry = _pools(rng, NB, bs, H, D)
     q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
     bt = jnp.asarray(rng.integers(1, NB, (S, MB)), jnp.int32)
-    pos = jnp.asarray([2, 7, 11], jnp.int32)
+    pos = jnp.asarray([2, 7, 19], jnp.int32)
     ref = _decode_ref(q, entry, bt, pos)
-    for g in (1, 2, 4):
-        out = pk.paged_decode_attention(q, entry, bt, pos, block_h=g)
+    for pages in (1, 2, 4, 8):
+        out = pk.paged_decode_attention(q, entry, bt, pos, pages=pages)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    **_tol("float32"))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["full", "int8"])
+@pytest.mark.parametrize("heads", [16, 30])
+def test_decode_tiles_ragged_lanes(heads, quantized, dtype):
+    """Tiles of 2 pages over ragged lanes, at both head counts the
+    benchmark serves (30 does not fill whole sublane tiles): a lane of one
+    token, one ending exactly on a page edge, one on a tile edge, one at
+    the table's full length, a permuted table with a block two lanes
+    share, and a lane that is not active — its output is unused and its
+    pages unread, shown by poisoning a block only it names."""
+    rng = np.random.default_rng(7)
+    S, H, D, NB, bs, MB, pages = 6, heads, 32, 40, 4, 6, 2
+    entry = _pools(rng, NB, bs, H, D, dtype, quantized)
+    q = jnp.asarray(rng.standard_normal((S, H, D)), dtype)
+    bt = rng.permutation(np.arange(2, NB))[: S * MB].reshape(S, MB)
+    bt[3, 0] = bt[2, 0]  # lanes 2 and 3 share their first block
+    poisoned = 1         # named by the inactive lane 4 alone
+    bt[4, :] = poisoned
+    bt = jnp.asarray(bt, jnp.int32)
+    if quantized:  # int8 cannot hold a NaN: poison the block's scales
+        entry = entry[:2] + tuple(e.at[poisoned].set(jnp.nan)
+                                  for e in entry[2:])
+    else:
+        entry = tuple(e.at[poisoned].set(jnp.nan) for e in entry)
+    # lengths: 1 token; a page edge (4); a tile edge (8); mid-page; the
+    # inactive lane; the whole table (24)
+    pos = jnp.asarray([0, bs - 1, pages * bs - 1, 13, 9, MB * bs - 1],
+                      jnp.int32)
+    active = jnp.asarray([1, 1, 1, 1, 0, 1], bool)
+    out = np.asarray(pk.paged_decode_attention(
+        q, entry, bt, pos, active=active, pages=pages), np.float32)
+    ref = np.asarray(_decode_ref(q, entry, bt, pos), np.float32)
+    live = np.asarray(active)
+    assert np.isfinite(out).all()  # the poisoned block was never read
+    np.testing.assert_allclose(out[live], ref[live], **_tol(dtype))
+    # the other tile sizes and the default agree (same lanes, same data)
+    for n in (1, 4, None):
+        again = np.asarray(pk.paged_decode_attention(
+            q, entry, bt, pos, active=active, pages=n), np.float32)
+        np.testing.assert_allclose(again[live], ref[live], **_tol(dtype))
+
+
+@pytest.mark.parametrize("heads,dim", [(16, 128), (30, 128), (4, 32)])
+def test_write_token_is_the_plain_scatter(heads, dim):
+    """The kernel route's write of the new token (a head's row at a time
+    into the pool's slab view, token- or head-major) leaves the pool as
+    ``pool.at[block, offset].set`` does, a head dim under 128 included."""
+    rng = np.random.default_rng(9)
+    S, NB, bs = 5, 11, 4
+    pool = jnp.asarray(rng.standard_normal((NB, bs, heads, dim)),
+                       jnp.bfloat16)
+    new = jnp.asarray(rng.standard_normal((S, heads, dim)), jnp.bfloat16)
+    blocks = jnp.asarray(rng.permutation(NB)[:S], jnp.int32)
+    offsets = jnp.asarray(rng.integers(0, bs, (S,)), jnp.int32)
+    got = jax.jit(pk.write_token)(pool, blocks, offsets, new)
+    assert got.shape == pool.shape and got.dtype == pool.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(pool.at[blocks, offsets].set(new), np.float32))
 
 
 def test_decode_shared_block_between_lanes():
@@ -187,26 +249,30 @@ def test_full_prefill_pseudo_table_parity(sq):
 
 
 def test_kernel_runtime_data_one_trace():
-    """Tables, positions and prefix lengths are runtime data: one jit
-    trace serves arbitrary churn of all three."""
+    """Tables, positions and the active lanes are runtime data: one jit
+    trace serves arbitrary admit/retire churn of all three."""
     rng = np.random.default_rng(5)
-    S, H, D, NB, bs, MB = 3, 2, 16, 9, 4, 3
+    S, H, D, NB, bs, MB = 4, 2, 16, 13, 4, 3
     entry = _pools(rng, NB, bs, H, D)
     q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
     traces = {"n": 0}
 
     @jax.jit
-    def step(q, entry, bt, pos):
+    def step(q, entry, bt, pos, active):
         traces["n"] += 1
-        return pk.paged_decode_attention(q, entry, bt, pos)
+        return pk.paged_decode_attention(q, entry, bt, pos, active=active,
+                                         pages=2)
 
-    for i in range(3):
+    for i in range(4):
         bt = jnp.asarray(rng.integers(1, NB, (S, MB)), jnp.int32)
         pos = jnp.asarray(rng.integers(0, MB * bs, (S,)), jnp.int32)
-        out = step(q, entry, bt, pos)
-        np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(_decode_ref(q, entry, bt, pos)),
+        active = np.ones(S, bool)
+        active[rng.integers(0, S, (i,))] = False  # 0..3 lanes retired
+        out = np.asarray(step(q, entry, bt, pos, jnp.asarray(active)))
+        ref = np.asarray(_decode_ref(q, entry, bt, pos))
+        np.testing.assert_allclose(out[active], ref[active],
                                    **_tol("float32"))
+        assert not out[~active].any()  # a lane that is not active: zeros
     assert traces["n"] == 1
 
 
@@ -508,12 +574,15 @@ def test_arena_kernel_layout_contract(model):
             api.close()
 
 
-def test_engine_kernel_off_is_default(model):
-    """Flag-off (the default): the gather path, kernel gauge 0 — the
-    bit-preserved baseline every parity test above compares against."""
+def test_engine_default_route_follows_the_device(model):
+    """``paged_kernel=None`` (the default) is resolved from the device:
+    here, where the kernel would run interpreted, the decode step takes
+    the XLA gather — the bit-preserved baseline every parity test above
+    compares against — and the record says so."""
     _, st = _serve(model, None, _workload(np.random.default_rng(4), n=2))
     assert st["kernel.paged"] == 0
     assert st["kernel.mesh"] == "gather@single"
+    assert st["kernel.mesh"].startswith("gather@")
 
 
 # ------------------------------------------------- SPMD partitioning (mesh)
@@ -701,10 +770,10 @@ def test_tuning_mesh_legacy_migration(tmp_path):
         tuning.set_store_path(None)
 
 
-def test_sharded_tuned_block_h_applies(tmp_path):
+def test_sharded_tuned_pages_applies(tmp_path):
     """A mesh-keyed tune actually reaches the sharded launch: the
-    record's block_h (legal for the LOCAL head count, 8//4 = 2) changes
-    nothing numerically — block_h stays a pure launch parameter under
+    record's tile size (looked up under the LOCAL head count, 8//4 = 2)
+    changes nothing numerically — it stays a pure launch parameter under
     shard_map."""
     mesh = serving_mesh(4, install=False)
     rng = np.random.default_rng(16)
@@ -717,7 +786,7 @@ def test_sharded_tuned_block_h_applies(tmp_path):
     tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
     try:
         key = tuning.bucket_key(h=H // 4, d=D, bs=bs, mb=MB)
-        tuning.adopt("paged_decode", key, {"block_h": 2}, 3.0,
+        tuning.adopt("paged_decode", key, {"pages": 2}, 3.0,
                      mesh=mesh_axes_key(mesh))
         tuning.reset()
         out = pk.paged_decode_attention(q, entry, bt, pos, mesh=mesh)

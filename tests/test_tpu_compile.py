@@ -14,6 +14,7 @@ mesh route. A compile that passes is not a chip run: numerics are held by
 the interpret-mode parity tests (``tests/test_paged_kernel.py``).
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs
 
@@ -47,8 +48,9 @@ def _described():
     _DEVICES[:] = topo.devices
 
 
-#: (heads, head_dim): 124M, gpt_1p3b, and their H/4 mesh-local shapes
-WIDTHS = [(12, 64), (16, 128), (3, 64), (4, 128)]
+#: (heads, head_dim): 124M, gpt_1p3b, their H/4 mesh-local shapes, and
+#: Olmo-Hybrid's 30 heads (not a whole number of sublane tiles)
+WIDTHS = [(12, 64), (16, 128), (3, 64), (4, 128), (30, 128)]
 _IDS = [f"H{h}D{d}" for h, d in WIDTHS]
 S, BS, MB, NB, SQ = 32, 16, 128, 1024, 512
 
@@ -91,10 +93,30 @@ def _entry(h, d, quantized):
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
 def test_paged_decode_compiles(h, d, quantized):
-    _compile(lambda q, bt, pos, *entry:
-             pa.paged_decode_attention(q, entry, bt, pos),
+    _compile(lambda q, bt, pos, act, *entry:
+             pa.paged_decode_attention(q, entry, bt, pos, active=act),
              _sds((S, h, d), jnp.bfloat16), _sds((S, MB), jnp.int32),
-             _sds((S,), jnp.int32), *_entry(h, d, quantized))
+             _sds((S,), jnp.int32), _sds((S,), jnp.bool_),
+             *_entry(h, d, quantized))
+
+
+@pytest.mark.parametrize("h,mb,nb", [(16, 128, 2730), (30, 256, 2560)],
+                         ids=["serve-batch-long", "serve-doc-hybrid"])
+def test_paged_decode_compiles_at_the_cells_shapes(h, mb, nb):
+    """The two serving cells' own decode shapes (32 lanes, bf16, block 16,
+    head dim 128): the kernel compiles, nothing shaped like the gathered
+    tables ``[S*MB, block, H, D]`` is in the program, and the pools reach
+    the kernel as they lie on the chip (``_head_major`` guessed the
+    layout XLA gives them: a wrong guess shows as a copy of a pool)."""
+    pools = (_sds((nb, BS, h, 128), jnp.bfloat16),) * 2
+    text = _compile(lambda q, bt, pos, act, *entry:
+                    pa.paged_decode_attention(q, entry, bt, pos, active=act),
+                    _sds((S, h, 128), jnp.bfloat16), _sds((S, mb), jnp.int32),
+                    _sds((S,), jnp.int32), _sds((S,), jnp.bool_), *pools)
+    assert f"[{S * mb},{BS},{h},128]" not in text
+    assert f"[{S},{mb * BS},{h},128]" not in text
+    assert not re.search(rf"= bf16\[{nb},[0-9,]*128\]\S* (copy|transpose)\(",
+                         text)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])

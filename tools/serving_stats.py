@@ -53,12 +53,13 @@ decoding (``constrain.admits`` / ``constrain.mask_updates`` /
 ``lora.arena_bytes`` and per-scenario ``*.active_slots`` gauges);
 ``FLAGS_serving_lora_rank`` / ``FLAGS_serving_lora_adapters`` size the
 arena in config mode.
-The Pallas paged-attention kernels (``FLAGS_serving_paged_kernel``,
-``ops.paged_attention``) add the trace-time ``kernel.decode_traces`` /
+The Pallas paged-attention kernels (``ops.paged_attention``; the decode
+step's default route where they compile natively, every route under
+``ServingConfig.paged_kernel=True``) add the trace-time ``kernel.decode_traces`` /
 ``kernel.prefill_traces`` / ``kernel.verify_traces`` counters (frozen
 after warmup in a healthy run — churn never re-lowers a kernel) and the
-end-of-run ``kernel.paged`` / ``kernel.tuned_entries`` gauges (mode +
-tuning-store coverage for this chip, benches/TUNED_KERNELS.json).
+end-of-run ``kernel.paged`` / ``kernel.tuned_entries`` gauges (the route
+the decode step was built with + tuning-store coverage for this chip, benches/TUNED_KERNELS.json).
 The mesh-sharded execution core (ISSUE 14, docs/distributed.md) adds the
 ``mesh.devices`` / ``mesh.model_axis`` / ``mesh.data_axis`` topology
 gauges — a tensor-parallel run shows ``mesh.model_axis`` > 1 with the
@@ -179,9 +180,6 @@ def _config_report() -> dict:
         # multi-LoRA adapter arena (serving.adapters; 0 rank = off)
         "serving_lora_rank": _flag_env("serving_lora_rank", 0),
         "serving_lora_adapters": _flag_env("serving_lora_adapters", 4),
-        # Pallas paged-attention kernels (ops.paged_attention; 0 = the
-        # XLA gather path)
-        "serving_paged_kernel": _flag_env("serving_paged_kernel", 0),
         # multi-tenant gateway (serving.gateway: router/tenancy/front door)
         "serving_replicas": _flag_env("serving_replicas", 2),
         "gateway_port": _flag_env("gateway_port", 8100),
