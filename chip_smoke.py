@@ -30,8 +30,8 @@ The native extension is not on this path: modules here import
 ``paddle_tpu.native`` but none calls ``native.load()``, so nothing is built
 from ``native/csrc`` and nothing under the home directory is read (the
 ``done`` line says whether the library was loaded). Kernel tuning records
-(``benches/TUNED_KERNELS.json``) were never published; their absence means
-the untuned defaults.
+(``ops.tuning``) were never published; their absence means the untuned
+defaults.
 """
 from __future__ import annotations
 
@@ -86,8 +86,8 @@ class Plan:
 #: (32 x 2048 worst-case tokens > 43,680): admission is by blocks.
 #:
 #: Training depth is cut to 12 of 24 layers. Rehearsal compiles for the
-#: described v5e (`memory_analysis()`, batch 4 x 1024, AMP O1 as bench.py
-#: uses it: f32 params, grads and Adam moments, 16 B/param): 24 layers are
+#: described v5e (`memory_analysis()`, batch 4 x 1024, AMP O1 as the cell
+#: `train-1chip` has it: f32 params, grads, Adam moments, 16 B/param): 24 are
 #: refused by the compiler (19.85 G of 15.75 G HBM); 16 layers compile at
 #: 14.81 GiB, 94% of HBM, which leaves under 1 GiB for the allocator's
 #: fragmentation and whatever else the process holds; 12 layers take
@@ -551,8 +551,8 @@ def phase_serve_kernel(model, plan: Plan, prompts, gather_toks, seed: int,
 def train_losses(plan: Plan, seed: int, steps: int, chip: bool,
                  shard: bool = False) -> Tuple[List[float], dict]:
     """``steps`` TrainStep updates of the depth-cut model on one fixed
-    batch, AMP O1 as bench.py uses it. Returns the losses and a record of
-    what the compiled step is and holds."""
+    batch, AMP O1 as the benchmark's training cell uses it. Returns the
+    losses and a record of what the compiled step is and holds."""
     import paddle_tpu as pt
     from paddle_tpu import amp
     from paddle_tpu.core import compile_cache

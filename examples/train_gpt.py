@@ -3,7 +3,7 @@
 Usage:
   python examples/train_gpt.py                  # tiny config, synthetic data
   python examples/train_gpt.py --hidden 768 --layers 12 --amp O2
-  BENCH-grade runs: see bench.py / benches/sweep.py.
+  Measured runs: the benchmark (BENCHMARK.json, benchmark/README.md).
 """
 import argparse
 

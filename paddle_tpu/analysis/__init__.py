@@ -35,7 +35,7 @@ from .registry import RegistryAnalyzer
 
 #: default corpus roots, relative to the repo root (tests/ is excluded:
 #: the fixture corpus under tests/fixtures/analysis is deliberately bad)
-DEFAULT_PATHS = ("paddle_tpu", "tools", "benches", "examples")
+DEFAULT_PATHS = ("paddle_tpu", "tools", "examples")
 
 
 def all_analyzers(full_corpus: bool = True):

@@ -11,8 +11,8 @@ resource instead of a per-bench hack:
   cache at ONE directory (:func:`resolve_cache_dir`: where
   ``JAX_COMPILATION_CACHE_DIR`` says when it is set, else the fixed
   ``<checkout>/.jax_cache``) and runs once at ``import paddle_tpu``, so
-  ``chip_smoke.py``, ``bench.py``, ``@to_static``, ``TrainStep``, eager
-  dispatch, and ``jit.save``'s export path all warm-start from the same
+  ``chip_smoke.py``, ``benchmark/run.py``, ``@to_static``, ``TrainStep``,
+  eager dispatch, and ``jit.save``'s export path all warm-start from the same
   cache. The directory is part of the cache key, so it never moves with
   the user, the process or the clock. Entries are keyed on HLO + compile
   options + backend, so CPU and TPU programs never collide.

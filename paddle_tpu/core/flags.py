@@ -91,28 +91,26 @@ define_flag("selected_devices", "",
 define_flag("sequence_parallel_mode", "auto",
             "Context parallelism for attention: auto|ring|ulysses|none.")
 define_flag("flash_block_q", 128,
-            "Pallas flash-attention q-block tile (benches/flash_tune.py "
-            "measures candidates on-chip).")
+            "Pallas flash-attention q-block tile. At the 128 defaults of "
+            "this and flash_block_k the kernel takes the tiles measured "
+            "for the chip's kind, where there are any; any other value "
+            "wins.")
 define_flag("flash_block_k", 128,
             "Pallas flash-attention k-block tile (multiple of 128).")
-define_flag("flash_use_tuned", True,
-            "Adopt on-chip tuned block sizes (benches/FLASH_TUNED.json) "
-            "when flash_block_q/_k sit at their 128 defaults. Set 0 to "
-            "force the safe defaults even with a tune record present.")
 define_flag("flash_attention_min_seqlen", -1,
             "Route attention through the Pallas flash kernel at kv "
             "sequence length >= this. -1 (default) = auto: 1024 when "
-            "on-chip-tuned blocks will actually be adopted for this chip "
-            "(FLASH_TUNED.json present, flash_block_q/_k at their 128 "
-            "defaults, flash_use_tuned on; tuned kernel measured faster "
-            "than XLA at every seqlen >= 1k on v5e), else 4608 (untuned "
+            "tiles measured for this chip's kind will be adopted "
+            "(flash_block_q/_k at their 128 defaults; that kernel "
+            "measured faster than XLA at every seqlen >= 1k on v5e), "
+            "else 4608 (the 128-tile "
             "kernel loses below ~4.6k). 0 = always flash.")
 
 # ---- Compilation cache / donation / bucketing (core.compile_cache) ----
 define_flag("xla_compile_cache", True,
             "Enable the persistent on-disk XLA compilation cache at import "
             "(core.compile_cache.initialize). Warm-starts every compiled "
-            "entry point: eager dispatch, to_static, TrainStep, benches.")
+            "entry point: eager dispatch, to_static, TrainStep.")
 define_flag("xla_compile_cache_min_compile_secs", 1.0,
             "Only persist compiles that took at least this many seconds "
             "(keeps thousands of tiny eager-op entries off disk).")

@@ -91,7 +91,7 @@ def get_mesh() -> Optional[Mesh]:
 
 
 def clear_mesh() -> None:
-    """Uninstall the global mesh (benches/tests that interleave mesh and
+    """Uninstall the global mesh (tests that interleave mesh and
     single-device builds; models constructed afterwards commit unsharded)."""
     global _global_mesh
     with _global_lock:

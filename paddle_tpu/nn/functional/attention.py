@@ -48,20 +48,16 @@ def _sdpa_reference(q, k, v, *, scale, causal, dropout_p=0.0, key=None):
 
 def _effective_min_seqlen(sk: int) -> int:
     """Resolve the flash-routing threshold. FLAGS default -1 = auto:
-    with on-chip-tuned blocks (FLASH_TUNED.json for this chip) the kernel
-    measured FASTER than XLA at every seqlen >= 1024 (replay-proof:
-    1.30x @1k, 1.56x @2k, 2.58x @4k, 18.4x @8k —
-    benches/flash_tpu_bench.py, v5e bf16 fwd+bwd d=64), so auto routes
-    from 1024; with untuned 128-blocks the
-    kernel loses below ~4.6k (r4 measurement), so auto stays at 4608.
-    An explicit flag value always wins; 0 = always flash.
+    with the tiles measured for this chip (``pallas_ops._TUNED_BLOCKS``)
+    the kernel measured FASTER than XLA at every seqlen >= 1024 (1.30x
+    @1k, 1.56x @2k, 2.58x @4k, 18.4x @8k; v5e bf16 fwd+bwd d=64), so auto
+    routes from 1024; with the 128-blocks the kernel loses below ~4.6k
+    (0.64-0.80x of XLA at 1k-4.6k), so auto stays at 4608. An explicit
+    flag value always wins; 0 = always flash.
 
-    The 1024 threshold applies only when the tuned blocks will actually be
-    ADOPTED — the same gate _default_blocks uses: flash_block_q/_k at their
-    128 defaults and flash_use_tuned truthy. With the escape hatch
-    (flash_use_tuned=0) or custom blocks, the kernel that runs is the
-    untuned one (measured 0.64–0.80x of XLA at 1k–4.6k), so auto must stay
-    at 4608."""
+    The 1024 threshold applies only when the measured tiles will actually
+    be ADOPTED: the same gate _default_blocks uses, flash_block_q/_k at
+    their 128 defaults."""
     from ...core import flags
 
     thr = int(flags.flag("flash_attention_min_seqlen"))
@@ -71,8 +67,7 @@ def _effective_min_seqlen(sk: int) -> int:
 
     blocks_at_default = (int(flags.flag("flash_block_q")),
                          int(flags.flag("flash_block_k"))) == (128, 128)
-    if (blocks_at_default and flags.flag("flash_use_tuned")
-            and _tuned_blocks(sk)):
+    if blocks_at_default and _tuned_blocks(sk):
         return 1024
     return 4608
 

@@ -392,71 +392,49 @@ def _flash_bwd_rule(scale, causal, blk_q, blk_k, res, do):
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-_TUNED_BLOCKS = None  # lazy-loaded {seq:int -> (blk_q, blk_k)}, {} if absent
-_TUNED_PATH = None  # test override for the FLASH_TUNED.json location
+#: Flash tiles by device kind and kv sequence length: (blk_q, blk_k).
+#: Measured on a v5e in bf16, forward and backward, each candidate checked
+#: against the reference before it was timed (CHANGES.md, PR 1). The
+#: benchmark's `train-1chip` cell runs the 1024 row; its ledger lines read
+#: it as `flash_time_share_pct`. A kind that is not here keeps the 128s:
+#: tiles verified on one TPU generation are not adopted on another (VMEM
+#: limits differ; Mosaic may reject them).
+_TUNED_BLOCKS = {
+    "TPU v5 lite": {1024: (512, 512), 2048: (512, 512),
+                    4096: (512, 512), 8192: (512, 512)},
+}
 
 
 def _tuned_blocks(seq):
-    """Per-seqlen best tiling measured on-chip by benches/flash_tune.py.
-    The shared kernel-tuning store (:mod:`paddle_tpu.ops.tuning`, kernel
-    ``"flash_fwd"``, bucketed by seqlen, device-kind gated) is consulted
-    first; the legacy FLASH_TUNED.json record (written only from
-    candidates that passed the numerics check) remains the fallback so a
-    pre-store tune keeps winning. Nearest measured seqlen wins within the
-    legacy record; {} when no tune has ever run (fresh checkout /
-    installed wheel)."""
+    """Measured tiles for this chip at the nearest measured seqlen, or
+    None. The shared kernel-tuning store (:mod:`paddle_tpu.ops.tuning`,
+    kernel ``"flash_fwd"``, bucketed by seqlen, device-kind gated) is
+    consulted first; ``_TUNED_BLOCKS`` second."""
     from . import tuning
 
     rec = tuning.lookup("flash_fwd", tuning.bucket_key(s=seq))
     if rec and "blk_q" in rec and "blk_k" in rec:
         return int(rec["blk_q"]), int(rec["blk_k"])
-    global _TUNED_BLOCKS
-    if _TUNED_BLOCKS is None:
-        import json
-        import os
-
-        path = _TUNED_PATH or os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))), "benches",
-            "FLASH_TUNED.json")
-        try:
-            with open(path) as f:
-                rec = json.load(f)
-            # the record is stamped with the chip it was measured on:
-            # tiles verified on one TPU generation must not be adopted on
-            # another (VMEM limits differ; Mosaic may reject them)
-            import jax
-
-            kind = getattr(jax.devices()[0], "device_kind", "")
-            if rec.get("device_kind") == kind:
-                _TUNED_BLOCKS = {int(s): (int(bk[0]), int(bk[1]))
-                                 for s, bk in rec["blocks"].items()}
-            else:
-                _TUNED_BLOCKS = {}
-        except Exception:  # absent OR malformed: never block attention
-            _TUNED_BLOCKS = {}
+    table = _TUNED_BLOCKS.get(jax.devices()[0].device_kind)
     # only adopt within the measured range: a tiling verified at 8192 was
-    # never lowered at 1024 (different VMEM footprint; Mosaic may reject
+    # never lowered at 512 (different VMEM footprint; Mosaic may reject
     # it), and short seqs route through XLA attention anyway
-    if not _TUNED_BLOCKS or seq < min(_TUNED_BLOCKS):
+    if not table or seq < min(table):
         return None
-    nearest = min(_TUNED_BLOCKS, key=lambda s: abs(s - seq))
-    return _TUNED_BLOCKS[nearest]
+    return table[min(table, key=lambda s: abs(s - seq))]
 
 
 def _default_blocks(seq=None):
-    """Tunable kernel tiling (FLAGS_flash_block_q/_k; benches/flash_tune.py
-    measures the grid on-chip). 128 matches the MXU/lane width and is the
-    safe default; larger k-blocks amortize grid overhead at long context.
-    When the flags sit at their defaults, an on-chip tune record
-    (FLASH_TUNED.json) takes precedence; non-default flags win, and
-    FLAGS_flash_use_tuned=0 is the explicit escape hatch that forces the
-    128 defaults even with a tune record present."""
+    """Kernel tiling: FLAGS_flash_block_q/_k. 128 matches the MXU/lane
+    width and is the safe default; larger k-blocks amortize grid overhead
+    at long context. With the flags at their defaults the tiles measured
+    for this chip (``_tuned_blocks``) are taken; any other flag value
+    wins."""
     from ..core import flags
 
     bq = int(flags.flag("flash_block_q"))
     bk = int(flags.flag("flash_block_k"))
-    if ((bq, bk) == (128, 128) and seq is not None
-            and flags.flag("flash_use_tuned")):
+    if (bq, bk) == (128, 128) and seq is not None:
         tuned = _tuned_blocks(seq)
         if tuned:
             return tuned

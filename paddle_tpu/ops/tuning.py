@@ -1,6 +1,6 @@
 """Shared kernel-tuning store: per-(kernel, chip, shape-bucket) records.
 
-Generalizes the flash kernel's ``FLASH_TUNED.json`` adoption machinery
+Generalizes the flash kernel's per-chip tile adoption
 (:func:`paddle_tpu.ops.pallas_ops._tuned_blocks`) into one store every
 Pallas kernel shares. A *record* is the best-measured launch parameters
 (tile sizes, head grouping, ...) for one kernel at one shape bucket on one
@@ -33,9 +33,10 @@ chip generation:
   record; a multi-device topology never does.
 
 Adoption is *persisted*: :func:`adopt` merges the record into
-``benches/TUNED_KERNELS.json`` (atomic tmp+replace write), so a tune run
-on a chip benefits every later process on that chip — exactly the
-FLASH_TUNED.json contract, shared. Lookups are memoized per process: the
+``TUNED_KERNELS.json`` in the checkout's compile-cache directory
+(``core.compile_cache.default_cache_dir()``; atomic tmp+replace write),
+so a tune run on a chip benefits every later process on that chip.
+Lookups are memoized per process: the
 params a compiled program traced against never change under it
 (zero-recompile discipline — a mid-run adopt only affects *new*
 processes).
@@ -60,8 +61,9 @@ _LOOKUPS: Dict[tuple, Optional[dict]] = {}  # per-process memo (stability)
 
 
 def _default_path() -> str:
-    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "benches", "TUNED_KERNELS.json")
+    from ..core.compile_cache import default_cache_dir
+
+    return os.path.join(default_cache_dir(), "TUNED_KERNELS.json")
 
 
 def store_path() -> str:

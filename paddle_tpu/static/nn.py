@@ -101,7 +101,7 @@ def sparse_embedding(input, size, padding_idx=None, is_test=False,
                 "side pull/push (RPC per batch) and cannot be recorded onto "
                 "a compiled Program tape — drive PS training in dygraph "
                 "(distributed.ps.PSEmbedding + TrainStep over the dense "
-                "part), as benches/baseline.py widedeep does")
+                "part)")
         from ..distributed.ps import PSEmbedding
 
         if slot is not None:

@@ -36,7 +36,7 @@ def restore_flags():
     keep = {k: pt.get_flags(k)[k] for k in
             ("trainstep_donate", "decode_donate", "shape_bucketing",
              "shape_bucket_min", "flash_attention_min_seqlen",
-             "flash_use_tuned", "flash_block_q", "flash_block_k")}
+             "flash_block_q", "flash_block_k")}
     try:
         yield
     finally:
@@ -108,10 +108,7 @@ def restore_cache_dir(monkeypatch):
 def test_env_var_places_the_cache_and_nothing_repoints_it(
         tmp_path, monkeypatch, restore_cache_dir):
     """JAX_COMPILATION_CACHE_DIR set -> the cache is there: neither the
-    explicit argument, nor a plain re-initialize, nor importing the bench
-    helpers moves it."""
-    import importlib
-
+    explicit argument nor a plain re-initialize moves it."""
     import jax
 
     env_dir, other = str(tmp_path / "from_env"), str(tmp_path / "other")
@@ -120,13 +117,9 @@ def test_env_var_places_the_cache_and_nothing_repoints_it(
     assert cc.resolve_cache_dir(other) == env_dir
     assert cc.initialize(cache_dir=other, force=True) == env_dir
     assert cc.initialize(force=True) == env_dir
-    import benches._common as bench_common
-
-    importlib.reload(bench_common)  # import-time side effects, if any
     assert cc.cache_dir() == env_dir
     assert jax.config.jax_compilation_cache_dir == env_dir
     assert os.path.isdir(env_dir) and not os.path.exists(other)
-    assert not hasattr(bench_common, "enable_compile_cache")
 
 
 def test_default_cache_dir_is_fixed_inside_the_checkout(monkeypatch):
@@ -393,37 +386,34 @@ def test_executor_state_dict_valid_after_donating_train_step():
 # ------------------------------------------- satellite regression: ADVICE.md
 
 
-def test_flash_auto_threshold_gated_on_tuned_adoption(restore_flags):
+def test_flash_auto_threshold_gated_on_tuned_adoption(restore_flags,
+                                                      monkeypatch):
+    import jax
+
     from paddle_tpu.nn.functional import attention
     from paddle_tpu.ops import pallas_ops
 
-    prev = pallas_ops._TUNED_BLOCKS
-    pallas_ops._TUNED_BLOCKS = {1024: (256, 512)}  # tune record "exists"
-    try:
-        pt.set_flags({"FLAGS_flash_attention_min_seqlen": -1,
-                      "FLAGS_flash_use_tuned": True,
-                      "FLAGS_flash_block_q": 128,
-                      "FLAGS_flash_block_k": 128})
-        # tuned blocks will be adopted -> aggressive 1024 threshold
-        assert attention._effective_min_seqlen(2048) == 1024
-        # escape hatch: tuned record present but NOT adopted -> the kernel
-        # that would run is the untuned one (0.64-0.80x of XLA at 1k-4.6k)
-        pt.set_flags({"FLAGS_flash_use_tuned": False})
-        assert attention._effective_min_seqlen(2048) == 4608
-        # custom blocks also bypass tuned adoption
-        pt.set_flags({"FLAGS_flash_use_tuned": True,
-                      "FLAGS_flash_block_q": 256})
-        assert attention._effective_min_seqlen(2048) == 4608
-        # an explicit flag value always wins
-        pt.set_flags({"FLAGS_flash_attention_min_seqlen": 2000,
-                      "FLAGS_flash_block_q": 128})
-        assert attention._effective_min_seqlen(2048) == 2000
-        # no tune record at all -> conservative threshold
-        pt.set_flags({"FLAGS_flash_attention_min_seqlen": -1})
-        pallas_ops._TUNED_BLOCKS = {}
-        assert attention._effective_min_seqlen(2048) == 4608
-    finally:
-        pallas_ops._TUNED_BLOCKS = prev
+    kind = jax.devices()[0].device_kind
+    # tiles measured for this chip "exist"
+    monkeypatch.setattr(pallas_ops, "_TUNED_BLOCKS",
+                        {kind: {1024: (256, 512)}})
+    pt.set_flags({"FLAGS_flash_attention_min_seqlen": -1,
+                  "FLAGS_flash_block_q": 128,
+                  "FLAGS_flash_block_k": 128})
+    # they will be adopted -> aggressive 1024 threshold
+    assert attention._effective_min_seqlen(2048) == 1024
+    # custom blocks bypass adoption: the kernel that would run is the
+    # 128-tile one (0.64-0.80x of XLA at 1k-4.6k)
+    pt.set_flags({"FLAGS_flash_block_q": 256})
+    assert attention._effective_min_seqlen(2048) == 4608
+    # an explicit flag value always wins
+    pt.set_flags({"FLAGS_flash_attention_min_seqlen": 2000,
+                  "FLAGS_flash_block_q": 128})
+    assert attention._effective_min_seqlen(2048) == 2000
+    # nothing measured for this chip -> conservative threshold
+    pt.set_flags({"FLAGS_flash_attention_min_seqlen": -1})
+    monkeypatch.setattr(pallas_ops, "_TUNED_BLOCKS", {})
+    assert attention._effective_min_seqlen(2048) == 4608
 
 
 def test_native_predictor_empty_options_bypasses_env(monkeypatch):

@@ -90,96 +90,113 @@ def test_flash_odd_shapes_fall_back():
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-4, atol=1e-4)
 
 
-def test_tuned_blocks_precedence(monkeypatch):
-    """FLASH_TUNED.json winners apply when the block flags sit at their
-    128 defaults; explicit flags always win; no tune record -> defaults."""
-    from paddle_tpu.core import flags
-    from paddle_tpu.ops import pallas_ops as po
+class _Dev:
+    """What ``jax.devices()[0]`` has to be for the kernel to see a chip
+    of this kind."""
 
+    def __init__(self, kind):
+        self.platform, self.device_kind = "tpu", kind
+
+
+def _on_a(monkeypatch, kind):
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(kind)])
+
+
+def _block_flags(q=128, k=128, thr=-1):
+    return {"flash_block_q": q, "flash_block_k": k,
+            "flash_attention_min_seqlen": thr}
+
+
+@pytest.fixture
+def restore_flash_flags():
+    from paddle_tpu.core import flags
+
+    keep = {name: flags.flag(name) for name in _block_flags()}
+    yield flags
+    flags.set_flags(keep)
+
+
+@pytest.mark.parametrize("kind,seq,want", [
+    ("TPU v5 lite", 1024, (512, 512)), ("TPU v5 lite", 2048, (512, 512)),
+    ("TPU v5 lite", 4096, (512, 512)), ("TPU v5 lite", 8192, (512, 512)),
+    ("TPU v5 lite", 512, None), ("TPU v4", 2048, None)])
+def test_measured_tiles_by_device_kind_and_seq(monkeypatch, kind, seq, want):
+    """The table as committed: what the benchmark's training cell runs at
+    1024 on a v5e, nothing under the smallest measured length, nothing
+    for a chip kind that was never measured."""
+    _on_a(monkeypatch, kind)
+    assert po._tuned_blocks(seq) == want
+    assert po._default_blocks(seq=seq) == (want or (128, 128))
+
+
+@pytest.mark.parametrize("kind,set_flags,want", [
+    ("TPU v5 lite", _block_flags(), 1024),
+    ("TPU v4", _block_flags(), 4608),
+    ("TPU v5 lite", _block_flags(q=256), 4608),
+    ("TPU v5 lite", _block_flags(thr=2000), 2000)],
+    ids=["measured-kind", "other-kind", "explicit-blocks",
+         "explicit-threshold"])
+def test_flash_threshold_follows_the_table(monkeypatch, restore_flash_flags,
+                                           kind, set_flags, want):
+    """Auto routes from 1024 only where the table's tiles will be the
+    ones that run; an explicit threshold always wins."""
+    from paddle_tpu.nn.functional.attention import _effective_min_seqlen
+
+    _on_a(monkeypatch, kind)
+    restore_flash_flags.set_flags(set_flags)
+    assert _effective_min_seqlen(1024) == want
+
+
+def test_tuned_blocks_precedence(monkeypatch, restore_flash_flags):
+    """Measured tiles apply when the block flags sit at their 128
+    defaults; explicit flags always win; nothing measured -> defaults."""
+    kind = jax.devices()[0].device_kind
     monkeypatch.setattr(po, "_TUNED_BLOCKS",
-                        {4096: (256, 512), 8192: (512, 512)})
+                        {kind: {4096: (256, 512), 8192: (512, 512)}})
     assert po._default_blocks(seq=5000) == (256, 512)  # nearest measured
     assert po._default_blocks(seq=8192) == (512, 512)
     assert po._default_blocks() == (128, 128)  # no seq context
     # below the measured range: a tiling verified at 4096+ was never
     # lowered at short seqs -> safe defaults
     assert po._default_blocks(seq=1024) == (128, 128)
-    flags.set_flags({"FLAGS_flash_block_q": 256})
-    try:
-        assert po._default_blocks(seq=8192) == (256, 128)  # explicit wins
-    finally:
-        flags.set_flags({"FLAGS_flash_block_q": 128})
-    # the documented escape hatch: force defaults despite a tune record
-    flags.set_flags({"FLAGS_flash_use_tuned": False})
-    try:
-        assert po._default_blocks(seq=8192) == (128, 128)
-    finally:
-        flags.set_flags({"FLAGS_flash_use_tuned": True})
+    restore_flash_flags.set_flags({"FLAGS_flash_block_q": 256})
+    assert po._default_blocks(seq=8192) == (256, 128)  # explicit wins
+    restore_flash_flags.set_flags({"FLAGS_flash_block_q": 128})
     monkeypatch.setattr(po, "_TUNED_BLOCKS", {})
     assert po._default_blocks(seq=8192) == (128, 128)
 
 
-def test_tuned_blocks_loader_device_kind_gate(tmp_path, monkeypatch):
-    """A tune record stamped with a different chip generation is ignored
-    (tiles verified on v5e must not load on v4); matching stamp loads;
-    malformed records degrade to defaults instead of raising."""
-    import json
-
-    import jax
-
-    from paddle_tpu.ops import pallas_ops as po
-
-    kind = getattr(jax.devices()[0], "device_kind", "")
-    path = tmp_path / "FLASH_TUNED.json"
-    monkeypatch.setattr(po, "_TUNED_PATH", str(path))
-
-    path.write_text(json.dumps(
-        {"device_kind": kind, "blocks": {"4096": [256, 512]}}))
-    monkeypatch.setattr(po, "_TUNED_BLOCKS", None)
+def test_tuned_blocks_loader_device_kind_gate(monkeypatch):
+    """Tiles measured on one chip generation are not adopted on another
+    (tiles verified on v5e must not run on v4); the kind that was
+    measured gets them."""
+    monkeypatch.setattr(po, "_TUNED_BLOCKS",
+                        {"TPU v99": {4096: (256, 512)}})
+    assert po._tuned_blocks(4096) is None  # this process is on "cpu"
+    _on_a(monkeypatch, "TPU v99")
     assert po._tuned_blocks(4096) == (256, 512)
-
-    path.write_text(json.dumps(
-        {"device_kind": "TPU v99", "blocks": {"4096": [256, 512]}}))
-    monkeypatch.setattr(po, "_TUNED_BLOCKS", None)
-    assert po._tuned_blocks(4096) is None
-
-    path.write_text("[128, 128]")  # malformed: old/other format
-    monkeypatch.setattr(po, "_TUNED_BLOCKS", None)
+    _on_a(monkeypatch, "TPU v98")
     assert po._tuned_blocks(4096) is None
 
 
-def test_effective_min_seqlen_auto(tmp_path, monkeypatch):
-    """FLAGS_flash_attention_min_seqlen=-1 (auto): 1024 with a tune record
-    for this chip, 4608 without; an explicit value always wins."""
-    import json
-
-    import jax
-
-    from paddle_tpu.core import flags
+def test_effective_min_seqlen_auto(monkeypatch, restore_flash_flags):
+    """FLAGS_flash_attention_min_seqlen=-1 (auto): 1024 with tiles
+    measured for this chip, 4608 without; an explicit value always wins."""
     from paddle_tpu.nn.functional.attention import _effective_min_seqlen
-    from paddle_tpu.ops import pallas_ops as po
 
-    kind = getattr(jax.devices()[0], "device_kind", "")
-    path = tmp_path / "FLASH_TUNED.json"
-    monkeypatch.setattr(po, "_TUNED_PATH", str(path))
-    old = flags.flag("flash_attention_min_seqlen")
-    try:
-        flags.set_flags({"flash_attention_min_seqlen": -1})
-        # no tune record -> conservative untuned break-even
-        monkeypatch.setattr(po, "_TUNED_BLOCKS", None)
-        assert _effective_min_seqlen(2048) == 4608
-        # record for this chip covering the seq -> tuned break-even
-        path.write_text(json.dumps(
-            {"device_kind": kind, "blocks": {"1024": [512, 512]}}))
-        monkeypatch.setattr(po, "_TUNED_BLOCKS", None)
-        assert _effective_min_seqlen(2048) == 1024
-        # explicit flag wins over auto
-        flags.set_flags({"flash_attention_min_seqlen": 9999})
-        assert _effective_min_seqlen(2048) == 9999
-        flags.set_flags({"flash_attention_min_seqlen": 0})
-        assert _effective_min_seqlen(2048) == 0
-    finally:
-        flags.set_flags({"flash_attention_min_seqlen": old})
+    flags = restore_flash_flags
+    kind = jax.devices()[0].device_kind
+    flags.set_flags({"flash_attention_min_seqlen": -1})
+    # nothing measured for this chip -> the 128-tile break-even
+    assert _effective_min_seqlen(2048) == 4608
+    # tiles for this chip covering the seq -> their break-even
+    monkeypatch.setattr(po, "_TUNED_BLOCKS", {kind: {1024: (512, 512)}})
+    assert _effective_min_seqlen(2048) == 1024
+    # explicit flag wins over auto
+    flags.set_flags({"flash_attention_min_seqlen": 9999})
+    assert _effective_min_seqlen(2048) == 9999
+    flags.set_flags({"flash_attention_min_seqlen": 0})
+    assert _effective_min_seqlen(2048) == 0
 
 
 @pytest.mark.parametrize("data,model", [(4, 1), (1, 4), (2, 2)],

@@ -8,8 +8,7 @@ streaming gateway (endpoints, 429/503 error taxonomy, SIGTERM drain).
 Pools that get ejected, drained, or scaled build their own instances —
 like the drain tests in test_serving.py, a drained pool refuses admissions
 forever. Tenancy gates are unit-tested without any engine (pure policy).
-Heavier load/fairness runs live in ``benches/bench_serving.py --gateway``;
-a miniature is here under the ``slow`` marker.
+A miniature load/fairness run is here under the ``slow`` marker.
 """
 import json
 import re
@@ -270,6 +269,41 @@ def test_crash_loop_ejects_and_reroutes_token_for_token(model):
         pool.scale_to(1)
         assert victim.removed
         assert len(pool.healthy_replicas()) == 1
+    finally:
+        pool.close()
+        paddle.set_flags(keep)
+
+
+def test_reroute_replays_on_the_survivors_warm_programs(model):
+    """The survivor absorbs a dead replica's streams on the programs it
+    has: once its decode step and every prefill bucket a journal replay
+    can land in are traced, a re-route traces nothing (prompt + journal
+    is prefilled in a bucket of the ladder, not at its own length)."""
+    keep = paddle.get_flags(["serving_max_rebuilds"])
+    paddle.set_flags({"serving_max_rebuilds": 1})
+    pool = ReplicaPool(model, replicas=2, respawn_backoff=600, **POOL_KW)
+    try:
+        rng = np.random.default_rng(9)
+        for rep in pool.replicas():
+            for plen in (10, 20, 28, 40, 60):
+                rep.api.submit(_prompt(rng, plen), max_new_tokens=2)
+            rep.api.run_until_idle()
+        prompts = [_prompt(rng, n) for n in (8, 12, 10, 12)]
+        rrs = [pool.submit(p, max_new_tokens=40) for p in prompts]
+        for _ in range(12):  # journals of a dozen tokens before the kill
+            pool.pump_once()
+        assert not any(rr.finished for rr in rrs)
+        victim = pool._replica_at(rrs[0]._replica_idx)
+        survivor, = [r for r in pool.replicas() if r is not victim]
+        eng = survivor.api.engine
+        traced = (eng.decode_traces, dict(eng.prefill_traces))
+        _kill_decode(victim)
+        outs = [pool.result(rr, timeout=120) for rr in rrs]
+        for p, out in zip(prompts, outs):
+            np.testing.assert_array_equal(out, _ref(model, p, 40))
+        assert sum(rr.reroutes for rr in rrs) >= 1
+        assert survivor.api.engine is eng  # never rebuilt
+        assert (eng.decode_traces, dict(eng.prefill_traces)) == traced
     finally:
         pool.close()
         paddle.set_flags(keep)

@@ -7,6 +7,7 @@ names the reason. The CPU path itself (``_default_accelerator`` choosing
 ``cpu``, interpret mode on the CPU backend) stays: it is how these tests run.
 """
 import os
+import pathlib
 import re
 import subprocess
 import sys
@@ -217,32 +218,30 @@ def _factory():  # importable by module path, as a worker needs it
     return GPTForCausalLM(gpt_tiny())
 
 
-def test_bench_without_a_chip_is_an_error():
+def test_the_benchmark_without_a_chip_is_an_error():
+    """The one yardstick fails without a chip; it does not fall back."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
-                       env=env, cwd=ROOT, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode != 0
-    assert "needs a TPU" in r.stdout + r.stderr
-    assert "mfu" not in r.stdout and "samples_per_sec" not in r.stdout
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "train-1chip", "--seed", "1", "--seconds", "1"],
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 2, r.stderr[-2000:]
+    assert "train-1chip needs 1 TPU chip(s)" in r.stderr
+    assert "'platform': 'cpu'" in r.stderr  # and says what it found
+    assert "metrics" not in r.stdout and "train_tokens_per_s" not in r.stdout
 
 
-def test_bench_unknown_device_kind_is_an_error(monkeypatch):
-    import importlib.util
-
-    import jax
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    class _Dev:
-        platform, device_kind = "tpu", "TPU v99"
-
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
-    with pytest.raises(SystemExit, match="no peak FLOP/s"):
-        bench.main()
+def test_the_package_reaches_into_no_benchmark_directory():
+    """No module of the package builds a path to a directory named
+    ``benches``: a kernel's choices are in its own module, the tuning
+    store's file is in the compile cache's directory."""
+    needle = re.compile(r"""["']benches["']""")
+    hits = [f"{path.relative_to(ROOT)}:{n}"
+            for path in sorted(pathlib.Path(ROOT, "paddle_tpu").rglob("*.py"))
+            for n, line in enumerate(
+                path.read_text(encoding="utf-8").splitlines(), 1)
+            if needle.search(line)]
+    assert not hits, hits
 
 
 # the names of the chip path that was retired: the plug-in, the relay it

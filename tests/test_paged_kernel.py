@@ -364,17 +364,21 @@ def test_tuning_bucket_key_buckets_like_compile_cache():
     assert tuning.bucket_key(d=64, h=12) == "d=64,h=12"
 
 
-def test_flash_tuned_blocks_reads_shared_store(tmp_path):
+def test_flash_tuned_blocks_reads_shared_store(tmp_path, monkeypatch):
     """_tuned_blocks consults the shared store first (kernel
-    "flash_fwd"), keeping FLASH_TUNED.json as the legacy fallback."""
+    "flash_fwd"), the kernel module's own table second."""
     from paddle_tpu.ops import pallas_ops
 
+    kind = tuning.device_kind()
+    monkeypatch.setattr(pallas_ops, "_TUNED_BLOCKS",
+                        {kind: {2048: (512, 512), 4096: (512, 512)}})
     tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
     try:
         tuning.adopt("flash_fwd", tuning.bucket_key(s=2048),
                      {"blk_q": 256, "blk_k": 512}, 10.0)
         tuning.reset()
-        assert pallas_ops._tuned_blocks(2048) == (256, 512)
+        assert pallas_ops._tuned_blocks(2048) == (256, 512)  # the store
+        assert pallas_ops._tuned_blocks(4096) == (512, 512)  # the table
     finally:
         tuning.set_store_path(None)
 

@@ -149,32 +149,3 @@ def test_interleaved_degenerate_paths():
     out2 = pipeline_apply_interleaved(chunk_fn, cp2, x, num_microbatches=4,
                                       num_chunks=1, mesh=mesh2)
     assert np.allclose(np.asarray(out2), ref2, atol=1e-5)
-
-
-def test_interleaved_beats_gpipe_wall_clock(tmp_path):
-    """VERDICT r3 weak-4: the formula's win must show on a clock, not just
-    in closed form. Runs the recorded bench (subprocess: it needs its own
-    8-device env) at M=4 — the largest predicted gain (1.27x) — and
-    accepts any measured win to stay robust to CPU noise; full M sweep
-    numbers live in benches/BASELINE_RESULTS.jsonl. d=1024: below that,
-    per-tick dispatch overhead on the emulated CPU mesh (the interleaved
-    schedule runs ~1.6x the ticks at 1/V the compute each) cancels the
-    bubble win and the ratio is pure noise."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ, PYTHONPATH="/root/repo")
-    r = subprocess.run(
-        [sys.executable, "-c",
-         "import sys; sys.path.insert(0, '/root/repo/benches'); "
-         "sys.path.insert(0, '/root/repo'); "
-         "import pipeline_bench as b, json; "
-         "print('ROW ' + json.dumps(b.measure(4, d=1024, iters=4)))"],
-        capture_output=True, text=True, timeout=600, env=env,
-        cwd="/root/repo")
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    row = json.loads(r.stdout.split("ROW ", 1)[1])
-    assert row["predicted_speedup"] > 1.2
-    assert row["speedup"] > 1.0, row  # measured win, noise-tolerant bar

@@ -181,11 +181,15 @@ def test_global_radix_index_residency():
 # ---------------------------------------------------------- engine: spill
 
 
-def test_spill_restore_byte_exact_including_int8_scales(model):
-    """An evicted prefix spilled to the host tier restores byte-identical
+@pytest.mark.parametrize("tier", ["host", "disk"])
+def test_spill_restore_byte_exact_including_int8_scales(model, tier,
+                                                        tmp_path):
+    """An evicted prefix spilled to the host tier (or, with a host budget
+    below one entry, through it to the disk tier) restores byte-identical
     — the int8 payload AND the f32 per-row scale pools — and the restore
     program never re-traces."""
-    store = HostKVCache(max_bytes=1 << 30, disk_dir="")
+    store = (HostKVCache(max_bytes=1 << 30, disk_dir="") if tier == "host"
+             else HostKVCache(max_bytes=1, disk_dir=str(tmp_path)))
     api = _tiered_api(model, store, quant_kv=True)
     try:
         rng = np.random.default_rng(1)
@@ -214,6 +218,7 @@ def test_spill_restore_byte_exact_including_int8_scales(model):
                     assert a.dtype == b.dtype
                     np.testing.assert_array_equal(a, b)
         assert eng.tier.restored_blocks == 2
+        assert (eng.tier.disk_hits > 0) == (tier == "disk")
         assert eng.restore_traces == 1
         # churn more spill/restore cycles: ONE compiled restore, ever
         for _ in range(2):

@@ -139,7 +139,7 @@ def test_paged_full_prefill_compiles(h, d):
 @pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
 def test_flash_fwd_bwd_compiles(h, d, blk):
     """Forward and both backward kernels, at the untuned tiling and the
-    (512, 512) that benches/FLASH_TUNED.json records for "TPU v5 lite"."""
+    (512, 512) that ``pallas_ops._TUNED_BLOCKS`` holds for "TPU v5 lite"."""
     x = _sds((2, 1024, h, d), jnp.bfloat16)
 
     def loss_and_grads(q, k, v):

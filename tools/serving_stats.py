@@ -59,7 +59,7 @@ step's default route where they compile natively, every route under
 ``kernel.prefill_traces`` / ``kernel.verify_traces`` counters (frozen
 after warmup in a healthy run — churn never re-lowers a kernel) and the
 end-of-run ``kernel.paged`` / ``kernel.tuned_entries`` gauges (the route
-the decode step was built with + tuning-store coverage for this chip, benches/TUNED_KERNELS.json).
+the decode step was built with + tuning-store coverage for this chip, ``ops.tuning``).
 The mesh-sharded execution core (ISSUE 14, docs/distributed.md) adds the
 ``mesh.devices`` / ``mesh.model_axis`` / ``mesh.data_axis`` topology
 gauges — a tensor-parallel run shows ``mesh.model_axis`` > 1 with the
