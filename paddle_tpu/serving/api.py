@@ -56,6 +56,15 @@ from .supervisor import EngineSupervisor
 
 _logger = logging.getLogger("paddle_tpu.serving")
 
+#: the longest a stream consumer blocks before it looks again. Liveness
+#: backstop only: every token, terminal state and backend swap wakes its
+#: consumer (``scheduler.StreamSignal``, the stream queue's sentinel), so
+#: this bounds what a wake-up lost to a bug would cost, and nothing waits
+#: for it in a healthy run. Seconds, not milliseconds, because a consumer
+#: whose request waits in the scheduler's queue (half of a loaded
+#: gateway's streams) pays one empty wake-up per period.
+STREAM_WAIT_S = 5.0
+
 #: every live ServingAPI, so process-level shutdown epilogues
 #: (``tools/serving_stats.py --run``, operator scripts) can drain them all
 _live_apis: "weakref.WeakSet" = weakref.WeakSet()
@@ -267,17 +276,20 @@ class ServingAPI:
     def stream(self, req: Request) -> Iterator[int]:
         """Yield ``req``'s tokens as they are generated; raises the
         request's error (deadline, shed, engine failure) at the end of a
-        failed stream."""
+        failed stream. With a pump thread the consumer blocks on the
+        stream queue until the pump puts a token or the finish sentinel;
+        without one the consumer is the pump, and an empty queue means
+        "step the scheduler"."""
         while True:
+            pumped = self._thread is not None
             try:
-                tok = req.stream_queue.get_nowait()
+                tok = req.stream_queue.get(
+                    pumped, STREAM_WAIT_S if pumped else None)
             except _queue.Empty:
                 if req.done_event.is_set():
                     break
-                if self._thread is None:
+                if not pumped:
                     self._pump_once()
-                else:
-                    time.sleep(0.001)
                 continue
             if tok is None:  # finish sentinel (always the queue's last item)
                 break

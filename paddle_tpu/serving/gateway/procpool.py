@@ -62,7 +62,7 @@ import numpy as np
 
 from ...core import flags, resilience
 from .. import metrics, telemetry
-from ..scheduler import RequestState
+from ..scheduler import RequestState, StreamSignal
 from . import worker
 from .router import _RESPAWN_BACKOFF_CAP, ReplicaPool, _is_reroutable
 
@@ -170,6 +170,11 @@ class RemoteRequest:
         self.state = RequestState.QUEUED
         self.error: Optional[BaseException] = None
         self.done_event = threading.Event()
+        # fired when a poll brought tokens or a terminal state: the
+        # router swaps its handle's own in at attach (as on a live
+        # ``scheduler.Request``), so a stream consumer of a background
+        # pool blocks until the watchdog's poll has something for it
+        self.signal = StreamSignal()
 
     @property
     def finished(self) -> bool:
@@ -198,6 +203,8 @@ class RemoteRequest:
             self.state = state
         if self.finished:
             self.done_event.set()
+        if tail or self.finished:
+            self.signal.fire()
 
     def _fail(self, cause: BaseException) -> None:
         if self.finished:
@@ -205,6 +212,7 @@ class RemoteRequest:
         self.error = cause
         self.state = RequestState.FAILED
         self.done_event.set()
+        self.signal.fire()
 
 
 # ---------------------------------------------------------- worker handle
@@ -690,9 +698,9 @@ class ProcessReplicaPool(ReplicaPool):
     boot takes seconds — it must not stall the survivors' token pumps),
     and guaranteed reaping."""
 
-    #: the watchdog loop already observes live streams and runs the WAL
-    #: sweep each supervision cycle — no separate sweeper thread
-    _wal_autosweep = False
+    #: the watchdog loop is this pool's housekeeping thread: it polls the
+    #: workers' tokens besides what the base pool's thread does
+    _own_housekeeping = False
 
     def __init__(self, model, replicas: Optional[int] = None,
                  config=None, tenants=None, background: bool = False,
@@ -963,12 +971,6 @@ class ProcessReplicaPool(ReplicaPool):
                     self._eject(rep, e)
                 else:
                     raise
-
-    def _observe_live(self) -> None:
-        with self._lock:
-            live = [rr for bucket in self._live.values() for rr in bucket]
-        for rr in live:
-            self._observe(rr)
 
     def _eject(self, rep, cause: BaseException) -> None:
         # fail the handle's live RemoteRequests BEFORE the base ejection:

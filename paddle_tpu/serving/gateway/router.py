@@ -44,11 +44,13 @@ the SIGTERM-drain semantics each API already had alone.
 
 Counters (``serving.metrics``): ``gateway.routed`` / ``gateway.rerouted``
 / ``gateway.affinity_routes`` / ``gateway.ejected`` / ``gateway.respawned``
-/ ``gateway.scale_downs`` / ``gateway.drains`` / ``gateway.guard_drains``;
+/ ``gateway.scale_downs`` / ``gateway.drains`` / ``gateway.guard_drains``
+/ ``gateway.stream_wakeups`` / ``gateway.stream_wait_timeouts``;
 gauges ``gateway.replicas_healthy`` / ``gateway.replicas_total`` /
-``gateway.outstanding``. Ejections/respawns mirror into
-``core.resilience`` as ``serving.replica_ejections`` /
-``serving.replica_respawns`` for the shared resilience dashboards.
+``gateway.outstanding`` / ``gateway.stream_consumers``. Ejections and
+respawns mirror into ``core.resilience`` as
+``serving.replica_ejections`` / ``serving.replica_respawns`` for the
+shared resilience dashboards.
 """
 from __future__ import annotations
 
@@ -64,8 +66,8 @@ import numpy as np
 
 from ...core import flags, resilience
 from .. import metrics, telemetry
-from ..api import ServingAPI
-from ..scheduler import Request, RequestState
+from ..api import STREAM_WAIT_S, ServingAPI
+from ..scheduler import Request, RequestState, StreamSignal
 from ..supervisor import CrashLoopError, is_transient_serving_error
 from .tenancy import TenantManager
 
@@ -73,6 +75,9 @@ _logger = logging.getLogger("paddle_tpu.serving.gateway")
 
 _RESPAWN_BACKOFF_CAP = 30.0
 _REAP_EVERY = 16  # submits between abandoned-handle sweeps
+#: period of a background pool's one housekeeping thread (respawn, breaker
+#: sweep, observe pass, WAL heartbeat)
+_HOUSEKEEPING_S = 0.005
 _gw_counter = itertools.count()
 
 
@@ -247,6 +252,11 @@ class RoutedRequest:
         self.state = RequestState.QUEUED
         self.error: Optional[BaseException] = None
         self.done_event = threading.Event()
+        # what a background pool's stream consumer blocks on: handed to
+        # every backend this handle rides (its tokens and its terminal
+        # state fire it) and fired by the router whenever it changes the
+        # handle under the consumer (attach, finalize, cancel)
+        self.signal = StreamSignal()
         self._lock = threading.Lock()
         self._base: List[int] = []      # tokens from previous backends
         self._backend: Optional[Request] = None
@@ -304,6 +314,7 @@ class RoutedRequest:
             backend = self._backend
         if backend is not None:
             backend.cancel()
+        self.signal.fire()
 
     # ------------------------------------------------------------ plumbing
 
@@ -320,6 +331,10 @@ class RoutedRequest:
             # every consumer already saw reach a terminal state
             if self.state == RequestState.QUEUED:
                 self.state = RequestState.RUNNING
+        # from here the backend's tokens and its terminal state wake this
+        # handle's consumers; the fire covers whatever it emitted before
+        backend.signal = self.signal
+        self.signal.fire()
 
     def _detach_journal(self) -> List[int]:
         """Fold the (dead) backend's tokens into the journal and detach;
@@ -343,6 +358,7 @@ class RoutedRequest:
             self.state = state
             self.error = error
         self.done_event.set()
+        self.signal.fire()
 
 
 class ReplicaPool:
@@ -355,10 +371,11 @@ class ReplicaPool:
     HTTP gateway runs on); ``background=False`` keeps pumping in the
     consumer's thread — deterministic, what the tests and bench drive."""
 
-    #: WAL'd background pools run a dedicated observe+commit sweeper
-    #: thread; subclasses with their own supervision loop (the process
-    #: pools' watchdog) turn this off and sweep from there instead
-    _wal_autosweep = True
+    #: a background pool runs ONE housekeeping thread (respawn, breaker
+    #: sweep, observe pass, WAL heartbeat) so that its stream consumers do
+    #: none of it; subclasses with their own supervision loop (the process
+    #: pools' watchdog) turn this off and do the same work from there
+    _own_housekeeping = True
 
     def __init__(self, model, replicas: Optional[int] = None,
                  config=None, tenants: Optional[TenantManager] = None,
@@ -421,6 +438,10 @@ class ReplicaPool:
         self._guard_grace: Optional[float] = None
         self.drain_count = 0
         self._reap_tick = 0
+        self._consumers = 0  # stream() generators alive, under its own lock
+        self._consumers_lock = threading.Lock()
+        self._housekeeping_stop = threading.Event()
+        self._housekeeper: Optional[threading.Thread] = None
         self._refresh_gauges()
         if wal is not None:
             # replay the previous incarnation's accepted streams: live
@@ -436,17 +457,15 @@ class ReplicaPool:
                                  daemon=True).start()
             else:
                 self._wal_recover()
-            if self._background and self._wal_autosweep:
-                # a background in-process pool has no pump thread of its
-                # own (each replica's engine pumps itself; consumers
-                # drive observe from their wait loops) — but durability
-                # must not depend on a client blocking in stream():
-                # this sweeper is the WAL's commit heartbeat. The
-                # process pools override _wal_autosweep off — their
-                # watchdog already observes live streams and sweeps.
-                threading.Thread(target=self._wal_sweeper_loop,
-                                 name="gateway-wal-sweep",
-                                 daemon=True).start()
+        if self._background and self._own_housekeeping:
+            # each replica's engine pumps itself and a stream consumer
+            # only waits for its tokens, so everything else a fleet needs
+            # done (and the WAL's commit heartbeat: durability must not
+            # depend on a client blocking in stream()) runs here, once
+            self._housekeeper = threading.Thread(
+                target=self._housekeeping_loop, name="gateway-housekeeping",
+                daemon=True)
+            self._housekeeper.start()
 
     def _spawn_api(self, idx: int) -> ServingAPI:
         api = ServingAPI(self._factory(), **self._api_kw)
@@ -930,10 +949,7 @@ class ReplicaPool:
         self._reap_tick += 1
         if self._reap_tick % _REAP_EVERY:
             return
-        with self._lock:
-            live = [rr for bucket in self._live.values() for rr in bucket]
-        for rr in live:
-            self._observe(rr)
+        self._observe_live()
 
     def _replica_at(self, idx: int) -> Optional[_Replica]:
         with self._lock:
@@ -1028,21 +1044,32 @@ class ReplicaPool:
         finally:
             self._wal_sweep_lock.release()
 
-    def _wal_sweeper_loop(self) -> None:
-        """Background WAL heartbeat: reconcile every live stream with its
-        backend (so finished streams get their TERMINAL record even with
-        no consumer polling) and run one batched sweep+commit. Exits on
-        drain/close — ``drain()`` runs the final sweep itself."""
-        while True:
-            with self._lock:
-                if self._closed or self._draining:
-                    return
-                live = [rr for bucket in self._live.values()
-                        for rr in bucket]
-            for rr in live:
-                self._observe(rr)
-            self._wal_sweep()
-            time.sleep(0.005)
+    def _observe_live(self) -> None:
+        with self._lock:
+            live = [rr for bucket in self._live.values() for rr in bucket]
+        for rr in live:
+            self._observe(rr)
+
+    def _housekeeping_loop(self) -> None:
+        """A background pool's one housekeeping thread: bring ejected
+        replicas back, eject those whose breaker opened, reconcile every
+        live stream with its backend (a finished stream gets finalized,
+        and its TERMINAL record, with no consumer attached) and run one
+        batched WAL sweep+commit — what every consumer's wait loop used
+        to do a thousand times a second. Runs through a graceful drain
+        (in-flight streams keep their commit cadence) and ends when the
+        drain does; ``drain()`` runs the final sweep itself."""
+        while not self._housekeeping_stop.wait(_HOUSEKEEPING_S):
+            try:
+                self._maybe_respawn()
+                self._sweep_health()
+                self._observe_live()
+                self._wal_sweep()
+            # analysis: allow(broad-except) — nothing else would respawn a
+            # replica or commit the WAL: a failed sweep must leave the
+            # thread alive for the next one
+            except Exception:
+                _logger.exception("gateway housekeeping sweep failed")
 
     def _wal_recover(self) -> None:
         """Replay the WAL's recovered state into this pool: live streams
@@ -1186,34 +1213,51 @@ class ReplicaPool:
             else:
                 raise
 
-    def _pump(self) -> None:
-        if self._background:
-            self._maybe_respawn()
-            self._sweep_health()
-            self._wal_sweep()
-            time.sleep(0.001)
-        else:
+    def _wait_for(self, rr: RoutedRequest, seen: int) -> None:
+        """One turn of a consumer with nothing to read. On a foreground
+        pool the consumer IS the pump: step every replica. On a
+        background pool the replicas pump themselves and housekeeping has
+        its own thread, so block until ``rr``'s signal fires past
+        ``seen`` (a token, a terminal backend, a re-route, a finalize, a
+        cancel); the timeout is the liveness backstop."""
+        if not self._background:
             self.pump_once()
+            return
+        fired = rr.signal.wait(seen, STREAM_WAIT_S)
+        metrics.bump("gateway.stream_wakeups")
+        if not fired:
+            metrics.bump("gateway.stream_wait_timeouts")
+
+    def _count_consumer(self, delta: int) -> None:
+        with self._consumers_lock:
+            self._consumers += delta
+            metrics.set_gauge("gateway.stream_consumers", self._consumers)
 
     def stream(self, rr: RoutedRequest):
         """Yield ``rr``'s tokens as they are generated — across replica
         ejections and re-routes. Raises the request's error at the end of
         a failed stream (mirrors ``ServingAPI.stream``)."""
         sent = 0
-        while True:
+        self._count_consumer(+1)
+        try:
+            while True:
+                seen = rr.signal.seq  # before the read: a fire after it
+                for tok in rr.tokens_from(sent):  # cuts the wait short
+                    yield int(tok)
+                    sent += 1
+                if rr.finished:
+                    break
+                self._observe(rr)
+                if rr.finished:
+                    continue  # flush tokens folded in by the finalize
+                self._wait_for(rr, seen)
+            # drain any tokens recorded between the last read and the
+            # finalize
             for tok in rr.tokens_from(sent):
                 yield int(tok)
                 sent += 1
-            if rr.finished:
-                break
-            self._observe(rr)
-            if rr.finished:
-                continue  # flush tokens folded in by the finalize
-            self._pump()
-        # drain any tokens recorded between the last read and the finalize
-        for tok in rr.tokens_from(sent):
-            yield int(tok)
-            sent += 1
+        finally:
+            self._count_consumer(-1)
         if rr.state == RequestState.FAILED and rr.error is not None:
             raise rr.error
 
@@ -1229,7 +1273,7 @@ class ReplicaPool:
             if self._background:
                 rr.done_event.wait(0.01)
             else:
-                self._pump()
+                self.pump_once()
         if rr.state == RequestState.FAILED:
             raise rr.error
         if rr.state == RequestState.CANCELLED:
@@ -1240,16 +1284,16 @@ class ReplicaPool:
         """Pump every replica until no routed request is live (foreground
         helper for tests/benches)."""
         while True:
+            self._observe_live()
             with self._lock:
                 live = [rr for bucket in self._live.values()
                         for rr in bucket]
-            for rr in live:
-                self._observe(rr)
-            with self._lock:
-                busy = any(bucket for bucket in self._live.values())
-            if not busy:
+            if not live:
                 return
-            self._pump()
+            if self._background:
+                live[0].done_event.wait(0.01)
+            else:
+                self.pump_once()
 
     # ------------------------------------------------------- drain / scale
 
@@ -1290,6 +1334,7 @@ class ReplicaPool:
         # disk NOW — before close() tears anything else down (satellite 2:
         # a clean shutdown never leaves live-looking records)
         self._wal_sweep(final=True)
+        self._housekeeping_stop.set()
         self._refresh_gauges()
 
     def close(self) -> None:
@@ -1308,6 +1353,9 @@ class ReplicaPool:
                 _logger.exception("closing replica %d failed", rep.idx)
         with self._lock:
             self._closed = True
+        hk = self._housekeeper
+        if hk is not None and hk is not threading.current_thread():
+            hk.join(timeout=2.0)  # its last sweep must not meet a closed WAL
         if self.wal is not None:
             self.wal.close()
 

@@ -42,7 +42,10 @@ Counter namespaces:
   ``affinity_routes`` (warm-cache wins within the bounded slack) /
   ``ejected`` / ``respawned`` (replica health) / ``scale_downs`` /
   ``drains`` / ``guard_drains`` / ``http_submits`` / ``http_streams`` /
-  ``client_disconnects`` (mid-stream hangups, cancelled server-side)
+  ``client_disconnects`` (mid-stream hangups, cancelled server-side) /
+  ``stream_wakeups`` (a background pool's stream consumer came back from
+  its wait: about one a token) / ``stream_wait_timeouts`` (it came back
+  by the backstop with nothing new)
 * ``tenant.*``     — quota admission: ``admitted`` / ``completed`` /
   ``shed_rate`` / ``shed_concurrency`` / ``shed_share``, plus per-tenant
   ``tenant.<name>.admitted`` / ``.shed`` / ``.tokens_out`` (goodput)
@@ -118,6 +121,7 @@ over its :class:`Meter`'s sliding window — idle tails decay it to 0
 instead of averaging into a lifetime mean),
 ``gateway.replicas_healthy`` / ``gateway.replicas_total`` /
 ``gateway.outstanding`` (the router's fleet picture),
+``gateway.stream_consumers`` (``ReplicaPool.stream`` generators alive),
 ``sampling.active_slots`` / ``constrain.active_slots`` /
 ``lora.active_slots`` (scenario mix of the live batch), and the adapter
 arena's ``lora.slots`` / ``lora.live`` / ``lora.arena_bytes``.

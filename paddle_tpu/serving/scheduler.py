@@ -59,6 +59,42 @@ _req_counter = itertools.count()
 _seq_counter = itertools.count()  # arrival / admission ordering ticks
 
 
+class StreamSignal:
+    """What a stream consumer blocks on while it has nothing to read.
+
+    A producer calls :meth:`fire` after every change a consumer may be
+    waiting for (a token landed, the request reached a terminal state, the
+    gateway swapped the backend under a routed handle); a consumer notes
+    :attr:`seq` BEFORE it reads, and blocks in :meth:`wait` on that value
+    only if the read found nothing. A fire between the read and the wait
+    has moved ``seq`` on, so the wait returns at once: no wake-up is lost,
+    whatever the number of consumers (an ``Event`` one consumer clears
+    would lose the other's)."""
+
+    __slots__ = ("_cond", "_seq")
+
+    def __init__(self):
+        self._cond = threading.Condition(threading.Lock())
+        self._seq = 0
+
+    @property
+    def seq(self) -> int:
+        return self._seq
+
+    def fire(self) -> None:
+        with self._cond:
+            self._seq += 1
+            self._cond.notify_all()
+
+    def wait(self, seen: int, timeout: float) -> bool:
+        """Block until fired past ``seen`` or ``timeout`` seconds; True if
+        it was fired."""
+        with self._cond:
+            if self._seq == seen:
+                self._cond.wait(timeout)
+            return self._seq != seen
+
+
 class RequestState:
     QUEUED = "QUEUED"
     RUNNING = "RUNNING"
@@ -78,7 +114,9 @@ class Request:        # compare numpy prompt payloads
     where decode left off. ``priority`` follows the vLLM convention —
     LOWER values are served first, default 0 is normal traffic.
     ``stream_queue``/``done_event`` are the streaming surface
-    ``api.stream()`` consumes."""
+    ``api.stream()`` consumes; ``signal`` fires beside them for a consumer
+    that reads ``tokens`` instead (the gateway's ``RoutedRequest`` hands
+    its own in at attach, so one wait covers every backend it rides)."""
 
     prompt: np.ndarray
     max_new_tokens: int = 32
@@ -103,6 +141,7 @@ class Request:        # compare numpy prompt payloads
     stream_queue: "_queue.SimpleQueue" = field(
         default_factory=_queue.SimpleQueue)
     done_event: threading.Event = field(default_factory=threading.Event)
+    signal: StreamSignal = field(default_factory=StreamSignal)
     _cancel: bool = False
     _arrival: int = 0     # submit-order tick (priority tie-break)
     _admit_seq: int = 0   # last admission tick ("most recent victim")
@@ -285,6 +324,7 @@ class Scheduler:
                        error=type(error).__name__ if error else None)
         req.stream_queue.put(None)  # stream sentinel
         req.done_event.set()
+        req.signal.fire()
 
     def _emit(self, req: Request, token: int) -> None:
         if req.finished:
@@ -305,6 +345,7 @@ class Scheduler:
         req._last_emit_ts = now
         req.tokens.append(int(token))
         req.stream_queue.put(int(token))
+        req.signal.fire()
         if req.constraint is not None:
             # advance the host-side walker one token and scatter the new
             # allowed-vocab row into the slot's mask (runtime data — the
