@@ -19,4 +19,10 @@ from .olmo_hybrid import (  # noqa: F401
     OlmoHybridModel,
     olmo_hybrid_tiny,
 )
+from .phi4flash import (  # noqa: F401
+    Phi4FlashConfig,
+    Phi4FlashForCausalLM,
+    Phi4FlashModel,
+    phi4flash_tiny,
+)
 from .widedeep import DeepFM, DistributedEmbedding, WideDeep  # noqa: F401

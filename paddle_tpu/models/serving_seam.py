@@ -3,17 +3,23 @@
 model shares (docs/serving_model_seam.md).
 
 **What a model declares** (methods on the ``nn.Layer`` it hands the engine;
-:class:`~paddle_tpu.models.gpt.GPTForCausalLM` and
-:class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM` both do):
+:class:`~paddle_tpu.models.gpt.GPTForCausalLM`,
+:class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM` and
+:class:`~paddle_tpu.models.phi4flash.Phi4FlashForCausalLM` do):
 
-* ``serving_spec() -> ServingSpec``: vocabulary, longest context, and one
-  :class:`KVLayerState` or :class:`RecurrentLayerState` per layer, in order:
-  the KIND of per-request state that layer keeps and its shape;
+* ``serving_spec() -> ServingSpec``: vocabulary, longest context, one state
+  declaration per layer, in order (the KIND of per-request state that layer
+  keeps and its shape: :class:`KVLayerState`, :class:`RecurrentLayerState`,
+  :class:`WindowLayerState`, :class:`SharedKVLayerState`,
+  :class:`StatelessLayerState`), and ``prefill_tail``: the first layer that
+  a prefill runs on each request's last valid token only (None: every layer
+  runs on every token);
 * ``serving_embed(ids, positions)``: ``[lanes, s]`` token ids at per-lane
   start positions -> hidden states;
 * ``serving_layers()``: the layers, each called
   ``layer(x, cache=view, start_pos=positions) -> (x, successor view)`` with a
-  cache view of ITS kind, built by the engine;
+  cache view of ITS kind, built by the engine; a layer whose class sets
+  ``uses_step_carry = True`` is also handed ``carry=``, see below;
 * ``serving_final(x)``: the final norm; ``serving_head(h_last)``:
   ``[b, hidden]`` arrays -> ``[b, vocab]`` logits;
 * ``serving_linears()``: ``(site, linear)`` for every matmul the int8
@@ -21,13 +27,34 @@ model shares (docs/serving_model_seam.md).
 * ``serving_embedding()``: the token table (never quantized: its dtype is
   the compute dtype).
 
-**The two cache-view protocols.** A ``"kv"`` layer drives
+**The cache-view protocols.** A ``"kv"`` layer drives
 ``view.update_and_attend(q, k, v) -> (attention output, successor)`` with
-``[b, s, heads, head_dim]`` arrays; the view owns the paged layout. A
+``q`` ``[b, s, heads, head_dim]`` and ``k``, ``v`` ``[b, s, kv_heads,
+head_dim]`` (``heads`` a multiple of ``kv_heads``: query head ``h`` reads
+K/V head ``h // (heads // kv_heads)``); the view owns the paged layout. A
+``"window"`` layer drives the same call; its view keeps the last ``window``
+tokens' K/V of each lane (a ring per lane in the slot-indexed store: the
+model has no positions, so the order inside the ring is free) and query
+``t`` attends keys ``t - window + 1 .. t``. A ``"shared"`` layer owns no
+cache: its view reads the pool of the layer it names, as that layer left it
+in this same call, through ``view.attend(q)``, and writes nothing. A
 ``"recurrent"`` layer drives ``view.read() -> state arrays`` (the order of
 its :class:`RecurrentLayerState`), ``view.valid_len`` (None, or the traced
 true length of a padded prefill) and ``view.write(new arrays) -> successor``;
-the view owns the per-lane store and which lanes a write reaches.
+the view owns the per-lane store and which lanes a write reaches. A
+``"none"`` layer is handed ``cache=None`` and returns ``(x, None)``.
+
+**The step carry.** ``forward_cached`` hands every layer that sets
+``uses_step_carry`` one dict, the same for the whole call: a layer publishes
+``carry[name] = value`` (``[b, s, ...]``) and a later layer of the same call
+reads it. It is no cache: nothing of it outlives the call.
+
+**The prefill tail.** With ``prefill_tail = n`` a prefill hands
+``forward_cached`` each request's last valid index: from layer ``n`` on the
+hidden states (and every carried value) are that one row. The view of a
+``"kv"`` layer ``n - 1`` says so too (``view.last``), so that such a layer
+can write K/V for every token and narrow everything else to the last row
+itself. Exact, not an approximation: nothing else of those layers is read.
 
 The engine never names a model class or a model's attribute beyond these.
 """
@@ -35,7 +62,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,11 +80,60 @@ from ..distributed.sharding_util import constraint
 @dataclass(frozen=True)
 class KVLayerState:
     """A layer whose per-request state is keys and values, one row a token:
-    it lives in the paged arena's block pools."""
+    it lives in the paged arena's block pools. ``num_heads`` query heads
+    read ``num_kv_heads`` K/V heads (None: as many)."""
 
     num_heads: int
     head_dim: int
     kind: str = "kv"
+    num_kv_heads: Optional[int] = None
+
+    @property
+    def kv_heads(self) -> int:
+        return int(self.num_kv_heads or self.num_heads)
+
+
+@dataclass(frozen=True)
+class WindowLayerState:
+    """A layer that keeps the last ``window`` tokens' keys and values: a
+    fixed size per lane, so it lives in the slot-indexed store (K and V
+    ``[kv_heads, window, head_dim]`` a lane: the heads outside the tokens,
+    as the attention over the ring reads them, so that neither the
+    one-row write nor the read relays the ring out), reset at admission
+    and freed with the lane, with no block accounting."""
+
+    num_heads: int
+    head_dim: int
+    window: int
+    num_kv_heads: Optional[int] = None
+    kind: str = "window"
+
+    @property
+    def kv_heads(self) -> int:
+        return int(self.num_kv_heads or self.num_heads)
+
+    def arrays(self, dtype: str):
+        """The store's arrays, in :class:`RecurrentLayerState`'s form."""
+        shape = (self.kv_heads, int(self.window), int(self.head_dim))
+        return (("k", shape, dtype), ("v", shape, dtype))
+
+
+@dataclass(frozen=True)
+class SharedKVLayerState:
+    """A layer that owns no cache and attends the pool of layer ``source``
+    (a ``"kv"`` layer before it) through the same block tables."""
+
+    source: int
+    num_heads: int
+    head_dim: int
+    kind: str = "shared"
+
+
+@dataclass(frozen=True)
+class StatelessLayerState:
+    """A layer with no per-request state of its own."""
+
+    kind: str = "none"
 
 
 @dataclass(frozen=True)
@@ -70,27 +146,59 @@ class RecurrentLayerState:
     kind: str = "recurrent"
 
 
+#: the kinds whose state lies in the arena's slot-indexed store
+SLOT_KINDS = ("recurrent", "window")
+
+
 @dataclass(frozen=True)
 class ServingSpec:
     vocab_size: int
     max_positions: int
     layers: Tuple[object, ...]
+    #: first layer a prefill runs on each request's last valid token only
+    prefill_tail: Optional[int] = None
 
     def kv_layers(self):
         return [s for s in self.layers if s.kind == "kv"]
 
-    def recurrent_layers(self):
-        return [s for s in self.layers if s.kind == "recurrent"]
+
+class SharedRef:
+    """Stands in ``forward_cached``'s views for a ``"shared"`` layer: its
+    real view is ``reader()`` of the SUCCESSOR view of layer ``source``,
+    which exists only once that layer has run."""
+
+    def __init__(self, source: int):
+        self.source = int(source)
 
 
-def forward_cached(model, ids, views, positions):
+def last_row(a, last):
+    """``a[:, last]`` kept as a length-1 axis (``last`` a traced scalar)."""
+    return jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1)
+
+
+def forward_cached(model, ids, views, positions, last=None,
+                   prefill_tail=None):
     """Embed -> layers (each handed its cache view) -> final norm: the one
     way a compiled serving program runs a model. Returns ``(hidden
-    [lanes, s, hidden] Tensor, successor views)``."""
+    [lanes, s, hidden] Tensor, successor views)``. With ``prefill_tail``
+    and ``last`` (a prefill of a model that declares a tail) the layers
+    from ``prefill_tail`` on see row ``last`` alone, and ``hidden`` is
+    ``[lanes, 1, hidden]``."""
     x = model.serving_embed(ids, positions)
-    new_views = []
-    for layer, view in zip(model.serving_layers(), views):
-        x, nv = layer(x, cache=view, start_pos=positions)
+    new_views, carry = [], {}
+    for i, (layer, view) in enumerate(zip(model.serving_layers(), views)):
+        if isinstance(view, SharedRef):
+            view = new_views[view.source].reader()
+        if prefill_tail is not None and i == prefill_tail:
+            if x.shape[1] != 1:  # layer i - 1 did not narrow it itself
+                x = Tensor(last_row(x._data, last))
+            for name, value in carry.items():
+                if value.shape[1] != 1:
+                    carry[name] = last_row(value, last)
+        if getattr(layer, "uses_step_carry", False):
+            x, nv = layer(x, cache=view, start_pos=positions, carry=carry)
+        else:
+            x, nv = layer(x, cache=view, start_pos=positions)
         new_views.append(nv)
     return model.serving_final(x), new_views
 
@@ -106,6 +214,8 @@ def masked_attention(qa, ka, va, mask):
     must produce token-for-token identical greedy decodes, so they must
     run the exact same ops (same dtypes, same -1e30 masking, same fp32
     softmax)."""
+    if ka.shape[2] != qa.shape[2]:
+        return _grouped_attention(qa, ka, va, mask)
     qt = jnp.swapaxes(qa, 1, 2)  # [b, h, s, d]
     kt = jnp.swapaxes(ka, 1, 2)
     vt = jnp.swapaxes(va, 1, 2)
@@ -114,6 +224,21 @@ def masked_attention(qa, ka, va, mask):
     logits = jnp.where(mask, logits, -1e30)
     p = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(qa.dtype)
     return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vt), 1, 2)
+
+
+def _grouped_attention(qa, ka, va, mask):
+    """:func:`masked_attention` where ``ka``/``va`` have fewer heads than
+    ``qa``: query head ``h`` reads K/V head ``h // group``. The same ops on
+    a ``[b, kv_heads, group, ...]`` view of the queries, so no K/V row is
+    repeated in memory. ``mask`` broadcasts against ``[b, heads, s,
+    kv_len]`` with a heads axis of 1."""
+    b, s, h, d = qa.shape
+    kvh = ka.shape[2]
+    qg = qa.reshape(b, s, kvh, h // kvh, d)
+    logits = jnp.einsum("bqkgd,btkd->bkgqt", qg, ka) / math.sqrt(d)
+    logits = jnp.where(mask[:, :, None], logits, -1e30)
+    p = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(qa.dtype)
+    return jnp.einsum("bkgqt,btkd->bqkgd", p, va).reshape(b, s, h, d)
 
 
 #: multi-LoRA hook (serving.adapters): called as hook(layer, x, y) inside

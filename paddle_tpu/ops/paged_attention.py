@@ -285,18 +285,20 @@ def write_token(pool, blocks, offsets, rows):
     return jnp.swapaxes(slab.reshape(nb, h, bs, d), 1, 2)
 
 
-def _tile_columns(pages, bs, heads, hq, head_major):
+def _tile_columns(pages, bs, heads, hq, head_major, group=1):
     """What every tile's mask is made of (host constants): the bias
-    ``[hq, cols]`` that leaves query row h only the columns of head h, and
-    the token within the tile ``[1, cols]`` of each column, for the row
-    order of :func:`_page_slabs`."""
+    ``[hq, cols]`` that leaves query row h only the columns of K/V head
+    ``h // group`` (``group`` query heads read one K/V head; 1: head h),
+    and the token within the tile ``[1, cols]`` of each column, for the
+    row order of :func:`_page_slabs`."""
     import numpy as np
 
     r = np.arange(bs * heads)
     head, tok = (r // bs, r % bs) if head_major else (r % heads, r // heads)
     head = np.tile(head, pages)
     tok = (np.arange(pages)[:, None] * bs + tok[None, :]).reshape(-1)
-    bias = np.where(head[None, :] == np.arange(hq)[:, None], 0.0, NEG_INF)
+    bias = np.where(head[None, :] == np.arange(hq)[:, None] // group, 0.0,
+                    NEG_INF)
     return bias.astype(np.float32), tok.astype(np.int32)[None, :]
 
 
@@ -417,14 +419,17 @@ def _decode_call(q, entry, block_tables, lengths, pages):
     and lowered kernel inside the step program (24 tracings of the kernel
     cost the serving cell a minute of set-up). ``pages``: the tuned tile
     size, or a falsy value for the default."""
-    S, H, D = q.shape
+    S, HQ, D = q.shape
     quantized = len(entry) == 4
-    NB, bs = entry[0].shape[:2]
+    NB, bs, H = entry[0].shape[:3]  # H: the pool's (K/V) heads
+    if HQ % H:
+        raise ValueError(f"{HQ} query heads do not divide over {H} K/V "
+                         "heads")
     MB = block_tables.shape[1]
     head_major = _head_major(H)
     kp, vp = (_page_slabs(pool) for pool in entry[:2])
     rows, Dp = bs * H, kp.shape[2]
-    hq = -(-H // 8) * 8  # query rows: whole f32 sublane tiles
+    hq = -(-HQ // 8) * 8  # query rows: whole f32 sublane tiles
     pages = _tile_pages(MB, rows * Dp * kp.dtype.itemsize, pages)
     cols = pages * rows
     # the copies run one tile ahead, across lanes: nxt[0] is the first live
@@ -434,13 +439,14 @@ def _decode_call(q, entry, block_tables, lengths, pages):
     nxt = jax.lax.cummin(jnp.where(lengths > 0, lane, S), axis=0,
                          reverse=True)
     nxt = jnp.concatenate([nxt, jnp.full((1,), S, jnp.int32)])
-    bias, tok = _tile_columns(pages, bs, H, hq, head_major)
+    bias, tok = _tile_columns(pages, bs, H, hq, head_major, HQ // H)
     q_spec = pl.BlockSpec((1, hq, Dp), lambda s, *_: (s, 0, 0))
     whole = lambda a: pl.BlockSpec(a.shape, lambda s, *_: (0,) * a.ndim)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, whole(bias), whole(tok), any_spec, any_spec]
     args = [block_tables, lengths, nxt,
-            jnp.pad(q, ((0, 0), (0, hq - H), (0, Dp - D))), bias, tok, kp, vp]
+            jnp.pad(q, ((0, 0), (0, hq - HQ), (0, Dp - D))), bias, tok, kp,
+            vp]
     if quantized:
         # per-token scales of each lane's table, laid out like a tile's
         # columns so they multiply score columns as they lie (a
@@ -477,14 +483,17 @@ def _decode_call(q, entry, block_tables, lengths, pages):
         interpret=_use_interpret(),
         name="paged_decode",
     )(*args)
-    return out[:, :H, :D]
+    return out[:, :HQ, :D]
 
 
 def paged_decode_attention(q, entry, block_tables, positions, active=None,
                            pages=None, mesh=None):
     """Decode attention straight through the block tables.
 
-    ``q`` is ``[S, H, D]`` (each slot's new token, heads unflattened);
+    ``q`` is ``[S, HQ, D]`` (each slot's new token, heads unflattened;
+    ``HQ`` a multiple of the pool's ``H``: query head ``h`` reads K/V head
+    ``h // (HQ // H)``, the mask of the tile's bias, so grouped queries
+    cost no second read of a K/V row);
     ``entry`` is one layer's whole arena pool entry — ``(k, v)`` pools
     shaped ``[num_blocks, block_size, H, D]``, or int8
     ``(k, v, k_scale, v_scale)`` with ``[num_blocks, block_size]`` scale
@@ -507,8 +516,8 @@ def paged_decode_attention(q, entry, block_tables, positions, active=None,
     if _mesh_routes(mesh):
         return _sharded_decode(q, entry, block_tables, lengths, pages, mesh)
     if pages is None:
-        pages = _tuned_pages(q.shape[1], q.shape[2], entry[0].shape[1],
-                             block_tables.shape[1])
+        pages = _tuned_pages(entry[0].shape[2], q.shape[2],
+                             entry[0].shape[1], block_tables.shape[1])
     return _decode_call(q, entry, block_tables, lengths, pages)
 
 
@@ -533,7 +542,7 @@ def _sharded_decode(q, entry, block_tables, lengths, pages, mesh):
     from ..distributed.sharding_util import (headwise_shard_map,
                                              mesh_axes_key)
 
-    S, H, D = q.shape
+    D, H = q.shape[2], entry[0].shape[2]  # the pool's heads shard
     if pages is None:
         pages = _tuned_pages(_local_heads(H, mesh), D, entry[0].shape[1],
                              block_tables.shape[1], mesh_axes_key(mesh))
@@ -553,10 +562,13 @@ def _sharded_decode(q, entry, block_tables, lengths, pages, mesh):
 
 
 def _prefill_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, *rest, bs,
-                    blk_q, blk_h, scale, quantized):
+                    blk_q, blk_h, scale, quantized, sq=None):
     """One (head-group, query-tile, logical-block) step of suffix/chunk
     prefill: flash-style causal attention at global positions
-    ``prefix_len + i`` (``meta_ref[0]`` = the runtime prefix length)."""
+    ``prefix_len + i`` (``meta_ref[0]`` = the runtime prefix length).
+    ``sq`` (grouped queries only): the query axis holds the ``sq`` rows of
+    each query head of a K/V head one after another, so a tile's first row
+    sits at position ``(qi * blk_q) % sq``."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -572,12 +584,17 @@ def _prefill_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, *rest, bs,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     prefix = meta_ref[0]
+    if sq is None:
+        row0, past = qi * blk_q, prefix + (qi + 1) * blk_q - 1
+    else:
+        row0 = (qi * blk_q) % sq
+        past = prefix + row0 + blk_q - 1
 
     # a block strictly past this tile's last global row is fully masked
-    @pl.when(j * bs <= prefix + (qi + 1) * blk_q - 1)
+    @pl.when(j * bs <= past)
     def _step():
         block = bt_ref[j]
-        rows = prefix + qi * blk_q + jax.lax.broadcasted_iota(
+        rows = prefix + row0 + jax.lax.broadcasted_iota(
             jnp.int32, (1, blk_q, bs), 1)
         cols = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (1, blk_q, bs), 2)
@@ -608,10 +625,14 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
     if _mesh_routes(mesh):
         return _sharded_prefill(q, entry, bt_row, prefix_len,
                                 block_q, block_h, mesh)
-    sq, H, D = q.shape
+    sq, HQ, D = q.shape
     quantized = len(entry) == 4
     kp, vp = entry[0], entry[1]
-    bs = kp.shape[1]
+    bs, H = kp.shape[1], kp.shape[2]  # H: the pool's (K/V) heads
+    group = HQ // H
+    if HQ % H:
+        raise ValueError(f"{HQ} query heads do not divide over {H} K/V "
+                         "heads")
     MB = bt_row.shape[0]
     if block_q is None and block_h is None:
         from . import tuning
@@ -623,13 +644,20 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
             block_q, block_h = rec.get("block_q"), rec.get("block_h")
     blk_q = _query_block(sq, block_q)
     blk_h = _head_group(H, block_h)
-    grid = (H // blk_h, sq // blk_q, MB)
     kern = functools.partial(_prefill_kernel, bs=bs, blk_q=blk_q,
                              blk_h=blk_h, scale=1.0 / math.sqrt(D),
-                             quantized=quantized)
+                             quantized=quantized,
+                             **({"sq": sq} if group > 1 else {}))
     # head-major query/output layout so neither the kernel nor Mosaic
-    # transposes inside VMEM; the swapaxes below stay in XLA
-    q_hm = jnp.swapaxes(q, 0, 1)  # [H, sq, D]
+    # transposes inside VMEM; the swapaxes below stay in XLA. Grouped
+    # queries: the rows of a K/V head's query heads lie one after another
+    # on the query axis, [H, group * sq, D], so a grid step still pairs
+    # one K/V head with one tile of rows
+    q_hm = jnp.swapaxes(q, 0, 1)  # [HQ, sq, D]
+    out_heads = HQ
+    if group > 1:
+        q_hm, sq = q_hm.reshape(H, group * sq, D), group * sq
+    grid = (H // blk_h, sq // blk_q, MB)
     in_specs = [
         pl.BlockSpec((blk_h, blk_q, D),
                      lambda g, qi, j, bt, meta: (g, qi, 0)),
@@ -666,7 +694,7 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
         interpret=_use_interpret(),
         name="paged_prefill",
     )(*args)
-    return jnp.swapaxes(out, 0, 1)
+    return jnp.swapaxes(out.reshape(out_heads, -1, D), 0, 1)
 
 
 def _sharded_prefill(q, entry, bt_row, prefix_len, block_q, block_h, mesh):
@@ -676,7 +704,7 @@ def _sharded_prefill(q, entry, bt_row, prefix_len, block_q, block_h, mesh):
     from ..distributed.sharding_util import (headwise_shard_map,
                                              mesh_axes_key)
 
-    sq, H, D = q.shape
+    (sq, _, D), H = q.shape, entry[0].shape[2]  # the pool's heads shard
     if block_q is None and block_h is None:
         from . import tuning
 
@@ -715,15 +743,16 @@ def paged_full_prefill_attention(q, k, v, block_size,
     ``sq`` adds sit at key positions ``>= sq``, above every query row, so
     the mask discards them like the XLA path's padding. One reshape/pad in
     XLA; no gather, no ``[sq, sq]`` materialized probability matrix —
-    kernel-on engines have no gather-path prefill left."""
-    sq, H, D = q.shape
+    kernel-on engines have no gather-path prefill left. ``k``/``v`` may
+    have fewer heads than ``q`` (grouped queries)."""
+    sq = q.shape[0]
     bs = int(block_size)
     nb = -(-sq // bs)
     pad = nb * bs - sq
     if pad:
         k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
-    entry = (k.reshape(nb, bs, H, D), v.reshape(nb, bs, H, D))
+    entry = tuple(a.reshape((nb, bs) + a.shape[1:]) for a in (k, v))
     table = jnp.arange(nb, dtype=jnp.int32)
     return paged_prefill_attention(q, entry, table, jnp.int32(0),
                                    block_q=block_q, block_h=block_h,

@@ -57,7 +57,8 @@ class DisaggReplicaPool(ProcessReplicaPool):
                  decode_replicas: Optional[int] = None,
                  disk_dir: Optional[str] = None, **pool_kw):
         spec = getattr(model, "serving_spec", None)
-        if spec is not None and spec().recurrent_layers():
+        if spec is not None and any(st.kind in ("recurrent", "window")
+                                    for st in spec().layers):
             # a request is handed over as its published BLOCK chain; a
             # recurrent layer's state is not blocks (a model factory is
             # refused by its workers' engines: the prefix cache names it)

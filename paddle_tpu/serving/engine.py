@@ -27,18 +27,27 @@ a fixed slot arena**:
 **The engine<->model seam** (``models/serving_seam.py``,
 docs/serving_model_seam.md): the engine names no model. A model declares
 its vocabulary, its longest context and, per layer, the KIND of per-request
-state that layer keeps (``serving_spec()``): ``"kv"`` (heads, head_dim:
-rows in the paged arena) or ``"recurrent"`` (fixed-size arrays per lane, in
-the arena's slot-indexed store). Every compiled program here runs the model
-one way, :func:`~paddle_tpu.models.serving_seam.forward_cached`: embed ->
-layers, each handed a cache view of ITS kind built here (the three paged
+state that layer keeps (``serving_spec()``): ``"kv"`` (query heads, K/V
+heads, head_dim: rows in the paged arena), ``"recurrent"`` (fixed-size
+arrays per lane, in the arena's slot-indexed store), ``"window"`` (the last
+``window`` tokens' K/V per lane, a ring in the same store), ``"shared"``
+(no state: it reads the pool of the ``"kv"`` layer it names, through the
+same block tables) or ``"none"``. Every compiled program here runs the
+model one way, :func:`~paddle_tpu.models.serving_seam.forward_cached`: embed
+-> layers, each handed a cache view of ITS kind built here (the three paged
 views below for ``"kv"`` layers, the two slot-state views for
-``"recurrent"`` ones) -> final norm; then ``serving_head``. A recurrent
-layer's lane is started from zeros and written by the prefill that admits a
+``"recurrent"`` ones, the two window views, a reader of another layer's
+successor view for ``"shared"`` ones) -> final norm; then ``serving_head``.
+A model that declares a ``prefill_tail`` has its prefill run the layers
+from there on each request's last valid token alone. A recurrent layer's
+lane is started from zeros and written by the prefill that admits a
 request, advanced in place by the decode step, and untouched while
-inactive. The options whose bookkeeping assumes every layer's state is
-blocks (prefix cache, KV tiering, speculative decoding, chunked prefill)
-refuse a model with a recurrent layer at construction.
+inactive; a window layer's ring is filled anew by that prefill. Blocks are
+counted for the layers that OWN a pool: a model with one ``"kv"`` layer and
+seven ``"shared"`` readers of it pages one layer. The options whose
+bookkeeping assumes every layer's state is blocks (prefix cache, KV
+tiering, speculative decoding, chunked prefill) refuse a model with a
+recurrent or window layer at construction.
 
 Decode numerics deliberately share
 ``models.serving_seam.masked_attention`` and the model's ``serving_head``
@@ -240,8 +249,6 @@ class _PagedCacheView:
     def update_and_attend(self, q, k, v):
         import jax.numpy as jnp
 
-        from ..models.serving_seam import masked_attention
-
         qa, ka, va = (t._data if isinstance(t, Tensor) else t
                       for t in (q, k, v))
         s_lanes = qa.shape[0]
@@ -263,24 +270,50 @@ class _PagedCacheView:
                           for pool, new in zip(self.entry, (ka, va)))
         else:
             entry = _scatter_rows(self.entry, row, off, ka[:, 0], va[:, 0])
-        if self.kernel:
-            from ..ops.paged_attention import paged_decode_attention
-
-            o = paged_decode_attention(qa[:, 0], entry,
-                                       self.block_tables, pos,
-                                       active=self.active,
-                                       mesh=self.mesh)[:, None]
-        else:
-            # gather each lane's logical context [S, max_blocks*bs, H, D]
-            t_len = self.block_tables.shape[1] * bs
-            k_all, v_all = _gather_ctx(entry, self.block_tables, qa.dtype)
-            mask = (jnp.arange(t_len)[None, :]
-                    <= pos[:, None])[:, None, None, :]
-            o = masked_attention(qa, k_all, v_all, mask)
+        o = _paged_attend(self, qa, entry)
         new = _PagedCacheView(entry, self.block_tables,
                               self.positions, self.active, bs,
                               kernel=self.kernel, mesh=self.mesh)
         return o, new
+
+    def reader(self):
+        """A view for a ``"shared"`` layer: it attends this pool as it is
+        now (this call's token already written) and writes nothing."""
+        return _PagedReadView(self)
+
+
+def _paged_attend(view, qa, entry):
+    """The attend side of the decode step's paged views: each lane's one
+    query ``qa`` ``[S, 1, heads, D]`` against its block table's context in
+    ``entry``, up to and including its write position."""
+    import jax.numpy as jnp
+
+    from ..models.serving_seam import masked_attention
+
+    pos, bs = view.positions, view.block_size
+    if view.kernel:
+        from ..ops.paged_attention import paged_decode_attention
+
+        return paged_decode_attention(qa[:, 0], entry, view.block_tables,
+                                      pos, active=view.active,
+                                      mesh=view.mesh)[:, None]
+    # gather each lane's logical context [S, max_blocks*bs, H, D]
+    t_len = view.block_tables.shape[1] * bs
+    k_all, v_all = _gather_ctx(entry, view.block_tables, qa.dtype)
+    mask = (jnp.arange(t_len)[None, :] <= pos[:, None])[:, None, None, :]
+    return masked_attention(qa, k_all, v_all, mask)
+
+
+class _PagedReadView:
+    """A ``"shared"`` layer's decode-step view: the pool entry of the layer
+    it names, read through the same block tables, never written."""
+
+    def __init__(self, source: "_PagedCacheView"):
+        self.source = source
+
+    def attend(self, q):
+        qa = q._data if isinstance(q, Tensor) else q
+        return _paged_attend(self.source, qa, self.source.entry)
 
 
 class _CapturePrefillView:
@@ -297,29 +330,56 @@ class _CapturePrefillView:
     path, bit-preserved."""
 
     def __init__(self, block_size: int = 0, kernel: bool = False,
-                 mesh=None):
+                 mesh=None, last=None):
         self.block_size = block_size
         self.kernel = kernel
         self.mesh = mesh
+        #: a prefill whose later layers run on the last valid row alone
+        #: (``ServingSpec.prefill_tail``): that row's index, traced. The
+        #: layer may then hand over one query row (that one) with every
+        #: row's K/V
+        self.last = last
 
     def update_and_attend(self, q, k, v):
-        import jax.numpy as jnp
-
-        from ..models.serving_seam import masked_attention
-
         qa, ka, va = (t._data if isinstance(t, Tensor) else t
                       for t in (q, k, v))
-        if self.kernel:
+        captured = _CapturedKV(ka, va, self.last)
+        if self.kernel and qa.shape[1] == ka.shape[1]:  # not one row alone
             from ..ops.paged_attention import paged_full_prefill_attention
 
             o = paged_full_prefill_attention(qa[0], ka[0], va[0],
                                              self.block_size,
                                              mesh=self.mesh)[None]
-            return o, (ka, va)
-        p = qa.shape[1]
-        mask = (jnp.arange(p)[None, :] <= jnp.arange(p)[:, None])[None, None]
-        o = masked_attention(qa, ka, va, mask)
-        return o, (ka, va)
+            return o, captured
+        return captured.attend(qa), captured
+
+
+class _CapturedKV:
+    """What a ``"kv"`` layer's prefill leaves behind: the chunk's K and V,
+    which the engine scatters into the slot's blocks, and which a
+    ``"shared"`` layer of the same call reads (:meth:`reader`)."""
+
+    def __init__(self, ka, va, last=None):
+        self.k, self.v, self.last = ka, va, last
+
+    def reader(self):
+        return self
+
+    def attend(self, q):
+        """Causal attention of ``q`` over the captured rows: as many rows
+        as were captured, each at its own position, or one row, at
+        ``last``."""
+        import jax.numpy as jnp
+
+        from ..models.serving_seam import masked_attention
+
+        qa = q._data if isinstance(q, Tensor) else q
+        p = self.k.shape[1]
+        cols = jnp.arange(p)[None, :]
+        rows = (jnp.arange(p)[:, None] if qa.shape[1] == p
+                else jnp.reshape(self.last, (1, 1)))
+        mask = (cols <= rows)[None, None]
+        return masked_attention(qa, self.k, self.v, mask)
 
 
 class _PrefixPrefillView:
@@ -436,11 +496,128 @@ class _SlotStatePrefillView:
         return _SlotStatePrefillView(entry, self.slot, self.valid_len)
 
 
+class _WindowDecodeView:
+    """One ``"window"`` layer's decode-step view: ``entry`` is that layer's
+    ``[S, kv_heads, window, D]`` K and V rings. The token at position ``p``
+    overwrites row ``p % window`` of its lane's ring, which then holds
+    positions ``p - window + 1 .. p`` (fewer while the context is shorter:
+    the rows past it are masked). The model has no positions, so the order
+    inside the ring is free. A lane that is not active writes into its own
+    ring, which the prefill that next admits a request to it fills anew.
+
+    The ring lies head-major, as the attention reads it (the layout the
+    chip's compiler gives a ``[S, window, kv_heads, D]`` ring on its own,
+    after which it relaid all of it out and back around the one-row
+    write: 16 copies of 42 MB a step at Phi-4-mini-flash's sizes), and
+    each head's row is scattered on its own into the ``[S, kv_heads *
+    window, D]`` view, where one row is minor-most: in place, as
+    :func:`paddle_tpu.ops.paged_attention.write_token` does for a
+    head-major pool."""
+
+    def __init__(self, entry, positions, window: int):
+        self.entry = entry
+        self.positions = positions  # [S] int32: write pos of new token
+        self.window = int(window)
+
+    def update_and_attend(self, q, k, v):
+        import jax.numpy as jnp
+
+        from ..models.serving_seam import masked_attention
+
+        qa, ka, va = (t._data if isinstance(t, Tensor) else t
+                      for t in (q, k, v))
+        w, pos = self.window, self.positions
+        s_lanes, heads = qa.shape[0], ka.shape[2]
+        lanes = jnp.arange(s_lanes)[:, None]
+        at = jnp.arange(heads)[None, :] * w + (pos % w)[:, None]  # [S, H]
+
+        def write(ring, new):  # ring [S, H, w, D], new [S, 1, H, D]
+            slab = ring.reshape(s_lanes, heads * w, ring.shape[-1])
+            slab = slab.at[lanes, at].set(new[:, 0].astype(ring.dtype))
+            return slab.reshape(ring.shape)
+
+        entry = tuple(write(ring, new)
+                      for ring, new in zip(self.entry, (ka, va)))
+        live = jnp.minimum(pos + 1, w)
+        mask = (jnp.arange(w)[None, :] < live[:, None])[:, None, None, :]
+        o = masked_attention(qa, jnp.swapaxes(entry[0], 1, 2),
+                             jnp.swapaxes(entry[1], 1, 2), mask)
+        return o, _WindowDecodeView(entry, pos, w)
+
+
+class _WindowPrefillView:
+    """One ``"window"`` layer's prefill view: query ``t`` of the (padded)
+    prompt attends keys ``t - window + 1 .. t``, a chunk of ``window``
+    queries at a time against its own chunk and the one before (blocks
+    wholly outside the window are never computed: the work grows linearly
+    with the prompt), and the last ``window`` rows before ``true_len`` go
+    into lane ``slot``'s ring (``[kv_heads, window, D]``), row ``t`` at
+    ``t % window``."""
+
+    def __init__(self, entry, slot, true_len, window: int):
+        self.entry = entry
+        self.slot = slot          # scalar int32: the lane being admitted
+        self.true_len = true_len  # scalar int32: real (unpadded) length
+        self.window = int(window)
+
+    def update_and_attend(self, q, k, v):
+        import jax.numpy as jnp
+
+        from ..models.serving_seam import masked_attention
+
+        qa, ka, va = (t._data if isinstance(t, Tensor) else t
+                      for t in (q, k, v))
+        w, p = self.window, qa.shape[1]
+        n = -(-p // w)
+
+        def chunks(a, lead):  # [1, p, H, D] -> [n, w, H, D], `lead` rows on
+            a = jnp.pad(a[0], ((lead, n * w - p), (0, 0), (0, 0)))
+            return a[:n * w].reshape((n, w) + a.shape[1:])
+
+        qi = jnp.arange(w)[:, None] + w        # a query's column in 2w keys
+        ki = jnp.arange(2 * w)[None, :]
+        band = (ki <= qi) & (qi - ki < w)      # [w, 2w]
+        first = ki >= w                        # chunk 0 has no chunk before
+
+        def one(xs):
+            qc, k0, k1, v0, v1, c = xs
+            mask = band & (first | (c > 0))
+            return masked_attention(
+                qc[None], jnp.concatenate([k0, k1])[None],
+                jnp.concatenate([v0, v1])[None], mask[None, None])[0]
+
+        o = jax.lax.map(one, (chunks(qa, 0), chunks(ka, w), chunks(ka, 0),
+                              chunks(va, w), chunks(va, 0), jnp.arange(n)))
+        o = o.reshape((1, n * w) + o.shape[2:])[:, :p]
+        # ring row r takes the last position t < true_len with t % w == r
+        last = self.true_len - 1
+        t_r = last - (last - jnp.arange(w)) % w
+        entry = tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                ring, jnp.swapaxes(new[0][jnp.maximum(t_r, 0)], 0, 1)[None]
+                .astype(ring.dtype), self.slot, axis=0)
+            for ring, new in zip(self.entry, (ka, va)))
+        return o, _WindowPrefillView(entry, self.slot, self.true_len, w)
+
+
+def _stateless_view(state):
+    """What ``forward_cached`` is handed for a layer that owns no state: a
+    reference to the layer whose pool a ``"shared"`` layer reads (its real
+    view exists only once that layer has run), nothing for a ``"none"``
+    layer."""
+    from ..models.serving_seam import SharedRef
+
+    return SharedRef(state.source) if state.kind == "shared" else None
+
+
 def _split_views(views, kinds):
     """The successor views' storage by kind, in layer order: ``(entries of
-    the "kv" layers, entries of the "recurrent" layers)``."""
+    the "kv" layers, entries of the layers whose state lies in the
+    slot-indexed store)``."""
+    from ..models.serving_seam import SLOT_KINDS
+
     kv = [v.entry for v, k in zip(views, kinds) if k == "kv"]
-    rec = [v.entry for v, k in zip(views, kinds) if k == "recurrent"]
+    rec = [v.entry for v, k in zip(views, kinds) if k in SLOT_KINDS]
     return kv, rec
 
 
@@ -641,12 +818,27 @@ class ServingEngine:
         # of state it keeps. The paged pools cover the "kv" layers, the
         # arena's slot-indexed store the "recurrent" ones
         spec = model.serving_spec()
+        self._layer_states = tuple(spec.layers)
         self._layer_kinds = tuple(st.kind for st in spec.layers)
-        kv_layers, rec_layers = spec.kv_layers(), spec.recurrent_layers()
-        if len({(st.num_heads, st.head_dim) for st in kv_layers}) > 1:
+        self._prefill_tail = spec.prefill_tail
+        kv_layers = spec.kv_layers()
+        if len({(st.kv_heads, st.head_dim) for st in kv_layers}) > 1:
             raise ValueError("the paged arena holds one (heads, head_dim) "
                              "for all of a model's kv layers")
-        self.recurrent = bool(rec_layers)
+        for i, st in enumerate(spec.layers):
+            if st.kind == "shared" and not (
+                    0 <= st.source < i
+                    and spec.layers[st.source].kind == "kv"):
+                raise ValueError(
+                    f"layer {i} shares the cache of layer {st.source}, "
+                    "which is no paged kv layer before it")
+        from ..models.serving_seam import SLOT_KINDS
+
+        # the layers whose state has a fixed size per lane, in layer order:
+        # "recurrent" ones and "window" ones share the slot-indexed store
+        slot_layers = [st for st in spec.layers if st.kind in SLOT_KINDS]
+        self._slot_kinds = tuple(st.kind for st in slot_layers)
+        self.recurrent = bool(slot_layers)
         self.num_slots = int(cfg.num_slots or flags.flag("serving_slots"))
         self.block_size = int(cfg.kv_block_size or flags.flag("kv_block_size"))
         self.max_model_len = int(cfg.max_model_len or spec.max_positions)
@@ -720,12 +912,14 @@ class ServingEngine:
         # the mesh rides along so the rebuilt arena re-commits the SAME
         # pool shardings (identical shapes AND placements => the
         # supervisor's rebuild/replay path stays zero-recompile on a mesh)
-        kv_heads, kv_dim = ((kv_layers[0].num_heads, kv_layers[0].head_dim)
+        kv_heads, kv_dim = ((kv_layers[0].kv_heads, kv_layers[0].head_dim)
                             if kv_layers else (1, 1))
         self._arena_args = (len(kv_layers), kv_heads, kv_dim,
                             num_blocks, self.block_size, kv_dtype,
                             self.quant_kv, self.mesh, self.num_slots,
-                            tuple(st.arrays for st in rec_layers))
+                            tuple(st.arrays if st.kind == "recurrent"
+                                  else st.arrays(kv_dtype)
+                                  for st in slot_layers))
         self.arena = KVArena(*self._arena_args)
         self.use_prefix_cache = (bool(flags.flag("serving_prefix_cache"))
                                  if cfg.prefix_cache is None
@@ -752,8 +946,9 @@ class ServingEngine:
                 if on:
                     raise ValueError(
                         f"{option} is not supported for a model with "
-                        "recurrent-state layers: it assumes every layer's "
-                        "state is paged blocks")
+                        "recurrent-state layers (a fixed-size state or "
+                        "window per lane): it assumes every layer's state "
+                        "is paged blocks")
         self.tier = None
         if self.kv_tiering and self.use_prefix_cache:
             from .tiered import TierView, get_tier_store
@@ -1045,12 +1240,13 @@ class ServingEngine:
 
         from ..core import rng as prng
         from ..jit import _swap_data
-        from ..models.serving_seam import forward_cached
+        from ..models.serving_seam import SLOT_KINDS, forward_cached
         from .sampling import sample_tokens
 
         model = self._model
         lora = self.lora
-        kinds = self._layer_kinds
+        states, kinds = self._layer_states, self._layer_kinds
+        tail = self._prefill_tail
         bs = self.block_size
         use_kernel = self.paged_kernel
         kmesh = self._kernel_mesh
@@ -1067,25 +1263,43 @@ class ServingEngine:
                 metrics.bump("kernel.prefill_traces")
             # a "kv" layer hands back its chunk's k/v to scatter below; a
             # "recurrent" layer starts lane `slot` from zeros and writes
-            # its final state there itself (through its view)
+            # its final state there itself (through its view), a "window"
+            # layer the last rows of the prompt; a "shared" layer reads
+            # what the layer it names captured; a model with a prefill
+            # tail runs its last layers on row `last` alone
+            last = None if tail is None else true_len - 1
             it_rec = iter(rec)
-            views = [_CapturePrefillView(bs, kernel=use_kernel, mesh=kmesh)
-                     if kind == "kv"
-                     else _SlotStatePrefillView(next(it_rec), slot, true_len)
-                     for kind in kinds]
+            views = []
+            for i, st in enumerate(states):
+                if st.kind == "kv":
+                    views.append(_CapturePrefillView(
+                        bs, kernel=use_kernel, mesh=kmesh,
+                        last=last if i + 1 == tail else None))
+                elif st.kind == "recurrent":
+                    views.append(_SlotStatePrefillView(next(it_rec), slot,
+                                                       true_len))
+                elif st.kind == "window":
+                    views.append(_WindowPrefillView(next(it_rec), slot,
+                                                    true_len, st.window))
+                else:
+                    views.append(_stateless_view(st))
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_args) if lora is not None
                           else _null_ctx()):
                         h, new_views = forward_cached(
-                            model, Tensor(ids), views, 0)
+                            model, Tensor(ids), views, 0, last=last,
+                            prefill_tail=tail)
                 with jax.named_scope("head_sample"):
-                    h_last = jax.lax.dynamic_index_in_dim(
-                        h._data, true_len - 1, axis=1, keepdims=False)
+                    if tail is None:
+                        h_last = jax.lax.dynamic_index_in_dim(
+                            h._data, true_len - 1, axis=1, keepdims=False)
+                    else:
+                        h_last = h._data[:, 0]
                     logits = model.serving_head(h_last)
             chunks = [v for v, k in zip(new_views, kinds) if k == "kv"]
             new_rec = [v.entry for v, k in zip(new_views, kinds)
-                       if k == "recurrent"]
+                       if k in SLOT_KINDS]
             p_idx = jnp.arange(p_bucket)
             row = rows[p_idx // bs]
             # padded positions (>= the true prompt length) scatter into the
@@ -1093,11 +1307,9 @@ class ServingEngine:
             row = jnp.where(p_idx < true_len, row, 0)
             off = p_idx % bs
             new_pools = []
-            for (kc, vc), entry in zip(chunks, pools):
-                kc = kc._data if isinstance(kc, Tensor) else kc
-                vc = vc._data if isinstance(vc, Tensor) else vc
+            for chunk, entry in zip(chunks, pools):
                 new_pools.append(
-                    _scatter_rows(entry, row, off, kc[0], vc[0]))
+                    _scatter_rows(entry, row, off, chunk.k[0], chunk.v[0]))
             # the first generated token goes through the SAME sampling
             # core as the decode step ([1, V] and [S, V] rows are
             # bit-identical per row); greedy/unmasked slots reproduce
@@ -1317,7 +1529,7 @@ class ServingEngine:
 
         model = self._model
         lora = self.lora
-        kinds = self._layer_kinds
+        states, kinds = self._layer_states, self._layer_kinds
         bs = self.block_size
         use_kernel = self.decode_kernel
         kmesh = self._kernel_mesh
@@ -1340,12 +1552,19 @@ class ServingEngine:
             top_p = jax.lax.bitcast_convert_type(state[_ST_TOP_P],
                                                  jnp.float32)
             it_kv, it_rec = iter(pools), iter(rec)
-            views = [_PagedCacheView(next(it_kv), block_tables, positions,
-                                     active, bs, kernel=use_kernel,
-                                     mesh=kmesh)
-                     if kind == "kv"
-                     else _SlotStateDecodeView(next(it_rec), active)
-                     for kind in kinds]
+            views = []
+            for st in states:
+                if st.kind == "kv":
+                    views.append(_PagedCacheView(
+                        next(it_kv), block_tables, positions, active, bs,
+                        kernel=use_kernel, mesh=kmesh))
+                elif st.kind == "recurrent":
+                    views.append(_SlotStateDecodeView(next(it_rec), active))
+                elif st.kind == "window":
+                    views.append(_WindowDecodeView(next(it_rec), positions,
+                                                   st.window))
+                else:
+                    views.append(_stateless_view(st))
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_pools, state[_ST_ADAPTER])
@@ -1831,6 +2050,12 @@ class ServingEngine:
             # tenant left) and wrote this request's state into it
             metrics.bump("state.resets")
         metrics.bump("tokens.prefill", st.clen - st.prefix_len)
+        # tokens through the layers before the model's prefill tail and
+        # through those of it (the last valid token alone; a model that
+        # declares no tail runs every layer on every token)
+        metrics.bump("prefill.body_tokens", st.clen - st.prefix_len)
+        metrics.bump("prefill.tail_tokens", st.clen - st.prefix_len
+                     if self._prefill_tail is None else 1)
         metrics.bump("tokens.generated")  # the next token, out of prefill
         self._refresh_gauges()
         return first
@@ -2216,9 +2441,21 @@ class ServingEngine:
         ``tools/serving_stats.py --run`` and ``EnginePredictor.close()``
         both read these."""
         metrics.set_gauge("arena.kv_bytes", self.arena.bytes_total())
+        # layers that own a paged pool, and layers that read one (a
+        # "shared" layer reads the pool of the layer it names)
+        metrics.set_gauge("arena.paged_layers",
+                          self._layer_kinds.count("kv"))
+        metrics.set_gauge("arena.kv_readers", self._layer_kinds.count("kv")
+                          + self._layer_kinds.count("shared"))
         if self.recurrent:
             metrics.set_gauge("state.bytes_total",
                               self.arena.state_bytes_total())
+            by_kind = {"recurrent": 0, "window": 0}
+            for kind, entry in zip(self._slot_kinds, self.arena.slot_state):
+                by_kind[kind] += sum(int(a.size) * a.dtype.itemsize
+                                     for a in entry)
+            metrics.set_gauge("state.ssm_bytes", by_kind["recurrent"])
+            metrics.set_gauge("state.window_bytes", by_kind["window"])
         by_ns = self.arena.bytes_by_namespace()
         metrics.set_gauge("arena.scale_bytes",
                           sum(d["scale_bytes"] for d in by_ns.values()))

@@ -198,6 +198,11 @@ DOCUMENTED_NAMESPACES = (
     # beside the paged KV arena — the resets counter,
     # bytes_total / lanes_in_use gauges (docs/serving_model_seam.md)
     "state",
+    # prefill.* (ISSUE 31): tokens an admission ran through the layers
+    # before the model's prefill tail (body_tokens) and through the tail
+    # (tail_tokens: one a prefill where a model declares a tail) —
+    # docs/observability.md, docs/serving_model_seam.md "The prefill tail"
+    "prefill",
     "queue", "slots", "tokens_per_sec",
 )
 

@@ -114,7 +114,7 @@ class SpecDecoder:
         if self.draft is not None:
             self.draft.eval()
             dspec = self.draft.serving_spec()
-            if dspec.recurrent_layers():
+            if any(st.kind != "kv" for st in dspec.layers):
                 raise ValueError("speculative decoding needs a draft model "
                                  "whose layers all keep paged KV state")
             if dspec.vocab_size != engine.vocab:
@@ -152,7 +152,7 @@ class SpecDecoder:
         # the namespace inherits the arena's int8+scale-pool layout
         kv_dtype = serving_compute_dtype(self.draft)
         self.engine.arena.add_namespace(
-            self.NAMESPACE, len(kv), kv[0].num_heads, kv[0].head_dim,
+            self.NAMESPACE, len(kv), kv[0].kv_heads, kv[0].head_dim,
             kv_dtype)
 
     def rebuild(self) -> None:
@@ -291,11 +291,9 @@ class SpecDecoder:
             row = jnp.where(p_idx < true_len, row, 0)
             off = p_idx % bs
             new_pools = []
-            for (kc, vc), entry in zip(chunks, pools):
-                kc = kc._data if isinstance(kc, Tensor) else kc
-                vc = vc._data if isinstance(vc, Tensor) else vc
+            for chunk, entry in zip(chunks, pools):
                 new_pools.append(
-                    _scatter_rows(entry, row, off, kc[0], vc[0]))
+                    _scatter_rows(entry, row, off, chunk.k[0], chunk.v[0]))
             return new_pools
 
         fn = (jax.jit(draft_prefill, donate_argnums=(3,))

@@ -162,6 +162,82 @@ def test_decode_tiles_ragged_lanes(heads, quantized, dtype):
         np.testing.assert_allclose(again[live], ref[live], **_tol(dtype))
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["full", "int8"])
+@pytest.mark.parametrize("kv_heads", [2, 10])
+def test_decode_grouped_queries(kv_heads, quantized, dtype):
+    """Four query heads a K/V head (2: a token-major pool; 10: a
+    head-major one, Phi-4-mini-flash's count): query head ``h`` reads K/V
+    head ``h // 4`` through the tile's bias, ragged lanes, an inactive
+    lane, every tile size."""
+    rng = np.random.default_rng(11)
+    S, H, D, NB, bs, MB = 5, kv_heads, 32, 40, 4, 6
+    entry = _pools(rng, NB, bs, H, D, dtype, quantized)
+    q = jnp.asarray(rng.standard_normal((S, 4 * H, D)), dtype)
+    bt = jnp.asarray(rng.permutation(np.arange(1, NB))[: S * MB].reshape(
+        S, MB), jnp.int32)
+    pos = jnp.asarray([0, 3, 7, 13, MB * bs - 1], jnp.int32)
+    active = jnp.asarray([1, 1, 0, 1, 1], bool)
+    live = np.asarray(active)
+    ref = np.asarray(_decode_ref(q, entry, bt, pos), np.float32)
+    assert ref.shape == (S, 4 * H, D)
+    for pages in (1, 2, None):
+        out = np.asarray(pk.paged_decode_attention(
+            q, entry, bt, pos, active=active, pages=pages), np.float32)
+        np.testing.assert_allclose(out[live], ref[live], **_tol(dtype))
+        assert not out[~live].any()
+
+
+def test_grouped_reference_is_attention_with_repeated_heads():
+    """What the grouped cases above are held to: ``masked_attention`` with
+    fewer K/V heads equals the plain one with each K/V head repeated."""
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.standard_normal((2, 5, 8, 16)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 9, 2, 16)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 9, 2, 16)), jnp.float32)
+    mask = jnp.asarray(rng.random((2, 1, 5, 9)) < 0.7).at[..., 0].set(True)
+    got = masked_attention(q, k, v, mask)
+    want = masked_attention(q, jnp.repeat(k, 4, 2), jnp.repeat(v, 4, 2),
+                            mask)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["full", "int8"])
+def test_prefill_grouped_queries(dtype, quantized):
+    """The prefill kernel at query heads 4 x K/V heads, several runtime
+    prefixes, every query tile and head group."""
+    rng = np.random.default_rng(13)
+    sq, H, D, NB, bs, MB = 8, 2, 32, 19, 4, 6
+    entry = _pools(rng, NB, bs, H, D, dtype, quantized)
+    q = jnp.asarray(rng.standard_normal((sq, 4 * H, D)), dtype)
+    bt_row = jnp.asarray(rng.permutation(np.arange(1, MB + 1)), jnp.int32)
+    for prefix in (0, 5, 13):
+        ref = np.asarray(_prefill_ref(q, entry, bt_row, prefix), np.float32)
+        for blk_q, blk_h in ((None, None), (2, 1), (8, 2)):
+            out = pk.paged_prefill_attention(q, entry, bt_row, prefix,
+                                             block_q=blk_q, block_h=blk_h)
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), ref,
+                err_msg=f"prefix={prefix} tile=({blk_q},{blk_h})",
+                **_tol(dtype))
+
+
+@pytest.mark.parametrize("sq", [8, 12])
+def test_full_prefill_grouped_queries(sq):
+    rng = np.random.default_rng(14)
+    H, D, bs = 2, 32, 8
+    q = jnp.asarray(rng.standard_normal((sq, 4 * H, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((sq, H, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((sq, H, D)), jnp.float32)
+    out = pk.paged_full_prefill_attention(q, k, v, bs)
+    mask = (jnp.arange(sq)[None, :] <= jnp.arange(sq)[:, None])[None, None]
+    ref = masked_attention(q[None], k[None], v[None], mask)[0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **_tol("float32"))
+
+
 @pytest.mark.parametrize("heads,dim", [(16, 128), (30, 128), (4, 32)])
 def test_write_token_is_the_plain_scatter(heads, dim):
     """The kernel route's write of the new token (a head's row at a time

@@ -100,23 +100,49 @@ def test_paged_decode_compiles(h, d, quantized):
              *_entry(h, d, quantized))
 
 
-@pytest.mark.parametrize("h,mb,nb", [(16, 128, 2730), (30, 256, 2560)],
-                         ids=["serve-batch-long", "serve-doc-hybrid"])
-def test_paged_decode_compiles_at_the_cells_shapes(h, mb, nb):
-    """The two serving cells' own decode shapes (32 lanes, bf16, block 16,
-    head dim 128): the kernel compiles, nothing shaped like the gathered
-    tables ``[S*MB, block, H, D]`` is in the program, and the pools reach
+@pytest.mark.parametrize("hq,h,mb,nb", [(16, 16, 128, 2730),
+                                        (30, 30, 256, 2560),
+                                        (40, 10, 1024, 16384)],
+                         ids=["serve-batch-long", "serve-doc-hybrid",
+                              "serve-reason-flash"])
+def test_paged_decode_compiles_at_the_cells_shapes(hq, h, mb, nb):
+    """The serving cells' own decode shapes (32 lanes, bf16, block 16,
+    head dim 128; the third: 40 query heads over a pool of 10 K/V heads):
+    the kernel compiles, nothing shaped like the gathered tables
+    ``[S*MB, block, H, D]`` is in the program, and the pools reach
     the kernel as they lie on the chip (``_head_major`` guessed the
     layout XLA gives them: a wrong guess shows as a copy of a pool)."""
     pools = (_sds((nb, BS, h, 128), jnp.bfloat16),) * 2
     text = _compile(lambda q, bt, pos, act, *entry:
                     pa.paged_decode_attention(q, entry, bt, pos, active=act),
-                    _sds((S, h, 128), jnp.bfloat16), _sds((S, mb), jnp.int32),
+                    _sds((S, hq, 128), jnp.bfloat16),
+                    _sds((S, mb), jnp.int32),
                     _sds((S,), jnp.int32), _sds((S,), jnp.bool_), *pools)
     assert f"[{S * mb},{BS},{h},128]" not in text
     assert f"[{S},{mb * BS},{h},128]" not in text
     assert not re.search(rf"= bf16\[{nb},[0-9,]*128\]\S* (copy|transpose)\(",
                          text)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_grouped_query_kernels_compile(quantized):
+    """40 query heads over 10 K/V heads of width 128 (the differential
+    form of 40 heads of 64 over 20): decode and both prefill entries."""
+    hq, h, d = 40, 10, 128
+    entry = _entry(h, d, quantized)
+    _compile(lambda q, bt, pos, act, *entry:
+             pa.paged_decode_attention(q, entry, bt, pos, active=act),
+             _sds((S, hq, d), jnp.bfloat16), _sds((S, MB), jnp.int32),
+             _sds((S,), jnp.int32), _sds((S,), jnp.bool_), *entry)
+    _compile(lambda q, bt, prefix, *entry:
+             pa.paged_prefill_attention(q, entry, bt, prefix),
+             _sds((SQ, hq, d), jnp.bfloat16), _sds((MB,), jnp.int32),
+             _sds((), jnp.int32), *entry)
+    if not quantized:
+        kv = _sds((SQ, h, d), jnp.bfloat16)
+        _compile(lambda q, k, v: pa.paged_full_prefill_attention(q, k, v,
+                                                                 BS),
+                 _sds((SQ, hq, d), jnp.bfloat16), kv, kv)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
