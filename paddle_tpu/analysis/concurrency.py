@@ -47,8 +47,8 @@ _MUTATOR_METHODS = {
 #: serving calls that block on device/compile latency — holding a lock
 #: across one stalls every contending thread behind the accelerator
 _BLOCKING_SERVING_CALLS = {
-    "decode_step", "prefill", "admit", "step", "_step_guarded",
-    "_pump_once", "run_until_idle", "drain",
+    "decode_step", "decode_turn", "decode_collect", "prefill", "admit",
+    "step", "_step_guarded", "_pump_once", "run_until_idle", "drain",
 }
 
 _SOCKET_CALLS = {"urlopen", "recv", "accept", "getaddrinfo",

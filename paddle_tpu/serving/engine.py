@@ -121,9 +121,10 @@ reproducing the plain engine exactly.
 from __future__ import annotations
 
 import warnings
+from collections import deque
 from contextlib import nullcontext as _null_ctx
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -714,6 +715,17 @@ class ServingConfig:
     mesh: Optional[object] = None
 
 
+class _StepInFlight(NamedTuple):
+    """A dispatched decode step whose tokens the host has not read: the
+    device array they will be in, the lanes the step ran, and each slot's
+    tenancy count when it was dispatched (a lane retired since, whoever
+    holds the slot now, no longer owns what this step computed for it)."""
+
+    tokens: jax.Array
+    lanes: np.ndarray
+    tenancy: np.ndarray
+
+
 @dataclass
 class _AdmitState:
     """Everything an in-flight admission carries between its setup
@@ -974,6 +986,16 @@ class ServingEngine:
         # state back, so the host mirrors above and below are uploaded only
         # after a host write: None = stale, set through _touch_slot_state
         self._state_dev = None
+        # decode steps dispatched and not yet read, oldest first (see
+        # decode_dispatch / decode_collect); _tenancy counts each slot's
+        # retirements, so a step read after a lane changed hands neither
+        # mirrors nor reports that lane; _ahead is set for the length of
+        # one decode_turn call; lanes_read: the lanes of the step read last
+        # whose token belongs to the request that holds the slot now
+        self._flight: deque = deque()
+        self._tenancy = np.zeros(s, np.int64)
+        self._ahead = False
+        self.lanes_read = np.zeros(s, np.bool_)
         # occupied ⊇ active: a slot mid-chunked-prefill holds blocks and
         # must not be re-picked, but its lane stays masked out of the
         # decode step until its first token exists
@@ -2138,6 +2160,7 @@ class ServingEngine:
         self._positions[slot] = 0
         self._last_tok[slot] = 0
         self._slot_limit[slot] = 0
+        self._tenancy[slot] += 1  # a step in flight no longer owns the lane
         self._clear_slot_scenario(slot)  # marks the device's copy stale
         metrics.bump("engine.retires")
         if flags.flag("serving_arena_invariants"):
@@ -2199,6 +2222,9 @@ class ServingEngine:
         self._bt_host[:] = 0
         self._bt_dev = None
         self._touch_slot_state()
+        # a step in flight died with the old arena: its tokens were never
+        # emitted, so the journals replay it
+        self.decode_drop()
         self._positions[:] = 0
         self._last_tok[:] = 0
         self._active[:] = False
@@ -2362,6 +2388,100 @@ class ServingEngine:
         counters first."""
         return self._get_step().lower(*self._step_args(self._active))
 
+    def decode_dispatch(self, active=None) -> _StepInFlight:
+        """The first half of a decode step: grow the block tables, send
+        what the host changed, dispatch the compiled step and let its
+        arguments go. Nothing here waits for the device. The step's
+        write positions advance now (they do not depend on its tokens, and
+        the next dispatch grows the tables from them); its tokens reach
+        the ``_last_tok`` mirror in :meth:`decode_collect`. Returns the
+        step in flight, which also joins the engine's queue of them."""
+        hists = self.hists
+        act = (self._active.copy() if active is None
+               else np.asarray(active, bool))
+        if active is not None:
+            # the device's copy holds the engine's own mask: this step
+            # sends the mirrors with the caller's, the next one again
+            self._touch_slot_state()
+        if self._state_dev is None and self._flight:
+            raise RuntimeError(
+                "decode_dispatch after a host write with a step in flight: "
+                "the mirrors lack its tokens; collect it first")
+        with telemetry.phase("decode.prepare", hists):
+            # grow block tables whose write position crossed a block
+            # boundary, then whatever the host changed since the last
+            # step goes to the device
+            for slot in np.flatnonzero(act):
+                self._grow_slot_to(slot, int(self._positions[slot]))
+            args = self._step_args(act)
+            # stale until this step is dispatched: a call that raises
+            # produced no next state (and may have consumed the donated
+            # pools), so the step after it starts from the mirrors
+            self._touch_slot_state()
+        with telemetry.phase("decode.dispatch", hists):
+            nxt, new_pools, new_rec, state = self._call(
+                self._get_step(), *args, name="serving.step")
+        with telemetry.phase("decode.wait", hists):
+            with telemetry.phase("decode.release", hists):
+                # the step's argument arrays and the donated pools must
+                # die HERE, while the device runs: each device array's
+                # destructor hands the GIL over and queues for it again,
+                # tens of ms a step under load. Kept alive to the
+                # function's end, that time came after the device's step
+                # instead of under it (PERF.md, PR 24: a third fewer
+                # tokens a second)
+                del args
+                self.arena.set_pools(new_pools)
+                self.arena.set_slot_state(new_rec)
+        if active is None:
+            # the mirrors as they read once this step's tokens are in:
+            # the next step may be dispatched from it before they are
+            self._state_dev = state
+        self._positions[act] += 1
+        step = _StepInFlight(nxt, act, self._tenancy.copy())
+        self._flight.append(step)
+        return step
+
+    def decode_collect(self) -> np.ndarray:
+        """The second half, of the OLDEST step in flight: block until the
+        device is done with it, the token vector is back and this thread
+        has the GIL again; mirror the tokens of the lanes that still hold
+        the request the step ran them for. A lane retired since the
+        dispatch (its request ended at the step before, or was preempted)
+        was computed for nobody: its token is dropped here, whoever holds
+        the slot by now. Returns the step's ``[num_slots]`` tokens; the
+        lanes that count are ``lanes_read``."""
+        step = self._flight.popleft()
+        with telemetry.phase("decode.wait", self.hists):
+            # a device that died under the step says so here
+            resilience.maybe_fault("serving_step")
+            out = np.asarray(step.tokens)
+        live = step.lanes & (step.tenancy == self._tenancy)
+        self._last_tok[live] = out[live]
+        self.lanes_read = live
+        n_live = int(live.sum())
+        metrics.bump("engine.steps")
+        metrics.bump("engine.lane_steps_discarded",
+                     int(step.lanes.sum()) - n_live)
+        metrics.bump("tokens.generated", n_live)
+        self._meter.tick(n_live)
+        metrics.set_gauge("tokens_per_sec", round(self._meter.rate(), 1))
+        return out
+
+    def decode_drop(self) -> None:
+        """Forget every step in flight, unread (nothing waits for the
+        device: its stream still orders them before any later prefill).
+        For a pump with nothing left running, a failed engine and
+        :meth:`rebuild`: the lanes those steps ran are all retired, so
+        no mirror needs their tokens."""
+        metrics.bump("engine.lane_steps_discarded",
+                     sum(int(step.lanes.sum()) for step in self._flight))
+        self._flight.clear()
+
+    @property
+    def steps_in_flight(self) -> int:
+        return len(self._flight)
+
     def decode_step(self, active=None) -> np.ndarray:
         """One iteration: every active slot's last token is forwarded at
         its own position, its k/v lands in its current block, and one new
@@ -2369,54 +2489,42 @@ class ServingEngine:
         garbage — callers must mask by activity). ``active`` overrides the
         lane mask (runtime data — same program): the speculative decoder
         drives the sampled/constrained/adapter lanes it must not cover
-        through here, see :meth:`spec_ineligible`."""
-        hists = self.hists
-        with telemetry.phase("decode_step", hists):
-            act = (self._active if active is None
-                   else np.asarray(active, bool))
-            if active is not None:
-                # the device's copy holds the engine's own mask: this step
-                # sends the mirrors with the caller's, the next one again
-                self._touch_slot_state()
-            with telemetry.phase("decode.prepare", hists):
-                # grow block tables whose write position crossed a block
-                # boundary, then whatever the host changed since the last
-                # step goes to the device
-                for slot in np.flatnonzero(act):
-                    self._grow_slot_to(slot, int(self._positions[slot]))
-                args = self._step_args(act)
-                # stale until this step's tokens are back: a call that
-                # raises produced no next state (and may have consumed the
-                # donated pools), so the step after it starts from the
-                # mirrors
-                self._touch_slot_state()
-            with telemetry.phase("decode.dispatch", hists):
-                nxt, new_pools, new_rec, state = self._call(
-                    self._get_step(), *args, name="serving.step")
-            with telemetry.phase("decode.wait", hists):
-                with telemetry.phase("decode.release", hists):
-                    # the step's argument arrays and the donated pools
-                    # must die HERE, while the device runs: each device
-                    # array's destructor hands the GIL over and queues
-                    # for it again, tens of ms a step under load. Kept
-                    # alive to the function's end, that time came after
-                    # the device's step instead of under it (PERF.md,
-                    # PR 24: a third fewer tokens a second)
-                    del args
-                    self.arena.set_pools(new_pools)
-                    self.arena.set_slot_state(new_rec)
-                # blocks until the device is done, the token vector is
-                # back AND this thread has the GIL again
-                out = np.asarray(nxt)
-            if active is None:
-                self._state_dev = state  # the mirrors below, advanced
-            self._positions[act] += 1
-            self._last_tok[act] = out[act]
-            metrics.bump("engine.steps")
-            metrics.bump("tokens.generated", int(act.sum()))
-            self._meter.tick(int(act.sum()))
-            metrics.set_gauge("tokens_per_sec", round(self._meter.rate(), 1))
-        return out
+        through here, see :meth:`spec_ineligible`.
+
+        Synchronous for a direct caller: dispatch, then collect. Inside
+        :meth:`decode_turn` with ``ahead`` (the scheduler's pump) the call
+        reads the oldest step in flight and, while the state the device
+        carries is still what the next step needs (no host write since the
+        last dispatch), dispatches that next step BEFORE the read: the
+        host's share of the turn then runs under the device's step."""
+        with telemetry.phase("decode_step", self.hists):
+            if active is not None and self._flight:
+                raise RuntimeError(
+                    "decode_step(active=...) with a step in flight: a "
+                    "lane-mask override is a synchronous turn")
+            if not self._flight:
+                self.decode_dispatch(active)
+            ahead = (self._ahead and active is None
+                     and self._state_dev is not None)
+            if ahead:
+                self.decode_dispatch()
+            # counted every turn, so a window of synchronous turns reads 0
+            metrics.bump("engine.steps_run_ahead", int(ahead))
+            return self.decode_collect()
+
+    def decode_turn(self, ahead: bool) -> Tuple[np.ndarray, np.ndarray]:
+        """The decode call of a caller that comes back every turn
+        (``Scheduler.step``): :meth:`decode_step`, reached through the
+        attribute so that whatever wraps it wraps the pump's steps too,
+        with one step left in flight behind the one it reads if ``ahead``
+        (the next step's inputs do not wait for this one's tokens on the
+        host). Returns the tokens of the step read and ``lanes_read``."""
+        self._ahead = bool(ahead)
+        try:
+            toks = self.decode_step()
+        finally:
+            self._ahead = False
+        return toks, self.lanes_read
 
     # -------------------------------------------------------------- stats
 

@@ -12,8 +12,13 @@ Counter namespaces:
 
 * ``requests.*``   — submitted / finished / cancelled / expired / failed
 * ``tokens.*``     — ``generated`` (decode) and ``prefill`` (prompt) tokens
-* ``engine.*``     — steps, admits, retires, rebuilds, trace counts,
-  ``step_uploads`` (host-to-device transfers made preparing decode steps)
+* ``engine.*``     — steps (decode steps whose tokens were read), admits,
+  retires, rebuilds, trace counts, ``step_uploads`` (host-to-device
+  transfers made preparing decode steps), ``steps_run_ahead`` (steps
+  dispatched while the step before was still un-read) and
+  ``lane_steps_discarded`` (lane-steps computed for a request that had
+  already ended or been preempted: never emitted, never counted in
+  ``tokens.generated``)
 * ``arena.*``      — block allocs / frees / reuse / alloc failures
 * ``scheduler.*``  — ``preemptions`` (starvation-triggered victim
   evictions), ``cache_skips`` (cache-affinity admissions past a cold head)
@@ -106,7 +111,9 @@ Counter namespaces:
   partition the pump thread's time; ``sched.admit`` (parent of
   ``prefill``), ``decode_step`` (parent of ``decode.prepare`` /
   ``decode.dispatch`` / ``decode.wait``, the last the parent of
-  ``decode.release``) and ``sched.emit`` lie inside ``sched.step``;
+  ``decode.release``; run ahead, one turn's ``decode_step`` prepares and
+  dispatches step N+1 and waits for step N) and ``sched.emit`` lie
+  inside ``sched.step``;
   ``submit.lock_wait`` is handler threads' time (docs/observability.md
   "Phases of the serving loop")
 

@@ -650,11 +650,21 @@ class Scheduler:
 
     def step(self) -> bool:
         """One scheduler iteration: the admission pass
-        (:meth:`_admit_pass`), one engine decode step, retire finished.
-        Returns True if any request made progress. Three phases
-        (``telemetry.phase``): ``sched.admit`` around the pass (parent of
-        the engine's ``prefill``), the engine's own ``decode_step``, and
-        ``sched.emit`` around the emit loop as a whole."""
+        (:meth:`_admit_pass`), one engine decode call, the emit of ONE
+        step's tokens, retire finished. On the plain path one decode step
+        stays in flight from turn to turn (``engine.decode_turn``): the
+        step whose tokens this turn emits was dispatched a turn ago, and
+        the next is dispatched before they are read, so the emit, the
+        pump's unlocked stretch and the next admission pass run under the
+        device's step. A host write to the slot state (an admission, a
+        retirement, a preemption, a cancel) makes the turn read without
+        dispatching, and the next turn starts again from the mirrors; a
+        constrained request or the speculative path keeps every turn
+        synchronous. Returns True if any request made progress. Three
+        phases (``telemetry.phase``): ``sched.admit`` around the pass
+        (parent of the engine's ``prefill``), the engine's own
+        ``decode_step``, and ``sched.emit`` around the emit loop as a
+        whole."""
         hists = getattr(self.engine, "hists", None)
         with telemetry.phase("sched.admit", hists):
             progress = self._admit_pass()
@@ -674,14 +684,37 @@ class Scheduler:
                             if self._check_boundary(req):
                                 break
             else:
-                toks = self.engine.decode_step()
+                # the tokens of the oldest step in flight, with the next
+                # one dispatched before they are read wherever it can be
+                # (engine.decode_step). A request admitted since that step
+                # was dispatched has no lane in it: its turn comes with
+                # the next step. One that ended since is not in `running`
+                toks, lanes = self.engine.decode_turn(self._may_run_ahead())
                 with telemetry.phase("sched.emit", hists):
                     for req in list(self.running):
-                        self._emit(req, int(toks[req.slot]))
+                        if lanes[req.slot]:
+                            self._emit(req, int(toks[req.slot]))
                         self._check_boundary(req)
             progress = True
+        if not self.running:
+            self._drop_in_flight()
         self._gauges()
         return progress
+
+    def _may_run_ahead(self) -> bool:
+        """Whether the step after the one in flight may be dispatched
+        before this one's tokens are read: not while a running request
+        has a ``constraint`` (its next mask row needs the token, through
+        the walker on the host). With ``engine.spec`` set the turn never
+        comes here."""
+        return all(r.constraint is None for r in self.running)
+
+    def _drop_in_flight(self) -> None:
+        """Nothing is running: a step dispatched ahead was computed for
+        requests that have all ended. No handle outlives the work."""
+        drop = getattr(self.engine, "decode_drop", None)
+        if drop is not None:
+            drop()
 
     def fail_all(self, error: BaseException) -> None:
         """Fail every queued and running request (engine fatality or
@@ -694,6 +727,7 @@ class Scheduler:
             self._finish(req, RequestState.FAILED, error)
         for req in list(self.running):
             self._finish(req, RequestState.FAILED, error)
+        self._drop_in_flight()
         self._gauges()
 
     def run_until_idle(self, max_steps: Optional[int] = None) -> None:

@@ -42,8 +42,10 @@ like ``metrics.bump`` keys):
   ``ttft`` (submit -> first emitted token), ``inter_token`` (gap between
   consecutive emitted tokens of one stream), ``queue_wait`` (enqueue ->
   admission), ``prefill`` (one admission / chunk prefill call),
-  ``decode_step`` (one ``engine.decode_step`` call: host preparation,
-  dispatch, the device step and the host read that ends it),
+  ``decode_step`` (one ``engine.decode_step`` call, the host's decode
+  turn: host preparation and dispatch of a step and the host read that
+  ends the oldest step in flight, which in the scheduler's pump is the
+  step before),
   ``spec_step`` (one speculative iteration), ``spec_verify`` (the fused
   propose+verify dispatch alone), ``restore`` (tier-restore scatter of one
   spilled chain), ``spill`` (tiering one evicted device block), ``e2e``

@@ -26,6 +26,7 @@ from paddle_tpu.serving import (
     ServingEngine,
 )
 from paddle_tpu.serving import metrics as serving_metrics
+from paddle_tpu.serving.engine import _ST_TOK
 
 pytestmark = pytest.mark.serving
 
@@ -54,14 +55,17 @@ def _uploads():
 
 def _always_dirty(engine):
     """Throw the device copy away before every step: the engine then
-    re-sends its mirrors each time, as it did before the state was kept."""
-    sound = engine._step_args
+    re-sends its mirrors each time, as it did before the state was kept.
+    The mirrors are whole only with no step in flight, so this run's pump
+    also reads every step before it dispatches the next."""
+    sound, turn = engine._step_args, engine.decode_turn
 
     def dirty_first(act):
         engine._touch_slot_state()
         return sound(act)
 
     engine._step_args = dirty_first
+    engine.decode_turn = lambda ahead: turn(False)
 
 
 def _watch_invariant(engine, seen):
@@ -73,12 +77,17 @@ def _watch_invariant(engine, seen):
 
     def checked(act):
         want = engine._pack_slot_state(act)
+        # a step dispatched behind one whose tokens are not read yet: the
+        # device is ahead of the last-token mirror, by those tokens
+        rows = [r for r in range(want.shape[0])
+                if r != _ST_TOK or not engine.steps_in_flight]
         if engine._state_dev is not None:
             seen["carried"] += 1
-            if not np.array_equal(np.asarray(engine._state_dev), want):
+            if not np.array_equal(np.asarray(engine._state_dev)[rows],
+                                  want[rows]):
                 seen["bad"].append(("carried", seen["steps"]))
         args = sound(act)
-        if not np.array_equal(np.asarray(args[3]), want):
+        if not np.array_equal(np.asarray(args[3])[rows], want[rows]):
             seen["bad"].append(("sent", seen["steps"]))
         seen["steps"] += 1
         return args

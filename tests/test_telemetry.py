@@ -464,10 +464,22 @@ def test_phases_nest_on_the_profilers_host_line(model, tmp_path):
     for parent, children in PHASE_TREE.items():
         for c in children:
             assert by[c] and all(inside(ev, by[parent]) for ev in by[c]), c
+    dispatched = 0
     for step in by["decode_step"]:
-        mine = [next(ev for ev in by["decode." + k] if inside(ev, [step]))
-                for k in ("prepare", "dispatch", "wait")]
-        assert mine[0][1] <= mine[1][0] and mine[1][1] <= mine[2][0]
+        mine = {k: [ev for ev in by["decode." + k] if inside(ev, [step])]
+                for k in ("prepare", "dispatch", "wait")}
+        # every turn reads one step; a turn that found the carried slot
+        # state stale (a host write since the last dispatch) only reads
+        assert mine["wait"] and len(mine["prepare"]) == len(mine["dispatch"])
+        waits = iter(mine["wait"])
+        for prep, disp in zip(mine["prepare"], mine["dispatch"]):
+            # prepare, dispatch, then the wait around this step's release
+            assert prep[1] <= disp[0] and disp[1] <= next(waits)[0]
+            dispatched += 1
+        # what is left is the read that ends the turn: of this turn's
+        # step, or of the one dispatched a turn ago
+        assert len(list(waits)) == 1
+    assert dispatched >= 5
     # the two halves of the pump's time never overlap
     turns = sorted(by["pump.unlocked"] + by["sched.step"])
     assert all(a[1] <= b[0] for a, b in zip(turns, turns[1:]))
