@@ -25,4 +25,10 @@ from .phi4flash import (  # noqa: F401
     Phi4FlashModel,
     phi4flash_tiny,
 )
+from .xing4 import (  # noqa: F401
+    Xing4Config,
+    Xing4ForCausalLM,
+    Xing4Model,
+    xing4_tiny,
+)
 from .widedeep import DeepFM, DistributedEmbedding, WideDeep  # noqa: F401

@@ -4,18 +4,22 @@ model shares (docs/serving_model_seam.md).
 
 **What a model declares** (methods on the ``nn.Layer`` it hands the engine;
 :class:`~paddle_tpu.models.gpt.GPTForCausalLM`,
-:class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM` and
-:class:`~paddle_tpu.models.phi4flash.Phi4FlashForCausalLM` do):
+:class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM`,
+:class:`~paddle_tpu.models.phi4flash.Phi4FlashForCausalLM` and
+:class:`~paddle_tpu.models.xing4.Xing4ForCausalLM` do):
 
 * ``serving_spec() -> ServingSpec``: vocabulary, longest context, one state
   declaration per layer, in order (the KIND of per-request state that layer
   keeps and its shape: :class:`KVLayerState`, :class:`RecurrentLayerState`,
   :class:`WindowLayerState`, :class:`SharedKVLayerState`,
-  :class:`StatelessLayerState`), and ``prefill_tail``: the first layer that
+  :class:`LatentKVLayerState`, :class:`StatelessLayerState`), and
+  ``prefill_tail``: the first layer that
   a prefill runs on each request's last valid token only (None: every layer
   runs on every token);
 * ``serving_embed(ids, positions)``: ``[lanes, s]`` token ids at per-lane
-  start positions -> hidden states;
+  start positions -> hidden states, ``[lanes, s, hidden]`` or with the
+  model's own axes behind ``s`` (Xing4.0: ``[lanes, s, 4, hidden]``, its
+  residual streams), which its layers carry and ``serving_final`` folds;
 * ``serving_layers()``: the layers, each called
   ``layer(x, cache=view, start_pos=positions) -> (x, successor view)`` with a
   cache view of ITS kind, built by the engine; a layer whose class sets
@@ -44,10 +48,40 @@ true length of a padded prefill) and ``view.write(new arrays) -> successor``;
 the view owns the per-lane store and which lanes a write reaches. A
 ``"none"`` layer is handed ``cache=None`` and returns ``(x, None)``.
 
+A ``"latent"`` layer (:class:`LatentKVLayerState`) computes its token's one
+row ``[c_kv | k_rope]`` itself (norm and rotary applied: the MODEL applies
+rotary, to the queries and to the row's rotary part, at ``start_pos +`` the
+token's index; the view never sees a position's angle) and reads
+``view.absorbed``. False, a prefill: it expands the prompt's keys and
+values from its rows and drives ``view.write_and_attend(q [1, s, heads,
+192], rows [1, s, W], scale, kv=(k [1, s, heads, 192], v [1, s, heads,
+128])) -> (attention output [1, s, heads, 128], successor)``: the view
+keeps the rows for the engine to scatter and attends causally over the
+expanded form. True, a decode step: it carries each head's query into the
+latent space and drives ``view.write_and_attend(q [lanes, 1, heads, W],
+rows [lanes, 1, W], scale) -> (the probabilities' sums of the rows' first
+``latent_dim`` values [lanes, 1, heads, latent_dim], successor)``, which it
+expands through the value half of its own up-projection. The view owns the
+pool's layout (rows are packed two to a pool row at W = 576), the block
+tables and the kernel.
+
+**Options a latent pool refuses** (a ``ValueError`` that names the option,
+at construction): ``prefix_cache``, ``kv_tiering``, ``chunked_prefill``
+(their programs attend a resident prefix through ``"kv"`` views),
+``spec_k`` (the verify programs likewise), ``quant_kv`` (no int8 form of a
+row), a device ``mesh`` of more than one chip (one row has no heads to
+shard), and the disaggregated handoff (``DisaggReplicaPool``).
+
 **The step carry.** ``forward_cached`` hands every layer that sets
 ``uses_step_carry`` one dict, the same for the whole call: a layer publishes
 ``carry[name] = value`` (``[b, s, ...]``) and a later layer of the same call
-reads it. It is no cache: nothing of it outlives the call.
+reads it. It is no cache: nothing of it outlives the call. The decode step
+seeds it with ``"lanes"`` (``[lanes, 1]`` bool: the lanes that hold a
+request), and reads one entry back: ``carry["counters"]``, a dict of int32
+scalars by counter name that layers add to (:func:`add_step_counters`);
+the step returns their sums behind its tokens, and the host adds each to
+the ``serving.metrics`` counter of its name when it reads the tokens: no
+transfer of their own.
 
 **The prefill tail.** With ``prefill_tail = n`` a prefill hands
 ``forward_cached`` each request's last valid index: from layer ``n`` on the
@@ -91,6 +125,25 @@ class KVLayerState:
     @property
     def kv_heads(self) -> int:
         return int(self.num_kv_heads or self.num_heads)
+
+
+@dataclass(frozen=True)
+class LatentKVLayerState:
+    """A layer whose per-request state is ONE row a token, shared by all
+    ``num_heads`` query heads: ``latent_dim`` values of compressed keys and
+    values (after their norm) and ``rope_dim`` of the one rotary key (after
+    its rotation), nothing per head. It lives in the paged arena like a
+    ``"kv"`` layer's, ``latent_dim + rope_dim`` values a token, with the
+    same block accounting."""
+
+    latent_dim: int
+    rope_dim: int
+    num_heads: int
+    kind: str = "latent"
+
+    @property
+    def width(self) -> int:
+        return int(self.latent_dim) + int(self.rope_dim)
 
 
 @dataclass(frozen=True)
@@ -148,6 +201,8 @@ class RecurrentLayerState:
 
 #: the kinds whose state lies in the arena's slot-indexed store
 SLOT_KINDS = ("recurrent", "window")
+#: the kinds whose state is rows in the arena's block pools
+PAGED_KINDS = ("kv", "latent")
 
 
 @dataclass(frozen=True)
@@ -161,6 +216,9 @@ class ServingSpec:
     def kv_layers(self):
         return [s for s in self.layers if s.kind == "kv"]
 
+    def latent_layers(self):
+        return [s for s in self.layers if s.kind == "latent"]
+
 
 class SharedRef:
     """Stands in ``forward_cached``'s views for a ``"shared"`` layer: its
@@ -171,21 +229,34 @@ class SharedRef:
         self.source = int(source)
 
 
+def add_step_counters(carry, values) -> None:
+    """Add a layer's int32 scalars ``{name: value}`` to the step's
+    counters (the carry's ``"counters"``)."""
+    into = carry.setdefault("counters", {})
+    for name, value in values.items():
+        into[name] = into[name] + value if name in into else value
+
+
 def last_row(a, last):
     """``a[:, last]`` kept as a length-1 axis (``last`` a traced scalar)."""
     return jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1)
 
 
 def forward_cached(model, ids, views, positions, last=None,
-                   prefill_tail=None):
+                   prefill_tail=None, carry=None):
     """Embed -> layers (each handed its cache view) -> final norm: the one
     way a compiled serving program runs a model. Returns ``(hidden
     [lanes, s, hidden] Tensor, successor views)``. With ``prefill_tail``
     and ``last`` (a prefill of a model that declares a tail) the layers
     from ``prefill_tail`` on see row ``last`` alone, and ``hidden`` is
-    ``[lanes, 1, hidden]``."""
+    ``[lanes, 1, hidden]``. Between embed and final norm the hidden states
+    are the model's own: ``[lanes, s, hidden]``, or with further axes
+    behind ``s`` (residual streams, ``[lanes, s, streams, hidden]``), which
+    ``serving_final`` folds away; nothing here reads past axis 1.
+    ``carry``: the step carry to start from (the decode step seeds it, see
+    the module's head); None starts an empty one."""
     x = model.serving_embed(ids, positions)
-    new_views, carry = [], {}
+    new_views, carry = [], ({} if carry is None else carry)
     for i, (layer, view) in enumerate(zip(model.serving_layers(), views)):
         if isinstance(view, SharedRef):
             view = new_views[view.source].reader()
@@ -193,7 +264,7 @@ def forward_cached(model, ids, views, positions, last=None,
             if x.shape[1] != 1:  # layer i - 1 did not narrow it itself
                 x = Tensor(last_row(x._data, last))
             for name, value in carry.items():
-                if value.shape[1] != 1:
+                if name != "counters" and value.shape[1] != 1:
                     carry[name] = last_row(value, last)
         if getattr(layer, "uses_step_carry", False):
             x, nv = layer(x, cache=view, start_pos=positions, carry=carry)

@@ -67,7 +67,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .pallas_ops import NEG_INF, _HAS_PALLAS, _LANES, _use_interpret
+from .pallas_ops import (NEG_INF, _HAS_PALLAS, _LANES, _flash_fwd_kernel,
+                         _use_interpret)
 
 if _HAS_PALLAS:
     from jax.experimental import pallas as pl
@@ -75,7 +76,8 @@ if _HAS_PALLAS:
 
 __all__ = ["available", "decode_in_place", "paged_decode_attention",
            "paged_prefill_attention", "paged_full_prefill_attention",
-           "write_token"]
+           "write_token", "paged_latent_decode", "latent_prefill_attention",
+           "latent_pack", "latent_rows", "write_latent_token"]
 
 
 #: scoped VMEM the kernels may use (the compiler's default, 16 MiB of the
@@ -411,6 +413,17 @@ def _decode_kernel(bt_ref, len_ref, nxt_ref, q_ref, bias_ref, tok_ref,
         o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
+def _next_live_lane(lengths):
+    """The decode kernels' copies run one tile ahead, across lanes:
+    ``nxt[0]`` is the first live lane, ``nxt[s + 1]`` the next after lane
+    ``s``, ``S`` where there is none."""
+    S = lengths.shape[0]
+    lane = jnp.arange(S, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(lengths > 0, lane, S), axis=0,
+                         reverse=True)
+    return jnp.concatenate([nxt, jnp.full((1,), S, jnp.int32)])
+
+
 @functools.partial(jax.jit, static_argnames=("pages",))
 def _decode_call(q, entry, block_tables, lengths, pages):
     """The kernel launch on one device's heads (``lengths`` ``[S]``: live
@@ -432,13 +445,8 @@ def _decode_call(q, entry, block_tables, lengths, pages):
     hq = -(-HQ // 8) * 8  # query rows: whole f32 sublane tiles
     pages = _tile_pages(MB, rows * Dp * kp.dtype.itemsize, pages)
     cols = pages * rows
-    # the copies run one tile ahead, across lanes: nxt[0] is the first live
-    # lane, nxt[s + 1] the next after lane s, S where there is none
     lengths = lengths.astype(jnp.int32)
-    lane = jnp.arange(S, dtype=jnp.int32)
-    nxt = jax.lax.cummin(jnp.where(lengths > 0, lane, S), axis=0,
-                         reverse=True)
-    nxt = jnp.concatenate([nxt, jnp.full((1,), S, jnp.int32)])
+    nxt = _next_live_lane(lengths)
     bias, tok = _tile_columns(pages, bs, H, hq, head_major, HQ // H)
     q_spec = pl.BlockSpec((1, hq, Dp), lambda s, *_: (s, 0, 0))
     whole = lambda a: pl.BlockSpec(a.shape, lambda s, *_: (0,) * a.ndim)
@@ -757,3 +765,247 @@ def paged_full_prefill_attention(q, k, v, block_size,
     return paged_prefill_attention(q, entry, table, jnp.int32(0),
                                    block_q=block_q, block_h=block_h,
                                    mesh=mesh)
+
+
+# ---------------------------------------------------------------- latent
+#
+# A latent (MLA) layer's state is ONE row a token, W values (the compressed
+# key/value ``c_kv`` and the one shared rotary key: 512 + 64). The chip
+# keeps an array's minor dimension in whole 128-lane tiles: a ``[blocks,
+# block, 576]`` pool would lie 640 wide (an eighth more bytes than it
+# counts) and Mosaic refuses to copy a 576-lane slice of it. So the pool
+# packs ``pack`` consecutive tokens into one row (:func:`latent_pack`: 2 at
+# 576, a row of 1152 = 9 tiles): ``[blocks, block / pack, pack * W]``, the
+# same bytes in the same order as ``[blocks, block, W]``, nothing padded.
+#
+# Decode is ABSORBED: every head's query has been carried into the latent
+# space (``[H, W]``), so a token's row is every head's key, and its first
+# ``value_dim`` values are every head's value. The kernel is
+# :func:`_decode_kernel`'s scheme with one pool and no head mask: per lane
+# the live tiles alone, a page copied ONCE and used as keys and as values,
+# all heads in one pair of matmuls per packed position, the softmax state
+# in float32. Packed position ``h`` of a row is reached by a query that is
+# zero outside lanes ``[h W, (h + 1) W)``; its probabilities times the whole
+# row accumulate ``[H, pack * W]``, of which the caller keeps lanes ``[h W,
+# h W + value_dim)``: no lane is ever sliced off a tile's boundary.
+
+
+def latent_pack(width: int) -> int:
+    """Tokens a pool row holds so that its lanes fill whole tiles."""
+    return 1 if width % _LANES == 0 else 2
+
+
+def latent_rows(pool, width: int):
+    """The pool as ``[blocks, block, W]`` (its logical form; on the chip
+    this moves bytes: for the XLA routes and the tests)."""
+    return pool.reshape(pool.shape[0], -1, width)
+
+
+def write_latent_token(pool, blocks, offsets, rows):
+    """One new token a lane into a packed latent pool: ``rows`` ``[S, W]``
+    go to token ``offsets[s]`` of block ``blocks[s]``. The packed row is
+    read, the token's lanes replaced, and the row written back (lanes of
+    different requests never share a block; lanes that are not active all
+    write scratch block 0, where anything may land)."""
+    width = rows.shape[-1]
+    pack = pool.shape[2] // width
+    if pack == 1:
+        return pool.at[blocks, offsets].set(rows.astype(pool.dtype))
+    r, h = offsets // pack, offsets % pack
+    old = pool[blocks, r]                                  # [S, pack W]
+    lane = jnp.arange(pool.shape[2], dtype=jnp.int32)[None, :] // width
+    new = jnp.where(lane == h[:, None],
+                    jnp.tile(rows.astype(pool.dtype), (1, pack)), old)
+    return pool.at[blocks, r].set(new)
+
+
+def _latent_kernel(bt_ref, len_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
+                   slot_ref, *, bs, pack, pages, scale):
+    s = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    t_tile = pages * bs
+    rpb = bs // pack                       # pool rows a block
+    hq, pw = q_ref.shape[2], q_ref.shape[3]
+    cols = pages * rpb
+
+    def each_live_page(lane, j, slot, act):
+        for i in range(pages):
+            page = j * pages + i
+
+            @pl.when(page * bs < len_ref[lane])
+            def _():
+                act(pltpu.make_async_copy(kv_hbm.at[bt_ref[lane, page]],
+                                          buf.at[slot, i], sem.at[slot]))
+
+    def start(lane, j, slot):
+        each_live_page(lane, j, slot, lambda c: c.start())
+
+    def wait(lane, j, slot):
+        each_live_page(lane, j, slot, lambda c: c.wait())
+
+    @pl.when(s == 0)
+    def _first():
+        # rows never copied meet zero probabilities: they must be finite
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+
+        @pl.when(nxt_ref[0] < n_lanes)
+        def _():
+            start(nxt_ref[0], 0, 0)
+
+    length = len_ref[s]
+    n_tiles = (length + t_tile - 1) // t_tile
+
+    @pl.when(n_tiles == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tiles > 0)
+    def _live():
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        tok0 = (col // rpb) * bs + (col % rpb) * pack  # of packed position 0
+
+        def tile(j, carry):
+            m_prev, l_prev, accs = carry
+            slot = (slot0 + j) % 2
+
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                start(s, j + 1, 1 - slot)
+
+            @pl.when(j + 1 == n_tiles)
+            def _():
+                nxt = nxt_ref[s + 1]
+
+                @pl.when(nxt < n_lanes)
+                def _():
+                    start(nxt, 0, 1 - slot)
+
+            wait(s, j, slot)
+            rows = buf[slot].reshape(cols, pw)
+            left = length - j * t_tile
+            scs = []
+            for h in range(pack):  # the row is the key of `pack` tokens
+                sc = jax.lax.dot_general(
+                    q_ref[0, h], rows, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                scs.append(jnp.where(tok0 + h < left, sc, NEG_INF))
+            m_new = m_prev
+            for sc in scs:
+                m_new = jnp.maximum(m_new, jnp.max(sc, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            l_new, new_accs = corr * l_prev, []
+            for sc, acc in zip(scs, accs):
+                p = jnp.exp(sc - m_new)
+                l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
+                new_accs.append(acc * corr + jax.lax.dot_general(
+                    p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))  # and the value
+            return m_new, l_new, tuple(new_accs)
+
+        slot0 = slot_ref[0]
+        _, l, accs = jax.lax.fori_loop(
+            0, n_tiles, tile,
+            (jnp.full((hq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((hq, 1), jnp.float32),
+             tuple(jnp.zeros((hq, pw), jnp.float32) for _ in range(pack))))
+        slot_ref[0] = (slot0 + n_tiles) % 2
+        for h in range(pack):
+            o_ref[0, h] = accs[h] / l
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("width", "value_dim", "scale", "pages"))
+def _latent_call(q, pool, block_tables, lengths, width, value_dim, scale,
+                 pages):
+    """The launch; jitted so that a model's layers share one lowered
+    kernel inside the step program (see :func:`_decode_call`)."""
+    S, HQ, _ = q.shape
+    NB, rpb, pw = pool.shape
+    pack = pw // width
+    bs = rpb * pack
+    MB = block_tables.shape[1]
+    hq = -(-HQ // 8) * 8  # query rows: whole f32 sublane tiles
+    pages = _tile_pages(MB, rpb * pw * pool.dtype.itemsize, pages)
+    lengths = lengths.astype(jnp.int32)
+    nxt = _next_live_lane(lengths)
+    # the query of packed position h: zero outside lanes [h W, (h + 1) W)
+    qs = jnp.stack([jnp.pad(q, ((0, 0), (0, hq - HQ),
+                                (h * width, (pack - 1 - h) * width)))
+                    for h in range(pack)], axis=1).astype(pool.dtype)
+    spec = pl.BlockSpec((1, pack, hq, pw), lambda s, *_: (s, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, pack=pack, pages=pages,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(S,),
+            in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, rpb, pw), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # buffer of the next tile
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, pack, hq, pw), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_use_interpret(),
+        name="paged_latent_decode",
+    )(block_tables, lengths, nxt, qs, pool)
+    o = sum(out[:, h, :HQ, h * width:h * width + value_dim]
+            for h in range(pack))
+    return o.astype(q.dtype)
+
+
+def paged_latent_decode(q, pool, block_tables, positions, value_dim: int,
+                        scale: float, active=None, pages=None):
+    """Absorbed latent attention of one new token a lane, straight through
+    the block tables. ``q`` ``[S, H, W]`` (each head's query carried into
+    the latent space, its rotary part behind); ``pool`` the packed latent
+    pool ``[num_blocks, block_size / pack, pack * W]``, a token's one row
+    of ``W`` values; lane ``s`` attends the rows at positions ``<=
+    positions[s]`` of its table: scores ``q . row * scale``, output the
+    probabilities' sum of the rows' first ``value_dim`` values, ``[S, H,
+    value_dim]`` in ``q.dtype``. A lane that is not ``active`` reads no page
+    and returns zeros. Tables, positions and ``active`` are runtime data.
+    ``pages`` (pages a tile) is a launch parameter; None: what fills
+    ``_TILE_BYTES``."""
+    lengths = positions.astype(jnp.int32) + 1
+    if active is not None:
+        lengths = jnp.where(active, lengths, 0)
+    return _latent_call(q, pool, block_tables, lengths, int(q.shape[-1]),
+                        int(value_dim), float(scale), pages or 0)
+
+
+def latent_prefill_attention(q, k, v, scale: float, block: int = 512):
+    """Causal attention of one prompt with keys wider than values (the
+    EXPANDED form of latent attention: ``q``, ``k`` ``[s, H, 192]``, ``v``
+    ``[s, H, 128]``): the flash forward kernel of ``ops/pallas_ops.py``
+    (:func:`_flash_fwd_kernel`, whose body takes any widths) over
+    head-major copies, the blocks above the diagonal skipped. ``s`` is a
+    cut into the largest blocks of at most ``block`` rows, a multiple of
+    128, that divide it (one block where none does). Returns ``[s, H, v
+    width]``."""
+    s, h, d = q.shape
+    dv = v.shape[-1]
+    blk = next((b for b in range(block, 0, -_LANES) if s % b == 0), s)
+    qf, kf, vf = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))
+    out = pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, scale=float(scale), causal=True,
+                          blk_q=blk, blk_k=blk, offset=0, with_lse=False),
+        grid=(h, s // blk, s // blk),
+        in_specs=[pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, blk, d), lambda b, i, j: (b, j, 0)),
+                  pl.BlockSpec((1, blk, dv), lambda b, i, j: (b, j, 0))],
+        out_specs=[pl.BlockSpec((1, blk, dv), lambda b, i, j: (b, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((h, s, dv), q.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+        name="latent_prefill_flash",
+    )(qf, kf, vf)[0]
+    return jnp.swapaxes(out, 0, 1)

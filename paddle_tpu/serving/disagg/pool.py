@@ -57,8 +57,8 @@ class DisaggReplicaPool(ProcessReplicaPool):
                  decode_replicas: Optional[int] = None,
                  disk_dir: Optional[str] = None, **pool_kw):
         spec = getattr(model, "serving_spec", None)
-        if spec is not None and any(st.kind in ("recurrent", "window")
-                                    for st in spec().layers):
+        kinds = {st.kind for st in spec().layers} if spec is not None else set()
+        if kinds & {"recurrent", "window"}:
             # a request is handed over as its published BLOCK chain; a
             # recurrent layer's state is not blocks (a model factory is
             # refused by its workers' engines: the prefix cache names it)
@@ -66,6 +66,13 @@ class DisaggReplicaPool(ProcessReplicaPool):
                 "disaggregated prefill/decode handoff is not supported for "
                 "a model with recurrent-state layers: it hands a request "
                 "over as paged blocks")
+        if "latent" in kinds:
+            # the handoff rides the prefix cache and the host tier, which
+            # a latent pool refuses (models/serving_seam.py)
+            raise ValueError(
+                "disaggregated prefill/decode handoff is not supported for "
+                "a model with latent-attention layers: it publishes K and "
+                "V blocks through the prefix cache")
         p, d = role_counts(prefill_replicas, decode_replicas)
         if p < 1 or d < 1:
             raise ValueError(

@@ -32,7 +32,10 @@ heads, head_dim: rows in the paged arena), ``"recurrent"`` (fixed-size
 arrays per lane, in the arena's slot-indexed store), ``"window"`` (the last
 ``window`` tokens' K/V per lane, a ring in the same store), ``"shared"``
 (no state: it reads the pool of the ``"kv"`` layer it names, through the
-same block tables) or ``"none"``. Every compiled program here runs the
+same block tables), ``"latent"`` (ONE row a token for all heads, in the
+paged arena: the two latent views below; a prefill attends the expanded
+form, the decode step the absorbed one through the block tables) or
+``"none"``. Every compiled program here runs the
 model one way, :func:`~paddle_tpu.models.serving_seam.forward_cached`: embed
 -> layers, each handed a cache view of ITS kind built here (the three paged
 views below for ``"kv"`` layers, the two slot-state views for
@@ -47,7 +50,8 @@ counted for the layers that OWN a pool: a model with one ``"kv"`` layer and
 seven ``"shared"`` readers of it pages one layer. The options whose
 bookkeeping assumes every layer's state is blocks (prefix cache, KV
 tiering, speculative decoding, chunked prefill) refuse a model with a
-recurrent or window layer at construction.
+recurrent or window layer at construction; those, int8 K/V and a mesh of
+more than one chip refuse a model with latent layers.
 
 Decode numerics deliberately share
 ``models.serving_seam.masked_attention`` and the model's ``serving_head``
@@ -601,6 +605,113 @@ class _WindowPrefillView:
         return o, _WindowPrefillView(entry, self.slot, self.true_len, w)
 
 
+class _LatentDecodeView:
+    """One ``"latent"`` layer's decode-step view: ``entry`` is its pool
+    entry ``(rows,)`` (:meth:`KVArena._fresh_latent`). The layer hands over
+    each lane's ABSORBED queries ``[S, 1, heads, W]`` and its new token's
+    one row ``[S, 1, W]``; the row is written at the lane's (block, offset)
+    (a lane that is not active writes scratch block 0) and every head
+    attends the lane's rows up to and including it: scores ``q . row *
+    scale``, output the probabilities' sum of the rows' first ``W -
+    rope_dim`` values. ``kernel``: through the Pallas latent decode kernel
+    (:func:`paddle_tpu.ops.paged_attention.paged_latent_decode`: the live
+    pages alone, each read once); else the XLA gather of the tables."""
+
+    absorbed = True
+
+    def __init__(self, entry, block_tables, positions, active,
+                 block_size: int, latent_dim: int, kernel: bool = False):
+        self.entry = entry
+        self.block_tables = block_tables
+        self.positions = positions
+        self.active = active
+        self.block_size = block_size
+        self.latent_dim = int(latent_dim)
+        self.kernel = kernel
+
+    def write_and_attend(self, q, rows, scale, kv=None):
+        import jax.numpy as jnp
+
+        from ..ops import paged_attention as pa
+
+        qa, ra = (t._data if isinstance(t, Tensor) else t for t in (q, rows))
+        bs, pos = self.block_size, self.positions
+        blk = self.block_tables[jnp.arange(qa.shape[0]), pos // bs]
+        blk = jnp.where(self.active, blk, 0)
+        pool = pa.write_latent_token(self.entry[0], blk, pos % bs, ra[:, 0])
+        if self.kernel:
+            o = pa.paged_latent_decode(qa[:, 0], pool, self.block_tables,
+                                       pos, self.latent_dim, scale,
+                                       active=self.active)[:, None]
+        else:
+            with jax.named_scope("kv_gather"):
+                ctx = pa.latent_rows(pool, ra.shape[-1])[self.block_tables]
+            ctx = ctx.reshape(qa.shape[0], -1, ra.shape[-1])  # [S, T, W]
+            sc = jnp.einsum("shw,stw->sht", qa[:, 0], ctx) * scale
+            mask = jnp.arange(ctx.shape[1])[None, :] <= pos[:, None]
+            sc = jnp.where(mask[:, None, :], sc, -1e30)
+            pr = jax.nn.softmax(sc.astype(jnp.float32), -1).astype(qa.dtype)
+            o = jnp.einsum("sht,std->shd", pr,
+                           ctx[..., :self.latent_dim])[:, None]
+        return o, _LatentDecodeView((pool,), self.block_tables, pos,
+                                    self.active, bs, self.latent_dim,
+                                    self.kernel)
+
+
+class _LatentPrefillView:
+    """One ``"latent"`` layer's prefill view: the layer hands over the
+    prompt's queries, its rows and the keys and values EXPANDED from them;
+    the rows are kept for the engine to scatter into the slot's blocks
+    (:func:`_scatter_latent`), and the attention is causal over the
+    expanded form (keys wider than values): the flash forward kernel
+    (:func:`paddle_tpu.ops.paged_attention.latent_prefill_attention`) where
+    ``kernel``, else plain XLA (the CPU's tiny prompts: its scores are
+    ``[heads, s, s]``)."""
+
+    absorbed = False
+
+    def __init__(self, kernel: bool = False, rows=None):
+        self.kernel = kernel
+        self.rows = rows
+
+    def write_and_attend(self, q, rows, scale, kv=None):
+        import jax.numpy as jnp
+
+        qa, ra, ka, va = (t._data if isinstance(t, Tensor) else t
+                          for t in (q, rows) + tuple(kv))
+        if self.kernel:
+            from ..ops.paged_attention import latent_prefill_attention
+
+            o = latent_prefill_attention(qa[0], ka[0], va[0], scale)[None]
+        else:
+            p = qa.shape[1]
+            sc = jnp.einsum("bqhd,bkhd->bhqk", qa, ka) * scale
+            mask = jnp.arange(p)[None, :] <= jnp.arange(p)[:, None]
+            sc = jnp.where(mask[None, None], sc, -1e30)
+            pr = jax.nn.softmax(sc.astype(jnp.float32), -1).astype(qa.dtype)
+            o = jnp.einsum("bhqk,bkhd->bqhd", pr, va)
+        return o, _LatentPrefillView(self.kernel, ra)
+
+
+def _scatter_latent(entry, table_rows, true_len, rows, block_size: int):
+    """A prompt's latent rows ``[p, W]`` into the slot's blocks
+    (``table_rows``: the block of each ``block_size`` positions). Rows go
+    in whole POOL rows (``pack`` consecutive tokens): a pool row whose
+    first token is at or past ``true_len`` is padding and lands in scratch
+    block 0; one that straddles ``true_len`` carries a padding token
+    behind a real one, which the decode step that writes that position
+    replaces before any mask lets it be read."""
+    import jax.numpy as jnp
+
+    pool = entry[0]
+    p, width = rows.shape
+    pack = pool.shape[2] // width
+    first = jnp.arange(p // pack) * pack            # each pool row's token
+    blk = jnp.where(first < true_len, table_rows[first // block_size], 0)
+    return (pool.at[blk, (first % block_size) // pack].set(
+        rows.reshape(p // pack, pack * width).astype(pool.dtype)),)
+
+
 def _stateless_view(state):
     """What ``forward_cached`` is handed for a layer that owns no state: a
     reference to the layer whose pool a ``"shared"`` layer reads (its real
@@ -615,9 +726,9 @@ def _split_views(views, kinds):
     """The successor views' storage by kind, in layer order: ``(entries of
     the "kv" layers, entries of the layers whose state lies in the
     slot-indexed store)``."""
-    from ..models.serving_seam import SLOT_KINDS
+    from ..models.serving_seam import PAGED_KINDS, SLOT_KINDS
 
-    kv = [v.entry for v, k in zip(views, kinds) if k == "kv"]
+    kv = [v.entry for v, k in zip(views, kinds) if k in PAGED_KINDS]
     rec = [v.entry for v, k in zip(views, kinds) if k in SLOT_KINDS]
     return kv, rec
 
@@ -837,6 +948,14 @@ class ServingEngine:
         if len({(st.kv_heads, st.head_dim) for st in kv_layers}) > 1:
             raise ValueError("the paged arena holds one (heads, head_dim) "
                              "for all of a model's kv layers")
+        # "latent" layers: ONE row a token in the same block pools
+        latent_layers = spec.latent_layers()
+        if latent_layers and (kv_layers or len(
+                {st.width for st in latent_layers}) > 1):
+            raise ValueError("the paged arena holds one shape of row: a "
+                             "model's latent layers share one width, and "
+                             "it has no kv layer beside them")
+        self.latent = bool(latent_layers)
         for i, st in enumerate(spec.layers):
             if st.kind == "shared" and not (
                     0 <= st.source < i
@@ -883,7 +1002,10 @@ class ServingEngine:
             # natively compiled, and reading the pools where they lie
             self.decode_kernel = not pallas_ops._use_interpret() and all(
                 paged_attention.decode_in_place(st.head_dim)
-                for st in kv_layers)
+                for st in kv_layers) and all(
+                paged_attention.decode_in_place(
+                    paged_attention.latent_pack(st.width) * st.width)
+                for st in latent_layers)
         else:
             self.decode_kernel = self.paged_kernel
         # the mesh the kernel calls route through (ISSUE 16): on a
@@ -926,25 +1048,45 @@ class ServingEngine:
         # supervisor's rebuild/replay path stays zero-recompile on a mesh)
         kv_heads, kv_dim = ((kv_layers[0].kv_heads, kv_layers[0].head_dim)
                             if kv_layers else (1, 1))
-        self._arena_args = (len(kv_layers), kv_heads, kv_dim,
-                            num_blocks, self.block_size, kv_dtype,
-                            self.quant_kv, self.mesh, self.num_slots,
-                            tuple(st.arrays if st.kind == "recurrent"
-                                  else st.arrays(kv_dtype)
-                                  for st in slot_layers))
-        self.arena = KVArena(*self._arena_args)
         self.use_prefix_cache = (bool(flags.flag("serving_prefix_cache"))
                                  if cfg.prefix_cache is None
                                  else bool(cfg.prefix_cache))
+        self.kv_tiering = (bool(flags.flag("serving_kv_tiering"))
+                           if cfg.kv_tiering is None
+                           else bool(cfg.kv_tiering))
+        if self.latent:
+            # each of these attends a resident prefix or verifies drafts
+            # through "kv" views, stores int8 K and V, or shards heads: a
+            # latent row is none of that yet. Refused by name
+            for on, option in (
+                    (self.use_prefix_cache, "prefix_cache"),
+                    (self.kv_tiering, "kv_tiering"),
+                    (spec_k > 0, "spec_k (speculative decoding)"),
+                    (self.chunk_size > 0, "chunked_prefill"),
+                    (self.quant_kv, "quant_kv"),
+                    (self._mesh_devices > 1, "mesh (more than one chip)")):
+                if on:
+                    raise ValueError(
+                        f"{option} is not supported for a model with "
+                        "latent-attention layers (one shared row a token "
+                        "in the paged pool): it assumes per-head K and V "
+                        "pools")
+        self._arena_args = (len(kv_layers) + len(latent_layers), kv_heads,
+                            kv_dim, num_blocks, self.block_size, kv_dtype,
+                            self.quant_kv,
+                            None if self.latent else self.mesh,
+                            self.num_slots,
+                            tuple(st.arrays if st.kind == "recurrent"
+                                  else st.arrays(kv_dtype)
+                                  for st in slot_layers),
+                            latent_layers[0].width if self.latent else 0)
+        self.arena = KVArena(*self._arena_args)
         # tiered KV cache (ISSUE 15): the TierView survives rebuild()
         # untouched — host/disk tiers are off-device by construction, so
         # crash recovery replays against a warm cache. The view's arena
         # signature (shape facts + quant mode + mesh fingerprint) keeps
         # incompatible engines from ever exchanging entries through a
         # shared store.
-        self.kv_tiering = (bool(flags.flag("serving_kv_tiering"))
-                           if cfg.kv_tiering is None
-                           else bool(cfg.kv_tiering))
         if self.recurrent:
             # each of these keeps, shares or rewinds a request's state as
             # BLOCKS; a recurrent layer's state is not blocks (it would
@@ -1052,6 +1194,8 @@ class ServingEngine:
         self.cow_traces = 0
         self.restore_traces = 0  # tier restore: one trace per arena shape
         self._step_jit = None
+        #: names of the counters the step returns behind its tokens
+        self._step_counters: Tuple[str, ...] = ()
         self._prefill_jits: Dict[int, object] = {}
         self._prefix_jits: Dict[int, object] = {}
         self._cow_jit = None
@@ -1075,6 +1219,8 @@ class ServingEngine:
         metrics.set_gauge("mesh.model_axis", self._mesh_model)
         metrics.set_gauge("mesh.data_axis", self._mesh_data)
         metrics.set_gauge("kernel.paged", int(self.decode_kernel))
+        metrics.set_gauge("kernel.paged_latent",
+                          int(self.decode_kernel and self.latent))
         # the EFFECTIVE attention route x mesh topology (ISSUE 16), per
         # arena namespace: "kernel@data1.model4", "gather@single", ... A
         # fallback (Pallas unavailable, flag off) is observable here
@@ -1262,7 +1408,8 @@ class ServingEngine:
 
         from ..core import rng as prng
         from ..jit import _swap_data
-        from ..models.serving_seam import SLOT_KINDS, forward_cached
+        from ..models.serving_seam import (PAGED_KINDS, SLOT_KINDS,
+                                           forward_cached)
         from .sampling import sample_tokens
 
         model = self._model
@@ -1271,6 +1418,9 @@ class ServingEngine:
         tail = self._prefill_tail
         bs = self.block_size
         use_kernel = self.paged_kernel
+        # a latent layer's prompt attention has no XLA form that fits a
+        # long prompt: it follows the decode step's route
+        latent_kernel = self.paged_kernel or self.decode_kernel
         kmesh = self._kernel_mesh
 
         def prefill(arrays, ids, true_len, pools, rows, samp, rec, slot,
@@ -1303,6 +1453,8 @@ class ServingEngine:
                 elif st.kind == "window":
                     views.append(_WindowPrefillView(next(it_rec), slot,
                                                     true_len, st.window))
+                elif st.kind == "latent":
+                    views.append(_LatentPrefillView(latent_kernel))
                 else:
                     views.append(_stateless_view(st))
             with _swap_data(self._objs, list(arrays)):
@@ -1319,7 +1471,8 @@ class ServingEngine:
                     else:
                         h_last = h._data[:, 0]
                     logits = model.serving_head(h_last)
-            chunks = [v for v, k in zip(new_views, kinds) if k == "kv"]
+            chunks = [v for v, k in zip(new_views, kinds)
+                      if k in PAGED_KINDS]
             new_rec = [v.entry for v, k in zip(new_views, kinds)
                        if k in SLOT_KINDS]
             p_idx = jnp.arange(p_bucket)
@@ -1330,6 +1483,10 @@ class ServingEngine:
             off = p_idx % bs
             new_pools = []
             for chunk, entry in zip(chunks, pools):
+                if isinstance(chunk, _LatentPrefillView):
+                    new_pools.append(_scatter_latent(
+                        entry, rows, true_len, chunk.rows[0], bs))
+                    continue
                 new_pools.append(
                     _scatter_rows(entry, row, off, chunk.k[0], chunk.v[0]))
             # the first generated token goes through the SAME sampling
@@ -1585,15 +1742,21 @@ class ServingEngine:
                 elif st.kind == "window":
                     views.append(_WindowDecodeView(next(it_rec), positions,
                                                    st.window))
+                elif st.kind == "latent":
+                    views.append(_LatentDecodeView(
+                        next(it_kv), block_tables, positions, active, bs,
+                        st.latent_dim, kernel=use_kernel))
                 else:
                     views.append(_stateless_view(st))
+            # the step carry's seed: the lanes that hold a request
+            carry = {"lanes": active[:, None]}
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_pools, state[_ST_ADAPTER])
                           if lora is not None else _null_ctx()):
                         h, new_views = forward_cached(
                             model, Tensor(last_tok[:, None]), views,
-                            positions)
+                            positions, carry=carry)
                 with jax.named_scope("head_sample"):
                     logits = model.serving_head(h._data[:, 0])
             # per-slot sampling over the constrained logits: temperature /
@@ -1615,6 +1778,16 @@ class ServingEngine:
             # _step_args uploads it in: one signature, one executable
             if mesh is not None:
                 new_state = replicate(new_state, mesh=mesh)
+            # what the layers counted this step rides behind the tokens
+            # (trace time: the names; a model that counts nothing leaves
+            # the token vector as it was)
+            counted = carry.get("counters", {})
+            self._step_counters = tuple(counted)
+            # analysis: allow(traced-branch) — `counted` is a dict by
+            # counter name: trace-time structure, whatever its values are
+            if counted:
+                nxt = jnp.concatenate([nxt] + [
+                    v.astype(nxt.dtype)[None] for v in counted.values()])
             return nxt, new_pools, new_rec, new_state
 
         self._step_jit = (jax.jit(step, donate_argnums=(1, 5))
@@ -2456,6 +2629,9 @@ class ServingEngine:
             # a device that died under the step says so here
             resilience.maybe_fault("serving_step")
             out = np.asarray(step.tokens)
+        for name, value in zip(self._step_counters, out[self.num_slots:]):
+            metrics.bump(name, int(value))
+        out = out[:self.num_slots]
         live = step.lanes & (step.tenancy == self._tenancy)
         self._last_tok[live] = out[live]
         self.lanes_read = live
@@ -2552,7 +2728,8 @@ class ServingEngine:
         # layers that own a paged pool, and layers that read one (a
         # "shared" layer reads the pool of the layer it names)
         metrics.set_gauge("arena.paged_layers",
-                          self._layer_kinds.count("kv"))
+                          self._layer_kinds.count("kv")
+                          + self._layer_kinds.count("latent"))
         metrics.set_gauge("arena.kv_readers", self._layer_kinds.count("kv")
                           + self._layer_kinds.count("shared"))
         if self.recurrent:

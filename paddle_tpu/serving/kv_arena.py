@@ -39,7 +39,10 @@ read-only by contract; a slot that must write into one copies it first
 
 **Two kinds of state, one manager** (the engine<->model seam,
 ``models/serving_seam.py``): the block pools above hold the ``"kv"``
-layers' state, which grows a row a token. A ``"recurrent"`` layer's state
+layers' state, which grows a row a token (a ``"latent"`` layer's likewise:
+ONE row a token for all heads, its entry the 1-tuple ``(rows,)`` of
+``latent_width`` values a token, same blocks, same tables, same
+accounting). A ``"recurrent"`` layer's state
 has a fixed size whatever the context, so it lives in a second,
 **slot-indexed** store: per such layer a tuple of ``[num_slots, *shape]``
 arrays (:attr:`KVArena.slot_state`), the lane a request decodes in being its
@@ -124,9 +127,14 @@ class KVArena:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_blocks: int, block_size: Optional[int] = None,
                  dtype: str = "float32", quantized: bool = False,
-                 mesh=None, num_slots: int = 0, slot_state=()):
+                 mesh=None, num_slots: int = 0, slot_state=(),
+                 latent_width: int = 0):
         """``slot_state``: per recurrent layer, its
-        ``((name, per-lane shape, dtype), ...)``; ``num_slots`` lanes each."""
+        ``((name, per-lane shape, dtype), ...)``; ``num_slots`` lanes each.
+        ``latent_width`` > 0: the ``num_layers`` pools are latent ones, ONE
+        row of that many values a token (``num_heads``, ``head_dim``
+        unused): each entry a 1-tuple ``(rows,)``, see
+        :meth:`_fresh_latent`."""
         import jax.numpy as jnp
 
         # mesh-sharded pools (ISSUE 14): every pool entry — primary and
@@ -155,8 +163,13 @@ class KVArena:
         # exist structurally (check_invariants audits the entry shape).
         self.dtype = dtype
         self.quantized = bool(quantized)
+        self.latent_width = int(latent_width)
+        if self.latent_width and (self.quantized or mesh is not None):
+            raise ValueError("a latent pool has no int8 form (quant_kv) "
+                             "and no heads to shard over a mesh")
         self._pools: List[Tuple] = [
-            self._fresh_entry(jnp, num_heads, head_dim)
+            self._fresh_latent(jnp) if self.latent_width
+            else self._fresh_entry(jnp, num_heads, head_dim)
             for _ in range(num_layers)]
         # LIFO: churny workloads keep re-taking the most recently freed
         # blocks (cache-friendly, and makes reuse observable)
@@ -208,6 +221,22 @@ class KVArena:
         from ..distributed.sharding_util import shard_kv_entry
 
         return shard_kv_entry(entry, self.mesh)
+
+    def _fresh_latent(self, jnp) -> Tuple:
+        """One latent layer's zeroed pool entry ``(rows,)``: ``[num_blocks,
+        block_size, W]`` values in the compute dtype, kept as
+        ``[num_blocks, block_size / pack, pack * W]`` (the same bytes in
+        the same order): ``pack`` consecutive tokens share a pool row so
+        that its lanes fill whole tiles on the chip
+        (:func:`paddle_tpu.ops.paged_attention.latent_pack`)."""
+        from ..ops.paged_attention import latent_pack
+
+        pack = latent_pack(self.latent_width)
+        if self.block_size % pack:
+            raise ValueError(f"kv_block_size {self.block_size} does not "
+                             f"hold whole rows of {pack} latent tokens")
+        return (jnp.zeros((self.num_blocks, self.block_size // pack,
+                           pack * self.latent_width), self.dtype),)
 
     @property
     def pools(self) -> List[Tuple]:
@@ -478,6 +507,8 @@ class KVArena:
             else:
                 quantized = self._ns_shapes[name][4]
             want = 4 if quantized else 2
+            if name == "primary" and self.latent_width:
+                want = 1
             for li, entry in enumerate(pools):
                 if len(entry) != want:
                     raise RuntimeError(

@@ -93,8 +93,18 @@ Counter namespaces:
   trace-time counters ``decode_traces`` / ``prefill_traces`` /
   ``verify_traces`` (the kernel twins of the engine's no-recompile
   counters — churn must never re-lower a kernel), plus the gauges
-  ``kernel.paged`` (0/1: the route the decode step was built with) and ``kernel.tuned_entries`` (tuning-store
+  ``kernel.paged`` (0/1: the route the decode step was built with),
+  ``kernel.paged_latent`` (0/1: that route is the latent decode kernel,
+  ``ops.paged_attention.paged_latent_decode``) and ``kernel.tuned_entries`` (tuning-store
   records for this chip — ``ops.tuning`` / benches/TUNED_KERNELS.json)
+
+* ``moe.*``        — the expert layers' load, summed by the decode step
+  program over its expert layers and the lanes that hold a request, and
+  read back behind the step's tokens (``serving_seam.add_step_counters``;
+  no transfer of its own): counters ``assignments`` (token, expert pairs),
+  ``max_expert_assignments`` (the busiest expert's, per layer and step),
+  ``experts_touched`` (experts that got a token, per layer and step) and
+  ``layer_steps`` (expert layers x steps)
 
 * ``state.*``      — the slot-indexed store of recurrent-layer state
   (``kv_arena.KVArena.slot_state``; engines of a model that declares a

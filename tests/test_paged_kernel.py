@@ -911,3 +911,79 @@ def test_mesh_kernel_supervisor_replay_parity():
     finally:
         api.close()
         paddle.set_flags({"fault_injection": keep})
+
+
+# ------------------------------------------------ the latent decode kernel
+
+
+def _latent_gather(q, pool, bt, pos, width, value_dim, scale, active):
+    """The XLA form over the same pool: every lane's table gathered,
+    masked softmax over the rows, the rows' first values weighted."""
+    S, MB = bt.shape
+    rows = pk.latent_rows(pool, width)[bt].reshape(S, -1, width)
+    sc = jnp.einsum("shw,stw->sht", q, rows) * scale
+    live = jnp.arange(rows.shape[1])[None, None] <= pos[:, None, None]
+    p = jax.nn.softmax(jnp.where(live, sc, -1e30), -1)
+    out = jnp.einsum("sht,std->shd", p, rows[..., :value_dim])
+    return out * active[:, None, None]
+
+
+@pytest.mark.parametrize("width,value_dim,heads,pages", [
+    (576, 512, 32, None), (576, 512, 32, 4), (40, 32, 4, 2), (256, 128, 5, 2)],
+    ids=["xing4-default-tile", "xing4-4-pages", "tiny", "unpacked-5-heads"])
+def test_latent_decode_kernel_matches_the_gather(width, value_dim, heads,
+                                                 pages):
+    """GPT-unlike shapes: one row of 576 a token for 32 heads, values its
+    first 512, block 16 (two tokens a pool row of 1152 lanes); a width
+    whose rows are not packed (256); lanes of one token, of a length that
+    ends inside a pool row, at a block boundary and past a tile, and one
+    that is not active. The kernel reads what the gather reads."""
+    rng = np.random.default_rng(3)
+    S, bs, MB, NB = 6, 16, 12, 80
+    pack = pk.latent_pack(width)
+    assert pack == (1 if width % 128 == 0 else 2)
+    pool = jnp.asarray(rng.normal(size=(NB, bs // pack, pack * width)),
+                       jnp.float32)
+    q = jnp.asarray(rng.normal(size=(S, heads, width)), jnp.float32)
+    bt = jnp.asarray(rng.permutation(np.arange(1, NB))[:S * MB]
+                     .reshape(S, MB), jnp.int32)
+    pos = jnp.asarray([0, 16, 100, 191, 64, 15], jnp.int32)
+    active = jnp.asarray([True, True, True, True, False, True])
+    scale = 0.5 / math.sqrt(width)
+    got = pk.paged_latent_decode(q, pool, bt, pos, value_dim, scale,
+                                 active=active, pages=pages)
+    want = _latent_gather(q, pool, bt, pos, width, value_dim, scale, active)
+    assert got.shape == (S, heads, value_dim)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+    assert float(jnp.max(jnp.abs(got[4]))) == 0.0
+
+
+def test_latent_token_write_replaces_one_tokens_lanes():
+    rng = np.random.default_rng(4)
+    width, bs, NB, S = 576, 16, 9, 4
+    pool = jnp.asarray(rng.normal(size=(NB, bs // 2, 2 * width)),
+                       jnp.float32)
+    rows = jnp.asarray(rng.normal(size=(S, width)), jnp.float32)
+    blocks = jnp.asarray([3, 5, 5, 0], jnp.int32)
+    offsets = jnp.asarray([0, 7, 8, 15], jnp.int32)
+    got = pk.latent_rows(pk.write_latent_token(pool, blocks, offsets, rows),
+                         width)
+    want = pk.latent_rows(pool, width).at[blocks, offsets].set(rows)
+    assert float(jnp.max(jnp.abs(got - want))) == 0.0
+
+
+@pytest.mark.parametrize("s", [48, 256, 384])
+def test_latent_prefill_flash_takes_keys_wider_than_values(s):
+    """The flash forward kernel's body with 192-wide keys and 128-wide
+    values against plain causal softmax (one block, two blocks of 128,
+    three)."""
+    rng = np.random.default_rng(5)
+    q, k = (jnp.asarray(rng.normal(size=(s, 4, 192)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(s, 4, 128)), jnp.float32)
+    got = pk.latent_prefill_attention(q, k, v, 0.07, block=128)
+    sc = jnp.einsum("qhd,khd->hqk", q, k) * 0.07
+    sc = jnp.where(jnp.arange(s)[None, :] <= jnp.arange(s)[:, None], sc,
+                   -1e30)
+    want = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(sc, -1), v)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5

@@ -237,3 +237,58 @@ def test_flash_under_a_mesh_compiles(data, model):
 
     text = _compile(loss_and_grads, x, x, x)
     assert text.count("tpu_custom_call") >= 3
+
+
+# ------------------------------------------------- the latent / expert cell
+
+
+def test_latent_decode_compiles_at_the_cells_shapes():
+    """``serve-doc-latent-moe``'s decode shapes: 64 lanes, 32 heads, one
+    row of 576 a token kept two to a pool row (1152 lanes), 640 blocks of
+    16 a lane, 24,576 blocks. The pool reaches the kernel as it lies: no
+    copy of it is in the program."""
+    text = _compile(
+        lambda q, pool, bt, pos, act: pa.paged_latent_decode(
+            q, pool, bt, pos, 512, 0.1, active=act),
+        _sds((64, 32, 576), jnp.bfloat16),
+        _sds((24576, 8, 1152), jnp.bfloat16), _sds((64, 640), jnp.int32),
+        _sds((64,), jnp.int32), _sds((64,), jnp.bool_))
+    assert not re.search(r"bf16\[24576,8,1152\][^\n]* (copy|transpose)\(",
+                         text)
+
+
+def test_a_576_lane_pool_row_is_refused_by_mosaic():
+    """Why the pool packs two tokens a row: Mosaic copies whole 128-lane
+    tiles, and a ``[blocks, 16, 576]`` pool lies 640 wide."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(
+            lambda q, pool, bt, pos: pa._latent_call(
+                q, pool, bt, pos, 576, 512, 0.1, 0),
+            _sds((64, 32, 576), jnp.bfloat16),
+            _sds((24576, 16, 576), jnp.bfloat16), _sds((64, 640), jnp.int32),
+            _sds((64,), jnp.int32))
+
+
+@pytest.mark.parametrize("s", [1024, 10240])
+def test_latent_prefill_flash_compiles(s):
+    _compile(lambda q, k, v: pa.latent_prefill_attention(q, k, v, 0.1),
+             _sds((s, 32, 192), jnp.bfloat16),
+             _sds((s, 32, 192), jnp.bfloat16),
+             _sds((s, 32, 128), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("tokens", [64, 1024, 10240],
+                         ids=["decode-step", "bucket-1024", "bucket-10240"])
+def test_expert_grouped_matmul_compiles(tokens, monkeypatch):
+    """Sort, megablox grouped matmul over 64 stacked experts of width 1024,
+    unsort, at a decode step's 256 assignments and at the smallest and the
+    largest prefill bucket's: each row tile of ``_TILES`` fits VMEM."""
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    _compile(lambda x, idx, w, up, down: gm.expert_ffn(x, idx, w, up, down,
+                                                       64),
+             _sds((tokens, 3584), jnp.bfloat16), _sds((tokens, 4), jnp.int32),
+             _sds((tokens, 4), jnp.float32),
+             _sds((64, 3584, 2048), jnp.bfloat16),
+             _sds((64, 1024, 3584), jnp.bfloat16))
