@@ -228,12 +228,13 @@ def _normalized(l_scr, acc_scr):
 _TILE_BYTES = 1 << 20
 
 
-def _tile_pages(max_blocks, page_bytes, pages=None) -> int:
+def _tile_pages(max_blocks, page_bytes, pages=None,
+                tile_bytes=_TILE_BYTES) -> int:
     """Pages per decode tile: ``pages`` if given (the tuning store, a
-    test), else what fills ``_TILE_BYTES``; a power of two of at least 8
+    test), else what fills ``tile_bytes``; a power of two of at least 8
     (so a tile's rows fill whole lane tiles of the scores at every head
     count) unless the table itself is shorter."""
-    n = int(pages) if pages else max(8, _TILE_BYTES // max(page_bytes, 1))
+    n = int(pages) if pages else max(8, tile_bytes // max(page_bytes, 1))
     n = 1 << (max(n, 1).bit_length() - 1)
     return max(1, min(n, 1 << (max(max_blocks, 1) - 1).bit_length()))
 
@@ -783,11 +784,28 @@ def paged_full_prefill_attention(q, k, v, block_size,
 # ``value_dim`` values are every head's value. The kernel is
 # :func:`_decode_kernel`'s scheme with one pool and no head mask: per lane
 # the live tiles alone, a page copied ONCE and used as keys and as values,
-# all heads in one pair of matmuls per packed position, the softmax state
-# in float32. Packed position ``h`` of a row is reached by a query that is
-# zero outside lanes ``[h W, (h + 1) W)``; its probabilities times the whole
-# row accumulate ``[H, pack * W]``, of which the caller keeps lanes ``[h W,
-# h W + value_dim)``: no lane is ever sliced off a tile's boundary.
+# the softmax state in float32. Packed position ``h`` of a row meets all
+# ``H`` queries in one product over the whole lane tiles that hold its key
+# (:func:`_latent_spans`: lanes ``[0, 640)`` and ``[512, 1152)`` of a
+# 1152-lane row; the query is zero in what those tiles hold of the
+# neighbour), and its probabilities meet the tiles that hold its value
+# (``[0, 512)`` and ``[512, 1152)``); the kernel keeps lanes ``[h W, h W +
+# value_dim)`` of each and writes their sum. A product costs what it
+# pushes through the MXU (its copied rows, cut into 128 x 128 tiles, times
+# the rows that stream against them), so the tiles of the neighbour's lanes
+# are left out: 19 tile passes of ``H`` rows per 128 pool rows where whole
+# rows would take 36 (on the chip the kernel alone, PR 36: 0.42 ms against
+# 0.59 a call of 310k live tokens; stacking both positions' queries into
+# one product of ``2 H`` rows over whole rows halves the passes and gains
+# nothing). No lane is sliced off a tile's boundary before the last step.
+#
+# What the kernel costs beside its products is its page descriptors, on the
+# same instruction stream: 16 ns to start one in straight code, whatever
+# its bytes (a page of 18 KB is 22 ns of HBM), and a branch costs as much
+# again. So the pages of a tile are started in groups of ``_LATENT_GROUP``
+# under one branch a group, and a group is waited for as one. A group with
+# a live page is copied whole; past the lane's last page it copies that
+# page again, so no table entry past a lane's length is ever read.
 
 
 def latent_pack(width: int) -> int:
@@ -819,29 +837,69 @@ def write_latent_token(pool, blocks, offsets, rows):
     return pool.at[blocks, r].set(new)
 
 
-def _latent_kernel(bt_ref, len_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
-                   slot_ref, *, bs, pack, pages, scale):
+#: pages of a tile whose copies start together (one branch, then straight
+#: code) and are waited for as one
+_LATENT_GROUP = 16
+#: a latent tile: 64 pages of 18 KB at block 16 (the per-tile costs, four
+#: small products' fill and drain among them, are paid half as often as at
+#: ``_TILE_BYTES``; twice this again gains 2% and wastes twice the products
+#: on a lane's last tile)
+_LATENT_TILE_BYTES = 2 * _TILE_BYTES
+
+
+def _latent_spans(width, value_dim, pack):
+    """Per packed position ``h`` the whole lane tiles of a pool row that
+    hold its key and its value: ``(lo, hi, hv)`` with the key in lanes
+    ``[lo, hi)`` and the value in ``[lo, hv)`` (576 wide, values 512:
+    ``(0, 640, 512)`` and ``(512, 1152, 1152)``)."""
+    pw = pack * width
+
+    def up(n):
+        return min(-(-n // _LANES) * _LANES, pw)
+
+    return tuple((h * width // _LANES * _LANES, up((h + 1) * width),
+                  up(h * width + value_dim)) for h in range(pack))
+
+
+def _latent_kernel(bt_ref, len_ref, nxt_ref, *refs, bs, pages, group, scale,
+                   width, spans):
+    pack = len(spans)
+    q_refs, (kv_hbm, o_ref, buf, sem, slot_ref) = refs[:pack], refs[pack:]
     s = pl.program_id(0)
     n_lanes = pl.num_programs(0)
     t_tile = pages * bs
     rpb = bs // pack                       # pool rows a block
-    hq, pw = q_ref.shape[2], q_ref.shape[3]
+    hq = o_ref.shape[1]
     cols = pages * rpb
 
-    def each_live_page(lane, j, slot, act):
-        for i in range(pages):
-            page = j * pages + i
-
-            @pl.when(page * bs < len_ref[lane])
+    def each_live_group(lane, j, act):
+        """``act(its first page)`` for every group of pages of tile ``j``
+        that holds a live token."""
+        for g in range(0, pages, group):
+            @pl.when((j * pages + g) * bs < len_ref[lane])
             def _():
-                act(pltpu.make_async_copy(kv_hbm.at[bt_ref[lane, page]],
-                                          buf.at[slot, i], sem.at[slot]))
+                act(g)
 
     def start(lane, j, slot):
-        each_live_page(lane, j, slot, lambda c: c.start())
+        last = (len_ref[lane] - 1) // bs  # the lane's last live page
+
+        def whole(g):
+            # the group a lane ends in is copied whole too: in place of a
+            # page past the lane's last, that last one again (a block of
+            # the lane's own; its columns there are masked)
+            for i in range(g, g + group):
+                page = jnp.minimum(j * pages + i, last)
+                pltpu.make_async_copy(kv_hbm.at[bt_ref[lane, page]],
+                                      buf.at[slot, i], sem.at[slot]).start()
+
+        each_live_group(lane, j, whole)
 
     def wait(lane, j, slot):
-        each_live_page(lane, j, slot, lambda c: c.wait())
+        def whole(g):  # its copies count as one of their bytes together
+            dst = buf.at[slot, pl.ds(g, group)]
+            pltpu.make_async_copy(dst, dst, sem.at[slot]).wait()
+
+        each_live_group(lane, j, whole)
 
     @pl.when(s == 0)
     def _first():
@@ -882,12 +940,17 @@ def _latent_kernel(bt_ref, len_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
                     start(nxt, 0, 1 - slot)
 
             wait(s, j, slot)
-            rows = buf[slot].reshape(cols, pw)
+
+            def rows(lo, hi):
+                return buf[slot, :, :, lo:hi].reshape(cols, hi - lo)
+
             left = length - j * t_tile
             scs = []
-            for h in range(pack):  # the row is the key of `pack` tokens
+            for h, (lo, hi, _) in enumerate(spans):
+                # the row is the key of `pack` tokens: each meets its own
+                # lane tiles of it alone
                 sc = jax.lax.dot_general(
-                    q_ref[0, h], rows, (((1,), (1,)), ((), ())),
+                    q_refs[h][0], rows(lo, hi), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 scs.append(jnp.where(tok0 + h < left, sc, NEG_INF))
             m_new = m_prev
@@ -895,11 +958,12 @@ def _latent_kernel(bt_ref, len_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
                 m_new = jnp.maximum(m_new, jnp.max(sc, axis=1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
             l_new, new_accs = corr * l_prev, []
-            for sc, acc in zip(scs, accs):
+            for sc, acc, (lo, _, hv) in zip(scs, accs, spans):
                 p = jnp.exp(sc - m_new)
                 l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
                 new_accs.append(acc * corr + jax.lax.dot_general(
-                    p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
+                    p.astype(buf.dtype), rows(lo, hv),
+                    (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32))  # and the value
             return m_new, l_new, tuple(new_accs)
 
@@ -908,10 +972,13 @@ def _latent_kernel(bt_ref, len_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
             0, n_tiles, tile,
             (jnp.full((hq, 1), NEG_INF, jnp.float32),
              jnp.zeros((hq, 1), jnp.float32),
-             tuple(jnp.zeros((hq, pw), jnp.float32) for _ in range(pack))))
+             tuple(jnp.zeros((hq, hv - lo), jnp.float32)
+                   for lo, _, hv in spans)))
         slot_ref[0] = (slot0 + n_tiles) % 2
-        for h in range(pack):
-            o_ref[0, h] = accs[h] / l
+        vd = o_ref.shape[2]
+        o = sum(acc[:, h * width - lo:h * width - lo + vd]
+                for h, (acc, (lo, _, _)) in enumerate(zip(accs, spans)))
+        o_ref[0] = (o / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -926,36 +993,40 @@ def _latent_call(q, pool, block_tables, lengths, width, value_dim, scale,
     bs = rpb * pack
     MB = block_tables.shape[1]
     hq = -(-HQ // 8) * 8  # query rows: whole f32 sublane tiles
-    pages = _tile_pages(MB, rpb * pw * pool.dtype.itemsize, pages)
+    pages = _tile_pages(MB, rpb * pw * pool.dtype.itemsize, pages,
+                        _LATENT_TILE_BYTES)
     lengths = lengths.astype(jnp.int32)
     nxt = _next_live_lane(lengths)
-    # the query of packed position h: zero outside lanes [h W, (h + 1) W)
-    qs = jnp.stack([jnp.pad(q, ((0, 0), (0, hq - HQ),
-                                (h * width, (pack - 1 - h) * width)))
-                    for h in range(pack)], axis=1).astype(pool.dtype)
-    spec = pl.BlockSpec((1, pack, hq, pw), lambda s, *_: (s, 0, 0, 0))
+    spans = _latent_spans(width, value_dim, pack)
+    # the query of packed position h, in the lane tiles that hold its key:
+    # zero outside the key's own lanes
+    qs = [jnp.pad(q, ((0, 0), (0, hq - HQ),
+                      (h * width - lo, hi - (h + 1) * width))
+                  ).astype(pool.dtype) for h, (lo, hi, _) in enumerate(spans)]
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, bs=bs, pack=pack, pages=pages,
-                          scale=scale),
+        functools.partial(_latent_kernel, bs=bs, pages=pages,
+                          group=min(_LATENT_GROUP, pages), scale=scale,
+                          width=width, spans=spans),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(S,),
-            in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=spec,
+            in_specs=[pl.BlockSpec((1, hq, hi - lo), lambda s, *_: (s, 0, 0))
+                      for lo, hi, _ in spans]
+            + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, hq, value_dim),
+                                   lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, pages, rpb, pw), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((1,), jnp.int32),  # buffer of the next tile
             ]),
-        out_shape=jax.ShapeDtypeStruct((S, pack, hq, pw), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, hq, value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="paged_latent_decode",
-    )(block_tables, lengths, nxt, qs, pool)
-    o = sum(out[:, h, :HQ, h * width:h * width + value_dim]
-            for h in range(pack))
-    return o.astype(q.dtype)
+    )(block_tables, lengths, nxt, *qs, pool)
+    return out[:, :HQ]
 
 
 def paged_latent_decode(q, pool, block_tables, positions, value_dim: int,
@@ -967,10 +1038,13 @@ def paged_latent_decode(q, pool, block_tables, positions, value_dim: int,
     of ``W`` values; lane ``s`` attends the rows at positions ``<=
     positions[s]`` of its table: scores ``q . row * scale``, output the
     probabilities' sum of the rows' first ``value_dim`` values, ``[S, H,
-    value_dim]`` in ``q.dtype``. A lane that is not ``active`` reads no page
-    and returns zeros. Tables, positions and ``active`` are runtime data.
-    ``pages`` (pages a tile) is a launch parameter; None: what fills
-    ``_TILE_BYTES``."""
+    value_dim]`` in ``q.dtype`` (the packed positions' parts are summed in
+    the kernel, in float32). Each packed position of a copied page meets
+    the ``H`` queries in one product over its own lane tiles, and its
+    probabilities the tiles of its value (see "latent" above). A lane that
+    is not ``active`` reads no page and returns zeros. Tables, positions
+    and ``active`` are runtime data. ``pages`` (pages a tile) is a launch
+    parameter; None: what fills ``_LATENT_TILE_BYTES``."""
     lengths = positions.astype(jnp.int32) + 1
     if active is not None:
         lengths = jnp.where(active, lengths, 0)

@@ -928,33 +928,62 @@ def _latent_gather(q, pool, bt, pos, width, value_dim, scale, active):
     return out * active[:, None, None]
 
 
-@pytest.mark.parametrize("width,value_dim,heads,pages", [
-    (576, 512, 32, None), (576, 512, 32, 4), (40, 32, 4, 2), (256, 128, 5, 2)],
-    ids=["xing4-default-tile", "xing4-4-pages", "tiny", "unpacked-5-heads"])
+_LATENT_POS = [0, 16, 100, 191, 64, 15]
+
+
+@pytest.mark.parametrize("width,value_dim,heads,pages,pos,dtype,tol", [
+    (576, 512, 32, None, _LATENT_POS, jnp.float32, 1e-5),
+    (576, 512, 32, 4, _LATENT_POS, jnp.float32, 1e-5),
+    (40, 32, 4, 2, _LATENT_POS, jnp.float32, 1e-5),
+    (256, 128, 5, 2, _LATENT_POS, jnp.float32, 1e-5),
+    (576, 512, 32, 2, [2, 32, 78, 190, 64, 14], jnp.float32, 1e-5),
+    (576, 512, 32, 4, [64, 128, 65, 63, 64, 129], jnp.float32, 1e-5),
+    (256, 128, 5, 4, [64, 128, 65, 63, 64, 129], jnp.float32, 1e-5),
+    (576, 512, 32, None, _LATENT_POS, jnp.bfloat16, 2e-2),
+    (576, 512, 32, 2, [2, 33, 78, 191, 64, 14], jnp.bfloat16, 2e-2)],
+    ids=["xing4-default-tile", "xing4-4-pages", "tiny", "unpacked-5-heads",
+         "odd-lengths-end-on-position-0", "one-tile-plus-one-token",
+         "unpacked-one-tile-plus-one-token", "bf16-32-heads",
+         "bf16-32-heads-short-tiles"])
 def test_latent_decode_kernel_matches_the_gather(width, value_dim, heads,
-                                                 pages):
+                                                 pages, pos, dtype, tol):
     """GPT-unlike shapes: one row of 576 a token for 32 heads, values its
     first 512, block 16 (two tokens a pool row of 1152 lanes); a width
     whose rows are not packed (256); lanes of one token, of a length that
     ends inside a pool row, at a block boundary and past a tile, and one
-    that is not active. The kernel reads what the gather reads."""
+    that is not active. What the stacked softmax could get wrong: lengths
+    that are odd, so the last pool row's packed position 1 is masked while
+    its position 0 is live (in the last row of a block, of a tile, and
+    the very first row); lanes of exactly one tile plus one token, whose
+    last tile holds one live column of one row block and none of the
+    other, next to a lane of one tile less a token and one of exactly a
+    tile; and the cell's own precision, a bf16 pool and bf16 queries of
+    32 heads held to the float32 gather over the same values. The kernel
+    reads what the gather reads, and no block that a table names past a
+    lane's length."""
     rng = np.random.default_rng(3)
     S, bs, MB, NB = 6, 16, 12, 80
     pack = pk.latent_pack(width)
     assert pack == (1 if width % 128 == 0 else 2)
     pool = jnp.asarray(rng.normal(size=(NB, bs // pack, pack * width)),
-                       jnp.float32)
-    q = jnp.asarray(rng.normal(size=(S, heads, width)), jnp.float32)
+                       jnp.float32).astype(dtype)
+    q = jnp.asarray(rng.normal(size=(S, heads, width)),
+                    jnp.float32).astype(dtype)
     bt = jnp.asarray(rng.permutation(np.arange(1, NB))[:S * MB]
                      .reshape(S, MB), jnp.int32)
-    pos = jnp.asarray([0, 16, 100, 191, 64, 15], jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
     active = jnp.asarray([True, True, True, True, False, True])
     scale = 0.5 / math.sqrt(width)
-    got = pk.paged_latent_decode(q, pool, bt, pos, value_dim, scale,
-                                 active=active, pages=pages)
-    want = _latent_gather(q, pool, bt, pos, width, value_dim, scale, active)
-    assert got.shape == (S, heads, value_dim)
-    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+    want = _latent_gather(q.astype(jnp.float32), pool.astype(jnp.float32),
+                          bt, pos, width, value_dim, scale, active)
+    # the kernel alone sees what a table holds past a lane's length (the
+    # scratch block 0, and in it what must never be read)
+    dead = jnp.arange(MB)[None, :] * bs > pos[:, None]
+    got = pk.paged_latent_decode(q, pool.at[0].set(jnp.nan),
+                                 jnp.where(dead, 0, bt), pos, value_dim,
+                                 scale, active=active, pages=pages)
+    assert got.shape == (S, heads, value_dim) and got.dtype == dtype
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < tol
     assert float(jnp.max(jnp.abs(got[4]))) == 0.0
 
 

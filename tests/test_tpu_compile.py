@@ -246,7 +246,10 @@ def test_latent_decode_compiles_at_the_cells_shapes():
     """``serve-doc-latent-moe``'s decode shapes: 64 lanes, 32 heads, one
     row of 576 a token kept two to a pool row (1152 lanes), 640 blocks of
     16 a lane, 24,576 blocks. The pool reaches the kernel as it lies: no
-    copy of it is in the program."""
+    copy of it is in the program. What leaves the kernel is the combined
+    ``[lanes, heads, 512]`` in the queries' dtype (the two packed
+    positions' parts, the second from lane 64 of its tiles, are summed
+    inside): no float32 ``[64, 2, 32, 1152]`` for XLA to slice and add."""
     text = _compile(
         lambda q, pool, bt, pos, act: pa.paged_latent_decode(
             q, pool, bt, pos, 512, 0.1, active=act),
@@ -255,6 +258,8 @@ def test_latent_decode_compiles_at_the_cells_shapes():
         _sds((64,), jnp.int32), _sds((64,), jnp.bool_))
     assert not re.search(r"bf16\[24576,8,1152\][^\n]* (copy|transpose)\(",
                          text)
+    assert re.search(r"= bf16\[64,32,512\][^\n]* custom-call\(", text)
+    assert "f32[64,2,32,1152]" not in text
 
 
 def test_a_576_lane_pool_row_is_refused_by_mosaic():
