@@ -124,9 +124,10 @@ reproducing the plain engine exactly.
 """
 from __future__ import annotations
 
+import time
 import warnings
 from collections import deque
-from contextlib import nullcontext as _null_ctx
+from contextlib import contextmanager, nullcontext as _null_ctx
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -828,13 +829,16 @@ class ServingConfig:
 
 class _StepInFlight(NamedTuple):
     """A dispatched decode step whose tokens the host has not read: the
-    device array they will be in, the lanes the step ran, and each slot's
+    device array they will be in, the lanes the step ran, each slot's
     tenancy count when it was dispatched (a lane retired since, whoever
-    holds the slot now, no longer owns what this step computed for it)."""
+    holds the slot now, no longer owns what this step computed for it),
+    and the engine's count of compiled calls once this one was made (the
+    step is the newest program on the device while they are equal)."""
 
     tokens: jax.Array
     lanes: np.ndarray
     tenancy: np.ndarray
+    seq: int
 
 
 @dataclass
@@ -1126,8 +1130,17 @@ class ServingEngine:
         # last token, active mask, sampling params, adapter ids: one packed
         # array, see _pack_slot_state). The step hands the next step's
         # state back, so the host mirrors above and below are uploaded only
-        # after a host write: None = stale, set through _touch_slot_state
+        # after a host write: None = stale, set through _touch_slot_state,
+        # which also keeps who wrote last (engine.restarts.<why>)
         self._state_dev = None
+        self._stale_why = "admit"
+        # compiled calls made (_call). When a blocking read of the newest
+        # one has returned, nothing this engine dispatched is unfinished:
+        # _empty is the open `device.empty` phase from then to the next
+        # _call; _idle says the scheduler ran out of work meanwhile
+        self._dispatched = 0
+        self._empty: Optional[telemetry.phase] = None
+        self._idle = False
         # decode steps dispatched and not yet read, oldest first (see
         # decode_dispatch / decode_collect); _tenancy counts each slot's
         # retirements, so a step read after a lane changed hands neither
@@ -1212,6 +1225,7 @@ class ServingEngine:
         # never inside them — see docs/observability.md "Overhead policy"
         self.hists = telemetry.HistogramSet()
         self._trace_ctx = ""  # the in-flight admission's trace id
+        self._prefill_ph: Optional[telemetry.phase] = None  # and its phase
         metrics.set_gauge("slots.total", s)
         # mesh/axis gauges (ISSUE 14): the live topology next to the mode
         # gauges — tools/serving_stats.py --run reports them per run
@@ -1794,10 +1808,20 @@ class ServingEngine:
                           if self.donate else jax.jit(step))
         return self._step_jit
 
-    def _call(self, fn, *args, name: str):
+    def _call(self, fn, *args, name: str, cause: str = "admit"):
         """Dispatch one compiled call. Donation makes a failed call
         non-retryable (its buffers may already be consumed), so the retry
-        policy only wraps the copying build."""
+        policy only wraps the copying build. ``cause`` is what this call
+        is, for the account of the empty device it may end
+        (``device.empty``): ``admit`` (a prefill and what it needs: the
+        copy of a shared block, a tier restore), ``restart`` or ``sync``
+        (a decode step, :meth:`decode_dispatch`)."""
+        self._dispatched += 1
+        empty, self._empty = self._empty, None
+        if empty is not None:
+            empty.stop("idle" if self._idle else cause)
+        self._idle = False
+
         def attempt(*a):
             # the fault probes sit inside the retried callable so injected
             # transient failures exercise the same recovery path real ones
@@ -1819,7 +1843,45 @@ class ServingEngine:
                                                   policy=self._retry)
             return attempt(*args)
 
+    def _device_drained(self, seq: int) -> None:
+        """A blocking read of compiled call number ``seq`` has returned.
+        If that call is the newest, nothing this engine dispatched is
+        unfinished: ``device.empty`` begins, and the next :meth:`_call`
+        ends it with its cause. The host learns of a program's end a
+        millisecond or two after the device (docs/observability.md), so
+        the account is a lower bound."""
+        if seq == self._dispatched and self._empty is None:
+            self._empty = telemetry.phase("device.empty", self.hists).begin()
+
+    def note_idle(self) -> None:
+        """The scheduler has neither running nor waiting work: whatever
+        the device stands empty for until the next compiled call is
+        nobody's delay (``time_us.device.empty.idle``, kept out of every
+        share). A step dropped unread may still be running, so here the
+        phase may begin up to a step early."""
+        self._idle = True
+        self._device_drained(self._dispatched)
+
     # ----------------------------------------------------- slot lifecycle
+
+    @contextmanager
+    def _admission(self, trace_id: str):
+        """The ``prefill`` phase around one admission or one chunk, and
+        the lane-time it takes from the requests that could have decoded
+        meanwhile: its elapsed microseconds times the lanes active when it
+        began (``prefill.lane_us_blocked``)."""
+        self._trace_ctx = trace_id
+        lanes = int(self._active.sum())
+        t0 = time.perf_counter()
+        with telemetry.phase("prefill", self.hists,
+                             trace_id=trace_id) as ph:
+            self._prefill_ph = ph
+            try:
+                yield
+            finally:
+                self._prefill_ph = None
+                metrics.bump("prefill.lane_us_blocked",
+                             round((time.perf_counter() - t0) * 1e6) * lanes)
 
     def admit(self, prompt: np.ndarray, max_new_tokens: int,
               tokens=None, sampling=None, adapter: int = 0,
@@ -1847,11 +1909,11 @@ class ServingEngine:
         same values and resumes bit-identically.
 
         Raises if no capacity; callers gate on :meth:`can_admit`."""
-        self._trace_ctx = trace_id
-        with telemetry.phase("prefill", self.hists, trace_id=trace_id):
-            st = self._admit_setup(prompt, max_new_tokens, tokens,
-                                   sampling=sampling, adapter=adapter,
-                                   mask=mask, spec_exclude=spec_exclude)
+        with self._admission(trace_id):
+            with telemetry.phase("prefill.setup", self.hists):
+                st = self._admit_setup(prompt, max_new_tokens, tokens,
+                                       sampling=sampling, adapter=adapter,
+                                       mask=mask, spec_exclude=spec_exclude)
             return st.slot, self._admit_prefill_all(st)
 
     def admit_begin(self, prompt: np.ndarray, max_new_tokens: int,
@@ -1866,21 +1928,22 @@ class ServingEngine:
         the first token appears. The slot is *occupied* (its blocks are
         held) but not *active* (its lane stays masked out of the decode
         step), so running streams keep decoding between chunks."""
-        self._trace_ctx = trace_id
-        with telemetry.phase("prefill", self.hists,
-                             trace_id=trace_id) as ph:
-            st = self._admit_setup(prompt, max_new_tokens, tokens,
-                                   sampling=sampling, adapter=adapter,
-                                   mask=mask, spec_exclude=spec_exclude)
+        with self._admission(trace_id):
+            with telemetry.phase("prefill.setup", self.hists):
+                st = self._admit_setup(prompt, max_new_tokens, tokens,
+                                       sampling=sampling, adapter=adapter,
+                                       mask=mask, spec_exclude=spec_exclude)
             chunk = self.chunk_size
             if chunk <= 0 or st.clen - st.prefix_len <= chunk:
                 return st.slot, self._admit_prefill_all(st)
-            ph.discard()  # the claim alone: admit_chunk times each chunk
-        st.trace_id = trace_id  # admit_chunk restores the trace context
-        st.done = st.prefix_len
-        self._chunk[st.slot] = st
-        metrics.bump("chunk.admits")
-        self._refresh_gauges()
+            # the claim alone (a `prefill` sample that holds its setup and
+            # no compiled call): admit_chunk times each chunk
+            with telemetry.phase("prefill.finish", self.hists):
+                st.trace_id = trace_id  # admit_chunk restores the context
+                st.done = st.prefix_len
+                self._chunk[st.slot] = st
+                metrics.bump("chunk.admits")
+                self._refresh_gauges()
         return st.slot, None
 
     def admit_chunk(self, slot: int) -> Optional[int]:
@@ -1894,45 +1957,50 @@ class ServingEngine:
         if st is None:
             raise RuntimeError(f"slot {slot} has no chunked prefill "
                                "in progress")
-        self._trace_ctx = st.trace_id
-        with telemetry.phase("prefill", self.hists, trace_id=st.trace_id):
+        with self._admission(st.trace_id):
             take = min(self.chunk_size, st.clen - st.done)
             try:
                 nxt, new_pools = self._suffix_prefill_call(
                     st.ctx, st.done + take, st.done, slot, chunked=True)
-                self.arena.set_pools(new_pools)
-                st.done += take
-                metrics.bump("chunk.chunks")
-                metrics.bump("chunk.tokens", take)
-                # incremental publish (FLAGS_serving_publish_chunks):
-                # every prompt block this chunk finished scattering becomes
-                # a radix node NOW — and, via the insert path's
-                # write_through (+ FLAGS_serving_tier_publish), tier/disk-
-                # resident — so a disagg prefill worker's partial chain is
-                # restorable the moment it exists. insert() is idempotent
-                # over the already-inserted prefix (resident nodes are
-                # skipped), and the new nodes' blocks are marked cached, so
-                # even an abort of the remaining chunks leaves them valid
-                # (cached blocks survive the reservation release).
-                if (self.prefix_cache is not None
-                        and flags.flag("serving_publish_chunks")):
-                    full = min(st.done, st.plen) // self.block_size
-                    if full > 0:
-                        self.prefix_cache.insert(
-                            st.prompt, self._bt_host[slot], full)
+                seq = self._dispatched
+                with telemetry.phase("prefill.finish", self.hists):
+                    self.arena.set_pools(new_pools)
+                    st.done += take
+                    metrics.bump("chunk.chunks")
+                    metrics.bump("chunk.tokens", take)
+                    # incremental publish (FLAGS_serving_publish_chunks):
+                    # every prompt block this chunk finished scattering
+                    # becomes a radix node NOW — and, via the insert
+                    # path's write_through (+ FLAGS_serving_tier_publish),
+                    # tier/disk-resident — so a disagg prefill worker's
+                    # partial chain is restorable the moment it exists.
+                    # insert() is idempotent over the already-inserted
+                    # prefix (resident nodes are skipped), and the new
+                    # nodes' blocks are marked cached, so even an abort of
+                    # the remaining chunks leaves them valid (cached
+                    # blocks survive the reservation release).
+                    if (self.prefix_cache is not None
+                            and flags.flag("serving_publish_chunks")):
+                        full = min(st.done, st.plen) // self.block_size
+                        if full > 0:
+                            self.prefix_cache.insert(
+                                st.prompt, self._bt_host[slot], full)
                 if (st.done >= st.clen and self.spec is not None
                         and not st.skip_draft):
-                    self.spec.prefill(slot, st.ctx)
+                    with telemetry.phase("prefill.draft", self.hists):
+                        self.spec.prefill(slot, st.ctx)
             # analysis: allow(broad-except) — cleanup-and-reraise: a
             # failed chunk must not leak the admission's blocks/refs/slot
             except Exception:
                 self._chunk.pop(slot, None)
                 self._admit_abort(st)
                 raise
-        if st.done < st.clen:
-            return None
-        self._chunk.pop(slot, None)
-        return self._admit_finish(st, int(nxt))
+            if st.done < st.clen:
+                # nothing waits for a chunk that yields no token: the
+                # decode steps between the chunks queue behind it
+                return None
+            self._chunk.pop(slot, None)
+            return self._admit_first_token(st, nxt, seq)
 
     def _admit_setup(self, prompt: np.ndarray, max_new_tokens: int,
                      tokens, sampling=None, adapter: int = 0,
@@ -2092,7 +2160,7 @@ class ServingEngine:
         self._seed[slot] = 0 if sp is None else int(sp.seed)
         self._sampled[slot] = not greedy
         self._adapter[slot] = adapter
-        self._touch_slot_state()
+        self._touch_slot_state("slot_update")
         if mask is not None:
             row = np.asarray(mask, bool).reshape(-1)
             if row.shape[0] != self.vocab:
@@ -2126,7 +2194,7 @@ class ServingEngine:
         self._sampled[slot] = False
         self._adapter[slot] = 0
         self._scenario_once[slot] = False
-        self._touch_slot_state()
+        self._touch_slot_state("slot_update")
         if self._constrained[slot]:
             self._mask_host[slot, :] = True
             self._constrained[slot] = False
@@ -2204,19 +2272,35 @@ class ServingEngine:
             if st.n_attached or st.cow:
                 nxt, new_pools = self._suffix_prefill_call(
                     st.ctx, st.clen, st.prefix_len, st.slot)
+                new_rec = None
             else:
                 nxt, new_pools, new_rec = self._full_prefill_call(
                     st.ctx, st.clen, st.res, st.slot)
-                self.arena.set_slot_state(new_rec)
-            self.arena.set_pools(new_pools)
+            seq = self._dispatched
+            with telemetry.phase("prefill.finish", self.hists):
+                if new_rec is not None:
+                    self.arena.set_slot_state(new_rec)
+                self.arena.set_pools(new_pools)
             if self.spec is not None and not st.skip_draft:
-                self.spec.prefill(st.slot, st.ctx)
+                with telemetry.phase("prefill.draft", self.hists):
+                    self.spec.prefill(st.slot, st.ctx)
         # analysis: allow(broad-except) — cleanup-and-reraise: a failed
         # prefill must not leak the admission's blocks/refs/slot
         except Exception:
             self._admit_abort(st)
             raise
-        return self._admit_finish(st, int(nxt))
+        return self._admit_first_token(st, nxt, seq)
+
+    def _admit_first_token(self, st: _AdmitState, nxt, seq: int) -> int:
+        """The end of an admission: wait for the prefill's token
+        (``prefill.wait``: the device runs the step that was in flight
+        ahead of the prefill, then the prefill), then activate the slot
+        (``prefill.finish``). ``seq`` numbers the prefill call."""
+        with telemetry.phase("prefill.wait", self.hists):
+            first = int(nxt)
+        self._device_drained(seq)
+        with telemetry.phase("prefill.finish", self.hists):
+            return self._admit_finish(st, first)
 
     def _admit_finish(self, st: _AdmitState, first: int) -> int:
         """Activate the slot: the whole context is scattered and its next
@@ -2238,7 +2322,7 @@ class ServingEngine:
         self._last_tok[slot] = first
         self._slot_limit[slot] = st.plen + st.max_new
         self._active[slot] = True
-        self._touch_slot_state()
+        self._touch_slot_state("admit")
         metrics.bump("engine.admits")
         if self.recurrent:
             # the prefill started the lane from zeros (whatever its last
@@ -2263,19 +2347,40 @@ class ServingEngine:
         under the slot's params at that positional key."""
         import jax.numpy as jnp
 
-        p_bucket = compile_cache.prefill_bucket(
-            clen, self.max_model_len, self.prefill_bucket_min)
-        ids = np.zeros((1, p_bucket), np.int32)
-        ids[0, :clen] = ctx
-        mbp = _ceil_div(p_bucket, self.block_size)
-        rows = np.zeros(mbp, np.int32)
-        rows[:len(res.taken)] = res.taken
-        fn = self._get_prefill(p_bucket)
-        return self._call(
-            fn, self._arrays, jnp.asarray(ids), jnp.int32(clen),
-            self.arena.pools, jnp.asarray(rows), self._samp_row(slot, clen),
-            self.arena.slot_state, jnp.int32(slot),
-            *self._lora_args(slot), name="serving.prefill")
+        with telemetry.phase("prefill.upload", self.hists):
+            p_bucket = compile_cache.prefill_bucket(
+                clen, self.max_model_len, self.prefill_bucket_min)
+            ids = np.zeros((1, p_bucket), np.int32)
+            ids[0, :clen] = ctx
+            mbp = _ceil_div(p_bucket, self.block_size)
+            rows = np.zeros(mbp, np.int32)
+            rows[:len(res.taken)] = res.taken
+            up = (jnp.asarray(ids), jnp.int32(clen), jnp.asarray(rows),
+                  self._samp_row(slot, clen), jnp.int32(slot))
+            lora = self._lora_args(slot)
+            self._count_prefill_call(p_bucket, clen, up, lora[1:])
+        with telemetry.phase("prefill.dispatch", self.hists):
+            fn = self._get_prefill(p_bucket)
+            out = self._call(
+                fn, self._arrays, up[0], up[1], self.arena.pools, up[2],
+                up[3], self.arena.slot_state, up[4], *lora,
+                name="serving.prefill")
+            # the uploaded arrays die inside the phase, not between two
+            # (a device array's destructor gives the GIL away)
+            del up, lora
+            return out
+
+    def _count_prefill_call(self, bucket: int, tokens: int, *sent) -> None:
+        """One compiled prefill call: the positions it computes (its
+        bucket; the real ones are ``tokens.prefill``), the bytes of the
+        arrays made for it on the device (``sent``), and both lengths as
+        arguments of the ``pt.prefill`` interval."""
+        metrics.bump("prefill.calls")
+        metrics.bump("prefill.positions_computed", bucket)
+        metrics.bump("prefill.upload_bytes",
+                     sum(a.nbytes for a in jax.tree_util.tree_leaves(sent)))
+        if self._prefill_ph is not None:
+            self._prefill_ph.note(bucket=bucket, tokens=tokens)
 
     def _suffix_prefill_call(self, ctx: np.ndarray, clen: int,
                              prefix_len: int, slot: int,
@@ -2287,24 +2392,32 @@ class ServingEngine:
         (already filled) block table, never recomputed."""
         import jax.numpy as jnp
 
-        slen = clen - prefix_len
-        s_bucket = compile_cache.prefill_bucket(
-            slen, self.max_model_len, self.prefill_bucket_min)
-        ids = np.zeros((1, s_bucket), np.int32)
-        ids[0, :slen] = ctx[prefix_len:clen]
-        fn = self._get_prefix_prefill(s_bucket)
-        if not chunked:
-            metrics.bump("prefix.suffix_prefills")
-        # the emitted token sits at context index `clen`; only the FINAL
-        # chunk of a chunked admission consumes it, where clen == the
-        # full context length — the same positional key either way
-        return self._call(
-            fn, self._arrays, jnp.asarray(ids), jnp.int32(slen),
-            jnp.int32(prefix_len), self.arena.pools,
-            jnp.asarray(self._bt_host[slot]), self._samp_row(slot, clen),
-            *self._lora_args(slot), name="serving.prefix_prefill")
+        with telemetry.phase("prefill.upload", self.hists):
+            slen = clen - prefix_len
+            s_bucket = compile_cache.prefill_bucket(
+                slen, self.max_model_len, self.prefill_bucket_min)
+            ids = np.zeros((1, s_bucket), np.int32)
+            ids[0, :slen] = ctx[prefix_len:clen]
+            # the emitted token sits at context index `clen`; only the
+            # FINAL chunk of a chunked admission consumes it, where clen
+            # == the full context length — the same positional key either
+            # way
+            up = (jnp.asarray(ids), jnp.int32(slen), jnp.int32(prefix_len),
+                  jnp.asarray(self._bt_host[slot]),
+                  self._samp_row(slot, clen))
+            lora = self._lora_args(slot)
+            self._count_prefill_call(s_bucket, slen, up, lora[1:])
+        with telemetry.phase("prefill.dispatch", self.hists):
+            fn = self._get_prefix_prefill(s_bucket)
+            if not chunked:
+                metrics.bump("prefix.suffix_prefills")
+            out = self._call(
+                fn, self._arrays, up[0], up[1], up[2], self.arena.pools,
+                up[3], up[4], *lora, name="serving.prefix_prefill")
+            del up, lora  # as in _full_prefill_call
+            return out
 
-    def retire(self, slot: int) -> None:
+    def retire(self, slot: int, why: str = "retire") -> None:
         """Free a slot: deactivate its lane, drop its shared-prefix
         references (refcount--; a shared block returns to the free list
         only when the last sharer lets go — or stays resident if the radix
@@ -2312,7 +2425,8 @@ class ServingEngine:
         included) through the same refcount layer. Also covers a slot
         mid-chunked-prefill (occupied but not yet active) — a cancelled
         long admission frees everything it claimed. Purely host-side
-        state — never recompiles."""
+        state — never recompiles. ``why`` (``retire``, or ``preempt`` from
+        the scheduler) names the writer of the slot state."""
         if not self._occupied[slot]:
             return
         self._occupied[slot] = False
@@ -2334,7 +2448,8 @@ class ServingEngine:
         self._last_tok[slot] = 0
         self._slot_limit[slot] = 0
         self._tenancy[slot] += 1  # a step in flight no longer owns the lane
-        self._clear_slot_scenario(slot)  # marks the device's copy stale
+        self._clear_slot_scenario(slot)
+        self._touch_slot_state(why)
         metrics.bump("engine.retires")
         if flags.flag("serving_arena_invariants"):
             self.check_invariants()
@@ -2394,7 +2509,7 @@ class ServingEngine:
                     self.prefix_cache.bind_index(old._index, old._replica)
         self._bt_host[:] = 0
         self._bt_dev = None
-        self._touch_slot_state()
+        self._touch_slot_state("error")
         # a step in flight died with the old arena: its tokens were never
         # emitted, so the journals replay it
         self.decode_drop()
@@ -2454,12 +2569,16 @@ class ServingEngine:
         with telemetry.phase("spec_step", self.hists):
             return self.spec.step()
 
-    def _touch_slot_state(self) -> None:
+    def _touch_slot_state(self, why: str) -> None:
         """A host write to any per-slot vector the decode step carries
         (positions, last token, active mask, sampling params, adapter
         ids): the device copy is stale and the next step re-sends the
-        mirrors, all in one upload."""
+        mirrors, all in one upload. ``why`` names the writer (``admit``,
+        ``retire``, ``preempt``, ``slot_update``, ``override``,
+        ``error``); the dispatch that restarts from the mirrors counts
+        the last one (``engine.restarts.<why>``)."""
         self._state_dev = None
+        self._stale_why = why
 
     def _pack_slot_state(self, act) -> np.ndarray:
         """The host mirrors as the decode step's one ``[8, S]`` int32
@@ -2487,15 +2606,24 @@ class ServingEngine:
         if self._mask_dev is None:
             self._mask_dev = jnp.asarray(self._mask_host)
             self._mask_dirty.clear()
-            metrics.bump("engine.step_uploads")
+            self._count_step_upload(self._mask_host)
         elif self._mask_dirty:
             rows = np.fromiter(self._mask_dirty, np.int32,
                                len(self._mask_dirty))
+            new = self._mask_host[rows]
             self._mask_dev = self._mask_dev.at[jnp.asarray(rows)].set(
-                jnp.asarray(self._mask_host[rows]))
+                jnp.asarray(new))
             self._mask_dirty.clear()
-            metrics.bump("engine.step_uploads", 2)  # row ids and rows
+            self._count_step_upload(rows, new)  # row ids and rows
         return self._mask_dev
+
+    @staticmethod
+    def _count_step_upload(*sent: np.ndarray) -> None:
+        """The transfers a decode step's preparation made, and their
+        bytes."""
+        metrics.bump("engine.step_uploads", len(sent))
+        metrics.bump("engine.step_upload_bytes",
+                     sum(a.nbytes for a in sent))
 
     def _samp_row(self, slot: int, pos: int):
         """One slot's sampling pytree for a prefill call ([1] shapes;
@@ -2528,12 +2656,12 @@ class ServingEngine:
         the host changed since the last step is sent again (the block
         table after a lane grew, the packed slot state after a host
         write, stale mask rows); ``engine.step_uploads`` counts the
-        transfers."""
+        transfers and ``engine.step_upload_bytes`` their bytes."""
         import jax.numpy as jnp
 
         if self._bt_dev is None:
             self._bt_dev = jnp.asarray(self._bt_host)
-            metrics.bump("engine.step_uploads")
+            self._count_step_upload(self._bt_host)
         if self._state_dev is None:
             # placed as the step hands it back (replicated on a mesh,
             # uncommitted without one), so that an uploaded and a carried
@@ -2545,7 +2673,7 @@ class ServingEngine:
                 from ..distributed.sharding_util import replicate
 
                 self._state_dev = replicate(state, mesh=self.mesh)
-            metrics.bump("engine.step_uploads")
+            self._count_step_upload(state)
         lora = () if self.lora is None else (self.lora.device_pools(),)
         return (self._arrays, self.arena.pools, self._bt_dev,
                 self._state_dev, self._mask_arg(), self.arena.slot_state,
@@ -2575,25 +2703,35 @@ class ServingEngine:
         if active is not None:
             # the device's copy holds the engine's own mask: this step
             # sends the mirrors with the caller's, the next one again
-            self._touch_slot_state()
-        if self._state_dev is None and self._flight:
-            raise RuntimeError(
-                "decode_dispatch after a host write with a step in flight: "
-                "the mirrors lack its tokens; collect it first")
+            self._touch_slot_state("override")
+        restart = self._state_dev is None
+        if restart:
+            if self._flight:
+                raise RuntimeError(
+                    "decode_dispatch after a host write with a step in "
+                    "flight: the mirrors lack its tokens; collect it first")
+            metrics.bump("engine.restarts." + self._stale_why)
         with telemetry.phase("decode.prepare", hists):
             # grow block tables whose write position crossed a block
             # boundary, then whatever the host changed since the last
             # step goes to the device
-            for slot in np.flatnonzero(act):
-                self._grow_slot_to(slot, int(self._positions[slot]))
-            args = self._step_args(act)
+            with telemetry.phase("decode.prepare.grow", hists):
+                for slot in np.flatnonzero(act):
+                    self._grow_slot_to(slot, int(self._positions[slot]))
+            with telemetry.phase("decode.prepare.upload", hists):
+                args = self._step_args(act)
             # stale until this step is dispatched: a call that raises
             # produced no next state (and may have consumed the donated
-            # pools), so the step after it starts from the mirrors
-            self._touch_slot_state()
+            # pools), so the step after it starts from the mirrors. No
+            # writer: the reason found stays
+            self._state_dev = None
         with telemetry.phase("decode.dispatch", hists):
+            # if the device stood empty, this step ends that: a restart
+            # where the pump would have run ahead but for a host write, a
+            # turn that is synchronous by design otherwise
             nxt, new_pools, new_rec, state = self._call(
-                self._get_step(), *args, name="serving.step")
+                self._get_step(), *args, name="serving.step",
+                cause="restart" if restart and self._ahead else "sync")
         with telemetry.phase("decode.wait", hists):
             with telemetry.phase("decode.release", hists):
                 # the step's argument arrays and the donated pools must
@@ -2611,7 +2749,8 @@ class ServingEngine:
             # the next step may be dispatched from it before they are
             self._state_dev = state
         self._positions[act] += 1
-        step = _StepInFlight(nxt, act, self._tenancy.copy())
+        step = _StepInFlight(nxt, act, self._tenancy.copy(),
+                             self._dispatched)
         self._flight.append(step)
         return step
 
@@ -2629,16 +2768,18 @@ class ServingEngine:
             # a device that died under the step says so here
             resilience.maybe_fault("serving_step")
             out = np.asarray(step.tokens)
+        if not self._flight:
+            self._device_drained(step.seq)
         for name, value in zip(self._step_counters, out[self.num_slots:]):
             metrics.bump(name, int(value))
         out = out[:self.num_slots]
         live = step.lanes & (step.tenancy == self._tenancy)
         self._last_tok[live] = out[live]
         self.lanes_read = live
-        n_live = int(live.sum())
+        n_live, n_ran = int(live.sum()), int(step.lanes.sum())
         metrics.bump("engine.steps")
-        metrics.bump("engine.lane_steps_discarded",
-                     int(step.lanes.sum()) - n_live)
+        metrics.bump("engine.lane_steps", n_ran)
+        metrics.bump("engine.lane_steps_discarded", n_ran - n_live)
         metrics.bump("tokens.generated", n_live)
         self._meter.tick(n_live)
         metrics.set_gauge("tokens_per_sec", round(self._meter.rate(), 1))
@@ -2750,9 +2891,6 @@ class ServingEngine:
 
     def _refresh_gauges(self) -> None:
         metrics.set_gauge("slots.active", self.active_slots())
-        if self.recurrent:
-            metrics.set_gauge("state.lanes_in_use",
-                              int(self._occupied.sum()))
         a = self.arena.stats()
         metrics.set_gauge("arena.blocks_free", a["blocks_free"])
         metrics.set_gauge("arena.blocks_total", a["blocks_total"])

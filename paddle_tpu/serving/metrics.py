@@ -14,11 +14,15 @@ Counter namespaces:
 * ``tokens.*``     — ``generated`` (decode) and ``prefill`` (prompt) tokens
 * ``engine.*``     — steps (decode steps whose tokens were read), admits,
   retires, rebuilds, trace counts, ``step_uploads`` (host-to-device
-  transfers made preparing decode steps), ``steps_run_ahead`` (steps
-  dispatched while the step before was still un-read) and
+  transfers made preparing decode steps) and ``step_upload_bytes``
+  (their bytes), ``steps_run_ahead`` (steps dispatched while the step
+  before was still un-read), ``lane_steps`` (lanes a read step ran),
   ``lane_steps_discarded`` (lane-steps computed for a request that had
   already ended or been preempted: never emitted, never counted in
-  ``tokens.generated``)
+  ``tokens.generated``) and ``restarts.<why>`` (decode steps prepared
+  from the host's mirrors because a host write had made the carried slot
+  state stale, by the last writer: ``admit``, ``retire``, ``preempt``,
+  ``slot_update``, ``override``, ``error``)
 * ``arena.*``      — block allocs / frees / reuse / alloc failures
 * ``scheduler.*``  — ``preemptions`` (starvation-triggered victim
   evictions), ``cache_skips`` (cache-affinity admissions past a cold head)
@@ -33,7 +37,7 @@ Counter namespaces:
 * ``spec.*``       — speculative decoding (``serving.spec_decode``):
   ``proposed`` / ``accepted`` / ``rollback_tokens`` (proposed but
   rejected — positions rolled back as runtime data) / ``emitted`` /
-  ``iterations`` / ``draft_prefills``
+  ``iterations``
 * ``chunk.*``      — chunked prefill: ``admits`` (admissions that went
   chunked) / ``chunks`` (compiled chunk calls) / ``tokens`` (prompt
   tokens scattered through chunks)
@@ -110,8 +114,8 @@ Counter namespaces:
   (``kv_arena.KVArena.slot_state``; engines of a model that declares a
   ``"recurrent"`` layer only): counter ``resets`` (an admission: the
   prefill started the lane from zeros and wrote the request's state into
-  it); gauges ``state.bytes_total`` (the store's bytes, beside
-  ``arena.kv_bytes`` for the paged pools) and ``state.lanes_in_use``
+  it); gauge ``state.bytes_total`` (the store's bytes, beside
+  ``arena.kv_bytes`` for the paged pools)
 
 * ``time_us.*``    — wall time of the serving loop by phase, in whole
   microseconds (``serving.telemetry.phase``): ``time_us.<phase>`` grows
@@ -119,13 +123,18 @@ Counter namespaces:
   that phase's share of wall time and over the delta of ``engine.steps``
   its mean per decode step. ``pump.unlocked`` and ``sched.step``
   partition the pump thread's time; ``sched.admit`` (parent of
-  ``prefill``), ``decode_step`` (parent of ``decode.prepare`` /
+  ``prefill``, itself the parent of ``prefill.setup`` / ``.upload`` /
+  ``.dispatch`` / ``.wait`` / ``.draft`` / ``.finish``), ``decode_step``
+  (parent of ``decode.prepare`` (``.grow`` + ``.upload``) /
   ``decode.dispatch`` / ``decode.wait``, the last the parent of
   ``decode.release``; run ahead, one turn's ``decode_step`` prepares and
   dispatches step N+1 and waits for step N) and ``sched.emit`` lie
   inside ``sched.step``;
-  ``submit.lock_wait`` is handler threads' time (docs/observability.md
-  "Phases of the serving loop")
+  ``submit.lock_wait`` is handler threads' time;
+  ``device.empty.<cause>`` (``restart`` / ``admit`` / ``sync`` /
+  ``idle``) is the time the engine knew the device had nothing of its
+  left to run, by what ended it (docs/observability.md "Phases of the
+  serving loop")
 
 Gauges: ``queue.depth``, ``queue.prefilling`` (chunked prefills in
 progress), ``spec.acceptance_rate``, ``slots.active``, ``slots.total``,
@@ -213,12 +222,15 @@ DOCUMENTED_NAMESPACES = (
     "time_us",
     # state.* (ISSUE 26): the slot-indexed store of recurrent-layer state
     # beside the paged KV arena — the resets counter,
-    # bytes_total / lanes_in_use gauges (docs/serving_model_seam.md)
+    # bytes_total gauge (docs/serving_model_seam.md)
     "state",
     # prefill.* (ISSUE 31): tokens an admission ran through the layers
     # before the model's prefill tail (body_tokens) and through the tail
     # (tail_tokens: one a prefill where a model declares a tail) —
-    # docs/observability.md, docs/serving_model_seam.md "The prefill tail"
+    # docs/observability.md, docs/serving_model_seam.md "The prefill tail";
+    # (ISSUE 37) compiled prefill calls, the positions they computed
+    # (positions_computed: the buckets), upload_bytes, lane_us_blocked —
+    # docs/observability.md "An admission, from inside"
     "prefill",
     "queue", "slots", "tokens_per_sec",
 )

@@ -478,7 +478,7 @@ class Scheduler:
         if reclaimable < need:
             return False
         victim = max(candidates, key=lambda r: (r.priority, r._admit_seq))
-        self.engine.retire(victim.slot)
+        self.engine.retire(victim.slot, why="preempt")
         telemetry.span(victim.trace_id, telemetry.PREEMPTED,
                        request_id=victim.request_id, slot=victim.slot,
                        by=waiter.request_id, tokens=len(victim.tokens))
@@ -698,6 +698,12 @@ class Scheduler:
             progress = True
         if not self.running:
             self._drop_in_flight()
+            if not self.has_work():
+                # whatever the device stands empty for from here on is
+                # nobody's delay (time_us.device.empty.idle)
+                note_idle = getattr(self.engine, "note_idle", None)
+                if note_idle is not None:
+                    note_idle()
         self._gauges()
         return progress
 

@@ -258,7 +258,6 @@ class SpecDecoder:
             engine.arena.ns_pools(self.NAMESPACE), jnp.asarray(rows),
             name="serving.draft_prefill")
         engine.arena.set_ns_pools(self.NAMESPACE, new_pools)
-        metrics.bump("spec.draft_prefills")
 
     def _get_prefill(self, p_bucket: int):
         fn = self._prefill_jits.get(p_bucket)
@@ -509,7 +508,7 @@ class SpecDecoder:
                 engine.arena.ns_pools(self.NAMESPACE), engine._bt_dev,
                 self._bt_dev, jnp.asarray(engine._positions),
                 jnp.asarray(engine._last_tok), jnp.asarray(act_spec),
-                jnp.asarray(allow), name="serving.spec_step")
+                jnp.asarray(allow), name="serving.spec_step", cause="sync")
             engine.arena.set_pools(t_pools)
             engine.arena.set_ns_pools(self.NAMESPACE, d_pools)
             tgt = np.asarray(tgt)      # [S, k+1] target greedy tokens
@@ -519,10 +518,13 @@ class SpecDecoder:
                 fn, engine._arrays, engine.arena.pools, engine._bt_dev,
                 jnp.asarray(engine._positions),
                 jnp.asarray(engine._last_tok), jnp.asarray(act_spec),
-                jnp.asarray(allow), name="serving.spec_step")
+                jnp.asarray(allow), name="serving.spec_step", cause="sync")
             engine.arena.set_pools(t_pools)
             tgt = np.asarray(tgt)      # [S, k] fused greedy tokens
             props = tgt                # self-draft: proposals ARE outputs
+        # the iteration's tokens are on the host: the device has nothing
+        # left of this engine's (device.empty, ended as `sync`)
+        engine._device_drained(engine._dispatched)
 
         out: Dict[int, List[int]] = {}
         n_emitted = n_proposed = n_accepted = n_rollback = 0
@@ -551,7 +553,9 @@ class SpecDecoder:
             engine._last_tok[slot] = accepted[-1]
             out[slot] = accepted
             n_emitted += len(accepted)
-        engine._touch_slot_state()  # the plain step's device copy is stale
+        # the plain step's device copy is stale (it runs the lanes
+        # speculation must not cover, by an `active=` override)
+        engine._touch_slot_state("override")
         self.iterations += 1
         self.proposed += n_proposed
         self.accepted += n_accepted

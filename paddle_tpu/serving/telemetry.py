@@ -53,9 +53,12 @@ like ``metrics.bump`` keys):
   ``ServingAPI.submit``, so the wait for the API lock is inside them.
   Every :class:`phase` name is a ``latency.*`` histogram too
   (``pump.unlocked``, ``sched.step``, ``sched.admit``, ``sched.emit``,
-  ``decode.prepare``, ``decode.dispatch``, ``decode.wait``,
-  ``decode.release``, ``submit.lock_wait``), with its exact sum in
-  ``time_us.<name>`` (``serving.metrics``).
+  ``prefill.setup`` / ``.upload`` / ``.dispatch`` / ``.wait`` /
+  ``.draft`` / ``.finish``, ``decode.prepare`` (``.grow``, ``.upload``),
+  ``decode.dispatch``, ``decode.wait``, ``decode.release``,
+  ``submit.lock_wait``, ``device.empty``), with its exact sum in
+  ``time_us.<name>`` (``serving.metrics``; ``device.empty`` by cause,
+  ``time_us.device.empty.<cause>``).
 * ``telemetry.*`` — the plane's own meta-counters (mirrored into
   ``serving.metrics``): ``spans`` recorded / ``spans_dropped`` (ring
   overflow, oldest-first).
@@ -282,7 +285,13 @@ class phase:
 
     Host side only, never under ``jit``. :meth:`stop` ends the phase
     before the block does (a lock wait ends when the lock is held);
-    :meth:`discard` ends it unrecorded."""
+    :meth:`discard` ends it unrecorded. A phase that begins in one call
+    and ends in another (``device.empty``) is held open by its owner:
+    :meth:`begin`, later ``stop(cause)``, where ``cause`` (what ended it)
+    becomes an argument of the interval and the last part of the counter's
+    name, ``time_us.<name>.<cause>``: one histogram, one counter a cause.
+    :meth:`note` adds arguments that are known only once the phase runs
+    (a prefill's bucket)."""
 
     __slots__ = ("name", "_sets", "_ann", "_t0")
 
@@ -297,6 +306,13 @@ class phase:
         self._t0 = time.perf_counter()
         return self
 
+    begin = __enter__
+
+    def note(self, **args) -> None:
+        """More arguments for the interval (a flag test with no profiler
+        session, as the annotation itself)."""
+        self._ann.set_metadata(**args)
+
     def _end(self) -> Optional[float]:
         t0, self._t0 = self._t0, None
         if t0 is None:
@@ -305,11 +321,15 @@ class phase:
         self._ann.__exit__(None, None, None)
         return dt
 
-    def stop(self) -> None:
+    def stop(self, cause: str = "") -> None:
+        if cause and self._t0 is not None:
+            self._ann.set_metadata(cause=cause)
         dt = self._end()
         if dt is not None:
             observe(f"latency.{self.name}", dt, *self._sets)
-            metrics.bump(f"time_us.{self.name}", round(dt * 1e6))
+            key = f"time_us.{self.name}.{cause}" if cause \
+                else f"time_us.{self.name}"
+            metrics.bump(key, round(dt * 1e6))
 
     def discard(self) -> None:
         self._end()

@@ -61,7 +61,7 @@ def _always_dirty(engine):
     sound, turn = engine._step_args, engine.decode_turn
 
     def dirty_first(act):
-        engine._touch_slot_state()
+        engine._touch_slot_state("override")
         return sound(act)
 
     engine._step_args = dirty_first
@@ -228,7 +228,7 @@ def test_a_step_that_raises_leaves_the_state_stale(model, engine):
     assert engine._state_dev is not None
     sound = engine._call
 
-    def failing(fn, *args, name):
+    def failing(fn, *args, name, **kw):
         raise RuntimeError("injected: the step's call died")
 
     engine._call = failing

@@ -6,7 +6,9 @@ trace-event conversion, the windowed ``metrics.Meter`` decay regression,
 and the profiler's per-run latency delta. ISSUE 24: ``telemetry.phase``
 (histogram, ``time_us.*`` counter, profiler annotation), the phases of the
 serving loop (closure, nesting, the profiler's clock), and first-token
-time that holds the wait for the API lock."""
+time that holds the wait for the API lock. ISSUE 37: the admission's
+tree under ``prefill``, padded positions, restarts by cause, the empty
+device (``device.empty``) and ``decode.prepare`` in two."""
 import glob
 import json
 import os
@@ -327,14 +329,30 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASE_TREE = {
     "sched.step": ("sched.admit", "decode_step", "sched.emit"),
     "sched.admit": ("prefill",),
+    "prefill": ("prefill.setup", "prefill.upload", "prefill.dispatch",
+                "prefill.wait", "prefill.finish"),
     "decode_step": ("decode.prepare", "decode.dispatch", "decode.wait"),
+    "decode.prepare": ("decode.prepare.grow", "decode.prepare.upload"),
     "decode.wait": ("decode.release",),
 }
+#: every child an admission can have (``prefill.draft`` only where
+#: speculation runs a draft model)
+PREFILL_CHILDREN = PHASE_TREE["prefill"] + ("prefill.draft",)
 
 
 def _time_us():
     return {k[len("time_us."):]: v for k, v in serving_metrics.stats().items()
             if k.startswith("time_us.")}
+
+
+def _counters():
+    return {k: v for k, v in serving_metrics.stats().items()
+            if isinstance(v, int)}
+
+
+def _moved(before):
+    """What the counters moved by since the snapshot ``before``."""
+    return serving_metrics.stats_delta(before, _counters(), drop_zero=True)
 
 
 def _prompts(rng, n, lo=5, hi=12):
@@ -457,6 +475,10 @@ def test_phases_nest_on_the_profilers_host_line(model, tmp_path):
         if lo <= a and b <= hi:
             by.setdefault(name[len("pt."):], []).append((a, b))
     assert len(by["decode_step"]) >= 5 and len(by["prefill"]) >= 2
+    # the empty device is one interval from the read that found it so to
+    # the next compiled call: it starts in one phase and ends in another,
+    # so it is exempt from containment (it is in no parent's list)
+    assert by["device.empty"] and all(b > a for a, b in by["device.empty"])
 
     def inside(child, parents):
         return any(a <= child[0] and child[1] <= b for a, b in parents)
@@ -486,6 +508,230 @@ def test_phases_nest_on_the_profilers_host_line(model, tmp_path):
     # the handler threads' lock waits are on lines of their own
     assert any(n == "pt.submit.lock_wait" for evs in lines if evs is not pump
                for n, _, _ in evs)
+
+
+# --------------------------------------------- an admission (ISSUE 37)
+
+
+def _admission_api(model, kind):
+    """An API whose admissions are of one kind, and the counter that says
+    they were."""
+    from paddle_tpu.serving import ServingConfig
+
+    kw = dict(API_KW)
+    if kind == "prefix_hit":
+        kw["prefix_cache"] = True
+    elif kind == "chunked":
+        kw["chunked_prefill"] = 8
+    elif kind == "draft":
+        paddle.seed(77)
+        draft = GPTForCausalLM(gpt_tiny())
+        draft.eval()
+        kw.update(spec_k=2, draft_model=draft)
+    return ServingAPI(model, ServingConfig(**kw))
+
+
+@pytest.mark.parametrize("kind", ["plain", "prefix_hit", "chunked", "draft"])
+def test_prefill_children_close_on_their_parent(model, kind):
+    """``prefill.setup`` + ``upload`` + ``dispatch`` + ``wait`` (+
+    ``draft``) + ``finish`` are what a ``prefill`` phase holds: never more
+    than it, and within 2% of it once each phase's own entry and exit (some
+    15 us at this size, nothing beside the chip's 30-300 ms admissions) is
+    allowed for. In the foreground, so no other thread holds the GIL
+    between two children."""
+    api = _admission_api(model, kind)
+    try:
+        rng = np.random.default_rng(37)
+        shared = rng.integers(0, 1024, (16,), dtype=np.int32)
+        prompts = [np.concatenate([shared, p]) for p in _prompts(rng, 7)]
+        api.submit(prompts[0], max_new_tokens=3)
+        api.run_until_idle()  # compiled, and the shared prefix is resident
+        uses = {k: h.n for k, h in telemetry.histograms().items()}
+        before = _counters()
+        reqs = [api.submit(p, max_new_tokens=6) for p in prompts[1:]]
+        api.run_until_idle()
+        d = _moved(before)
+    finally:
+        api.close()
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    used = {k[len("latency."):]: h.n - uses.get(k, 0)
+            for k, h in telemetry.histograms().items()}
+    n = sum(used.get(c, 0) for c in PREFILL_CHILDREN)
+    kids = sum(d.get("time_us." + c, 0) for c in PREFILL_CHILDREN)
+    parent = d["time_us.prefill"]
+    assert kids <= parent + n / 2 + 1, d
+    assert kids >= 0.98 * parent - 25 * n, (kids, parent, n)
+    assert d["engine.admits"] == 6
+    # every compiled call counted, with the positions it computed
+    assert d["prefill.positions_computed"] >= d["tokens.prefill"]
+    assert d["prefill.upload_bytes"] > 4 * d["prefill.positions_computed"]
+    if kind == "prefix_hit":
+        assert d["tokens.prefill_avoided"] == 6 * 16
+        assert d["prefill.calls"] == d["prefix.suffix_prefills"] == 6
+    elif kind == "chunked":
+        assert d["prefill.calls"] == d["chunk.chunks"] > 6
+        # a chunk of 8 runs in the ladder's first bucket, 16
+        assert d["prefill.positions_computed"] == 16 * d["chunk.chunks"]
+    else:
+        assert d["prefill.calls"] == 6
+    assert ("time_us.prefill.draft" in d) == (kind == "draft")
+    if kind == "draft":
+        # speculation keeps every turn synchronous: what the device stood
+        # empty for is `sync`, never a restart
+        assert d["time_us.device.empty.sync"] > 0
+        assert "time_us.device.empty.restart" not in d
+        return  # the speculative step prepares for itself
+    # decode.prepare in two, closed the same way
+    parts = (d["time_us.decode.prepare.grow"]
+             + d["time_us.decode.prepare.upload"])
+    prepare, uses = d["time_us.decode.prepare"], used["decode.prepare"]
+    assert 0.98 * prepare - 50 * uses <= parts <= prepare + uses + 1
+    assert d["engine.step_upload_bytes"] > 0 and d["engine.lane_steps"] > 0
+
+
+def test_padded_positions_are_counted_beside_the_real_ones(model):
+    """Prompts of 5, 7, 9, 13, 17, 25 and 33 tokens on the ladder from 4
+    (2^k and 3 * 2^(k-1)) prefill in buckets of 6, 8, 12, 16, 24, 32 and
+    48: 146 positions computed for 109 real ones, and the benchmark's
+    ``prefill_padding_pct`` reads 100 x (1 - 109 / 146)."""
+    api = ServingAPI(model, prefill_bucket_min=4, **API_KW)
+    try:
+        rng = np.random.default_rng(38)
+        before = _counters()
+        for n in (5, 7, 9, 13, 17, 25, 33):
+            api.submit(rng.integers(0, 1024, (n,), dtype=np.int32),
+                       max_new_tokens=2)
+            api.run_until_idle()
+        d = _moved(before)
+    finally:
+        api.close()
+    assert d["prefill.calls"] == 7
+    assert d["tokens.prefill"] == d["prefill.body_tokens"] == 109
+    assert d["prefill.positions_computed"] == 146
+    padding = 100.0 * (1.0 - d["tokens.prefill"]
+                       / d["prefill.positions_computed"])
+    assert padding == pytest.approx(100.0 * 37 / 146, abs=1e-12)
+    # nobody was decoding while these prefills ran: no lane-time was lost
+    assert "prefill.lane_us_blocked" not in d
+    # lanes a read step ran: one request alone, one lane a step
+    assert d["engine.lane_steps"] == d["engine.steps"] == 7
+
+
+def test_restarts_are_counted_by_who_made_the_state_stale(model):
+    """Two requests admitted in one pass start the pump from the mirrors
+    once (``admit``); one admitted in the middle of their run costs one
+    more; the one that ends first, with nobody waiting for its slot, one
+    ``retire``; and while prefills run beside decoding lanes the lane-time
+    they take is counted."""
+    api = ServingAPI(model, **API_KW)
+    try:
+        rng = np.random.default_rng(39)
+        p = _prompts(rng, 4)
+        api.submit(p[0], max_new_tokens=2)
+        api.run_until_idle()  # compiled
+        before = _counters()
+        long_a = api.submit(p[1], max_new_tokens=40)
+        short = api.submit(p[2], max_new_tokens=12)
+        for _ in range(4):
+            api._pump_once()
+        first = _moved(before)
+        before = _counters()
+        api.submit(p[3], max_new_tokens=40)
+        for _ in range(4):
+            api._pump_once()
+        middle = _moved(before)
+        assert not short.finished
+        before = _counters()
+        while not short.finished:
+            api._pump_once()
+        for _ in range(3):
+            api._pump_once()
+        assert not long_a.finished
+        retired = _moved(before)
+        api.run_until_idle()
+    finally:
+        api.close()
+
+    def restarts(d):
+        return {k[len("engine.restarts."):]: v for k, v in d.items()
+                if k.startswith("engine.restarts.")}
+
+    assert restarts(first) == {"admit": 1} and first["engine.admits"] == 2
+    assert restarts(middle) == {"admit": 1} and middle["engine.admits"] == 1
+    assert restarts(retired) == {"retire": 1}
+    assert retired["engine.retires"] == 1 and "engine.admits" not in retired
+    # the admission in the middle stopped two decoding lanes
+    assert middle["prefill.lane_us_blocked"] >= 2 * middle["time_us.prefill"]
+    # each restart ended a stretch in which the device had nothing to do;
+    # so did the second prefill of the pass that admitted two. The prefill
+    # in the middle queued behind the step in flight: no gap before it
+    assert middle["time_us.device.empty.restart"] > 0
+    assert retired["time_us.device.empty.restart"] > 0
+    assert first["time_us.device.empty.admit"] > 0
+    assert "time_us.device.empty.admit" not in middle
+
+
+def test_only_idle_grows_while_the_server_has_no_work(model):
+    api = ServingAPI(model, background=True, **API_KW)
+    try:
+        rng = np.random.default_rng(40)
+        p = _prompts(rng, 2)
+        api.result(api.submit(p[0], max_new_tokens=3), timeout=300)
+        time.sleep(0.05)  # the pump has seen that nothing is left
+        gaps = telemetry.histogram("latency.device.empty").n
+        before = _counters()
+        time.sleep(0.3)
+        assert _moved(before) == {} or set(_moved(before)) <= {
+            "time_us.pump.unlocked"}  # an open phase counts at its end
+        api.result(api.submit(p[1], max_new_tokens=3), timeout=300)
+        time.sleep(0.05)
+        d = _moved(before)
+    finally:
+        api.close()
+    assert d["time_us.device.empty.idle"] >= 300_000
+    others = sum(v for k, v in d.items()
+                 if k.startswith("time_us.device.empty.")
+                 and not k.endswith(".idle"))
+    assert others < 100_000, d
+    # one gap that the admission ended as `idle`, then the request's own
+    assert telemetry.histogram("latency.device.empty").n > gaps
+
+
+def test_the_run_report_gives_the_admissions_tree_and_the_restarts(model):
+    """``tools/serving_stats.py --run`` reads the same counters: the
+    ``prefill`` phase's children in ms a compiled call, the padding, the
+    restarts by who made the state stale and the empty device by cause."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "serving_stats", os.path.join(REPO, "tools", "serving_stats.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.admission_report({"tokens.generated": 5}) == {}
+    api = ServingAPI(model, **API_KW)
+    try:
+        rng = np.random.default_rng(41)
+        before = serving_metrics.stats()
+        for p in _prompts(rng, 3):
+            api.submit(p, max_new_tokens=6)
+        api.run_until_idle()
+        delta = serving_metrics.stats_delta(before, serving_metrics.stats(),
+                                            drop_zero=True)
+    finally:
+        api.close()
+    rep = tool.admission_report(delta)
+    assert rep["prefill_calls"] == rep["admits"] == 3
+    kids = rep["children_ms_per_call"]
+    assert list(kids) == ["setup", "upload", "dispatch", "wait", "finish"]
+    assert 0.9 * rep["prefill_ms_per_call"] - 0.2 <= sum(kids.values()) \
+        <= rep["prefill_ms_per_call"] + 0.01
+    # prompts of 5 to 11 tokens in the ladder's first bucket, 16
+    assert rep["padding_pct"] == pytest.approx(
+        100.0 * (1.0 - delta["tokens.prefill"] / 48), abs=0.01)
+    assert rep["upload_kb_per_call"] > 0.0
+    assert rep["restarts"] == {"admit": 1}  # one pass admitted all three
+    assert set(rep["device_empty_ms"]) >= {"admit", "restart"}
+    assert rep["restart_ms_per_admission"] > 0.0
 
 
 def test_ttft_and_e2e_include_the_wait_for_the_api_lock(model):
@@ -552,6 +798,16 @@ def test_metric_key_lint_knows_every_phase_key(model):
         api.close()
     keys = list(serving_metrics.stats())
     assert any(k.startswith("time_us.decode.") for k in keys)
+    for key in ("time_us.prefill.setup", "time_us.prefill.upload",
+                "time_us.prefill.dispatch", "time_us.prefill.wait",
+                "time_us.prefill.finish", "time_us.decode.prepare.grow",
+                "time_us.decode.prepare.upload", "prefill.calls",
+                "prefill.positions_computed", "prefill.upload_bytes",
+                "engine.restarts.admit", "engine.step_upload_bytes",
+                "engine.lane_steps"):
+        assert key in keys, key
+    assert any(k.startswith("time_us.device.empty.") for k in keys)
+    assert "latency.device.empty" in telemetry.histograms()
     for key in keys:
         assert key.split(".", 1)[0] in \
             serving_metrics.DOCUMENTED_NAMESPACES, key

@@ -117,6 +117,16 @@ calls) rendered as a per-run p50/p95/p99 percentile table, plus the
 ``telemetry.spans`` / ``telemetry.spans_dropped`` trace-ring counters
 (the headline ``serving.ttft_p50_ms`` / ``serving.inter_token_p99_ms``
 percentiles live on the shared ``memory_stats`` surface).
+A run report also gives what an ADMISSION cost (``admission`` in the
+JSON, a block of lines in the text), from the engine's own phase counters
+(docs/observability.md, "An admission, from inside"): the compiled
+prefill calls, the ``prefill`` phase's mean a call and its children
+(``setup`` + ``upload`` + ``dispatch`` + ``wait`` (+ ``draft``) +
+``finish``), the padded share of the positions those calls computed,
+their upload bytes, the lane-time they took from decoding requests, then
+the restarts from the host's mirrors by who made the slot state stale
+(``engine.restarts.<why>``) and the time the device stood empty by what
+ended it (``time_us.device.empty.<cause>``).
 A run report also prints the end-of-run arena/prefix/gateway gauges
 (occupancy, cached/resident blocks, high-water, fragmentation, replica
 health) next to the delta — point-in-time state, not differenced.
@@ -215,6 +225,48 @@ def _config_report() -> dict:
     }
 
 
+#: the children of the ``prefill`` phase, in the order an admission runs them
+PREFILL_CHILDREN = ("setup", "upload", "dispatch", "wait", "draft", "finish")
+
+
+def admission_report(delta: dict) -> dict:
+    """What an admission cost in a run, from the delta of the serving
+    counters: the ``prefill`` phase's tree in ms a compiled call, the
+    padded positions, then the restarts and the empty device by cause.
+    Empty when the run made no compiled prefill call."""
+    calls = delta.get("prefill.calls", 0)
+    if not calls:
+        return {}
+
+    def ms(us, n=calls):
+        return round(us / n / 1e3, 3) if n else None
+
+    def by_suffix(prefix):
+        return {k[len(prefix):]: v for k, v in sorted(delta.items())
+                if k.startswith(prefix)}
+
+    computed = delta.get("prefill.positions_computed", 0)
+    admits = delta.get("engine.admits", 0)
+    empty = by_suffix("time_us.device.empty.")
+    return {
+        "prefill_calls": calls,
+        "admits": admits,
+        "prefill_ms_per_call": ms(delta.get("time_us.prefill", 0)),
+        "children_ms_per_call": {
+            c: ms(delta["time_us.prefill." + c]) for c in PREFILL_CHILDREN
+            if "time_us.prefill." + c in delta},
+        "padding_pct": round(100.0 * (1.0 - delta.get("tokens.prefill", 0)
+                                      / computed), 2) if computed else None,
+        "upload_kb_per_call": round(delta.get("prefill.upload_bytes", 0)
+                                    / calls / 1e3, 2),
+        "lane_s_blocked": round(delta.get("prefill.lane_us_blocked", 0)
+                                / 1e6, 3),
+        "restarts": by_suffix("engine.restarts."),
+        "device_empty_ms": {c: round(us / 1e3, 3) for c, us in empty.items()},
+        "restart_ms_per_admission": ms(empty.get("restart", 0), admits),
+    }
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--json", action="store_true", help="JSON output")
@@ -274,8 +326,9 @@ def main(argv=None) -> int:
                           "p99_ms": round(h.percentile(99) * 1e3, 3),
                           "mean_ms": round(h.mean() * 1e3, 3)}
                    for name, h in sorted(hists.items())}
+        admission = admission_report(delta)
         rec = {"wall_secs": round(wall, 3), "stats": delta,
-               "gauges": gauges, "latency": latency,
+               "gauges": gauges, "latency": latency, "admission": admission,
                "tokens_per_sec": round(toks / wall, 2) if wall > 0 else None}
         if args.json:
             print(json.dumps(rec))
@@ -284,7 +337,9 @@ def main(argv=None) -> int:
                              f"tokens_per_sec: {rec['tokens_per_sec']}"]
                             + [f"{k}: {v}" for k, v in sorted(delta.items())]
                             + [f"gauge {k}: {v}"
-                               for k, v in sorted(gauges.items())]))
+                               for k, v in sorted(gauges.items())]
+                            + [f"admission {k}: {v}"
+                               for k, v in admission.items()]))
             table = telemetry.percentile_table(hists)
             if table:
                 print(table)
