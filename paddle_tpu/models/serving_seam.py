@@ -17,9 +17,9 @@ model shares (docs/serving_model_seam.md).
   a prefill runs on each request's last valid token only (None: every layer
   runs on every token);
 * ``serving_embed(ids, positions)``: ``[lanes, s]`` token ids at per-lane
-  start positions -> hidden states, ``[lanes, s, hidden]`` or with the
-  model's own axes behind ``s`` (Xing4.0: ``[lanes, s, 4, hidden]``, its
-  residual streams), which its layers carry and ``serving_final`` folds;
+  start positions -> hidden states, ``[lanes, s, hidden]`` or the model's
+  own behind ``s`` (Xing4.0: ``[lanes, s, 4 hidden]``, its four residual
+  streams side by side), which its layers carry and ``serving_final`` folds;
 * ``serving_layers()``: the layers, each called
   ``layer(x, cache=view, start_pos=positions) -> (x, successor view)`` with a
   cache view of ITS kind, built by the engine; a layer whose class sets
@@ -29,7 +29,12 @@ model shares (docs/serving_model_seam.md).
 * ``serving_linears()``: ``(site, linear)`` for every matmul the int8
   quantizer and the LoRA arena may touch, in model order;
 * ``serving_embedding()``: the token table (never quantized: its dtype is
-  the compute dtype).
+  the compute dtype);
+* optionally ``serving_prepare()``: in place and idempotent, called once
+  before the engine takes its snapshot of the weights: derive the buffers
+  the served programs read from the parameters as they are now (Xing4.0:
+  the mixers' matrices as its hyper-connection kernel reads them), so that
+  no program derives them again at every call.
 
 **The cache-view protocols.** A ``"kv"`` layer drives
 ``view.update_and_attend(q, k, v) -> (attention output, successor)`` with
@@ -75,7 +80,9 @@ shard), and the disaggregated handoff (``DisaggReplicaPool``).
 **The step carry.** ``forward_cached`` hands every layer that sets
 ``uses_step_carry`` one dict, the same for the whole call: a layer publishes
 ``carry[name] = value`` (``[b, s, ...]``) and a later layer of the same call
-reads it. It is no cache: nothing of it outlives the call. The decode step
+reads it (Xing4.0's layers hand the stream update behind their last
+sublayer to the next layer's first kernel so: ``"hc.y"``, ``"hc.mix"``). It
+is no cache: nothing of it outlives the call. The decode step
 seeds it with ``"lanes"`` (``[lanes, 1]`` bool: the lanes that hold a
 request), and reads one entry back: ``carry["counters"]``, a dict of int32
 scalars by counter name that layers add to (:func:`add_step_counters`);
@@ -212,6 +219,11 @@ class ServingSpec:
     layers: Tuple[object, ...]
     #: first layer a prefill runs on each request's last valid token only
     prefill_tail: Optional[int] = None
+    #: Pallas kernels the model's own layers launch (the cache views'
+    #: kernels are the engine's): the engine sets the gauge
+    #: ``kernel.<name>`` for each, 1 on a TPU, 0 where the interpreter
+    #: runs the kernel's body
+    kernels: Tuple[str, ...] = ()
 
     def kv_layers(self):
         return [s for s in self.layers if s.kind == "kv"]
@@ -250,9 +262,9 @@ def forward_cached(model, ids, views, positions, last=None,
     and ``last`` (a prefill of a model that declares a tail) the layers
     from ``prefill_tail`` on see row ``last`` alone, and ``hidden`` is
     ``[lanes, 1, hidden]``. Between embed and final norm the hidden states
-    are the model's own: ``[lanes, s, hidden]``, or with further axes
-    behind ``s`` (residual streams, ``[lanes, s, streams, hidden]``), which
-    ``serving_final`` folds away; nothing here reads past axis 1.
+    are the model's own: ``[lanes, s, hidden]``, or wider or with further
+    axes behind ``s`` (residual streams, ``[lanes, s, streams hidden]``),
+    which ``serving_final`` folds away; nothing here reads past axis 1.
     ``carry``: the step carry to start from (the decode step seeds it, see
     the module's head); None starts an empty one."""
     x = model.serving_embed(ids, positions)

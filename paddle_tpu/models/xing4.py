@@ -7,10 +7,16 @@ state, are at the head of ``benchmark/reference/xing4.py``. What is specific
 to the served form:
 
 * **Streams.** Between embed and final norm the hidden states are
-  ``[lanes, s, hc_mult, hidden]`` float32 (the embedding repeated; summed
-  before the final norm). Each sublayer reads ``u = H_pre X`` and writes
-  ``X' = H_res X + outer(H_post, y)``; mixers, Sinkhorn and the update are
-  float32, the sublayer itself runs in the weights' dtype.
+  ``[lanes, s, hc_mult * hidden]`` float32 (the embedding repeated, stream
+  ``j`` the slice ``[j hidden, (j + 1) hidden)``; summed before the final
+  norm). Each sublayer reads ``u = H_pre X`` and writes ``X' = H_res X +
+  outer(H_post, y)``; mixers, Sinkhorn and the update are float32, the
+  sublayer itself runs in the weights' dtype. A layer runs them as ONE
+  kernel a sublayer (:mod:`paddle_tpu.ops.hyper_connection`: the update
+  behind the previous sublayer, the mixers, the read-out and its RMSNorm
+  in one pass over the streams); :class:`HyperConnection`'s ``mixers`` /
+  ``pre`` / ``post`` are the same equations in ``jax.numpy`` over
+  ``[lanes, s, hc_mult, hidden]``, which the tests hold the kernel to.
 * **The cache is one row a token**: ``[RMSNorm(c_kv) | rotary(k_rope)]``,
   ``kv_lora_rank + qk_rope_head_dim`` values, declared as a
   :class:`~paddle_tpu.models.serving_seam.LatentKVLayerState`. A prefill
@@ -44,6 +50,7 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from ..ops import grouped_matmul as gm
+from ..ops import hyper_connection as hc
 from .serving_seam import (LatentKVLayerState, ServingSpec,
                            add_step_counters, serving_linear)
 
@@ -286,6 +293,35 @@ class HyperConnection(nn.Layer):
                     for j in range(self.n))
         return mixed + post[..., None] * y.astype(F32)[..., None, :]
 
+    def _packed(self):
+        return hc.pack_mixer_params(self.w._data, self.norm._data,
+                                    self.a._data, self.b._data, self.n)
+
+    def pack(self):
+        """Keep the mixers' weights as the kernel reads them (buffers
+        ``packed_w``, ``packed_ab``), from the parameters as they are now:
+        :meth:`read` then derives nothing. Again after the weights change."""
+        params = self._packed()
+        self.register_buffer("packed_w", Tensor(params.w), persistable=False)
+        self.register_buffer("packed_ab", Tensor(params.ab),
+                             persistable=False)
+
+    def read(self, X, gain, dtype, prev=None, want_f32: bool = False):
+        """The kernel's form of :meth:`post` (of the sublayer before, ``prev``
+        its ``(y, mix)``), :meth:`pre` and the sublayer's RMSNorm with
+        ``gain``, on flat streams ``[b, s, n h]`` -> ``(X', u in dtype, u
+        unrounded or None, mix)``."""
+        c = self.cfg
+        if "packed_w" in self._buffers:
+            params = hc.MixerParams(self.packed_w._data, self.packed_ab._data)
+        else:
+            params = self._packed()
+        return hc.hyper_connection(
+            X, params, gain, n=self.n, iters=int(c.hc_sinkhorn_iters),
+            rms_eps=float(c.rms_norm_eps), hc_eps=float(c.hc_eps),
+            clamp=(c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max),
+            out_dtype=dtype, prev=prev, want_f32=want_f32)
+
 
 class Xing4Attention(nn.Layer):
     def __init__(self, cfg: Xing4Config):
@@ -421,9 +457,13 @@ class Xing4MoE(nn.Layer):
 class Xing4DecoderLayer(nn.Layer):
     uses_step_carry = True  # the expert layer adds to the step's counters
 
-    def __init__(self, cfg: Xing4Config, dense: bool):
+    def __init__(self, cfg: Xing4Config, dense: bool, hands_on: bool = False):
+        """``hands_on``: a layer follows in the stack, so under a step carry
+        this one leaves its last update to that layer's first kernel
+        (``carry["hc.y"]``, ``carry["hc.mix"]``) and returns the streams as
+        they were BEFORE it: one pass over them a sublayer, not two."""
         super().__init__()
-        self.eps, self.dense = float(cfg.rms_norm_eps), dense
+        self.dense, self.hands_on = dense, hands_on
         one = I.Constant(1.0)
         h = int(cfg.hidden_size)
         self.hc_attn = HyperConnection(cfg)
@@ -435,21 +475,28 @@ class Xing4DecoderLayer(nn.Layer):
                     else Xing4MoE(cfg))
 
     def forward(self, x, cache=None, start_pos=0, carry=None):
-        X = x._data                                  # [b, s, n, h] float32
+        X = x._data                                  # [b, s, n h] float32
         dtype = self.attn_norm._data.dtype           # the weights' dtype
+        prev = None
+        if carry is not None and "hc.y" in carry:    # the layer before's
+            prev = carry.pop("hc.y"), carry.pop("hc.mix")
         with jax.named_scope("mhc"):
-            u, mix = self.hc_attn.pre(X)
-            u = _rms(u, self.attn_norm._data, self.eps, dtype)
+            X, u, _, mix = self.hc_attn.read(X, self.attn_norm._data, dtype,
+                                             prev=prev)
         with jax.named_scope("mla"):
             y, new_cache = self.attn(Tensor(u), cache, start_pos)
         with jax.named_scope("mhc"):
-            X = self.hc_attn.post(X, y._data, mix)
-            u, mix = self.hc_mlp.pre(X)
-            u32 = _rms(u, self.mlp_norm._data, self.eps, F32)
+            X, u, u32, mix = self.hc_mlp.read(
+                X, self.mlp_norm._data, dtype, prev=(y._data, mix),
+                want_f32=not self.dense)
         with jax.named_scope("mlp" if self.dense else "moe"):
-            y = self.mlp(Tensor(u32.astype(dtype)), carry, u32)
+            y = self.mlp(Tensor(u), carry, u32)
+        if self.hands_on and carry is not None:
+            carry["hc.y"], carry["hc.mix"] = y._data, mix
+            return Tensor(X), new_cache
         with jax.named_scope("mhc"):
-            X = self.hc_mlp.post(X, y._data, mix)
+            X = hc.hyper_connection_update(X, y._data, mix,
+                                           n=self.hc_mlp.n)
         return Tensor(X), new_cache
 
 
@@ -471,9 +518,10 @@ class Xing4Model(nn.Layer):
         super().__init__()
         self.cfg = cfg
         self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
+        depth = int(cfg.num_hidden_layers)
         self.layers = nn.LayerList(
-            [Xing4DecoderLayer(cfg, cfg.is_dense(i))
-             for i in range(int(cfg.num_hidden_layers))])
+            [Xing4DecoderLayer(cfg, cfg.is_dense(i), hands_on=i < depth - 1)
+             for i in range(depth)])
         self.norm = self.create_parameter(
             [int(cfg.hidden_size)], default_initializer=I.Constant(1.0))
 
@@ -487,10 +535,12 @@ class Xing4ForCausalLM(nn.Layer):
         self.mtp = Xing4MTP(cfg) if with_mtp else None
 
     def _streams(self, emb):
-        n = int(self.cfg.hc_mult)
-        e = emb.astype(F32)
-        return jnp.broadcast_to(e[:, :, None, :],
-                                e.shape[:2] + (n,) + e.shape[2:])
+        return jnp.tile(emb.astype(F32), (1, 1, int(self.cfg.hc_mult)))
+
+    def _summed(self, x):
+        """``[b, s, n h]`` streams -> their sum ``[b, s, h]``."""
+        h = int(self.cfg.hidden_size)
+        return sum(x[..., j:j + h] for j in range(0, x.shape[-1], h))
 
     def _final_streams(self, input_ids, absorbed: bool):
         x = self.serving_embed(input_ids, 0)
@@ -511,7 +561,7 @@ class Xing4ForCausalLM(nn.Layer):
         the summed streams at ``i`` and the embedding of token ``i + 1``."""
         m, eps = self.mtp, float(self.cfg.rms_norm_eps)
         ids = _arr(input_ids)
-        h = jnp.sum(self._final_streams(input_ids, False)._data, 2)[:, :-1]
+        h = self._summed(self._final_streams(input_ids, False)._data)[:, :-1]
         e = self.model.embed_tokens(Tensor(ids[:, 1:]))._data
         z = serving_linear(m.proj, Tensor(jnp.concatenate(
             [_rms(h, m.hnorm._data, eps, e.dtype),
@@ -532,7 +582,14 @@ class Xing4ForCausalLM(nn.Layer):
                                    int(c.num_attention_heads))
         return ServingSpec(vocab_size=int(c.vocab_size),
                            max_positions=int(c.max_position_embeddings),
-                           layers=(state,) * int(c.num_hidden_layers))
+                           layers=(state,) * int(c.num_hidden_layers),
+                           kernels=("hyper_connection",))
+
+    def serving_prepare(self):
+        """Every hyper-connection's weights in the kernel's form, once."""
+        for layer in self.sublayers():
+            if isinstance(layer, HyperConnection):
+                layer.pack()
 
     def serving_embed(self, ids, positions):
         """The token's embedding repeated into the streams (positions are
@@ -546,7 +603,7 @@ class Xing4ForCausalLM(nn.Layer):
         """The streams summed, then the final norm: ``[b, s, hidden]`` in
         the weights' dtype."""
         dtype = self.model.embed_tokens.weight._data.dtype
-        return Tensor(_rms(jnp.sum(x._data, axis=2), self.model.norm._data,
+        return Tensor(_rms(self._summed(x._data), self.model.norm._data,
                            float(self.cfg.rms_norm_eps), dtype))
 
     def serving_head(self, h_last):

@@ -930,6 +930,8 @@ class ServingEngine:
             self.lora.bind_engine(self)  # unregister liveness guard
         else:
             self.lora = None
+        if hasattr(model, "serving_prepare"):
+            model.serving_prepare()  # derived buffers: before the snapshot
         params, buffers = model.functional_state()
         self._objs = list(params.values()) + list(buffers.values())
         if self._mesh_devices > 1:
@@ -1235,6 +1237,12 @@ class ServingEngine:
         metrics.set_gauge("kernel.paged", int(self.decode_kernel))
         metrics.set_gauge("kernel.paged_latent",
                           int(self.decode_kernel and self.latent))
+        if spec.kernels:  # the model's own (serving_seam.ServingSpec)
+            from ..ops.pallas_ops import _use_interpret
+
+            for name in spec.kernels:
+                metrics.set_gauge(f"kernel.{name}",
+                                  int(not _use_interpret()))
         # the EFFECTIVE attention route x mesh topology (ISSUE 16), per
         # arena namespace: "kernel@data1.model4", "gather@single", ... A
         # fallback (Pallas unavailable, flag off) is observable here

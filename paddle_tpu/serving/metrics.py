@@ -99,7 +99,11 @@ Counter namespaces:
   counters — churn must never re-lower a kernel), plus the gauges
   ``kernel.paged`` (0/1: the route the decode step was built with),
   ``kernel.paged_latent`` (0/1: that route is the latent decode kernel,
-  ``ops.paged_attention.paged_latent_decode``) and ``kernel.tuned_entries`` (tuning-store
+  ``ops.paged_attention.paged_latent_decode``), ``kernel.hyper_connection``
+  (0/1, set for a model whose ``ServingSpec.kernels`` names it: the
+  streams' update, mixers and read-out run as the Pallas kernel
+  ``ops.hyper_connection``, not as its body in the interpreter) and
+  ``kernel.tuned_entries`` (tuning-store
   records for this chip — ``ops.tuning`` / benches/TUNED_KERNELS.json)
 
 * ``moe.*``        — the expert layers' load, summed by the decode step

@@ -297,3 +297,84 @@ def test_expert_grouped_matmul_compiles(tokens, monkeypatch):
              _sds((tokens, 4), jnp.float32),
              _sds((64, 3584, 2048), jnp.bfloat16),
              _sds((64, 1024, 3584), jnp.bfloat16))
+
+
+# ------------------------------------------ the fused hyper-connection
+
+_HC_N, _HC_H = 4, 3584
+_HC_KW = dict(n=_HC_N, iters=20, rms_eps=1e-6, hc_eps=1e-6,
+              clamp=(-30.0, 30.0), out_dtype=jnp.bfloat16)
+
+
+def _hc_chain(sublayers):
+    """``sublayers`` hyper-connections of Xing4.0's widths chained as a
+    layer stack chains them (each kernel updates the streams behind the
+    sublayer before it, the last update alone), a ``[3584, 3584]`` bf16
+    matmul standing in for each sublayer; every second one hands out the
+    unrounded read-out as well, as an expert layer's does."""
+    from paddle_tpu.ops import hyper_connection as hc
+
+    def chain(x, ws, abs_, gains, mats):
+        prev = None
+        for i, (w, ab, g, m) in enumerate(zip(ws, abs_, gains, mats)):
+            x, u, _, mix = hc.hyper_connection(
+                x, hc.MixerParams(w, ab), g, prev=prev, want_f32=i % 2 == 1,
+                **_HC_KW)
+            prev = (jnp.dot(u, m), mix)
+        return hc.hyper_connection_update(x, *prev, n=_HC_N)
+
+    def args(lead):
+        k = _HC_N * _HC_H
+        return (_sds(lead + (k,), jnp.float32),
+                [_sds((k, 128), jnp.bfloat16)] * sublayers,
+                [_sds((48, 1), jnp.float32)] * sublayers,
+                [_sds((_HC_H,), jnp.bfloat16)] * sublayers,
+                [_sds((_HC_H, _HC_H), jnp.bfloat16)] * sublayers)
+    return chain, args
+
+
+@pytest.fixture
+def _hc_for_the_chip(monkeypatch):
+    from paddle_tpu.ops import hyper_connection as hc
+
+    monkeypatch.setattr(hc, "_use_interpret", lambda: False)
+    hc._call.clear_cache()  # the launch is jitted: lower it for the chip
+    yield
+    hc._call.clear_cache()
+
+
+@pytest.mark.parametrize("lead", [(1, 8192), (1, 10240), (64, 1)],
+                         ids=["bucket-8192", "bucket-10240", "decode-step"])
+def test_hyper_connection_compiles_at_the_cells_shapes(lead,
+                                                       _hc_for_the_chip):
+    """``serve-doc-latent-moe``'s shapes: the two largest prefill buckets
+    and the 64-lane step; 4 float32 streams of 3584, bf16 sublayers."""
+    chain, args = _hc_chain(2)
+    text = _compile(chain, *args(lead))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # the streams lie [positions, n h] throughout: no T(4,128) relayout
+    assert not re.search(r"f32\[[0-9,]*,4,3584\]", text)
+
+
+def test_hyper_connection_chain_by_the_compilers_own_count(
+        _hc_for_the_chip):
+    """A four-sublayer chain at 4,096 positions, by ``cost_analysis()``
+    (the kernel states what it moves: ``pl.CostEstimate``): under 3 passes
+    over the streams a sublayer once the stand-in matmuls are taken off
+    (the ``jax.numpy`` form: 12.0), and under 8 kernels a sublayer (101)."""
+    positions, sublayers = 4096, 4
+    chain, args = _hc_chain(sublayers)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(chain, donate_argnums=0).lower(
+            *args((1, positions))).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    streams = positions * _HC_N * _HC_H * 4
+    matmuls = sublayers * 2 * _HC_H * (2 * positions + _HC_H)
+    passes = (cost["bytes accessed"] - matmuls) / streams / sublayers
+    assert 2.0 < passes < 3.0, passes
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    kernels = len(re.findall(
+        r" (?:fusion|custom-call|copy|convolution|dot)\(", entry))
+    assert sublayers <= kernels < 8 * sublayers, kernels

@@ -22,6 +22,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models import xing4 as X
 from paddle_tpu.models.serving_seam import LatentKVLayerState
 from paddle_tpu.ops import grouped_matmul as gm
+from paddle_tpu.ops import hyper_connection as HC
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving import metrics as serving_metrics
 
@@ -125,7 +126,8 @@ def test_what_the_model_declares():
     # the streams: what embed hands the layers and final folds away
     ids = paddle.to_tensor(np.zeros((2, 5), np.int32))
     x = model.serving_embed(ids, 0)
-    assert x.shape == [2, 5, 4, 64] and x._data.dtype == jnp.float32
+    # (four streams of 64 side by side: ops/hyper_connection.py's layout)
+    assert x.shape == [2, 5, 4 * 64] and x._data.dtype == jnp.float32
     assert model.serving_final(x).shape == [2, 5, 64]
 
 
@@ -166,18 +168,23 @@ def test_tolerance_fails_what_is_wrong(weights, wrong, monkeypatch):
     program in bfloat16 where float32 is stated, Sinkhorn's columns before
     its rows, every token rotated as position 0, routing without the
     selection bias, chosen scores not normalized, the sublayer read off
-    stream 0 alone."""
+    stream 0 alone. (Sinkhorn and the read-out are swapped where the layers
+    run them: inside ``ops/hyper_connection.py``'s kernel.)"""
     ids = _prompt(np.random.default_rng(2), 60)
     cfg, w, dtype = dict(CFG), weights, "float32"
     if wrong == "bfloat16_for_float32":
         dtype = "bfloat16"
     elif wrong == "columns_then_rows":
-        def swapped(m, iters, eps):
+        # (in the served path's own Sinkhorn, the kernel's: ``cols[j]`` is
+        # column ``j`` of each position's matrix, positions in the lanes)
+        def swapped(cols, iters, eps):
             for _ in range(2):  # two rounds: far from converged
-                m = m / (jnp.sum(m, -2, keepdims=True) + eps)
-                m = m / (jnp.sum(m, -1, keepdims=True) + eps)
-            return m
-        monkeypatch.setattr(X, "sinkhorn", swapped)
+                cols = [c / (jnp.sum(c, axis=0, keepdims=True) + eps)
+                        for c in cols]
+                rows = sum(cols[1:], cols[0])
+                cols = [c / (rows + eps) for c in cols]
+            return cols
+        monkeypatch.setattr(HC, "_sinkhorn", swapped)
     elif wrong == "rotary_at_position_0":
         monkeypatch.setattr(X, "_positions",
                             lambda start, b, s: jnp.zeros((b, s), jnp.int32))
@@ -188,19 +195,17 @@ def test_tolerance_fails_what_is_wrong(weights, wrong, monkeypatch):
     elif wrong == "unnormalized_topk":
         cfg["norm_topk_prob"] = False
     elif wrong == "one_stream":
-        pre = X.HyperConnection.pre
-
-        def first_stream(self, Xs):
-            _, mix = pre(self, Xs)
-            return Xs[:, :, 0], mix
-        monkeypatch.setattr(X.HyperConnection, "pre", first_stream)
+        monkeypatch.setattr(HC, "_read_out",
+                            lambda pre, streams: streams[0])
     model = _build(dtype)
     keep = paddle.get_flags(["eager_jit_ops"])
     paddle.set_flags({"eager_jit_ops": False})
+    HC._call.clear_cache()  # the kernel's launch is jitted: trace it anew
     try:
         got = model(paddle.to_tensor(ids[None]))._data[0]
     finally:
         paddle.set_flags(keep)
+        HC._call.clear_cache()  # and let no later test meet the swap
     want = ref.logits(w, cfg, ids)
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) > 10 * TOL
 
