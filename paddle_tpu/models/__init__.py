@@ -13,6 +13,12 @@ from .gpt import (  # noqa: F401
     gpt_base,
     gpt_tiny,
 )
+from .longcat_flash import (  # noqa: F401
+    LongcatFlashConfig,
+    LongcatFlashForCausalLM,
+    LongcatFlashModel,
+    longcat_flash_tiny,
+)
 from .olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig,
     OlmoHybridForCausalLM,

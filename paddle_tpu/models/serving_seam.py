@@ -5,8 +5,9 @@ model shares (docs/serving_model_seam.md).
 **What a model declares** (methods on the ``nn.Layer`` it hands the engine;
 :class:`~paddle_tpu.models.gpt.GPTForCausalLM`,
 :class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM`,
-:class:`~paddle_tpu.models.phi4flash.Phi4FlashForCausalLM` and
-:class:`~paddle_tpu.models.xing4.Xing4ForCausalLM` do):
+:class:`~paddle_tpu.models.phi4flash.Phi4FlashForCausalLM`,
+:class:`~paddle_tpu.models.xing4.Xing4ForCausalLM` and
+:class:`~paddle_tpu.models.longcat_flash.LongcatFlashForCausalLM` do):
 
 * ``serving_spec() -> ServingSpec``: vocabulary, longest context, one state
   declaration per layer, in order (the KIND of per-request state that layer
@@ -81,8 +82,11 @@ shard), and the disaggregated handoff (``DisaggReplicaPool``).
 ``uses_step_carry`` one dict, the same for the whole call: a layer publishes
 ``carry[name] = value`` (``[b, s, ...]``) and a later layer of the same call
 reads it (Xing4.0's layers hand the stream update behind their last
-sublayer to the next layer's first kernel so: ``"hc.y"``, ``"hc.mix"``). It
-is no cache: nothing of it outlives the call. The decode step
+sublayer to the next layer's first kernel so: ``"hc.y"``, ``"hc.mix"``;
+LongCat-Flash's decoder layer, which has two cache entries, is served as two
+half-layers, and its expert layer's output crosses from the first to the end
+of the second so: ``"scmoe.s"``). It is no cache: nothing of it outlives the
+call. The decode step
 seeds it with ``"lanes"`` (``[lanes, 1]`` bool: the lanes that hold a
 request), and reads one entry back: ``carry["counters"]``, a dict of int32
 scalars by counter name that layers add to (:func:`add_step_counters`);

@@ -323,24 +323,36 @@ class HyperConnection(nn.Layer):
             out_dtype=dtype, prev=prev, want_f32=want_f32)
 
 
-class Xing4Attention(nn.Layer):
-    def __init__(self, cfg: Xing4Config):
+class LatentAttention(nn.Layer):
+    """Latent (MLA) attention over a ``"latent"`` cache view, expanded or
+    absorbed as the view says (the module's head): the mathematics every
+    model with such a layer shares; the sizes, the softmax scale and the
+    rotary's frequencies are the model's. ``q_scale`` multiplies the
+    normed query latent before ``W_qb``, ``kv_scale`` the normed ``c_kv``
+    before ``W_kvb``. The cached row holds ``c_kv`` unscaled, so
+    ``kv_scale`` is folded where both forms meet it: into ``q_nope`` (the
+    scores' no-rotary part is linear in the keys) and into the attention's
+    output (linear in the values)."""
+
+    def __init__(self, hidden: int, heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, nope: int, rope: int, v_dim: int,
+                 eps: float, scale: float, inv_freq, amp: float = 1.0,
+                 q_scale: float = 1.0, kv_scale: float = 1.0):
         super().__init__()
-        h, self.heads = int(cfg.hidden_size), int(cfg.num_attention_heads)
-        self.nope, self.rope = (int(cfg.qk_nope_head_dim),
-                                int(cfg.qk_rope_head_dim))
-        self.vd, self.kvr = int(cfg.v_head_dim), int(cfg.kv_lora_rank)
-        self.eps = float(cfg.rms_norm_eps)
-        self.scale = softmax_scale(cfg)
-        self.inv_freq, self.amp = yarn_frequencies(cfg)
-        self.q_a = _linear(h, cfg.q_lora_rank)
-        self.q_b = _linear(cfg.q_lora_rank,
+        h, self.heads = int(hidden), int(heads)
+        self.nope, self.rope = int(nope), int(rope)
+        self.vd, self.kvr = int(v_dim), int(kv_lora_rank)
+        self.eps, self.scale = float(eps), float(scale)
+        self.inv_freq, self.amp = inv_freq, amp
+        self.q_scale, self.kv_scale = float(q_scale), float(kv_scale)
+        self.q_a = _linear(h, q_lora_rank)
+        self.q_b = _linear(q_lora_rank,
                            self.heads * (self.nope + self.rope))
         self.kv_a = _linear(h, self.kvr + self.rope)
         self.kv_b = _linear(self.kvr, self.heads * (self.nope + self.vd))
         self.o = _linear(self.heads * self.vd, h)
         one = I.Constant(1.0)
-        self.q_a_norm = self.create_parameter([int(cfg.q_lora_rank)],
+        self.q_a_norm = self.create_parameter([int(q_lora_rank)],
                                               default_initializer=one)
         self.kv_a_norm = self.create_parameter([self.kvr],
                                                default_initializer=one)
@@ -356,9 +368,13 @@ class Xing4Attention(nn.Layer):
         pos = _positions(start_pos, b, s)
         cq = _rms(serving_linear(self.q_a, x)._data, self.q_a_norm._data,
                   self.eps)
+        if self.q_scale != 1.0:
+            cq = cq * self.q_scale
         q = serving_linear(self.q_b, Tensor(cq))._data.reshape(
             b, s, heads, nope + self.rope)
         q_nope = q[..., :nope]
+        if self.kv_scale != 1.0:
+            q_nope = q_nope * self.kv_scale
         q_rope = _rotary(q[..., nope:], pos, self.inv_freq, self.amp)
         row = serving_linear(self.kv_a, x)._data
         rows = jnp.concatenate(            # what the cache holds, no more
@@ -382,11 +398,24 @@ class Xing4Attention(nn.Layer):
                 kv=(jnp.concatenate([kv[..., :nope], k_rope], -1),
                     kv[..., nope:]))
         o = _arr(o).astype(dtype).reshape(b, s, heads * self.vd)
+        if self.kv_scale != 1.0:
+            o = o * self.kv_scale
         return serving_linear(self.o, Tensor(o)), new_cache
 
 
+class Xing4Attention(LatentAttention):
+    def __init__(self, cfg: Xing4Config):
+        inv_freq, amp = yarn_frequencies(cfg)
+        super().__init__(
+            cfg.hidden_size, cfg.num_attention_heads, cfg.q_lora_rank,
+            cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim, cfg.rms_norm_eps, softmax_scale(cfg), inv_freq,
+            amp)
+
+
 class Xing4MLP(nn.Layer):
-    """SwiGLU; ``up`` is ``[gate | up]``."""
+    """SwiGLU; ``up`` is ``[gate | up]`` (any model's dense MLP of that
+    form: LongCat-Flash's two a layer too)."""
 
     def __init__(self, hidden: int, width: int):
         super().__init__()

@@ -299,6 +299,64 @@ def test_expert_grouped_matmul_compiles(tokens, monkeypatch):
              _sds((64, 1024, 3584), jnp.bfloat16))
 
 
+# ----------------------------------- the shortcut-connected expert cell
+
+
+def test_latent_decode_compiles_at_64_heads_and_128_lanes():
+    """``serve-agent-scmoe``'s decode shapes: 128 lanes, 64 heads (twice
+    the other latent cell's: the tiles and the float32 softmax state at
+    ``[64, 576]`` queries fit VMEM), 448 blocks of 16 a lane, 20,480
+    blocks. The pool reaches the kernel as it lies."""
+    text = _compile(
+        lambda q, pool, bt, pos, act: pa.paged_latent_decode(
+            q, pool, bt, pos, 512, 192 ** -0.5, active=act),
+        _sds((128, 64, 576), jnp.bfloat16),
+        _sds((20480, 8, 1152), jnp.bfloat16), _sds((128, 448), jnp.int32),
+        _sds((128,), jnp.int32), _sds((128,), jnp.bool_))
+    assert not re.search(r"bf16\[20480,8,1152\][^\n]* (copy|transpose)\(",
+                         text)
+    assert re.search(r"= bf16\[128,64,512\][^\n]* custom-call\(", text)
+
+
+def test_latent_prefill_flash_compiles_at_64_heads():
+    _compile(lambda q, k, v: pa.latent_prefill_attention(q, k, v, 0.1),
+             _sds((7168, 64, 192), jnp.bfloat16),
+             _sds((7168, 64, 192), jnp.bfloat16),
+             _sds((7168, 64, 128), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("tokens, rows", [(128, 64), (256, 128),
+                                          (4096, 2048), (7168, 3584)],
+                         ids=["decode-step", "bucket-256", "bucket-4096",
+                              "bucket-7168"])
+def test_small_share_grouped_matmul_compiles(tokens, rows, monkeypatch):
+    """A sixteenth of a share: 16 held experts of width 2048 among 768
+    columns, 12 a token. A pass of the grouped matmuls takes ``rows`` of
+    the ``12 tokens`` assignments (twice the share's even load) through
+    ``[rows, 6144] x [16, 6144, 4096]`` and ``[rows, 2048] x [16, 2048,
+    6144]``; ``_tiling`` picks a ``tk`` that divides 6144 and 2048, and
+    each row tile fits scoped VMEM with both buffers. The passes run under
+    one ``while`` (their count is the step's own), the kernel inside it."""
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    assert gm.row_cap(12 * tokens, 16, 768) == rows
+    for k, n in ((6144, 4096), (2048, 6144)):
+        tm, tk, tn = gm._tiling(rows, k, n)
+        assert rows % tm == 0 and k % tk == 0 and n % tn == 0
+        assert tk % 128 == 0 and tn % 128 == 0
+        assert 2 * 2 * (tm * tk + tk * tn) + 4 * tm * tn < 16 * 2 ** 20
+    text = _compile(
+        lambda x, idx, w, up, down: gm.expert_ffn(x, idx, w, up, down, 768),
+        _sds((tokens, 6144), jnp.bfloat16), _sds((tokens, 12), jnp.int32),
+        _sds((tokens, 12), jnp.float32),
+        _sds((16, 6144, 4096), jnp.bfloat16),
+        _sds((16, 2048, 6144), jnp.bfloat16))
+    assert " while(" in text
+    # no array of every assignment's row: the largest gather is a pass's
+    assert f"[{12 * tokens},6144]" not in text
+
+
 # ------------------------------------------ the fused hyper-connection
 
 _HC_N, _HC_H = 4, 3584
