@@ -315,7 +315,7 @@ class OlmoHybridForCausalLM(nn.Layer):
             for layer in self.model.layers)
         return ServingSpec(vocab_size=int(c.vocab_size),
                            max_positions=int(c.max_position_embeddings),
-                           layers=layers)
+                           layers=layers, kernels=("gdn_chunk",))
 
     def serving_embed(self, ids, positions):
         return self.model.embed_tokens(ids)  # no positions to add

@@ -226,7 +226,7 @@ class ServingSpec:
     #: Pallas kernels the model's own layers launch (the cache views'
     #: kernels are the engine's): the engine sets the gauge
     #: ``kernel.<name>`` for each, 1 on a TPU, 0 where the interpreter
-    #: runs the kernel's body
+    #: runs the kernel's body or the ``jax.numpy`` route stands in for it
     kernels: Tuple[str, ...] = ()
 
     def kv_layers(self):

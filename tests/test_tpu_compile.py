@@ -436,3 +436,76 @@ def test_hyper_connection_chain_by_the_compilers_own_count(
     kernels = len(re.findall(
         r" (?:fusion|custom-call|copy|convolution|dot)\(", entry))
     assert sublayers <= kernels < 8 * sublayers, kernels
+
+
+# -------------------------------- the chunkwise gated delta rule's kernel
+
+_GDN_H, _GDN_DK, _GDN_DV = 30, 96, 192
+
+
+@pytest.fixture
+def _gdn_route(monkeypatch):
+    """Steers ``ops/gated_delta.py`` onto its kernel (``True``) or its
+    ``jax.numpy`` form; the launch is jitted, so lower it anew."""
+    from paddle_tpu.ops import gated_delta as gd
+
+    def route(kernel: bool):
+        monkeypatch.setattr(gd, "_use_interpret", lambda: not kernel)
+        gd._gdn_call.clear_cache()
+    yield route
+    gd._gdn_call.clear_cache()
+
+
+@pytest.mark.parametrize("positions", [1024, 4096])
+def test_gdn_chunk_compiles_at_the_cells_shapes(positions, _gdn_route):
+    """``serve-doc-hybrid``'s linear-attention layers: 30 heads of ``dk``
+    96 (neither a whole lane tile nor a whole sublane tile of heads) and
+    ``dv`` 192, bf16 operands, the smallest and the largest prefill
+    bucket: Mosaic takes the blocks, the float32 matmuls of the inverse
+    and the state update's transposed product, and the VMEM fits."""
+    from paddle_tpu.ops import gated_delta as gd
+
+    _gdn_route(True)
+    lead = (1, positions, _GDN_H)
+    text = _compile(
+        lambda *a: gd.gated_delta_chunked(*a, mm_dtype=jnp.bfloat16),
+        _sds(lead + (_GDN_DK,), jnp.float32),
+        _sds(lead + (_GDN_DK,), jnp.float32),
+        _sds(lead + (_GDN_DV,), jnp.float32),
+        _sds(lead, jnp.float32), _sds(lead, jnp.float32),
+        _sds((1, _GDN_H, _GDN_DV, _GDN_DK), jnp.float32),
+        _sds((), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%gdn_chunk[.\d]* = ", text)
+
+
+def test_gdn_core_holds_no_more_with_the_kernel(_gdn_route):
+    """Everything of a linear-attention mixer between its projections
+    (``models/olmo_hybrid.py`` ``_gdn_core``) at the 4,096 bucket, by the
+    compiler's own analysis: the kernel's route keeps no more temporaries
+    alive than the ``jax.numpy`` form's (whose float32 chunk arrays it
+    removes), so the largest prefill program does not grow."""
+    import functools
+
+    from paddle_tpu.models.olmo_hybrid import _gdn_core
+
+    t, width = 4096, _GDN_H * (2 * _GDN_DK + _GDN_DV)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (_sds((1, t, width), bf16), _sds((1, t, _GDN_H), bf16),
+            _sds((1, t, _GDN_H), bf16),
+            _sds((1, t, _GDN_H * _GDN_DV), bf16), _sds((4, width), bf16),
+            _sds((_GDN_H,), bf16), _sds((_GDN_H,), bf16),
+            _sds((_GDN_DV,), bf16),
+            _sds((1, _GDN_H, _GDN_DV, _GDN_DK), f32),
+            _sds((1, 3, width), bf16), _sds((), jnp.int32))
+    temps = {}
+    for kernel in (True, False):
+        _gdn_route(kernel)
+        core = functools.partial(  # a new function: jit traces it anew
+            _gdn_core, heads=_GDN_H, dk=_GDN_DK, dv=_GDN_DV, eps=1e-6,
+            beta_scale=2.0)
+        compiled = jax.jit(core).lower(*args).compile()
+        assert bool(re.search(r"%gdn_chunk[.\d]* = ",
+                              compiled.as_text())) == kernel
+        temps[kernel] = compiled.memory_analysis().temp_size_in_bytes
+    assert temps[True] <= temps[False], temps
