@@ -31,6 +31,12 @@ from .phi4flash import (  # noqa: F401
     Phi4FlashModel,
     phi4flash_tiny,
 )
+from .trinity import (  # noqa: F401
+    TrinityConfig,
+    TrinityForCausalLM,
+    TrinityModel,
+    trinity_tiny,
+)
 from .xing4 import (  # noqa: F401
     Xing4Config,
     Xing4ForCausalLM,

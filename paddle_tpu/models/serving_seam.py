@@ -6,8 +6,9 @@ model shares (docs/serving_model_seam.md).
 :class:`~paddle_tpu.models.gpt.GPTForCausalLM`,
 :class:`~paddle_tpu.models.olmo_hybrid.OlmoHybridForCausalLM`,
 :class:`~paddle_tpu.models.phi4flash.Phi4FlashForCausalLM`,
-:class:`~paddle_tpu.models.xing4.Xing4ForCausalLM` and
-:class:`~paddle_tpu.models.longcat_flash.LongcatFlashForCausalLM` do):
+:class:`~paddle_tpu.models.xing4.Xing4ForCausalLM`,
+:class:`~paddle_tpu.models.longcat_flash.LongcatFlashForCausalLM` and
+:class:`~paddle_tpu.models.trinity.TrinityForCausalLM` do):
 
 * ``serving_spec() -> ServingSpec``: vocabulary, longest context, one state
   declaration per layer, in order (the KIND of per-request state that layer
@@ -43,11 +44,13 @@ model shares (docs/serving_model_seam.md).
 head_dim]`` (``heads`` a multiple of ``kv_heads``: query head ``h`` reads
 K/V head ``h // (heads // kv_heads)``); the view owns the paged layout. A
 ``"window"`` layer drives the same call; its view keeps the last ``window``
-tokens' K/V of each lane (a ring per lane in the slot-indexed store: the
-model has no positions, so the order inside the ring is free) and query
-``t`` attends keys ``t - window + 1 .. t``. A ``"shared"`` layer owns no
-cache: its view reads the pool of the layer it names, as that layer left it
-in this same call, through ``view.attend(q)``, and writes nothing. A
+tokens' K/V of each lane (a ring per lane in the slot-indexed store; its
+order is free: a model with positions turns each key at its own position
+before it hands the row over, so a row carries its position in its values)
+and query ``t`` attends keys ``t - window + 1 .. t``. A ``"shared"`` layer
+owns no cache: its view reads the pool of the layer it names, as that
+layer left it in this same call, through ``view.attend(q)``, and writes
+nothing. A
 ``"recurrent"`` layer drives ``view.read() -> state arrays`` (the order of
 its :class:`RecurrentLayerState`), ``view.valid_len`` (None, or the traced
 true length of a padded prefill) and ``view.write(new arrays) -> successor``;

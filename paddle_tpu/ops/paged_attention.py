@@ -77,7 +77,8 @@ if _HAS_PALLAS:
 __all__ = ["available", "decode_in_place", "paged_decode_attention",
            "paged_prefill_attention", "paged_full_prefill_attention",
            "write_token", "paged_latent_decode", "latent_prefill_attention",
-           "latent_pack", "latent_rows", "write_latent_token"]
+           "swa_prefill_attention", "latent_pack", "latent_rows",
+           "write_latent_token"]
 
 
 #: scoped VMEM the kernels may use (the compiler's default, 16 MiB of the
@@ -1083,3 +1084,130 @@ def latent_prefill_attention(q, k, v, scale: float, block: int = 512):
         name="latent_prefill_flash",
     )(qf, kf, vf)[0]
     return jnp.swapaxes(out, 0, 1)
+
+
+# ------------------------------------------------- banded (window) prefill
+#
+# A sliding layer's prompt attention: query ``t`` sees keys ``t - window +
+# 1 .. t``. At a window of 4,096 the XLA form's scores are gigabytes a
+# chunk, so the prompt goes through a flash kernel whose key axis is the
+# BAND: of the ``s / tile`` key tiles a query tile could see, the grid
+# visits the ``ceil((window - 1) / tile) + 1`` that reach into its window
+# (all that lie at or under the diagonal where there is no window), by an
+# index map that counts back from the diagonal tile. A tile before the
+# sequence's start maps to tile 0 and is skipped: the pipeline copies a
+# block only when its index changes, so what is not multiplied is not
+# copied either.
+
+#: rows of a query tile and of a key tile of the banded prefill kernel
+_SWA_BLOCK = 512
+
+
+def _swa_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                        *, scale, blk, band, window):
+    """One (query head, query tile, band step) of
+    :func:`swa_prefill_attention`: ``pallas_ops._flash_fwd_kernel``'s online
+    softmax, the key tile ``band - 1 - j`` tiles under the diagonal one. A
+    row that sees nothing of a tile leaves ``exp(0)`` there under a running
+    maximum of ``NEG_INF``; the diagonal tile, which comes last and holds
+    the row's own key, scales that away (``exp(NEG_INF - m)`` is 0)."""
+    qi, j = pl.program_id(1), pl.program_id(2)
+    kt = qi - (band - 1) + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def step(masked: bool):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if masked:
+            rows = qi * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = kt * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen = cols <= rows
+            if window is not None:
+                seen &= rows - cols < window
+            s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = corr * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    # a tile wholly inside the band needs no mask: every key of it is at
+    # or under every row's own and within its window
+    inside = kt < qi
+    if window is not None:
+        inside &= (qi - kt + 1) * blk - 1 < window
+
+    @pl.when((kt >= 0) & inside)
+    def _whole():
+        step(False)
+
+    @pl.when((kt >= 0) & jnp.logical_not(inside))
+    def _edge():
+        step(True)
+
+    @pl.when(j == band - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[:] / l_scr[:, 0:1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "block"))
+def _swa_call(q, k, v, window, block):
+    """The launch; jitted so that a model's layers of one kind share one
+    lowered kernel inside a prefill program (see :func:`_decode_call`)."""
+    s, h, d = q.shape
+    kvh = k.shape[1]
+    group = h // kvh
+    blk = min(block, -(-s // 8) * 8)
+    n = -(-s // blk)
+    band = n if window is None else min(n, -(-(window - 1) // blk) + 1)
+    pad = ((0, n * blk - s), (0, 0), (0, 0))
+    # head-major copies; the pad rows are keys past every real query and
+    # queries nobody reads
+    qf, kf, vf = (jnp.swapaxes(jnp.pad(a, pad), 0, 1) for a in (q, k, v))
+    key_tile = lambda b, i, j: (b // group,
+                                jnp.maximum(i - (band - 1) + j, 0), 0)
+    out = pl.pallas_call(
+        functools.partial(_swa_prefill_kernel, scale=1.0 / math.sqrt(d),
+                          blk=blk, band=band, window=window),
+        grid=(h, n, band),
+        in_specs=[pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, blk, d), key_tile),
+                  pl.BlockSpec((1, blk, d), key_tile)],
+        out_specs=[pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((h, n * blk, d), q.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+        name="swa_prefill_flash",
+    )(qf, kf, vf)[0]
+    return jnp.swapaxes(out, 0, 1)[:s]
+
+
+def swa_prefill_attention(q, k, v, window=None, block=None):
+    """Attention of one prompt over a window of its own keys: ``q`` ``[s,
+    H, D]``, ``k``, ``v`` ``[s, KVH, D]`` (``H`` a multiple of ``KVH``:
+    query head ``h`` reads K/V head ``h // (H // KVH)`` through the index
+    map, no K/V row repeated in memory); query ``t`` attends keys ``j <=
+    t`` with ``t - j < window`` (the window counts the query's own
+    position; None: every key at or before it), scores over ``sqrt(D)``.
+    Tiles of ``block`` rows (None: :data:`_SWA_BLOCK`; ``s`` is padded to
+    whole tiles); the key tiles outside a query tile's band are neither
+    multiplied nor copied, so a window costs ``O(s window)`` and none
+    ``O(s^2 / 2)``. Returns ``[s, H, D]`` in ``q.dtype``."""
+    return _swa_call(q, k, v, None if window is None else int(window),
+                     int(block or _SWA_BLOCK))

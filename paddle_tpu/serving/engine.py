@@ -327,13 +327,16 @@ class _CapturePrefillView:
     (padded) prompt chunk, returning the chunk's k/v as the successor cache
     so the engine can scatter them into the slot's arena blocks.
 
-    With ``kernel=True`` the attention routes through the Pallas prefill
-    kernel's no-table entry
-    (:func:`paddle_tpu.ops.paged_attention.paged_full_prefill_attention` —
-    the chunk's own K/V viewed as a contiguous pseudo-table, prefix 0), so
-    a kernel-on engine runs ALL of its prefill shapes through the one
-    flash-style kernel; ``kernel=False`` is the original masked_attention
-    path, bit-preserved."""
+    With ``kernel=True`` a whole prompt (every query row present) goes
+    through the flash prefill kernel
+    (:func:`paddle_tpu.ops.paged_attention.swa_prefill_attention` with no
+    window: tiles of up to 512 rows, grouped queries through the index
+    map, the tiles above the diagonal neither multiplied nor copied); on a
+    mesh of several chips through the paged prefill kernel's no-table
+    entry (:func:`~paddle_tpu.ops.paged_attention.paged_full_prefill_attention`,
+    which shards over heads and walks the keys a block of the pool at a
+    time). ``kernel=False`` is the original masked_attention path,
+    bit-preserved."""
 
     def __init__(self, block_size: int = 0, kernel: bool = False,
                  mesh=None, last=None):
@@ -351,11 +354,14 @@ class _CapturePrefillView:
                       for t in (q, k, v))
         captured = _CapturedKV(ka, va, self.last)
         if self.kernel and qa.shape[1] == ka.shape[1]:  # not one row alone
-            from ..ops.paged_attention import paged_full_prefill_attention
+            from ..ops import paged_attention as pa
 
-            o = paged_full_prefill_attention(qa[0], ka[0], va[0],
-                                             self.block_size,
-                                             mesh=self.mesh)[None]
+            if self.mesh is None:
+                o = pa.swa_prefill_attention(qa[0], ka[0], va[0])[None]
+            else:
+                o = pa.paged_full_prefill_attention(
+                    qa[0], ka[0], va[0], self.block_size,
+                    mesh=self.mesh)[None]
             return o, captured
         return captured.attend(qa), captured
 
@@ -507,9 +513,13 @@ class _WindowDecodeView:
     ``[S, kv_heads, window, D]`` K and V rings. The token at position ``p``
     overwrites row ``p % window`` of its lane's ring, which then holds
     positions ``p - window + 1 .. p`` (fewer while the context is shorter:
-    the rows past it are masked). The model has no positions, so the order
-    inside the ring is free. A lane that is not active writes into its own
-    ring, which the prefill that next admits a request to it fills anew.
+    the rows past it are masked). The order inside the ring is free: a
+    softmax over a set of keys does not read their order, and a model with
+    positions (rotary) turns each key at its own position BEFORE the row
+    is written, so a row carries its position in its values, not in its
+    place. A lane that is not active writes into its own ring, which the
+    prefill that next admits a request to it fills anew. The attention is
+    XLA's over the whole ring, ``window`` rows a lane whatever is live.
 
     The ring lies head-major, as the attention reads it (the layout the
     chip's compiler gives a ``[S, window, kv_heads, D]`` ring on its own,
@@ -553,26 +563,56 @@ class _WindowDecodeView:
 
 class _WindowPrefillView:
     """One ``"window"`` layer's prefill view: query ``t`` of the (padded)
-    prompt attends keys ``t - window + 1 .. t``, a chunk of ``window``
-    queries at a time against its own chunk and the one before (blocks
-    wholly outside the window are never computed: the work grows linearly
-    with the prompt), and the last ``window`` rows before ``true_len`` go
-    into lane ``slot``'s ring (``[kv_heads, window, D]``), row ``t`` at
-    ``t % window``."""
+    prompt attends keys ``t - window + 1 .. t``, and the last ``window``
+    rows before ``true_len`` go into lane ``slot``'s ring (``[kv_heads,
+    window, D]``), row ``t`` at ``t % window`` (the keys as the model
+    handed them over: rotated, where it rotates). Key tiles wholly outside
+    the window are never computed, so the work grows linearly with the
+    prompt, by either route: ``kernel=True`` (the engine's prefill route
+    is the kernel, ``ServingConfig.paged_kernel``) through the banded
+    flash kernel
+    (:func:`paddle_tpu.ops.paged_attention.swa_prefill_attention`), which
+    holds a tile of scores at a time; ``kernel=False`` in XLA, a chunk of
+    ``window`` queries at a time against its own chunk and the one before,
+    whose scores ``[heads, window, 2 window]`` are materialised (84 MB at
+    a window of 512, 6.4 GB at 4,096: a model of that window is served
+    with the kernel route)."""
 
-    def __init__(self, entry, slot, true_len, window: int):
+    def __init__(self, entry, slot, true_len, window: int,
+                 kernel: bool = False):
         self.entry = entry
         self.slot = slot          # scalar int32: the lane being admitted
         self.true_len = true_len  # scalar int32: real (unpadded) length
         self.window = int(window)
+        self.kernel = kernel
 
     def update_and_attend(self, q, k, v):
         import jax.numpy as jnp
 
-        from ..models.serving_seam import masked_attention
-
         qa, ka, va = (t._data if isinstance(t, Tensor) else t
                       for t in (q, k, v))
+        if self.kernel:
+            from ..ops.paged_attention import swa_prefill_attention
+
+            o = swa_prefill_attention(qa[0], ka[0], va[0], self.window)[None]
+        else:
+            o = self._attend_chunks(qa, ka, va)
+        # ring row r takes the last position t < true_len with t % w == r
+        w, last = self.window, self.true_len - 1
+        t_r = last - (last - jnp.arange(w)) % w
+        entry = tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                ring, jnp.swapaxes(new[0][jnp.maximum(t_r, 0)], 0, 1)[None]
+                .astype(ring.dtype), self.slot, axis=0)
+            for ring, new in zip(self.entry, (ka, va)))
+        return o, _WindowPrefillView(entry, self.slot, self.true_len, w,
+                                     kernel=self.kernel)
+
+    def _attend_chunks(self, qa, ka, va):
+        import jax.numpy as jnp
+
+        from ..models.serving_seam import masked_attention
+
         w, p = self.window, qa.shape[1]
         n = -(-p // w)
 
@@ -594,16 +634,7 @@ class _WindowPrefillView:
 
         o = jax.lax.map(one, (chunks(qa, 0), chunks(ka, w), chunks(ka, 0),
                               chunks(va, w), chunks(va, 0), jnp.arange(n)))
-        o = o.reshape((1, n * w) + o.shape[2:])[:, :p]
-        # ring row r takes the last position t < true_len with t % w == r
-        last = self.true_len - 1
-        t_r = last - (last - jnp.arange(w)) % w
-        entry = tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                ring, jnp.swapaxes(new[0][jnp.maximum(t_r, 0)], 0, 1)[None]
-                .astype(ring.dtype), self.slot, axis=0)
-            for ring, new in zip(self.entry, (ka, va)))
-        return o, _WindowPrefillView(entry, self.slot, self.true_len, w)
+        return o.reshape((1, n * w) + o.shape[2:])[:, :p]
 
 
 class _LatentDecodeView:
@@ -806,9 +837,14 @@ class ServingConfig:
     # K/V through the block tables with the paged decode kernel where it
     # compiles natively (a TPU backend) and reads the pools where they
     # lie (head_dim a multiple of 128), and take the XLA gather where it
-    # would run interpreted (CPU); prefill keeps the XLA path. True: every
-    # kernel route (decode, prefill, suffix/chunked prefill), raising when
-    # Pallas is missing. False: XLA everywhere. Captured at construction
+    # would run interpreted (CPU); prefill keeps the XLA path, whose scores
+    # are materialised ([heads, s, s] for a "kv" layer, [heads, window,
+    # 2 window] a chunk for a "window" layer: a model whose prompts or
+    # window make that gigabytes asks for True). True: every kernel route
+    # (decode; prefill: the flash kernel over whole tiles for "kv" and
+    # "window" layers alike, banded for the latter; suffix/chunked
+    # prefill through the block tables), raising when Pallas is missing.
+    # False: XLA everywhere. Captured at construction
     # like the quant trio — part of the engine's program key; the route
     # the decode step was built with is `kernel.paged` / kernel_route().
     paged_kernel: Optional[bool] = None
@@ -1473,8 +1509,9 @@ class ServingEngine:
                     views.append(_SlotStatePrefillView(next(it_rec), slot,
                                                        true_len))
                 elif st.kind == "window":
-                    views.append(_WindowPrefillView(next(it_rec), slot,
-                                                    true_len, st.window))
+                    views.append(_WindowPrefillView(
+                        next(it_rec), slot, true_len, st.window,
+                        kernel=use_kernel))
                 elif st.kind == "latent":
                     views.append(_LatentPrefillView(latent_kernel))
                 else:

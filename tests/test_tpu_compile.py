@@ -357,6 +357,51 @@ def test_small_share_grouped_matmul_compiles(tokens, rows, monkeypatch):
     assert f"[{12 * tokens},6144]" not in text
 
 
+
+# ------------------------------------ the window-and-experts cell (Trinity)
+
+
+@pytest.mark.parametrize("s", [1024, 16384])
+@pytest.mark.parametrize("window", [4096, None], ids=["banded", "causal"])
+def test_swa_prefill_flash_compiles(s, window):
+    """``serve-mixed-swa-moe``'s prefill attention at its smallest and its
+    largest bucket: 48 query heads over 8 K/V heads of 128 through the
+    index map, tiles of 512, the sliding layers' band of 9 key tiles and
+    the full layer's causal half."""
+    text = _compile(lambda q, k, v: pa.swa_prefill_attention(q, k, v, window),
+                    _sds((s, 48, 128), jnp.bfloat16),
+                    _sds((s, 8, 128), jnp.bfloat16),
+                    _sds((s, 8, 128), jnp.bfloat16))
+    assert "swa_prefill_flash" in text
+
+
+@pytest.mark.parametrize("tokens, rows", [(32, 64), (1024, 1024),
+                                          (16384, 16384)],
+                         ids=["decode-step", "bucket-1024", "bucket-16384"])
+def test_eighth_share_grouped_matmul_compiles(tokens, rows, monkeypatch):
+    """An eighth of the experts: 32 held of 256 routed, width 3072, 4 a
+    token: ``[rows, 3072] x [32, 3072, 6144]`` and ``[rows, 3072] x [32,
+    3072, 3072]``, a pass taking ``rows`` of the ``4 tokens`` assignments;
+    ``_tiling`` gives whole tiles (``tk`` 1536 for a decode step, 768
+    from 2,048 rows on, ``tn`` 1024) that fit scoped VMEM with both
+    buffers."""
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    assert gm.row_cap(4 * tokens, 32, 256) == rows
+    for k, n in ((3072, 6144), (3072, 3072)):
+        tm, tk, tn = gm._tiling(rows, k, n)
+        assert k % tk == 0 and n % tn == 0 and tn == 1024
+        assert tk == (1536 if rows < 2048 else 768)
+        assert 2 * 2 * (tm * tk + tk * tn) + 4 * tm * tn < 16 * 2 ** 20
+    _compile(
+        lambda x, idx, w, up, down: gm.expert_ffn(x, idx, w, up, down, 256),
+        _sds((tokens, 3072), jnp.bfloat16), _sds((tokens, 4), jnp.int32),
+        _sds((tokens, 4), jnp.float32),
+        _sds((32, 3072, 6144), jnp.bfloat16),
+        _sds((32, 3072, 3072), jnp.bfloat16))
+
+
 # ------------------------------------------ the fused hyper-connection
 
 _HC_N, _HC_H = 4, 3584
