@@ -279,7 +279,13 @@ def write_token(pool, blocks, offsets, rows):
     copies to a token-major layout and back to make it so (24 ms a decode
     step at Olmo-Hybrid's shapes), so there each head's row is scattered
     on its own into the :func:`_page_slabs` view, where one row is
-    minor-most. Returns the pool, same shape."""
+    minor-most. Returns the pool, same shape. A prefill's write is the
+    sibling ``serving.engine._scatter_blocks``, and the two differ because
+    what they write does: here one row a lane, in as many blocks as lanes,
+    so the window can only be made minor by the view; there whole blocks
+    of one lane, a window that covers every minor dimension under either
+    layout (and the swapped view would cost a token-major pool two
+    copies)."""
     nb, bs, h, d = pool.shape
     if not _head_major(h):  # the window is minor already: 4 us a pool
         return pool.at[blocks, offsets].set(rows)

@@ -67,10 +67,11 @@ double-buffering what is by far the engine's largest allocation.
 docs/quantization.md) rides the same data path: weights stream int8 and
 dequantize in-kernel (:func:`paddle_tpu.models.serving_seam.serving_linear`),
 the KV arena stores int8 with per-block scale pools carried inside every
-pool entry (quantize-on-scatter in :func:`_scatter_rows`,
-dequant-on-attend in :func:`_gather_ctx`), and each mode is captured at
-construction as part of the engine's program key exactly like the
-donation flag. All default off — the unquantized path is bit-identical.
+pool entry (quantize-on-scatter in :func:`_scatter_rows` and
+:func:`_scatter_blocks`, dequant-on-attend in :func:`_gather_ctx`), and
+each mode is captured at construction as part of the engine's program key
+exactly like the donation flag. All default off — the unquantized path is
+bit-identical.
 
 **Scenario diversity** (ISSUE 12) rides the same runtime-data contract:
 per-slot sampling params + positional PRNG seeds
@@ -173,6 +174,50 @@ def _scatter_rows(entry, row, off, kc, vc):
     qv, sv = quantize_kv(vc)
     return (kp.at[row, off].set(qk), vp.at[row, off].set(qv),
             ks.at[row, off].set(sk), vs.at[row, off].set(sv))
+
+
+def _scatter_blocks(entry, table_rows, true_len, kc, vc, block_size: int):
+    """A whole prompt's k/v ``[p, H, D]`` into the slot's blocks
+    (``table_rows``: the block of each ``block_size`` positions), in
+    whole BLOCKS: the scatter's window then covers every minor dimension
+    of a pool, so the chip writes it in place whichever way it lays the
+    pool out (a window of one position's ``(H, D)`` is not minor in a
+    head-major pool, ``paged_attention._head_major``, and
+    :func:`_scatter_rows` there costs three copies of the whole pool). A
+    full prefill starts at position 0 of blocks the slot owns alone. A
+    block whose first position is at or past ``true_len`` is padding and
+    lands in scratch block 0; the one that straddles ``true_len`` carries
+    padded positions behind real ones, each of which the decode step that
+    writes that position (``write_token``) replaces before any mask lets
+    it be read (``<= pos``). A chunk that is not a whole number of blocks
+    is padded up to one, behind ``true_len``. An int8 ``(k, v, k_scale,
+    v_scale)`` entry quantizes as :func:`_scatter_rows` does, payload and
+    scale pools written by the same block ids."""
+    import jax.numpy as jnp
+
+    n_blk = _ceil_div(kc.shape[0], block_size)
+    blk = jnp.where(jnp.arange(n_blk) * block_size < true_len,
+                    table_rows[:n_blk], 0)
+    pad = ((0, n_blk * block_size - kc.shape[0]), (0, 0), (0, 0))
+    kc, vc = jnp.pad(kc, pad), jnp.pad(vc, pad)
+    if len(entry) == 4:
+        from ..quantization import quantize_kv
+
+        (kc, sk), (vc, sv) = quantize_kv(kc), quantize_kv(vc)
+        chunks = (kc, vc, sk, sv)
+    else:
+        chunks = (kc, vc)
+
+    def write(pool, chunk):
+        chunk = chunk.reshape((n_blk, block_size) + chunk.shape[1:])
+        if pool.ndim == 2:
+            # a scale pool's row is narrower than a lane tile: to scatter
+            # whole rows the compiler transposes the pool and back, so
+            # each scale goes where it lies, by the same block ids
+            return pool.at[blk[:, None], jnp.arange(block_size)].set(chunk)
+        return pool.at[blk].set(chunk)
+
+    return tuple(write(p, c) for p, c in zip(entry, chunks))
 
 
 @jax.named_scope("kv_gather")  # metadata on the device's operations
@@ -325,7 +370,8 @@ class _PagedReadView:
 class _CapturePrefillView:
     """Prefill-side cache protocol object: plain causal attention over the
     (padded) prompt chunk, returning the chunk's k/v as the successor cache
-    so the engine can scatter them into the slot's arena blocks.
+    so the engine can write them into the slot's arena blocks, in whole
+    blocks (:func:`_scatter_blocks`).
 
     With ``kernel=True`` a whole prompt (every query row present) goes
     through the flash prefill kernel
@@ -368,8 +414,9 @@ class _CapturePrefillView:
 
 class _CapturedKV:
     """What a ``"kv"`` layer's prefill leaves behind: the chunk's K and V,
-    which the engine scatters into the slot's blocks, and which a
-    ``"shared"`` layer of the same call reads (:meth:`reader`)."""
+    which the engine writes into the slot's blocks, a whole block at a
+    time (:func:`_scatter_blocks`), and which a ``"shared"`` layer of the
+    same call reads (:meth:`reader`)."""
 
     def __init__(self, ka, va, last=None):
         self.k, self.v, self.last = ka, va, last
@@ -1462,8 +1509,6 @@ class ServingEngine:
         fn = self._prefill_jits.get(p_bucket)
         if fn is not None:
             return fn
-        import jax.numpy as jnp
-
         from ..core import rng as prng
         from ..jit import _swap_data
         from ..models.serving_seam import (PAGED_KINDS, SLOT_KINDS,
@@ -1534,20 +1579,17 @@ class ServingEngine:
                       if k in PAGED_KINDS]
             new_rec = [v.entry for v, k in zip(new_views, kinds)
                        if k in SLOT_KINDS]
-            p_idx = jnp.arange(p_bucket)
-            row = rows[p_idx // bs]
-            # padded positions (>= the true prompt length) scatter into the
-            # scratch block: bucketing never pollutes live cache state
-            row = jnp.where(p_idx < true_len, row, 0)
-            off = p_idx % bs
+            # blocks (pool rows, for a latent layer) wholly past the true
+            # prompt length land in the scratch block: bucketing never
+            # pollutes another lane's cache state
             new_pools = []
             for chunk, entry in zip(chunks, pools):
                 if isinstance(chunk, _LatentPrefillView):
                     new_pools.append(_scatter_latent(
                         entry, rows, true_len, chunk.rows[0], bs))
                     continue
-                new_pools.append(
-                    _scatter_rows(entry, row, off, chunk.k[0], chunk.v[0]))
+                new_pools.append(_scatter_blocks(
+                    entry, rows, true_len, chunk.k[0], chunk.v[0], bs))
             # the first generated token goes through the SAME sampling
             # core as the decode step ([1, V] and [S, V] rows are
             # bit-identical per row); greedy/unmasked slots reproduce
@@ -2404,6 +2446,11 @@ class ServingEngine:
                   self._samp_row(slot, clen), jnp.int32(slot))
             lora = self._lora_args(slot)
             self._count_prefill_call(p_bucket, clen, up, lora[1:])
+            if not self.latent:
+                # every array of every "kv" layer's entry, in whole
+                # blocks (:func:`_scatter_blocks`)
+                metrics.bump("prefill.block_writes",
+                             sum(len(e) for e in self.arena.pools))
         with telemetry.phase("prefill.dispatch", self.hists):
             fn = self._get_prefill(p_bucket)
             out = self._call(

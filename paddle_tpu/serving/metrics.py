@@ -235,8 +235,9 @@ DOCUMENTED_NAMESPACES = (
     # (tail_tokens: one a prefill where a model declares a tail) —
     # docs/observability.md, docs/serving_model_seam.md "The prefill tail";
     # (ISSUE 37) compiled prefill calls, the positions they computed
-    # (positions_computed: the buckets), upload_bytes, lane_us_blocked —
-    # docs/observability.md "An admission, from inside"
+    # (positions_computed: the buckets), upload_bytes, lane_us_blocked;
+    # (ISSUE 45) block_writes: pool arrays a full prefill wrote in whole
+    # blocks — docs/observability.md "An admission, from inside"
     "prefill",
     "queue", "slots", "tokens_per_sec",
 )

@@ -264,12 +264,11 @@ class SpecDecoder:
         if fn is not None:
             return fn
         import jax
-        import jax.numpy as jnp
 
         from ..core import rng as prng
         from ..jit import _swap_data
         from ..models.serving_seam import forward_cached
-        from .engine import _CapturePrefillView, _scatter_rows
+        from .engine import _CapturePrefillView, _scatter_blocks
 
         draft = self.draft
         n_layers = len(draft.serving_spec().layers)
@@ -285,15 +284,9 @@ class SpecDecoder:
             with _swap_data(self._d_objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     _, chunks = forward_cached(draft, Tensor(ids), views, 0)
-            p_idx = jnp.arange(p_bucket)
-            row = rows[p_idx // bs]
-            row = jnp.where(p_idx < true_len, row, 0)
-            off = p_idx % bs
-            new_pools = []
-            for chunk, entry in zip(chunks, pools):
-                new_pools.append(
-                    _scatter_rows(entry, row, off, chunk.k[0], chunk.v[0]))
-            return new_pools
+            return [_scatter_blocks(entry, rows, true_len, chunk.k[0],
+                                    chunk.v[0], bs)
+                    for chunk, entry in zip(chunks, pools)]
 
         fn = (jax.jit(draft_prefill, donate_argnums=(3,))
               if self.engine.donate else jax.jit(draft_prefill))

@@ -20,6 +20,7 @@ import os
 import queue as pyqueue
 import time
 import weakref
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -1151,3 +1152,141 @@ def test_drain_completes_in_flight_within_grace(model):
             api.submit(p, max_new_tokens=2)
     finally:
         api.close()
+
+
+# ------------------------------------------- a prefill's whole-block write
+
+
+def _row_scatter_reference(entry, table_rows, true_len, kc, vc, block_size):
+    """What the full prefill did before it wrote whole blocks: one
+    ``(block, offset)`` pair a POSITION, the padded ones to scratch block
+    0 (``_scatter_rows``, which suffix prefills and the decode step still
+    use). Same signature as ``_scatter_blocks``: the reference here."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.engine import _scatter_rows
+
+    p_idx = jnp.arange(kc.shape[0])
+    row = jnp.where(p_idx < true_len, table_rows[p_idx // block_size], 0)
+    return _scatter_rows(entry, row, p_idx % block_size, kc, vc)
+
+
+@pytest.mark.parametrize("p", [64, 44], ids=["whole_blocks", "ragged"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_scatter_blocks_matches_row_scatter(quantized, p):
+    """``_scatter_blocks`` against the row scatter on pools full of noise
+    (block 8, 3 heads of 4; a chunk of ``p`` positions of which 37 are
+    real, so block 4 of the chunk straddles ``true_len`` and the rest is
+    padding; 44 is not a whole number of blocks): every real position bit
+    for bit, payload and scales; nothing outside the slot's own blocks
+    and scratch block 0 touched, not even the blocks the table names for
+    the wholly padded part."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.engine import _scatter_blocks
+
+    rng = np.random.default_rng(p + quantized)
+    nb, bs, h, d, true_len = 24, 8, 3, 4, 37
+    if quantized:
+        entry = tuple(jnp.asarray(rng.integers(-127, 127, (nb, bs, h, d)),
+                                  jnp.int8) for _ in range(2))
+        entry += tuple(jnp.asarray(rng.random((nb, bs)), jnp.float32)
+                       for _ in range(2))
+    else:
+        entry = tuple(jnp.asarray(rng.standard_normal((nb, bs, h, d)),
+                                  jnp.float32) for _ in range(2))
+    kc, vc = (jnp.asarray(rng.standard_normal((p, h, d)), jnp.float32)
+              for _ in range(2))
+    # a table row for EVERY block of the chunk, the padded ones too
+    table = rng.permutation(np.arange(1, nb))[:-(-p // bs)].astype(np.int32)
+    args = (jnp.asarray(table), jnp.int32(true_len), kc, vc, bs)
+    got = [np.asarray(a) for a in _scatter_blocks(entry, *args)]
+    ref = [np.asarray(a) for a in _row_scatter_reference(entry, *args)]
+    was = [np.asarray(a) for a in entry]
+    pos = np.arange(true_len)
+    own = table[:-(-true_len // bs)]
+    others = np.setdiff1d(np.arange(1, nb), own)
+    assert len(got) == len(entry)
+    for g, r, w in zip(got, ref, was):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g[table[pos // bs], pos % bs],
+                                      r[table[pos // bs], pos % bs])
+        np.testing.assert_array_equal(g[others], w[others])
+    # the reference leaves the straddling block's tail as it was; the
+    # block write does not, and nothing reads it (below)
+    tail = (own[-1], slice(true_len % bs, None))
+    np.testing.assert_array_equal(ref[0][tail], was[0][tail])
+    assert not np.array_equal(got[0][tail], was[0][tail])
+
+
+@pytest.mark.parametrize("quant_kv", [False, True], ids=["f32", "int8"])
+def test_block_prefill_under_a_padded_bucket(model, monkeypatch, quant_kv):
+    """A 37-token prompt (block 8: 4 whole blocks and 5 positions of a
+    fifth) admitted under a bucket of 64 beside a running lane: its
+    positions lie in the pool bit for bit as the row scatter lays them,
+    the other lane's blocks and every free block are as they were (the
+    three wholly padded blocks of the chunk went to scratch block 0),
+    and 20 decode steps, which cross the straddling block's end and two
+    more, give the tokens of the same prompt under a bucket that fits it
+    exactly and under the row scatter."""
+    from paddle_tpu.serving import engine as engine_mod
+
+    rng = np.random.default_rng(45)
+    first, prompt = _prompt(rng, 10), _prompt(rng, 37)
+
+    def serve(bucket_min):
+        eng = ServingEngine(model, num_slots=2, kv_block_size=8,
+                            max_model_len=MAX_LEN, quant_kv=quant_kv,
+                            prefill_bucket_min=bucket_min)
+        other, _ = eng.admit(first, max_new_tokens=40)
+        before = [np.asarray(a) for e in eng.arena.pools for a in e]
+        c0 = serving_metrics.stats().get("prefill.block_writes", 0)
+        slot, tok = eng.admit(prompt, max_new_tokens=24)
+        writes = serving_metrics.stats().get("prefill.block_writes", 0) - c0
+        after = [np.asarray(a) for e in eng.arena.pools for a in e]
+        toks = [tok] + [int(eng.decode_step()[slot]) for _ in range(20)]
+        return SimpleNamespace(
+            eng=eng, before=before, after=after, toks=toks, writes=writes,
+            own=eng._bt_host[slot, :5].copy(),
+            other=eng._bt_host[other, :2].copy())
+
+    pos = np.arange(37)
+    got = serve(64)
+    assert list(got.eng.prefill_traces) == [64]
+    assert got.writes == len(got.after) == (4 if quant_kv else 2) * len(
+        got.eng.arena.pools)
+    assert (got.own > 0).all()
+    untouched = np.setdiff1d(np.arange(1, got.eng.arena.num_blocks), got.own)
+    assert set(got.other) <= set(untouched)
+    for b, a in zip(got.before, got.after):
+        np.testing.assert_array_equal(a[untouched], b[untouched])
+
+    fit = serve(37)
+    assert list(fit.eng.prefill_traces) == [37] and fit.toks == got.toks
+
+    monkeypatch.setattr(engine_mod, "_scatter_blocks",
+                        _row_scatter_reference)
+    ref = serve(64)
+    assert ref.writes == got.writes  # the engine's count, not the write's
+    for a, r, b in zip(got.after, ref.after, ref.before):
+        np.testing.assert_array_equal(a[got.own[pos // 8], pos % 8],
+                                      r[ref.own[pos // 8], pos % 8])
+        # the reference it is: the straddling block's tail as it was
+        np.testing.assert_array_equal(r[ref.own[4], 5:], b[ref.own[4], 5:])
+    assert ref.toks == got.toks
+
+
+def test_prefill_block_writes_counts_pools_an_admission(api):
+    """``prefill.block_writes``: K and V of every layer, once a full
+    prefill call, whatever the prompt's length."""
+    pools = sum(len(e) for e in api.engine.arena.pools)
+    assert pools == 2 * len(api.engine.arena.pools) > 0
+    rng = np.random.default_rng(7)
+    api.run_until_idle()
+    before = serving_metrics.stats()
+    for n in (3, 20):
+        api.submit(_prompt(rng, n), max_new_tokens=2)
+    api.run_until_idle()
+    moved = serving_metrics.stats_delta(before, serving_metrics.stats())
+    assert moved["prefill.calls"] == 2
+    assert moved["prefill.block_writes"] == 2 * pools

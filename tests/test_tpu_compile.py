@@ -84,10 +84,10 @@ def _compile(fn, *args):
     return text
 
 
-def _entry(h, d, quantized):
+def _entry(h, d, quantized, nb=NB):
     dt = jnp.int8 if quantized else jnp.bfloat16
-    pools = (_sds((NB, BS, h, d), dt),) * 2
-    return pools + ((_sds((NB, BS), jnp.float32),) * 2 if quantized else ())
+    pools = (_sds((nb, BS, h, d), dt),) * 2
+    return pools + ((_sds((nb, BS), jnp.float32),) * 2 if quantized else ())
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -122,6 +122,34 @@ def test_paged_decode_compiles_at_the_cells_shapes(hq, h, mb, nb):
     assert f"[{S},{mb * BS},{h},128]" not in text
     assert not re.search(rf"= bf16\[{nb},[0-9,]*128\]\S* (copy|transpose)\(",
                          text)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("nb,h,p", [(2730, 16, 2048), (2560, 30, 4096),
+                                    (16384, 10, 8192), (24576, 8, 16384)],
+                         ids=["serve-batch-long", "serve-doc-hybrid",
+                              "serve-reason-flash", "serve-mixed-swa-moe"])
+def test_prefill_pool_write_is_in_place_at_the_cells_shapes(nb, h, p,
+                                                            quantized):
+    """A prefill's write of its chunk into the donated pool entry
+    (``engine._scatter_blocks``) at the four cells' pool shapes, two of
+    them head-major on the chip (30 and 10 heads): no ``copy`` or
+    ``transpose`` with the pool's leading dimension (``[NB, ...]`` or its
+    ``[NB*16, ...]`` view) is in the program, payload or scale pool. The
+    row scatter (``_scatter_rows``) has six a head-major entry."""
+    from paddle_tpu.serving.engine import _scatter_blocks
+
+    entry = _entry(h, 128, quantized, nb)
+    kv = _sds((p, h, 128), jnp.bfloat16)
+    text = jax.jit(
+        lambda entry, rows, true_len, k, v: _scatter_blocks(
+            entry, rows, true_len, k, v, BS),
+        donate_argnums=(0,)).lower(
+            entry, _sds((p // BS,), jnp.int32), _sds((), jnp.int32), kv,
+            kv).compile().as_text()
+    assert "scatter" in text
+    assert not re.search(rf"= \w+\[({nb}|{nb * BS})[0-9,]*\]\S* "
+                         r"(copy|transpose)\(", text)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
