@@ -67,7 +67,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .pallas_ops import (NEG_INF, _HAS_PALLAS, _LANES, _flash_fwd_kernel,
+from .pallas_ops import (NEG_INF, _HAS_PALLAS, _LANES, _causal_mask,
                          _use_interpret)
 
 if _HAS_PALLAS:
@@ -1059,12 +1059,58 @@ def paged_latent_decode(q, pool, block_tables, positions, value_dim: int,
                         int(value_dim), float(scale), pages or 0)
 
 
+def _latent_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                           *, scale, blk):
+    """One (head, qi, ki) step of the latent prefill: the online softmax of
+    ``pallas_ops._flash_fwd_kernel`` as it stood before that kernel's tiles
+    went by ``_causal_tile`` (every tile at or under the diagonal one
+    masked step, the tiles above it skipped), kept apart so that a change
+    to the training kernel moves no served token (PERF.md section 6,
+    PR 46)."""
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki <= qi)
+    def _step():
+        q = q_ref[0]  # [blk, d]
+        k = k_ref[0]
+        v = v_ref[0]  # [blk, dv]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [blk, blk]
+        s = _causal_mask(s, (qi - ki) * blk)
+        m_prev = m_scr[:, 0:1]  # [blk, 1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = correction * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [blk, dv]
+        acc_scr[:] = acc_scr[:] * correction + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        denom = l_scr[:, 0:1]
+        denom = jnp.where(denom == 0.0, 1.0, denom)
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+
 def latent_prefill_attention(q, k, v, scale: float, block: int = 512):
     """Causal attention of one prompt with keys wider than values (the
     EXPANDED form of latent attention: ``q``, ``k`` ``[s, H, 192]``, ``v``
-    ``[s, H, 128]``): the flash forward kernel of ``ops/pallas_ops.py``
-    (:func:`_flash_fwd_kernel`, whose body takes any widths) over
-    head-major copies, the blocks above the diagonal skipped. ``s`` is a
+    ``[s, H, 128]``): a flash forward kernel (:func:`_latent_prefill_kernel`)
+    over head-major copies, the blocks above the diagonal skipped. ``s`` is
     cut into the largest blocks of at most ``block`` rows, a multiple of
     128, that divide it (one block where none does). Returns ``[s, H, v
     width]``."""
@@ -1073,8 +1119,7 @@ def latent_prefill_attention(q, k, v, scale: float, block: int = 512):
     blk = next((b for b in range(block, 0, -_LANES) if s % b == 0), s)
     qf, kf, vf = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))
     out = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, scale=float(scale), causal=True,
-                          blk_q=blk, blk_k=blk, offset=0, with_lse=False),
+        functools.partial(_latent_prefill_kernel, scale=float(scale), blk=blk),
         grid=(h, s // blk, s // blk),
         in_specs=[pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0)),
                   pl.BlockSpec((1, blk, d), lambda b, i, j: (b, j, 0)),

@@ -81,6 +81,138 @@ def test_flash_backward_causal_shorter_kv():
                                    err_msg=f"d{name} mismatch")
 
 
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+#: id: (sq, sk, key width, value width, blk_q, blk_k, causal, dtype, the
+#: fused backward's budget or None for the module's). Tiles in a 2 x 2 or
+#: 4 x 4 grid hold one wholly under the diagonal (one unmasked step),
+#: diagonal ones (masked; by rows of sub-blocks, 128 rows here, where a
+#: square tile holds four or more a side, nothing right of the diagonal
+#: sub-block) and ones above it (skipped, not copied).
+_PAIR_CASES = {
+    "causal-2x2-tiles-of-4x4-sub-blocks": (1024, 1024, 64, 64, 512, 512, True, F32, None),
+    "causal-one-tile-of-4x4-sub-blocks-bf16": (512, 512, 128, 128, 512, 512, True, BF16, None),
+    "causal-2x2-masked-diagonal-tiles": (512, 512, 64, 64, 256, 256, True, F32, None),
+    "causal-4x4": (512, 512, 64, 64, 128, 128, True, F32, None),
+    "causal-4x4-head128-bf16": (512, 512, 128, 128, 128, 128, True, BF16, None),
+    "causal-blk_q-over-blk_k": (512, 512, 64, 64, 256, 128, True, F32, None),
+    "causal-blk_q-under-blk_k": (512, 512, 128, 128, 128, 256, True, F32, None),
+    "causal-longer-kv-sub-blocks": (512, 1024, 64, 64, 512, 512, True, F32, None),
+    "causal-longer-kv": (256, 512, 64, 64, 128, 256, True, F32, None),
+    "causal-longer-kv-off-the-tiles": (256, 384, 64, 64, 256, 128, True, BF16, None),
+    "causal-shorter-kv-masked-rows": (512, 256, 64, 64, 256, 128, True, F32, None),
+    "causal-shorter-kv-sub-blocks": (1024, 512, 64, 64, 512, 512, True, F32, None),
+    "full": (256, 256, 64, 64, 128, 128, False, F32, None),
+    "full-longer-kv-bf16": (256, 512, 128, 128, 128, 256, False, BF16, None),
+    "split-route-causal-sub-blocks": (512, 512, 64, 64, 512, 512, True, F32, 0),
+    "split-route-causal-shorter-kv": (512, 256, 64, 64, 128, 128, True, F32, 0),
+    "split-route-full": (256, 256, 128, 128, 128, 128, False, F32, 0),
+    "fused-route-at-its-budget": (256, 256, 64, 64, 128, 128, True, F32,
+                                  256 * 64 * 12),
+    "split-route-a-byte-under": (256, 256, 64, 64, 128, 128, True, F32,
+                                 256 * 64 * 12 - 1),
+    "keys-192-values-128-2x2-tiles": (512, 512, 192, 128, 256, 256, True, F32, None),
+    "keys-192-values-128-bf16": (512, 512, 192, 128, 128, 128, True, BF16, None),
+}
+
+
+@pytest.fixture
+def sub_blocks_of_128(monkeypatch):
+    """``_DIAG_SUB`` = 128, so that a 512-row tile goes by sub-block rows.
+    The launches are jitted: trace them anew, and let no later test meet
+    these traces."""
+    monkeypatch.setattr(po, "_DIAG_SUB", 128)
+    po._forward_call.clear_cache(), po._backward_call.clear_cache()
+    yield
+    po._forward_call.clear_cache(), po._backward_call.clear_cache()
+
+
+@pytest.mark.parametrize("case", list(_PAIR_CASES), ids=list(_PAIR_CASES))
+def test_flash_pair_matches_reference(case, monkeypatch, sub_blocks_of_128):
+    """The causal pair (``_causal_tile``: unmasked tiles under the diagonal,
+    sub-blocks of a diagonal tile, nothing above it) and the ONE backward
+    kernel against ``_attention_reference``: values and all three
+    gradients; keys wider than values forward only (the latent prefill,
+    which keeps a body of its own). ``flash.bwd_fused`` / ``flash.bwd_split`` say
+    which backward was traced: the route follows dQ's bytes."""
+    from paddle_tpu.core import compile_cache
+    from paddle_tpu.ops import paged_attention as pa
+
+    sq, sk, dk, dv, blk_q, blk_k, causal, dtype, budget = _PAIR_CASES[case]
+    if budget is not None:
+        monkeypatch.setattr(po, "_FUSED_BWD_VMEM", budget)
+    rng = np.random.RandomState(len(case))
+    q, k, v = (jnp.asarray(rng.standard_normal((1, s, 2, d)), dtype)
+               for s, d in ((sq, dk), (sk, dk), (sk, dv)))
+    scale = 1.0 / np.sqrt(dk)
+    tol = dict(rtol=2e-3, atol=2e-3) if dtype == F32 else \
+        dict(rtol=3e-2, atol=3e-2)
+    gtol = dict(rtol=5e-2, atol=5e-2) if dtype == F32 else \
+        dict(rtol=1e-1, atol=1e-1)
+    valid = min(sq, sk)  # rows sq - valid .. see a key; earlier ones none
+    f32 = [t.astype(F32) for t in (q, k, v)]
+
+    if dk != dv:
+        assert blk_q == blk_k
+        got = pa.latent_prefill_attention(q[0], k[0], v[0], scale,
+                                          block=blk_q)[None]
+        want = po._attention_reference(*f32, scale, causal)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), **tol)
+        return
+
+    def loss(attn, rows):
+        # the plain softmax is 0/0 on rows that see no key; the kernel
+        # gives them 0, so summing its every row is summing the valid ones
+        return lambda q, k, v: (attn(q, k, v)[:, rows].astype(F32) ** 2).sum()
+
+    flash = lambda q, k, v: po._flash_attention(q, k, v, scale, causal,
+                                                blk_q, blk_k)
+    ref = lambda q, k, v: po._attention_reference(q, k, v, scale, causal)
+    before = compile_cache.stats()
+    got, g1 = jax.value_and_grad(loss(flash, slice(None)),
+                                 argnums=(0, 1, 2))(q, k, v)
+    moved = compile_cache.stats_delta(before, compile_cache.stats(),
+                                      drop_zero=True)
+    fused = sq * dk * (4 + 2 * q.dtype.itemsize) <= po._FUSED_BWD_VMEM
+    assert moved.get("flash.bwd_fused", 0) == int(fused)
+    assert moved.get("flash.bwd_split", 0) == int(not fused)
+
+    want, g2 = jax.value_and_grad(loss(ref, slice(sq - valid, None)),
+                                  argnums=(0, 1, 2))(*f32)
+    out = np.asarray(flash(q, k, v), np.float32)
+    np.testing.assert_allclose(out[:, sq - valid:],
+                               np.asarray(ref(*f32))[:, sq - valid:], **tol)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-2)
+    if valid < sq:  # lse = NEG_INF there: no output and no gradient
+        assert np.abs(out[:, :sq - valid]).max() == 0.0
+        assert np.abs(np.asarray(g1[0], np.float32)[:, :sq - valid]).max() == 0.0
+    for a, b, name in zip(g1, g2, "qkv"):
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(
+            a, b, err_msg=f"d{name} mismatch ({case})",
+            **{**gtol, "atol": gtol["atol"] * max(1.0, np.abs(b).max())})
+
+
+@pytest.mark.parametrize("seq,want", [(1024, (1024, 1024)),
+                                      (1536, (512, 512)),
+                                      (3072, (1024, 1024)),
+                                      (1152, (128, 128))])
+def test_a_measured_tile_halves_to_fit_the_sequence(monkeypatch, seq, want):
+    """The table's 1,024-row tiles at a length they do not divide: the
+    largest half of them that does, never the fall back to the 128s that a
+    512-row table never needed at 1,536."""
+    seen = []
+    monkeypatch.setattr(po, "_default_blocks", lambda seq=None: (1024, 1024))
+    monkeypatch.setattr(po, "_flash_attention",
+                        lambda q, k, v, scale, causal, bq, bk:
+                        seen.append((bq, bk)))
+    x = jnp.zeros((1, seq, 1, 64), jnp.bfloat16)
+    po.flash_attention(x, x, x, causal=True)
+    assert seen == [want]
+
+
 def test_flash_odd_shapes_fall_back():
     # non-multiple-of-128 seq len must route to the XLA reference path
     q, k, v = _qkv(1, 100, 2, 32)
@@ -117,8 +249,8 @@ def restore_flash_flags():
 
 
 @pytest.mark.parametrize("kind,seq,want", [
-    ("TPU v5 lite", 1024, (512, 512)), ("TPU v5 lite", 2048, (512, 512)),
-    ("TPU v5 lite", 4096, (512, 512)), ("TPU v5 lite", 8192, (512, 512)),
+    ("TPU v5 lite", 1024, (1024, 1024)), ("TPU v5 lite", 2048, (1024, 1024)),
+    ("TPU v5 lite", 4096, (1024, 1024)), ("TPU v5 lite", 8192, (1024, 512)),
     ("TPU v5 lite", 512, None), ("TPU v4", 2048, None)])
 def test_measured_tiles_by_device_kind_and_seq(monkeypatch, kind, seq, want):
     """The table as committed: what the benchmark's training cell runs at

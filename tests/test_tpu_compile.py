@@ -192,19 +192,90 @@ def test_paged_full_prefill_compiles(h, d):
 @pytest.mark.parametrize("blk", [128, 512], ids=["untuned", "tuned"])
 @pytest.mark.parametrize("h,d", WIDTHS, ids=_IDS)
 def test_flash_fwd_bwd_compiles(h, d, blk):
-    """Forward and both backward kernels, at the untuned tiling and the
-    (512, 512) that ``pallas_ops._TUNED_BLOCKS`` holds for "TPU v5 lite"."""
+    """Forward and the one backward kernel, at the untuned tiling and at
+    512 x 512 (the latent prefill's tiles)."""
     x = _sds((2, 1024, h, d), jnp.bfloat16)
+    text = _compile(_flash_loss_and_grads(blk, blk), x, x, x)
+    assert _flash_kernels(text) == ["flash_bwd", "flash_fwd"]
 
+
+def _flash_loss_and_grads(blk_q=None, blk_k=None):
     def loss_and_grads(q, k, v):
         def loss(q, k, v):
             o = pallas_ops.flash_attention(q, k, v, causal=True,
-                                           blk_q=blk, blk_k=blk)
+                                           blk_q=blk_q, blk_k=blk_k)
             return o.astype(jnp.float32).sum()
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return loss_and_grads
 
-    text = _compile(loss_and_grads, x, x, x)
-    assert text.count("tpu_custom_call") >= 3  # fwd, dK/dV, dQ
+
+def _flash_kernels(text):
+    """The flash kernels of a compiled program, by their instructions'
+    names (what ``flash_time_share_pct`` matches in a trace)."""
+    return sorted(set(re.findall(
+        r"%\w*?(flash_fwd|flash_bwd_dkv|flash_bwd_dq|flash_bwd)[\w.]* = ",
+        text)))
+
+
+def _kernel_vmem(text, name):
+    """Bytes of scoped VMEM the compiler ALLOCATED to the kernel ``name``
+    (``used_scoped_memory_configs`` on its custom call, memory space 1:
+    ``memory_analysis()`` counts HBM only)."""
+    line, = [l for l in text.splitlines()
+             if re.search(rf"%{name}[\d.]* = .*custom-call\(", l)]
+    used, = re.findall(r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                       r'"offset":"0","size":"(\d+)"\}\]', line)
+    return int(used)
+
+
+@pytest.mark.parametrize("batch,seq", [(4, 1024), (2, 2048), (1, 4096),
+                                       (1, 8192)],
+                         ids=["train-1chip", "s2048", "s4096", "s8192"])
+def test_flash_pair_compiles_at_the_tables_tiles(batch, seq, monkeypatch):
+    """``train-1chip``'s shapes (4 x 1,024 tokens, 16 heads of 128: 64
+    batch-heads) and the table's longer rows, each at the tiles
+    ``_TUNED_BLOCKS`` holds for a v5e: the fused backward holds dQ of a
+    whole sequence in VMEM, inside ``_FUSED_BWD_VMEM`` (the budget that
+    chooses the route), and what the compiler allocates to the kernel in
+    all (dQ, the tiles twice, the score-sized values) lies under the
+    ``_VMEM_LIMIT`` the launch states."""
+    from paddle_tpu.core import compile_cache
+
+    monkeypatch.setattr(jax, "devices", lambda *a: _DEVICES)
+    tiles = pallas_ops._tuned_blocks(seq)
+    assert tiles == pallas_ops._TUNED_BLOCKS["TPU v5 lite"][seq]
+    x = _sds((batch, seq, 16, 128), jnp.bfloat16)
+    dq_bytes = seq * 128 * (4 + 2 * 2)
+    assert dq_bytes <= pallas_ops._FUSED_BWD_VMEM < pallas_ops._VMEM_LIMIT
+    before = compile_cache.stats()
+    text = _compile(_flash_loss_and_grads(), x, x, x)
+    moved = compile_cache.stats_delta(before, compile_cache.stats(),
+                                      drop_zero=True)
+    assert _flash_kernels(text) == ["flash_bwd", "flash_fwd"]
+    assert moved.get("flash.bwd_fused") == 1 and "flash.bwd_split" not in moved
+    assert f"bf16[{batch * 16},{seq},128]" in text
+    assert dq_bytes < _kernel_vmem(text, "flash_bwd") <= pallas_ops._VMEM_LIMIT
+    assert _kernel_vmem(text, "flash_fwd") <= pallas_ops._VMEM_LIMIT
+
+
+@pytest.mark.parametrize("dtype,seq", [(jnp.bfloat16, 16384),
+                                       (jnp.float32, 8192)],
+                         ids=["bf16-16384", "float32-8192"])
+def test_flash_backward_beyond_the_budget_is_the_split_pair(dtype, seq):
+    """dQ's accumulator and output block of 16,384 x 128 in bf16 (16 MiB)
+    or 8,192 x 128 in float32 (12 MiB) pass the budget, so the two
+    reduction kernels run (``flash.bwd_split``), at the table's tiles."""
+    from paddle_tpu.core import compile_cache
+
+    x = _sds((1, seq, 2, 128), dtype)
+    before = compile_cache.stats()
+    text = _compile(_flash_loss_and_grads(
+        *pallas_ops._TUNED_BLOCKS["TPU v5 lite"][8192]), x, x, x)
+    moved = compile_cache.stats_delta(before, compile_cache.stats(),
+                                      drop_zero=True)
+    assert _flash_kernels(text) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                    "flash_fwd"]
+    assert moved.get("flash.bwd_split") == 1 and "flash.bwd_fused" not in moved
 
 
 # ------------------------------------------------ four described devices
@@ -264,7 +335,7 @@ def test_flash_under_a_mesh_compiles(data, model):
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     text = _compile(loss_and_grads, x, x, x)
-    assert text.count("tpu_custom_call") >= 3
+    assert _flash_kernels(text) == ["flash_bwd", "flash_fwd"]
 
 
 # ------------------------------------------------- the latent / expert cell
