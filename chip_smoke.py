@@ -29,9 +29,7 @@ this checkout. A second run sharing the directory shows
 The native extension is not on this path: modules here import
 ``paddle_tpu.native`` but none calls ``native.load()``, so nothing is built
 from ``native/csrc`` and nothing under the home directory is read (the
-``done`` line says whether the library was loaded). Kernel tuning records
-(``ops.tuning``) were never published; their absence means the untuned
-defaults.
+``done`` line says whether the library was loaded).
 """
 from __future__ import annotations
 
@@ -480,14 +478,14 @@ def phase_serve(model, plan: Plan, prompts, parity: Parity, chip: bool):
 
 def kernel_parity(plan: Plan, seed: int, quantized: bool) -> dict:
     """Raw kernel output against the gather reference (the construction of
-    tests/test_paged_kernel.py: ``_gather_ctx`` + ``masked_attention``) at
+    tests/test_paged_kernel.py: ``gather_ctx`` + ``masked_attention``) at
     the served widths, on random pools."""
     import jax.numpy as jnp
 
     from paddle_tpu.models.serving_seam import masked_attention
     from paddle_tpu.ops import paged_attention as pk
     from paddle_tpu.quantization import quantize_kv
-    from paddle_tpu.serving.engine import _gather_ctx
+    from paddle_tpu.serving.cache_views import gather_ctx
 
     rng = np.random.default_rng(seed)
     H, D, bs = plan.heads, plan.hidden // plan.heads, plan.block
@@ -505,7 +503,7 @@ def kernel_parity(plan: Plan, seed: int, quantized: bool) -> dict:
     bt = jnp.asarray(rng.integers(1, NB, (S, MB)), jnp.int32)
     pos = jnp.asarray(rng.integers(0, t_len, (S,)), jnp.int32)
     out = pk.paged_decode_attention(q, entry, bt, pos)
-    k_all, v_all = _gather_ctx(entry, bt, q.dtype)
+    k_all, v_all = gather_ctx(entry, bt, q.dtype)
     mask = (jnp.arange(t_len)[None, :] <= pos[:, None])[:, None, None, :]
     ref = masked_attention(q[:, None], k_all, v_all, mask)[:, 0]
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -517,7 +515,7 @@ def kernel_parity(plan: Plan, seed: int, quantized: bool) -> dict:
     prefix = t_len - sq - bs // 2
     q = jnp.asarray(rng.standard_normal((sq, H, D)), jnp.bfloat16)
     out = pk.paged_prefill_attention(q, entry, bt[0], jnp.int32(prefix))
-    k_all, v_all = _gather_ctx(entry, bt[0], q.dtype)
+    k_all, v_all = gather_ctx(entry, bt[0], q.dtype)
     gpos = prefix + jnp.arange(sq)
     mask = (jnp.arange(t_len)[None, :] <= gpos[:, None])[None, None]
     ref = masked_attention(q[None], k_all[None], v_all[None], mask)[0]

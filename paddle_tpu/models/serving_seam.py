@@ -213,12 +213,6 @@ class RecurrentLayerState:
     kind: str = "recurrent"
 
 
-#: the kinds whose state lies in the arena's slot-indexed store
-SLOT_KINDS = ("recurrent", "window")
-#: the kinds whose state is rows in the arena's block pools
-PAGED_KINDS = ("kv", "latent")
-
-
 @dataclass(frozen=True)
 class ServingSpec:
     vocab_size: int

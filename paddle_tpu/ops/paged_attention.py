@@ -1,7 +1,7 @@
 """Pallas paged-attention kernels over KV-arena block tables.
 
 The serving engine's XLA path pays a *gather tax* on every decode step:
-``engine._gather_ctx`` materializes each lane's whole logical context
+``cache_views.gather_ctx`` materializes each lane's whole logical context
 (``kp[table]`` — ``[S, max_blocks*block_size, H, D]`` of mostly-masked
 rows, dequantized from int8 first when the arena is quantized) before
 ``masked_attention`` reads a single useful element. These kernels read
@@ -20,11 +20,11 @@ Two kernels, same online-softmax core as the training flash kernel
 * :func:`paged_decode_attention` — one new token per slot. A lane's
   context is read in tiles of several pages, double buffered, only the
   pages that hold a live token (``positions`` is the ``start_pos`` of
-  ``engine._PagedCacheView``; a lane that is not ``active`` reads
+  ``cache_views.PagedCacheView``; a lane that is not ``active`` reads
   nothing); all heads of a tile in one pair of matmuls (see "decode"
   below).
 * :func:`paged_prefill_attention` — a suffix/chunk of queries for ONE
-  slot against its table (the ``engine._PrefixPrefillView`` contract):
+  slot against its table (the ``cache_views.PrefixPrefillView`` contract):
   query ``i`` sits at global position ``prefix_len + i`` and attends
   keys at global index ``<= prefix_len + i``. ``prefix_len`` is runtime
   data (scalar prefetch), so every chunk of every admission reuses one
@@ -34,9 +34,9 @@ Block tables, positions and prefix lengths are *runtime data*
 (scalar-prefetch operands): admit/retire/accept/reject churn never
 recompiles — the same invariant the XLA path holds. Launch parameters
 (decode's ``pages`` per tile, prefill's ``block_q`` query tiling and
-``block_h`` head grouping) come from the
-shared per-(kernel, chip, shape-bucket) tuning store
-(:mod:`paddle_tpu.ops.tuning`); absent a record the safe defaults run.
+``block_h`` head grouping) are derived from the launch's shapes, here
+(:func:`_tile_pages`, :func:`_query_block`, :func:`_head_group`); the
+arguments of those names are for a test that forces a tile.
 
 Numerics: the online softmax is mathematically identical to the gather
 path's full-width softmax but associates differently, so parity is
@@ -54,10 +54,8 @@ over the "model" axis, so each device runs this SAME kernel on its local
 head shard (the launch sees the local ``H``) through the
 replicated per-slot block tables, with zero cross-chip K/V traffic; the
 heads-sharded output hands straight to the row-parallel output
-projection's psum. Launch params resolve from the tuning store under the
-mesh-topology key (:func:`paddle_tpu.ops.tuning.lookup` with ``mesh=``)
-BEFORE the manual region, against the local head count. A 1-device mesh
-(or ``mesh=None``) skips the wrapper entirely — bit-identical to PR 13.
+projection's psum. A 1-device mesh (or ``mesh=None``) skips the wrapper
+entirely — bit-identical to PR 13.
 """
 from __future__ import annotations
 
@@ -67,12 +65,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .pallas_ops import (NEG_INF, _HAS_PALLAS, _LANES, _causal_mask,
-                         _use_interpret)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if _HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .pallas_ops import NEG_INF, _LANES, _causal_mask, _use_interpret
 
 __all__ = ["available", "decode_in_place", "paged_decode_attention",
            "paged_prefill_attention", "paged_full_prefill_attention",
@@ -87,11 +83,11 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def available() -> bool:
-    """Whether the paged kernels can run here (Pallas importable with
+    """Whether the paged kernels can run here (Pallas with
     scalar-prefetch support). The engine checks ONCE at construction and
     refuses to build a kernel engine without them — never a traced
     branch, never a silent gather path."""
-    return _HAS_PALLAS and hasattr(pltpu, "PrefetchScalarGridSpec")
+    return hasattr(pltpu, "PrefetchScalarGridSpec")
 
 
 def _head_group(num_heads: int, block_h) -> int:
@@ -122,17 +118,6 @@ def _mesh_routes(mesh) -> bool:
     deployment posture) or no mesh calls pallas directly, so those two
     stay bit-identical by construction."""
     return mesh is not None and int(mesh.devices.size) > 1
-
-
-def _local_heads(num_heads: int, mesh) -> int:
-    """The per-device head count inside the manual region: ``H // mp``
-    when the payload pools shard (``shard_kv_entry``'s divisibility rule),
-    else the full ``H`` (replicated pools, replicated kernel)."""
-    from ..distributed.sharding_util import MODEL_AXIS
-
-    mp = mesh.shape.get(MODEL_AXIS, 1)
-    return num_heads // mp if (mp > 1 and num_heads % mp == 0) \
-        else num_heads
 
 
 #: int8 scale pools stream in whole f32 sublane tiles: a ``(1, bs)`` block
@@ -231,8 +216,7 @@ _TILE_BYTES = 1 << 20
 
 def _tile_pages(max_blocks, page_bytes, pages=None,
                 tile_bytes=_TILE_BYTES) -> int:
-    """Pages per decode tile: ``pages`` if given (the tuning store, a
-    test), else what fills ``tile_bytes``; a power of two of at least 8
+    """Pages per decode tile: ``pages`` if given (a test), else what fills ``tile_bytes``; a power of two of at least 8
     (so a tile's rows fill whole lane tiles of the scores at every head
     count) unless the table itself is shorter."""
     n = int(pages) if pages else max(8, tile_bytes // max(page_bytes, 1))
@@ -280,8 +264,8 @@ def write_token(pool, blocks, offsets, rows):
     step at Olmo-Hybrid's shapes), so there each head's row is scattered
     on its own into the :func:`_page_slabs` view, where one row is
     minor-most. Returns the pool, same shape. A prefill's write is the
-    sibling ``serving.engine._scatter_blocks``, and the two differ because
-    what they write does: here one row a lane, in as many blocks as lanes,
+    sibling ``serving.cache_views.scatter_blocks``, and the two differ
+    because what they write does: here one row a lane, in as many blocks as lanes,
     so the window can only be made minor by the view; there whole blocks
     of one lane, a window that covers every minor dimension under either
     layout (and the swapped view would cost a token-major pool two
@@ -517,13 +501,13 @@ def paged_decode_attention(q, entry, block_tables, positions, active=None,
     gather path is never materialized). ``block_tables`` is ``[S, MB]``
     int32, ``positions`` ``[S]`` int32 (the new token's write position —
     keys at global index ``<= positions[s]`` are attended, matching
-    ``masked_attention``'s mask in ``_PagedCacheView``). ``active``
+    ``masked_attention``'s mask in ``PagedCacheView``). ``active``
     (``[S]`` bool, default all) marks the lanes that hold a request: a
     lane that does not reads no page and returns zeros. Returns
     ``[S, H, D]`` in ``q.dtype``. Tables, positions and ``active`` are
     runtime data: one compiled program serves every churn pattern, and a
     lane costs the pages it has live. ``pages`` (pages per tile) is a
-    launch parameter; None asks the tuning store, then ``_TILE_BYTES``.
+    launch parameter; None takes what fills ``_TILE_BYTES``.
     On a multi-device ``mesh`` the call runs per model-shard (module
     docstring, "SPMD partitioning")."""
     lengths = positions.astype(jnp.int32) + 1
@@ -531,41 +515,20 @@ def paged_decode_attention(q, entry, block_tables, positions, active=None,
         lengths = jnp.where(active, lengths, 0)
     if _mesh_routes(mesh):
         return _sharded_decode(q, entry, block_tables, lengths, pages, mesh)
-    if pages is None:
-        pages = _tuned_pages(entry[0].shape[2], q.shape[2],
-                             entry[0].shape[1], block_tables.shape[1])
-    return _decode_call(q, entry, block_tables, lengths, pages)
-
-
-def _tuned_pages(heads, dim, block_size, max_blocks, mesh_key=None):
-    """The tuning store's tile size for this launch (``heads``: what one
-    device launches with), or 0: the default."""
-    from . import tuning
-
-    rec = tuning.lookup(
-        "paged_decode",
-        tuning.bucket_key(h=heads, d=dim, bs=block_size, mb=max_blocks),
-        mesh=mesh_key)
-    return (rec or {}).get("pages") or 0
+    return _decode_call(q, entry, block_tables, lengths, pages or 0)
 
 
 def _sharded_decode(q, entry, block_tables, lengths, pages, mesh):
-    """Per-shard decode: resolve launch params OUTSIDE the manual region
-    under the mesh-topology tuning key (against the LOCAL head count each
-    device actually launches with), then map the plain kernel over the
-    mesh — heads-sharded q/K/V in, replicated tables/lengths/scales
-    through, heads-sharded output back."""
-    from ..distributed.sharding_util import (headwise_shard_map,
-                                             mesh_axes_key)
+    """Per-shard decode: map the plain kernel over the mesh —
+    heads-sharded q/K/V in, replicated tables/lengths/scales through,
+    heads-sharded output back."""
+    from ..distributed.sharding_util import headwise_shard_map
 
-    D, H = q.shape[2], entry[0].shape[2]  # the pool's heads shard
-    if pages is None:
-        pages = _tuned_pages(_local_heads(H, mesh), D, entry[0].shape[1],
-                             block_tables.shape[1], mesh_axes_key(mesh))
+    H = entry[0].shape[2]  # the pool's heads shard
     n = len(entry)
 
     def kernel(q, *rest):
-        return _decode_call(q, rest[:n], rest[n], rest[n + 1], pages)
+        return _decode_call(q, rest[:n], rest[n], rest[n + 1], pages or 0)
 
     mapped = headwise_shard_map(
         kernel, mesh,
@@ -633,7 +596,7 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
     produce garbage the caller discards, exactly like the XLA path);
     ``bt_row`` is ``[MB]`` int32, ``prefix_len`` a (traced) scalar: query
     ``i`` attends keys at global index ``<= prefix_len + i``, the
-    ``_PrefixPrefillView`` mask verbatim. The suffix's own K/V must
+    ``PrefixPrefillView`` mask verbatim. The suffix's own K/V must
     already be scattered into the pools (same call order as the XLA
     path: scatter, then attend). Returns ``[sq, H, D]``. On a
     multi-device ``mesh`` the call runs per model-shard (module
@@ -650,14 +613,6 @@ def paged_prefill_attention(q, entry, bt_row, prefix_len,
         raise ValueError(f"{HQ} query heads do not divide over {H} K/V "
                          "heads")
     MB = bt_row.shape[0]
-    if block_q is None and block_h is None:
-        from . import tuning
-
-        rec = tuning.lookup(
-            "paged_prefill",
-            tuning.bucket_key(sq=sq, h=H, d=D, bs=bs, mb=MB))
-        if rec:
-            block_q, block_h = rec.get("block_q"), rec.get("block_h")
     blk_q = _query_block(sq, block_q)
     blk_h = _head_group(H, block_h)
     kern = functools.partial(_prefill_kernel, bs=bs, blk_q=blk_q,
@@ -717,26 +672,14 @@ def _sharded_prefill(q, entry, bt_row, prefix_len, block_q, block_h, mesh):
     """Per-shard suffix/chunk prefill — same structure as
     :func:`_sharded_decode`; ``prefix_len`` rides replicated like the
     table (runtime data, identical on every device)."""
-    from ..distributed.sharding_util import (headwise_shard_map,
-                                             mesh_axes_key)
+    from ..distributed.sharding_util import headwise_shard_map
 
-    (sq, _, D), H = q.shape, entry[0].shape[2]  # the pool's heads shard
-    if block_q is None and block_h is None:
-        from . import tuning
-
-        rec = tuning.lookup(
-            "paged_prefill",
-            tuning.bucket_key(sq=sq, h=_local_heads(H, mesh), d=D,
-                              bs=entry[0].shape[1], mb=bt_row.shape[0]),
-            mesh=mesh_axes_key(mesh))
-        block_q = (rec or {}).get("block_q") or 0
-        block_h = (rec or {}).get("block_h") or 0
+    H = entry[0].shape[2]  # the pool's heads shard
     n = len(entry)
 
     def kernel(q, *rest):
         return paged_prefill_attention(q, rest[:n], rest[n], rest[n + 1],
-                                       block_q=block_q or 0,
-                                       block_h=block_h or 0)
+                                       block_q=block_q, block_h=block_h)
 
     mapped = headwise_shard_map(
         kernel, mesh,
@@ -755,7 +698,7 @@ def paged_full_prefill_attention(q, k, v, block_size,
     chunk's own keys/values) are viewed as ``ceil(sq/bs)`` **pseudo-blocks**
     and addressed through an identity (``arange``) pseudo-table with
     ``prefix_len = 0``: query ``i`` attends keys ``<= i`` — the
-    ``_CapturePrefillView`` causal mask verbatim. The pad rows a non-divisible
+    ``CapturePrefillView`` causal mask verbatim. The pad rows a non-divisible
     ``sq`` adds sit at key positions ``>= sq``, above every query row, so
     the mask discards them like the XLA path's padding. One reshape/pad in
     XLA; no gather, no ``[sq, sq]`` materialized probability matrix —

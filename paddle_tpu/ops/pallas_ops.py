@@ -31,13 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128  # residuals (lse, delta) are stored lane-broadcast [.., s, 128]
@@ -638,15 +633,8 @@ _TUNED_BLOCKS = {
 
 
 def _tuned_blocks(seq):
-    """Measured tiles for this chip at the nearest measured seqlen, or
-    None. The shared kernel-tuning store (:mod:`paddle_tpu.ops.tuning`,
-    kernel ``"flash_fwd"``, bucketed by seqlen, device-kind gated) is
-    consulted first; ``_TUNED_BLOCKS`` second."""
-    from . import tuning
-
-    rec = tuning.lookup("flash_fwd", tuning.bucket_key(s=seq))
-    if rec and "blk_q" in rec and "blk_k" in rec:
-        return int(rec["blk_q"]), int(rec["blk_k"])
+    """Measured tiles for this chip at the nearest measured seqlen
+    (``_TUNED_BLOCKS``), or None."""
     table = _TUNED_BLOCKS.get(jax.devices()[0].device_kind)
     # only adopt within the measured range: a tiling verified at 8192 was
     # never lowered at 512 (different VMEM footprint; Mosaic may reject
@@ -678,7 +666,7 @@ def flash_attention(q, k, v, scale: Optional[float] = None, causal: bool = False
     """Blockwise flash attention, layout [batch, seq, heads, head_dim]."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if not _HAS_PALLAS or not _shapes_ok(q, k):
+    if not _shapes_ok(q, k):
         return _attention_reference(q, k, v, scale, causal)
     sq, sk = q.shape[1], k.shape[1]
     dq, dk = _default_blocks(seq=sk)

@@ -115,7 +115,7 @@ def dequantize_kv(q, scale, dtype):
 
     This is the home of the dequant math: the XLA gather path calls it
     over gathered context (per block when the compute dtype is narrower
-    than f32 — ``engine._gather_ctx``). The Pallas paged kernels
+    than f32 — ``cache_views.gather_ctx``). The Pallas paged kernels
     (:mod:`paddle_tpu.ops.paged_attention`) apply the same per-token
     scales to the score columns and the probabilities instead
     (``q.(k*s) == (q.k)*s``): a ``[1, bs]`` lane row of scales cannot be

@@ -37,7 +37,7 @@ import threading
 from typing import List, Optional, Sequence
 
 from ...core import resilience
-from .. import metrics, telemetry
+from .. import cache_views, metrics, telemetry
 from ..gateway.procpool import ProcessReplicaPool
 from ..gateway.router import RoutedRequest, _Replica
 from ..scheduler import RequestState
@@ -57,22 +57,13 @@ class DisaggReplicaPool(ProcessReplicaPool):
                  decode_replicas: Optional[int] = None,
                  disk_dir: Optional[str] = None, **pool_kw):
         spec = getattr(model, "serving_spec", None)
-        kinds = {st.kind for st in spec().layers} if spec is not None else set()
-        if kinds & {"recurrent", "window"}:
-            # a request is handed over as its published BLOCK chain; a
-            # recurrent layer's state is not blocks (a model factory is
-            # refused by its workers' engines: the prefix cache names it)
-            raise ValueError(
-                "disaggregated prefill/decode handoff is not supported for "
-                "a model with recurrent-state layers: it hands a request "
-                "over as paged blocks")
-        if "latent" in kinds:
-            # the handoff rides the prefix cache and the host tier, which
-            # a latent pool refuses (models/serving_seam.py)
-            raise ValueError(
-                "disaggregated prefill/decode handoff is not supported for "
-                "a model with latent-attention layers: it publishes K and "
-                "V blocks through the prefix cache")
+        if spec is not None:
+            # a request is handed over as its published K/V BLOCK chain,
+            # through the prefix cache and the host tier: refused for the
+            # kinds of state that are not that (a model factory is refused
+            # by its workers' engines: the prefix cache names it)
+            cache_views.refuse_options(spec().layers,
+                                       {cache_views.HANDOFF: True})
         p, d = role_counts(prefill_replicas, decode_replicas)
         if p < 1 or d < 1:
             raise ValueError(

@@ -25,33 +25,23 @@ a fixed slot arena**:
   one executable serving every occupancy pattern.
 
 **The engine<->model seam** (``models/serving_seam.py``,
-docs/serving_model_seam.md): the engine names no model. A model declares
-its vocabulary, its longest context and, per layer, the KIND of per-request
-state that layer keeps (``serving_spec()``): ``"kv"`` (query heads, K/V
-heads, head_dim: rows in the paged arena), ``"recurrent"`` (fixed-size
-arrays per lane, in the arena's slot-indexed store), ``"window"`` (the last
-``window`` tokens' K/V per lane, a ring in the same store), ``"shared"``
-(no state: it reads the pool of the ``"kv"`` layer it names, through the
-same block tables), ``"latent"`` (ONE row a token for all heads, in the
-paged arena: the two latent views below; a prefill attends the expanded
-form, the decode step the absorbed one through the block tables) or
-``"none"``. Every compiled program here runs the
-model one way, :func:`~paddle_tpu.models.serving_seam.forward_cached`: embed
--> layers, each handed a cache view of ITS kind built here (the three paged
-views below for ``"kv"`` layers, the two slot-state views for
-``"recurrent"`` ones, the two window views, a reader of another layer's
-successor view for ``"shared"`` ones) -> final norm; then ``serving_head``.
-A model that declares a ``prefill_tail`` has its prefill run the layers
-from there on each request's last valid token alone. A recurrent layer's
-lane is started from zeros and written by the prefill that admits a
-request, advanced in place by the decode step, and untouched while
-inactive; a window layer's ring is filled anew by that prefill. Blocks are
-counted for the layers that OWN a pool: a model with one ``"kv"`` layer and
-seven ``"shared"`` readers of it pages one layer. The options whose
-bookkeeping assumes every layer's state is blocks (prefix cache, KV
-tiering, speculative decoding, chunked prefill) refuse a model with a
-recurrent or window layer at construction; those, int8 K/V and a mesh of
-more than one chip refuse a model with latent layers.
+docs/serving_model_seam.md): the engine names no model and no kind of
+layer state. A model declares its vocabulary, its longest context and, per
+layer, the KIND of per-request state that layer keeps (``serving_spec()``).
+What a kind needs of the server is :mod:`paddle_tpu.serving.cache_views`'s
+to say, in one table by kind: where its state lies (rows in the paged
+arena's block pools, a fixed size a lane in the arena's slot-indexed store,
+nowhere), the cache view a program hands the layer, how a prefill commits
+what the view left behind, the options it cannot be served with. Every
+compiled program here runs the model one way,
+:func:`~paddle_tpu.models.serving_seam.forward_cached`: embed -> layers,
+each handed the view the table builds for ITS kind -> final norm; then
+``serving_head``. A model that declares a ``prefill_tail`` has its prefill
+run the layers from there on each request's last valid token alone. State
+in the slot store is started anew by the prefill that admits a request,
+advanced in place by the decode step, and untouched while its lane is
+inactive. Blocks are counted for the layers that OWN a pool: a model with
+one such layer and seven readers of it pages one layer.
 
 Decode numerics deliberately share
 ``models.serving_seam.masked_attention`` and the model's ``serving_head``
@@ -67,8 +57,8 @@ double-buffering what is by far the engine's largest allocation.
 docs/quantization.md) rides the same data path: weights stream int8 and
 dequantize in-kernel (:func:`paddle_tpu.models.serving_seam.serving_linear`),
 the KV arena stores int8 with per-block scale pools carried inside every
-pool entry (quantize-on-scatter in :func:`_scatter_rows` and
-:func:`_scatter_blocks`, dequant-on-attend in :func:`_gather_ctx`), and
+pool entry (quantize-on-scatter and dequant-on-attend in
+:mod:`~paddle_tpu.serving.cache_views`), and
 each mode is captured at construction as part of the engine's program key
 exactly like the donation flag. All default off — the unquantized path is
 bit-identical.
@@ -137,7 +127,13 @@ import numpy as np
 
 from ..core import compile_cache, flags, resilience
 from ..core.tensor import Tensor
-from . import metrics, telemetry
+from . import cache_views, metrics, telemetry
+# benchmark/tests/test_hybrid_cell.py and test_flash_cell.py patch these two
+# classes through this module, and this PR may not edit them; nothing here
+# reads the names (ROADMAP.md C9: the line goes with the next `benchmark` PR)
+from .cache_views import (  # noqa: F401
+    SlotStatePrefillView as _SlotStatePrefillView,
+    WindowDecodeView as _WindowDecodeView)
 from .kv_arena import ArenaExhaustedError, KVArena, Reservation
 from .prefix_cache import PrefixCache
 from .spec_decode import SpecDecoder
@@ -153,663 +149,6 @@ def _ceil_div(a: int, b: int) -> int:
 _ST_ROWS = 8
 (_ST_POS, _ST_TOK, _ST_ACT, _ST_TEMP, _ST_TOP_K, _ST_TOP_P, _ST_SEED,
  _ST_ADAPTER) = range(_ST_ROWS)
-
-
-def _scatter_rows(entry, row, off, kc, vc):
-    """Scatter one chunk's k/v rows at ``(row, off)`` into a pool entry.
-    A full-precision ``(k, v)`` entry writes the rows as-is (op-for-op
-    the pre-quantization path); an int8 ``(k, v, k_scale, v_scale)``
-    entry quantizes-on-scatter: each token row is symmetric-int8 quantized
-    (:func:`paddle_tpu.quantization.quantize_kv`) and its per-row scale
-    lands in the scale pools at the SAME (row, off) — payload and scale
-    can never go out of step. The entry-length branch is tuple structure
-    (static at trace time), never traced data."""
-    if len(entry) == 2:
-        kp, vp = entry
-        return (kp.at[row, off].set(kc), vp.at[row, off].set(vc))
-    from ..quantization import quantize_kv
-
-    kp, vp, ks, vs = entry
-    qk, sk = quantize_kv(kc)
-    qv, sv = quantize_kv(vc)
-    return (kp.at[row, off].set(qk), vp.at[row, off].set(qv),
-            ks.at[row, off].set(sk), vs.at[row, off].set(sv))
-
-
-def _scatter_blocks(entry, table_rows, true_len, kc, vc, block_size: int):
-    """A whole prompt's k/v ``[p, H, D]`` into the slot's blocks
-    (``table_rows``: the block of each ``block_size`` positions), in
-    whole BLOCKS: the scatter's window then covers every minor dimension
-    of a pool, so the chip writes it in place whichever way it lays the
-    pool out (a window of one position's ``(H, D)`` is not minor in a
-    head-major pool, ``paged_attention._head_major``, and
-    :func:`_scatter_rows` there costs three copies of the whole pool). A
-    full prefill starts at position 0 of blocks the slot owns alone. A
-    block whose first position is at or past ``true_len`` is padding and
-    lands in scratch block 0; the one that straddles ``true_len`` carries
-    padded positions behind real ones, each of which the decode step that
-    writes that position (``write_token``) replaces before any mask lets
-    it be read (``<= pos``). A chunk that is not a whole number of blocks
-    is padded up to one, behind ``true_len``. An int8 ``(k, v, k_scale,
-    v_scale)`` entry quantizes as :func:`_scatter_rows` does, payload and
-    scale pools written by the same block ids."""
-    import jax.numpy as jnp
-
-    n_blk = _ceil_div(kc.shape[0], block_size)
-    blk = jnp.where(jnp.arange(n_blk) * block_size < true_len,
-                    table_rows[:n_blk], 0)
-    pad = ((0, n_blk * block_size - kc.shape[0]), (0, 0), (0, 0))
-    kc, vc = jnp.pad(kc, pad), jnp.pad(vc, pad)
-    if len(entry) == 4:
-        from ..quantization import quantize_kv
-
-        (kc, sk), (vc, sv) = quantize_kv(kc), quantize_kv(vc)
-        chunks = (kc, vc, sk, sv)
-    else:
-        chunks = (kc, vc)
-
-    def write(pool, chunk):
-        chunk = chunk.reshape((n_blk, block_size) + chunk.shape[1:])
-        if pool.ndim == 2:
-            # a scale pool's row is narrower than a lane tile: to scatter
-            # whole rows the compiler transposes the pool and back, so
-            # each scale goes where it lies, by the same block ids
-            return pool.at[blk[:, None], jnp.arange(block_size)].set(chunk)
-        return pool.at[blk].set(chunk)
-
-    return tuple(write(p, c) for p, c in zip(entry, chunks))
-
-
-@jax.named_scope("kv_gather")  # metadata on the device's operations
-def _gather_ctx(entry, table, dtype):
-    """Gather a block table's logical context from one pool entry:
-    ``table`` is ``[..., max_blocks]`` int32; returns ``(k_all, v_all)``
-    shaped ``[..., max_blocks*block_size, heads, dim]``. Int8 entries
-    dequantize-on-attend through their per-row scales in f32 before the
-    cast to the attention compute ``dtype`` — per table ROW (``lax.map``
-    over the lanes) when the compute dtype is narrower than f32, so the
-    f32 intermediate is one lane's context, never a second full-width
-    copy of the whole batch's context (which used to double peak context
-    bytes on the quantized fallback path). One lane per map step keeps
-    the within-lane dequant fully vectorized — the loop is S iterations,
-    not S*max_blocks. Per-element math is identical either way (one f32
-    multiply, one cast), so the output is bitwise unchanged."""
-    kp, vp = entry[0], entry[1]
-    if len(entry) == 4:
-        import jax.numpy as jnp
-
-        from ..quantization import dequantize_kv
-
-        ks, vs = entry[2], entry[3]
-        if jnp.dtype(dtype).itemsize >= 4:
-            # f32 compute: the dequant output IS the f32 buffer — nothing
-            # to save by chunking
-            k_all = dequantize_kv(kp[table], ks[table], dtype)
-            v_all = dequantize_kv(vp[table], vs[table], dtype)
-        else:
-            def _deq_lane(row):  # row: one lane's [max_blocks] table
-                return (dequantize_kv(kp[row], ks[row], dtype),
-                        dequantize_kv(vp[row], vs[row], dtype))
-
-            lanes = table.reshape(-1, table.shape[-1])
-            k_all, v_all = jax.lax.map(_deq_lane, lanes)
-            k_all = k_all.reshape(table.shape + kp.shape[1:])
-            v_all = v_all.reshape(table.shape + vp.shape[1:])
-    else:
-        k_all = kp[table]
-        v_all = vp[table]  # [..., mb, bs, H, D]
-    shp = k_all.shape
-    out_shape = shp[:-4] + (shp[-4] * shp[-3],) + shp[-2:]
-    return k_all.reshape(out_shape), v_all.reshape(out_shape)
-
-
-class _PagedCacheView:
-    """One ``"kv"`` layer's decode-step view of the paged arena (the ``cache``
-    protocol object an attention layer drives): write the new token's
-    k/v at each lane's (block, offset), gather the lane's block table, and
-    attend under the per-lane position mask. ``entry`` is the layer's
-    whole arena pool entry — ``(k, v)`` or, with ``FLAGS_serving_quant_kv``,
-    ``(k, v, k_scale, v_scale)`` (quantize-on-scatter / dequant-on-attend
-    via :func:`_scatter_rows` / :func:`_gather_ctx`).
-
-    With ``kernel=True`` (the engine's ``decode_kernel``: asked for, or
-    the default on a device where the kernel compiles natively; captured
-    at engine construction like the quant/donation flags) the attend side
-    routes through the Pallas paged-decode kernel
-    (:func:`paddle_tpu.ops.paged_attention.paged_decode_attention`):
-    K/V are read directly through the block table — no gather into a
-    contiguous ``[S, max_blocks*bs, H, D]`` buffer, int8 dequant fused
-    in-kernel, a lane costing the pages it has live and a lane that is
-    not ``active`` none. The scatter of the new token stays in XLA either way
-    (one row per lane — there is no gather to kill there). ``kernel`` is
-    trace-time *structure*: toggling it is a different engine build,
-    never a mid-run branch. ``mesh`` rides the same way (ISSUE 16): on a
-    multi-device mesh the kernel call runs per model-shard through
-    ``headwise_shard_map`` — None keeps the direct pallas path."""
-
-    def __init__(self, entry, block_tables, positions, active,
-                 block_size: int, kernel: bool = False, mesh=None):
-        self.entry = entry
-        self.block_tables = block_tables  # [S, max_blocks] int32
-        self.positions = positions        # [S] int32: write pos of new token
-        self.active = active              # [S] bool
-        self.block_size = block_size
-        self.kernel = kernel
-        self.mesh = mesh
-
-    def update_and_attend(self, q, k, v):
-        import jax.numpy as jnp
-
-        qa, ka, va = (t._data if isinstance(t, Tensor) else t
-                      for t in (q, k, v))
-        s_lanes = qa.shape[0]
-        bs = self.block_size
-        pos = self.positions
-        # physical write target; inactive lanes are routed to scratch block
-        # 0 so their (garbage) writes never touch live cache state
-        row = self.block_tables[jnp.arange(s_lanes), pos // bs]
-        row = jnp.where(self.active, row, 0)
-        off = pos % bs
-        if self.kernel and len(self.entry) == 2 and self.mesh is None:
-            from ..ops.paged_attention import write_token
-
-            # the same write as _scatter_rows, made in place whichever
-            # way the chip lays the pool out, as the kernel reads it (an
-            # int8 entry quantizes on scatter, and a pool sharded over
-            # heads keeps its head axis, below)
-            entry = tuple(write_token(pool, row, off, new[:, 0])
-                          for pool, new in zip(self.entry, (ka, va)))
-        else:
-            entry = _scatter_rows(self.entry, row, off, ka[:, 0], va[:, 0])
-        o = _paged_attend(self, qa, entry)
-        new = _PagedCacheView(entry, self.block_tables,
-                              self.positions, self.active, bs,
-                              kernel=self.kernel, mesh=self.mesh)
-        return o, new
-
-    def reader(self):
-        """A view for a ``"shared"`` layer: it attends this pool as it is
-        now (this call's token already written) and writes nothing."""
-        return _PagedReadView(self)
-
-
-def _paged_attend(view, qa, entry):
-    """The attend side of the decode step's paged views: each lane's one
-    query ``qa`` ``[S, 1, heads, D]`` against its block table's context in
-    ``entry``, up to and including its write position."""
-    import jax.numpy as jnp
-
-    from ..models.serving_seam import masked_attention
-
-    pos, bs = view.positions, view.block_size
-    if view.kernel:
-        from ..ops.paged_attention import paged_decode_attention
-
-        return paged_decode_attention(qa[:, 0], entry, view.block_tables,
-                                      pos, active=view.active,
-                                      mesh=view.mesh)[:, None]
-    # gather each lane's logical context [S, max_blocks*bs, H, D]
-    t_len = view.block_tables.shape[1] * bs
-    k_all, v_all = _gather_ctx(entry, view.block_tables, qa.dtype)
-    mask = (jnp.arange(t_len)[None, :] <= pos[:, None])[:, None, None, :]
-    return masked_attention(qa, k_all, v_all, mask)
-
-
-class _PagedReadView:
-    """A ``"shared"`` layer's decode-step view: the pool entry of the layer
-    it names, read through the same block tables, never written."""
-
-    def __init__(self, source: "_PagedCacheView"):
-        self.source = source
-
-    def attend(self, q):
-        qa = q._data if isinstance(q, Tensor) else q
-        return _paged_attend(self.source, qa, self.source.entry)
-
-
-class _CapturePrefillView:
-    """Prefill-side cache protocol object: plain causal attention over the
-    (padded) prompt chunk, returning the chunk's k/v as the successor cache
-    so the engine can write them into the slot's arena blocks, in whole
-    blocks (:func:`_scatter_blocks`).
-
-    With ``kernel=True`` a whole prompt (every query row present) goes
-    through the flash prefill kernel
-    (:func:`paddle_tpu.ops.paged_attention.swa_prefill_attention` with no
-    window: tiles of up to 512 rows, grouped queries through the index
-    map, the tiles above the diagonal neither multiplied nor copied); on a
-    mesh of several chips through the paged prefill kernel's no-table
-    entry (:func:`~paddle_tpu.ops.paged_attention.paged_full_prefill_attention`,
-    which shards over heads and walks the keys a block of the pool at a
-    time). ``kernel=False`` is the original masked_attention path,
-    bit-preserved."""
-
-    def __init__(self, block_size: int = 0, kernel: bool = False,
-                 mesh=None, last=None):
-        self.block_size = block_size
-        self.kernel = kernel
-        self.mesh = mesh
-        #: a prefill whose later layers run on the last valid row alone
-        #: (``ServingSpec.prefill_tail``): that row's index, traced. The
-        #: layer may then hand over one query row (that one) with every
-        #: row's K/V
-        self.last = last
-
-    def update_and_attend(self, q, k, v):
-        qa, ka, va = (t._data if isinstance(t, Tensor) else t
-                      for t in (q, k, v))
-        captured = _CapturedKV(ka, va, self.last)
-        if self.kernel and qa.shape[1] == ka.shape[1]:  # not one row alone
-            from ..ops import paged_attention as pa
-
-            if self.mesh is None:
-                o = pa.swa_prefill_attention(qa[0], ka[0], va[0])[None]
-            else:
-                o = pa.paged_full_prefill_attention(
-                    qa[0], ka[0], va[0], self.block_size,
-                    mesh=self.mesh)[None]
-            return o, captured
-        return captured.attend(qa), captured
-
-
-class _CapturedKV:
-    """What a ``"kv"`` layer's prefill leaves behind: the chunk's K and V,
-    which the engine writes into the slot's blocks, a whole block at a
-    time (:func:`_scatter_blocks`), and which a ``"shared"`` layer of the
-    same call reads (:meth:`reader`)."""
-
-    def __init__(self, ka, va, last=None):
-        self.k, self.v, self.last = ka, va, last
-
-    def reader(self):
-        return self
-
-    def attend(self, q):
-        """Causal attention of ``q`` over the captured rows: as many rows
-        as were captured, each at its own position, or one row, at
-        ``last``."""
-        import jax.numpy as jnp
-
-        from ..models.serving_seam import masked_attention
-
-        qa = q._data if isinstance(q, Tensor) else q
-        p = self.k.shape[1]
-        cols = jnp.arange(p)[None, :]
-        rows = (jnp.arange(p)[:, None] if qa.shape[1] == p
-                else jnp.reshape(self.last, (1, 1)))
-        mask = (cols <= rows)[None, None]
-        return masked_attention(qa, self.k, self.v, mask)
-
-
-class _PrefixPrefillView:
-    """Suffix-only prefill over a slot whose prefix KV is already resident
-    (matched radix-cache blocks attached to the block table by reference):
-    scatter only the suffix chunk's k/v at global positions
-    ``prefix_len + i`` via the slot's table, then attend each suffix query
-    against the full gathered context — prefix blocks are read, never
-    recomputed. ``prefix_len`` is a traced scalar and the table is runtime
-    int32 data, so every (cache hit, prefix length) reuses ONE compiled
-    program per suffix-length bucket.
-
-    With ``kernel=True`` the attend side routes through the Pallas
-    chunked-prefill kernel
-    (:func:`paddle_tpu.ops.paged_attention.paged_prefill_attention`) —
-    same scatter-then-attend order, same global-position mask, but the
-    resident prefix is streamed block-by-block through the table instead
-    of gathered into a contiguous buffer. Chunked prefill rides this
-    view, so every chunk of a long admission skips the gather too."""
-
-    def __init__(self, entry, bt_row, prefix_len, true_len,
-                 block_size: int, kernel: bool = False, mesh=None):
-        self.entry = entry            # the layer's whole arena pool entry
-        self.bt_row = bt_row          # [max_blocks] int32: the slot's table
-        self.prefix_len = prefix_len  # scalar int32: resident context length
-        self.true_len = true_len      # scalar int32: real (unpadded) suffix
-        self.block_size = block_size
-        self.kernel = kernel
-        self.mesh = mesh
-
-    def update_and_attend(self, q, k, v):
-        import jax.numpy as jnp
-
-        from ..models.serving_seam import masked_attention
-
-        qa, ka, va = (t._data if isinstance(t, Tensor) else t
-                      for t in (q, k, v))
-        p = qa.shape[1]
-        bs = self.block_size
-        p_idx = jnp.arange(p)
-        gpos = self.prefix_len + p_idx  # global write positions
-        bi = jnp.clip(gpos // bs, 0, self.bt_row.shape[0] - 1)
-        # padded suffix positions scatter into the scratch block, exactly
-        # like full prefill's padding — bucketing never pollutes live state
-        row = jnp.where(p_idx < self.true_len, self.bt_row[bi], 0)
-        off = gpos % bs
-        entry = _scatter_rows(self.entry, row, off, ka[0], va[0])
-        if self.kernel:
-            from ..ops.paged_attention import paged_prefill_attention
-
-            o = paged_prefill_attention(qa[0], entry, self.bt_row,
-                                        self.prefix_len,
-                                        mesh=self.mesh)[None]
-        else:
-            t_len = self.bt_row.shape[0] * bs
-            k_all, v_all = _gather_ctx(entry, self.bt_row, qa.dtype)
-            k_all, v_all = k_all[None], v_all[None]
-            mask = (jnp.arange(t_len)[None, :] <= gpos[:, None])[None, None]
-            o = masked_attention(qa, k_all, v_all, mask)
-        new = _PrefixPrefillView(entry, self.bt_row,
-                                 self.prefix_len, self.true_len, bs,
-                                 kernel=self.kernel, mesh=self.mesh)
-        return o, new
-
-
-class _SlotStateDecodeView:
-    """One ``"recurrent"`` layer's decode-step view of the slot-indexed
-    store: ``entry`` is that layer's ``[S, ...]`` state arrays. The layer
-    reads every lane's state, advances it one token and writes it back;
-    an inactive lane keeps what it had."""
-
-    valid_len = None  # one real token a lane: nothing is padded
-
-    def __init__(self, entry, active):
-        self.entry = entry
-        self.active = active  # [S] bool
-
-    def read(self):
-        return self.entry
-
-    def write(self, new):
-        import jax.numpy as jnp
-
-        def keep(n, old):
-            act = self.active.reshape((-1,) + (1,) * (old.ndim - 1))
-            return jnp.where(act, n.astype(old.dtype), old)
-
-        return _SlotStateDecodeView(
-            tuple(keep(n, o) for n, o in zip(new, self.entry)), self.active)
-
-
-class _SlotStatePrefillView:
-    """One ``"recurrent"`` layer's prefill view: the admitted request
-    starts from a ZERO state (the lane is reset, whatever its last tenant
-    left), runs over the true length of the padded prompt (``valid_len``),
-    and its final state is written into lane ``slot``."""
-
-    def __init__(self, entry, slot, true_len):
-        self.entry = entry
-        self.slot = slot          # scalar int32: the lane being admitted
-        self.valid_len = true_len  # scalar int32: real (unpadded) length
-
-    def read(self):
-        import jax.numpy as jnp
-
-        return tuple(jnp.zeros((1,) + a.shape[1:], a.dtype)
-                     for a in self.entry)
-
-    def write(self, new):
-        entry = tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                old, n.astype(old.dtype), self.slot, axis=0)
-            for n, old in zip(new, self.entry))
-        return _SlotStatePrefillView(entry, self.slot, self.valid_len)
-
-
-class _WindowDecodeView:
-    """One ``"window"`` layer's decode-step view: ``entry`` is that layer's
-    ``[S, kv_heads, window, D]`` K and V rings. The token at position ``p``
-    overwrites row ``p % window`` of its lane's ring, which then holds
-    positions ``p - window + 1 .. p`` (fewer while the context is shorter:
-    the rows past it are masked). The order inside the ring is free: a
-    softmax over a set of keys does not read their order, and a model with
-    positions (rotary) turns each key at its own position BEFORE the row
-    is written, so a row carries its position in its values, not in its
-    place. A lane that is not active writes into its own ring, which the
-    prefill that next admits a request to it fills anew. The attention is
-    XLA's over the whole ring, ``window`` rows a lane whatever is live.
-
-    The ring lies head-major, as the attention reads it (the layout the
-    chip's compiler gives a ``[S, window, kv_heads, D]`` ring on its own,
-    after which it relaid all of it out and back around the one-row
-    write: 16 copies of 42 MB a step at Phi-4-mini-flash's sizes), and
-    each head's row is scattered on its own into the ``[S, kv_heads *
-    window, D]`` view, where one row is minor-most: in place, as
-    :func:`paddle_tpu.ops.paged_attention.write_token` does for a
-    head-major pool."""
-
-    def __init__(self, entry, positions, window: int):
-        self.entry = entry
-        self.positions = positions  # [S] int32: write pos of new token
-        self.window = int(window)
-
-    def update_and_attend(self, q, k, v):
-        import jax.numpy as jnp
-
-        from ..models.serving_seam import masked_attention
-
-        qa, ka, va = (t._data if isinstance(t, Tensor) else t
-                      for t in (q, k, v))
-        w, pos = self.window, self.positions
-        s_lanes, heads = qa.shape[0], ka.shape[2]
-        lanes = jnp.arange(s_lanes)[:, None]
-        at = jnp.arange(heads)[None, :] * w + (pos % w)[:, None]  # [S, H]
-
-        def write(ring, new):  # ring [S, H, w, D], new [S, 1, H, D]
-            slab = ring.reshape(s_lanes, heads * w, ring.shape[-1])
-            slab = slab.at[lanes, at].set(new[:, 0].astype(ring.dtype))
-            return slab.reshape(ring.shape)
-
-        entry = tuple(write(ring, new)
-                      for ring, new in zip(self.entry, (ka, va)))
-        live = jnp.minimum(pos + 1, w)
-        mask = (jnp.arange(w)[None, :] < live[:, None])[:, None, None, :]
-        o = masked_attention(qa, jnp.swapaxes(entry[0], 1, 2),
-                             jnp.swapaxes(entry[1], 1, 2), mask)
-        return o, _WindowDecodeView(entry, pos, w)
-
-
-class _WindowPrefillView:
-    """One ``"window"`` layer's prefill view: query ``t`` of the (padded)
-    prompt attends keys ``t - window + 1 .. t``, and the last ``window``
-    rows before ``true_len`` go into lane ``slot``'s ring (``[kv_heads,
-    window, D]``), row ``t`` at ``t % window`` (the keys as the model
-    handed them over: rotated, where it rotates). Key tiles wholly outside
-    the window are never computed, so the work grows linearly with the
-    prompt, by either route: ``kernel=True`` (the engine's prefill route
-    is the kernel, ``ServingConfig.paged_kernel``) through the banded
-    flash kernel
-    (:func:`paddle_tpu.ops.paged_attention.swa_prefill_attention`), which
-    holds a tile of scores at a time; ``kernel=False`` in XLA, a chunk of
-    ``window`` queries at a time against its own chunk and the one before,
-    whose scores ``[heads, window, 2 window]`` are materialised (84 MB at
-    a window of 512, 6.4 GB at 4,096: a model of that window is served
-    with the kernel route)."""
-
-    def __init__(self, entry, slot, true_len, window: int,
-                 kernel: bool = False):
-        self.entry = entry
-        self.slot = slot          # scalar int32: the lane being admitted
-        self.true_len = true_len  # scalar int32: real (unpadded) length
-        self.window = int(window)
-        self.kernel = kernel
-
-    def update_and_attend(self, q, k, v):
-        import jax.numpy as jnp
-
-        qa, ka, va = (t._data if isinstance(t, Tensor) else t
-                      for t in (q, k, v))
-        if self.kernel:
-            from ..ops.paged_attention import swa_prefill_attention
-
-            o = swa_prefill_attention(qa[0], ka[0], va[0], self.window)[None]
-        else:
-            o = self._attend_chunks(qa, ka, va)
-        # ring row r takes the last position t < true_len with t % w == r
-        w, last = self.window, self.true_len - 1
-        t_r = last - (last - jnp.arange(w)) % w
-        entry = tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                ring, jnp.swapaxes(new[0][jnp.maximum(t_r, 0)], 0, 1)[None]
-                .astype(ring.dtype), self.slot, axis=0)
-            for ring, new in zip(self.entry, (ka, va)))
-        return o, _WindowPrefillView(entry, self.slot, self.true_len, w,
-                                     kernel=self.kernel)
-
-    def _attend_chunks(self, qa, ka, va):
-        import jax.numpy as jnp
-
-        from ..models.serving_seam import masked_attention
-
-        w, p = self.window, qa.shape[1]
-        n = -(-p // w)
-
-        def chunks(a, lead):  # [1, p, H, D] -> [n, w, H, D], `lead` rows on
-            a = jnp.pad(a[0], ((lead, n * w - p), (0, 0), (0, 0)))
-            return a[:n * w].reshape((n, w) + a.shape[1:])
-
-        qi = jnp.arange(w)[:, None] + w        # a query's column in 2w keys
-        ki = jnp.arange(2 * w)[None, :]
-        band = (ki <= qi) & (qi - ki < w)      # [w, 2w]
-        first = ki >= w                        # chunk 0 has no chunk before
-
-        def one(xs):
-            qc, k0, k1, v0, v1, c = xs
-            mask = band & (first | (c > 0))
-            return masked_attention(
-                qc[None], jnp.concatenate([k0, k1])[None],
-                jnp.concatenate([v0, v1])[None], mask[None, None])[0]
-
-        o = jax.lax.map(one, (chunks(qa, 0), chunks(ka, w), chunks(ka, 0),
-                              chunks(va, w), chunks(va, 0), jnp.arange(n)))
-        return o.reshape((1, n * w) + o.shape[2:])[:, :p]
-
-
-class _LatentDecodeView:
-    """One ``"latent"`` layer's decode-step view: ``entry`` is its pool
-    entry ``(rows,)`` (:meth:`KVArena._fresh_latent`). The layer hands over
-    each lane's ABSORBED queries ``[S, 1, heads, W]`` and its new token's
-    one row ``[S, 1, W]``; the row is written at the lane's (block, offset)
-    (a lane that is not active writes scratch block 0) and every head
-    attends the lane's rows up to and including it: scores ``q . row *
-    scale``, output the probabilities' sum of the rows' first ``W -
-    rope_dim`` values. ``kernel``: through the Pallas latent decode kernel
-    (:func:`paddle_tpu.ops.paged_attention.paged_latent_decode`: the live
-    pages alone, each read once); else the XLA gather of the tables."""
-
-    absorbed = True
-
-    def __init__(self, entry, block_tables, positions, active,
-                 block_size: int, latent_dim: int, kernel: bool = False):
-        self.entry = entry
-        self.block_tables = block_tables
-        self.positions = positions
-        self.active = active
-        self.block_size = block_size
-        self.latent_dim = int(latent_dim)
-        self.kernel = kernel
-
-    def write_and_attend(self, q, rows, scale, kv=None):
-        import jax.numpy as jnp
-
-        from ..ops import paged_attention as pa
-
-        qa, ra = (t._data if isinstance(t, Tensor) else t for t in (q, rows))
-        bs, pos = self.block_size, self.positions
-        blk = self.block_tables[jnp.arange(qa.shape[0]), pos // bs]
-        blk = jnp.where(self.active, blk, 0)
-        pool = pa.write_latent_token(self.entry[0], blk, pos % bs, ra[:, 0])
-        if self.kernel:
-            o = pa.paged_latent_decode(qa[:, 0], pool, self.block_tables,
-                                       pos, self.latent_dim, scale,
-                                       active=self.active)[:, None]
-        else:
-            with jax.named_scope("kv_gather"):
-                ctx = pa.latent_rows(pool, ra.shape[-1])[self.block_tables]
-            ctx = ctx.reshape(qa.shape[0], -1, ra.shape[-1])  # [S, T, W]
-            sc = jnp.einsum("shw,stw->sht", qa[:, 0], ctx) * scale
-            mask = jnp.arange(ctx.shape[1])[None, :] <= pos[:, None]
-            sc = jnp.where(mask[:, None, :], sc, -1e30)
-            pr = jax.nn.softmax(sc.astype(jnp.float32), -1).astype(qa.dtype)
-            o = jnp.einsum("sht,std->shd", pr,
-                           ctx[..., :self.latent_dim])[:, None]
-        return o, _LatentDecodeView((pool,), self.block_tables, pos,
-                                    self.active, bs, self.latent_dim,
-                                    self.kernel)
-
-
-class _LatentPrefillView:
-    """One ``"latent"`` layer's prefill view: the layer hands over the
-    prompt's queries, its rows and the keys and values EXPANDED from them;
-    the rows are kept for the engine to scatter into the slot's blocks
-    (:func:`_scatter_latent`), and the attention is causal over the
-    expanded form (keys wider than values): the flash forward kernel
-    (:func:`paddle_tpu.ops.paged_attention.latent_prefill_attention`) where
-    ``kernel``, else plain XLA (the CPU's tiny prompts: its scores are
-    ``[heads, s, s]``)."""
-
-    absorbed = False
-
-    def __init__(self, kernel: bool = False, rows=None):
-        self.kernel = kernel
-        self.rows = rows
-
-    def write_and_attend(self, q, rows, scale, kv=None):
-        import jax.numpy as jnp
-
-        qa, ra, ka, va = (t._data if isinstance(t, Tensor) else t
-                          for t in (q, rows) + tuple(kv))
-        if self.kernel:
-            from ..ops.paged_attention import latent_prefill_attention
-
-            o = latent_prefill_attention(qa[0], ka[0], va[0], scale)[None]
-        else:
-            p = qa.shape[1]
-            sc = jnp.einsum("bqhd,bkhd->bhqk", qa, ka) * scale
-            mask = jnp.arange(p)[None, :] <= jnp.arange(p)[:, None]
-            sc = jnp.where(mask[None, None], sc, -1e30)
-            pr = jax.nn.softmax(sc.astype(jnp.float32), -1).astype(qa.dtype)
-            o = jnp.einsum("bhqk,bkhd->bqhd", pr, va)
-        return o, _LatentPrefillView(self.kernel, ra)
-
-
-def _scatter_latent(entry, table_rows, true_len, rows, block_size: int):
-    """A prompt's latent rows ``[p, W]`` into the slot's blocks
-    (``table_rows``: the block of each ``block_size`` positions). Rows go
-    in whole POOL rows (``pack`` consecutive tokens): a pool row whose
-    first token is at or past ``true_len`` is padding and lands in scratch
-    block 0; one that straddles ``true_len`` carries a padding token
-    behind a real one, which the decode step that writes that position
-    replaces before any mask lets it be read."""
-    import jax.numpy as jnp
-
-    pool = entry[0]
-    p, width = rows.shape
-    pack = pool.shape[2] // width
-    first = jnp.arange(p // pack) * pack            # each pool row's token
-    blk = jnp.where(first < true_len, table_rows[first // block_size], 0)
-    return (pool.at[blk, (first % block_size) // pack].set(
-        rows.reshape(p // pack, pack * width).astype(pool.dtype)),)
-
-
-def _stateless_view(state):
-    """What ``forward_cached`` is handed for a layer that owns no state: a
-    reference to the layer whose pool a ``"shared"`` layer reads (its real
-    view exists only once that layer has run), nothing for a ``"none"``
-    layer."""
-    from ..models.serving_seam import SharedRef
-
-    return SharedRef(state.source) if state.kind == "shared" else None
-
-
-def _split_views(views, kinds):
-    """The successor views' storage by kind, in layer order: ``(entries of
-    the "kv" layers, entries of the layers whose state lies in the
-    slot-indexed store)``."""
-    from ..models.serving_seam import PAGED_KINDS, SLOT_KINDS
-
-    kv = [v.entry for v, k in zip(views, kinds) if k in PAGED_KINDS]
-    rec = [v.entry for v, k in zip(views, kinds) if k in SLOT_KINDS]
-    return kv, rec
 
 
 @dataclass
@@ -1027,38 +366,19 @@ class ServingEngine:
         self._arrays = [p._data for p in self._objs]
 
         # what the model declares (the seam): sizes, and per layer the kind
-        # of state it keeps. The paged pools cover the "kv" layers, the
-        # arena's slot-indexed store the "recurrent" ones
+        # of state it keeps. What each kind needs of this engine is the
+        # table's to say (cache_views.KINDS): the block pools hold ONE shape
+        # of row for the layers that own one, the slot-indexed store the
+        # state of those with a fixed size per lane
         spec = model.serving_spec()
         self._layer_states = tuple(spec.layers)
-        self._layer_kinds = tuple(st.kind for st in spec.layers)
+        self._layer_kinds = tuple(cache_views.KINDS[st.kind]
+                                  for st in spec.layers)
         self._prefill_tail = spec.prefill_tail
-        kv_layers = spec.kv_layers()
-        if len({(st.kv_heads, st.head_dim) for st in kv_layers}) > 1:
-            raise ValueError("the paged arena holds one (heads, head_dim) "
-                             "for all of a model's kv layers")
-        # "latent" layers: ONE row a token in the same block pools
-        latent_layers = spec.latent_layers()
-        if latent_layers and (kv_layers or len(
-                {st.width for st in latent_layers}) > 1):
-            raise ValueError("the paged arena holds one shape of row: a "
-                             "model's latent layers share one width, and "
-                             "it has no kv layer beside them")
-        self.latent = bool(latent_layers)
-        for i, st in enumerate(spec.layers):
-            if st.kind == "shared" and not (
-                    0 <= st.source < i
-                    and spec.layers[st.source].kind == "kv"):
-                raise ValueError(
-                    f"layer {i} shares the cache of layer {st.source}, "
-                    "which is no paged kv layer before it")
-        from ..models.serving_seam import SLOT_KINDS
-
-        # the layers whose state has a fixed size per lane, in layer order:
-        # "recurrent" ones and "window" ones share the slot-indexed store
-        slot_layers = [st for st in spec.layers if st.kind in SLOT_KINDS]
-        self._slot_kinds = tuple(st.kind for st in slot_layers)
-        self.recurrent = bool(slot_layers)
+        kv_heads, kv_dim, latent_width = cache_views.pool_row(spec.layers)
+        paged_layers, self._slot_layers = cache_views.by_store(
+            spec.layers, spec.layers)
+        self.recurrent = bool(self._slot_layers)
         self.num_slots = int(cfg.num_slots or flags.flag("serving_slots"))
         self.block_size = int(cfg.kv_block_size or flags.flag("kv_block_size"))
         self.max_model_len = int(cfg.max_model_len or spec.max_positions)
@@ -1090,11 +410,9 @@ class ServingEngine:
 
             # natively compiled, and reading the pools where they lie
             self.decode_kernel = not pallas_ops._use_interpret() and all(
-                paged_attention.decode_in_place(st.head_dim)
-                for st in kv_layers) and all(
                 paged_attention.decode_in_place(
-                    paged_attention.latent_pack(st.width) * st.width)
-                for st in latent_layers)
+                    cache_views.KINDS[st.kind].minor(st))
+                for st in paged_layers)
         else:
             self.decode_kernel = self.paged_kernel
         # the mesh the kernel calls route through (ISSUE 16): on a
@@ -1135,63 +453,40 @@ class ServingEngine:
         # the mesh rides along so the rebuilt arena re-commits the SAME
         # pool shardings (identical shapes AND placements => the
         # supervisor's rebuild/replay path stays zero-recompile on a mesh)
-        kv_heads, kv_dim = ((kv_layers[0].kv_heads, kv_layers[0].head_dim)
-                            if kv_layers else (1, 1))
         self.use_prefix_cache = (bool(flags.flag("serving_prefix_cache"))
                                  if cfg.prefix_cache is None
                                  else bool(cfg.prefix_cache))
         self.kv_tiering = (bool(flags.flag("serving_kv_tiering"))
                            if cfg.kv_tiering is None
                            else bool(cfg.kv_tiering))
-        if self.latent:
-            # each of these attends a resident prefix or verifies drafts
-            # through "kv" views, stores int8 K and V, or shards heads: a
-            # latent row is none of that yet. Refused by name
-            for on, option in (
-                    (self.use_prefix_cache, "prefix_cache"),
-                    (self.kv_tiering, "kv_tiering"),
-                    (spec_k > 0, "spec_k (speculative decoding)"),
-                    (self.chunk_size > 0, "chunked_prefill"),
-                    (self.quant_kv, "quant_kv"),
-                    (self._mesh_devices > 1, "mesh (more than one chip)")):
-                if on:
-                    raise ValueError(
-                        f"{option} is not supported for a model with "
-                        "latent-attention layers (one shared row a token "
-                        "in the paged pool): it assumes per-head K and V "
-                        "pools")
-        self._arena_args = (len(kv_layers) + len(latent_layers), kv_heads,
-                            kv_dim, num_blocks, self.block_size, kv_dtype,
-                            self.quant_kv,
-                            None if self.latent else self.mesh,
+        # the options a kind of state among the model's cannot be served
+        # with are refused by name, not served with a silently wrong answer
+        cache_views.refuse_options(spec.layers, {
+            "prefix_cache": self.use_prefix_cache,
+            "kv_tiering": self.kv_tiering,
+            "spec_k (speculative decoding)": spec_k > 0,
+            "chunked_prefill": self.chunk_size > 0,
+            "quant_kv": self.quant_kv,
+            "mesh (more than one chip)": self._mesh_devices > 1})
+        self._arena_args = (len(paged_layers), kv_heads, kv_dim, num_blocks,
+                            self.block_size, kv_dtype, self.quant_kv,
+                            # a latent row has no heads to shard
+                            None if latent_width else self.mesh,
                             self.num_slots,
-                            tuple(st.arrays if st.kind == "recurrent"
-                                  else st.arrays(kv_dtype)
-                                  for st in slot_layers),
-                            latent_layers[0].width if self.latent else 0)
+                            tuple(cache_views.KINDS[st.kind].arrays(
+                                st, kv_dtype) for st in self._slot_layers),
+                            latent_width)
         self.arena = KVArena(*self._arena_args)
+        # pool arrays a full prefill writes in whole blocks (an admission)
+        self._block_writes = sum(
+            len(entry) for st, entry in zip(paged_layers, self.arena.pools)
+            if cache_views.KINDS[st.kind].block_writes)
         # tiered KV cache (ISSUE 15): the TierView survives rebuild()
         # untouched — host/disk tiers are off-device by construction, so
         # crash recovery replays against a warm cache. The view's arena
         # signature (shape facts + quant mode + mesh fingerprint) keeps
         # incompatible engines from ever exchanging entries through a
         # shared store.
-        if self.recurrent:
-            # each of these keeps, shares or rewinds a request's state as
-            # BLOCKS; a recurrent layer's state is not blocks (it would
-            # need a snapshot per cached prefix, per chunk, per rollback).
-            # Refuse by name instead of serving a silently wrong answer
-            for on, option in (
-                    (self.use_prefix_cache, "prefix_cache"),
-                    (self.kv_tiering, "kv_tiering"),
-                    (spec_k > 0, "spec_k (speculative decoding)"),
-                    (self.chunk_size > 0, "chunked_prefill")):
-                if on:
-                    raise ValueError(
-                        f"{option} is not supported for a model with "
-                        "recurrent-state layers (a fixed-size state or "
-                        "window per lane): it assumes every layer's state "
-                        "is paged blocks")
         self.tier = None
         if self.kv_tiering and self.use_prefix_cache:
             from .tiered import TierView, get_tier_store
@@ -1199,7 +494,7 @@ class ServingEngine:
             store = (cfg.tier_store if cfg.tier_store is not None
                      else get_tier_store())
             self.tier = TierView(store, signature=(
-                len(kv_layers), kv_heads, kv_dim, self.block_size,
+                len(paged_layers), kv_heads, kv_dim, self.block_size,
                 kv_dtype, self.quant_kv, self.mesh_key))
         self.prefix_cache = (PrefixCache(self.arena, self.block_size,
                                          tier=self.tier)
@@ -1318,8 +613,10 @@ class ServingEngine:
         metrics.set_gauge("mesh.model_axis", self._mesh_model)
         metrics.set_gauge("mesh.data_axis", self._mesh_data)
         metrics.set_gauge("kernel.paged", int(self.decode_kernel))
-        metrics.set_gauge("kernel.paged_latent",
-                          int(self.decode_kernel and self.latent))
+        for row in cache_views.KINDS.values():
+            if row.kernel_gauge:  # a kind with a decode kernel of its own
+                metrics.set_gauge(row.kernel_gauge, int(
+                    self.decode_kernel and row in self._layer_kinds))
         if spec.kernels:  # the model's own (serving_seam.ServingSpec)
             from ..ops.pallas_ops import _use_interpret
 
@@ -1334,13 +631,6 @@ class ServingEngine:
         metrics.set_gauge("kernel.mesh", self.kernel_route())
         for ns in ["primary"] + self.arena.namespaces():
             metrics.set_gauge(f"kernel.mesh.{ns}", self.kernel_route())
-        if self.decode_kernel:
-            from ..ops import tuning as kernel_tuning
-
-            # the tuning store's coverage for this chip, next to the mode
-            # gauge: a chip with 0 entries runs the safe default launch
-            # params until a tune bench adopts better ones
-            metrics.set_gauge("kernel.tuned_entries", kernel_tuning.entries())
         metrics.set_gauge("tier.enabled", int(self.tier is not None))
         metrics.set_gauge("quant.weights", int(self.quant_weights))
         metrics.set_gauge("quant.kv", int(self.quant_kv))
@@ -1511,8 +801,7 @@ class ServingEngine:
             return fn
         from ..core import rng as prng
         from ..jit import _swap_data
-        from ..models.serving_seam import (PAGED_KINDS, SLOT_KINDS,
-                                           forward_cached)
+        from ..models.serving_seam import forward_cached
         from .sampling import sample_tokens
 
         model = self._model
@@ -1521,8 +810,8 @@ class ServingEngine:
         tail = self._prefill_tail
         bs = self.block_size
         use_kernel = self.paged_kernel
-        # a latent layer's prompt attention has no XLA form that fits a
-        # long prompt: it follows the decode step's route
+        # a kind whose prompt attention has no XLA form that fits a long
+        # prompt follows the decode step's route
         latent_kernel = self.paged_kernel or self.decode_kernel
         kmesh = self._kernel_mesh
 
@@ -1543,24 +832,13 @@ class ServingEngine:
             # what the layer it names captured; a model with a prefill
             # tail runs its last layers on row `last` alone
             last = None if tail is None else true_len - 1
-            it_rec = iter(rec)
-            views = []
-            for i, st in enumerate(states):
-                if st.kind == "kv":
-                    views.append(_CapturePrefillView(
-                        bs, kernel=use_kernel, mesh=kmesh,
-                        last=last if i + 1 == tail else None))
-                elif st.kind == "recurrent":
-                    views.append(_SlotStatePrefillView(next(it_rec), slot,
-                                                       true_len))
-                elif st.kind == "window":
-                    views.append(_WindowPrefillView(
-                        next(it_rec), slot, true_len, st.window,
-                        kernel=use_kernel))
-                elif st.kind == "latent":
-                    views.append(_LatentPrefillView(latent_kernel))
-                else:
-                    views.append(_stateless_view(st))
+            ctx = cache_views.PrefillContext(slot, true_len, bs, use_kernel,
+                                             latent_kernel, kmesh)
+            entries = cache_views.layer_entries(states, pools, rec)
+            views = [kind.prefill_view(st, entry, ctx._replace(last=last)
+                                       if i + 1 == tail else ctx)
+                     for i, (kind, st, entry)
+                     in enumerate(zip(kinds, states, entries))]
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_args) if lora is not None
@@ -1575,21 +853,12 @@ class ServingEngine:
                     else:
                         h_last = h._data[:, 0]
                     logits = model.serving_head(h_last)
-            chunks = [v for v, k in zip(new_views, kinds)
-                      if k in PAGED_KINDS]
-            new_rec = [v.entry for v, k in zip(new_views, kinds)
-                       if k in SLOT_KINDS]
-            # blocks (pool rows, for a latent layer) wholly past the true
-            # prompt length land in the scratch block: bucketing never
-            # pollutes another lane's cache state
-            new_pools = []
-            for chunk, entry in zip(chunks, pools):
-                if isinstance(chunk, _LatentPrefillView):
-                    new_pools.append(_scatter_latent(
-                        entry, rows, true_len, chunk.rows[0], bs))
-                    continue
-                new_pools.append(_scatter_blocks(
-                    entry, rows, true_len, chunk.k[0], chunk.v[0], bs))
+            # each kind commits what its view left behind: blocks (pool
+            # rows) wholly past the true prompt length land in the scratch
+            # block, so bucketing never pollutes another lane's cache state
+            new_pools, new_rec = cache_views.by_store(states, [
+                kind.commit(view, entry, rows, ctx) for kind, view, entry
+                in zip(kinds, new_views, entries)])
             # the first generated token goes through the SAME sampling
             # core as the decode step ([1, V] and [S, V] rows are
             # bit-identical per row); greedy/unmasked slots reproduce
@@ -1638,10 +907,9 @@ class ServingEngine:
                 # trace-time: the paged-kernel twin of prefill_traces —
                 # asserts chunk/hit churn never re-lowers the kernel
                 metrics.bump("kernel.prefill_traces")
-            views = [_PrefixPrefillView(entry, bt_row, prefix_len,
-                                        true_len, bs, kernel=use_kernel,
-                                        mesh=kmesh)
-                     for entry in pools]
+            views = [cache_views.PrefixPrefillView(
+                entry, bt_row, prefix_len, true_len, bs, kernel=use_kernel,
+                mesh=kmesh) for entry in pools]
             with _swap_data(self._objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     with (lora.bind(*lora_args) if lora is not None
@@ -1831,24 +1099,12 @@ class ServingEngine:
             temp = jax.lax.bitcast_convert_type(state[_ST_TEMP], jnp.float32)
             top_p = jax.lax.bitcast_convert_type(state[_ST_TOP_P],
                                                  jnp.float32)
-            it_kv, it_rec = iter(pools), iter(rec)
-            views = []
-            for st in states:
-                if st.kind == "kv":
-                    views.append(_PagedCacheView(
-                        next(it_kv), block_tables, positions, active, bs,
-                        kernel=use_kernel, mesh=kmesh))
-                elif st.kind == "recurrent":
-                    views.append(_SlotStateDecodeView(next(it_rec), active))
-                elif st.kind == "window":
-                    views.append(_WindowDecodeView(next(it_rec), positions,
-                                                   st.window))
-                elif st.kind == "latent":
-                    views.append(_LatentDecodeView(
-                        next(it_kv), block_tables, positions, active, bs,
-                        st.latent_dim, kernel=use_kernel))
-                else:
-                    views.append(_stateless_view(st))
+            ctx = cache_views.DecodeContext(block_tables, positions, active,
+                                            bs, use_kernel, kmesh)
+            views = [kind.decode_view(st, entry, ctx)
+                     for kind, st, entry in zip(
+                         kinds, states,
+                         cache_views.layer_entries(states, pools, rec))]
             # the step carry's seed: the lanes that hold a request
             carry = {"lanes": active[:, None]}
             with _swap_data(self._objs, list(arrays)):
@@ -1869,7 +1125,9 @@ class ServingEngine:
                 nxt = sample_tokens(logits, temp, state[_ST_TOP_K], top_p,
                                     state[_ST_SEED], positions + 1,
                                     allowed=vmask)
-            new_pools, new_rec = _split_views(new_views, kinds)
+            new_pools, new_rec = (
+                [v.entry for v in owned]
+                for owned in cache_views.by_store(states, new_views))
             # the next step's state, as the host's mirrors will read after
             # this one: active lanes advance a position and hold `nxt`
             new_state = state.at[_ST_POS].set(
@@ -2446,11 +1704,8 @@ class ServingEngine:
                   self._samp_row(slot, clen), jnp.int32(slot))
             lora = self._lora_args(slot)
             self._count_prefill_call(p_bucket, clen, up, lora[1:])
-            if not self.latent:
-                # every array of every "kv" layer's entry, in whole
-                # blocks (:func:`_scatter_blocks`)
-                metrics.bump("prefill.block_writes",
-                             sum(len(e) for e in self.arena.pools))
+            if self._block_writes:
+                metrics.bump("prefill.block_writes", self._block_writes)
         with telemetry.phase("prefill.dispatch", self.hists):
             fn = self._get_prefill(p_bucket)
             out = self._call(
@@ -2960,20 +2215,21 @@ class ServingEngine:
         metrics.set_gauge("arena.kv_bytes", self.arena.bytes_total())
         # layers that own a paged pool, and layers that read one (a
         # "shared" layer reads the pool of the layer it names)
-        metrics.set_gauge("arena.paged_layers",
-                          self._layer_kinds.count("kv")
-                          + self._layer_kinds.count("latent"))
-        metrics.set_gauge("arena.kv_readers", self._layer_kinds.count("kv")
-                          + self._layer_kinds.count("shared"))
+        for gauge in sorted({g for row in cache_views.KINDS.values()
+                             for g in row.counted_in}):
+            metrics.set_gauge(gauge, sum(gauge in row.counted_in
+                                         for row in self._layer_kinds))
         if self.recurrent:
             metrics.set_gauge("state.bytes_total",
                               self.arena.state_bytes_total())
-            by_kind = {"recurrent": 0, "window": 0}
-            for kind, entry in zip(self._slot_kinds, self.arena.slot_state):
-                by_kind[kind] += sum(int(a.size) * a.dtype.itemsize
-                                     for a in entry)
-            metrics.set_gauge("state.ssm_bytes", by_kind["recurrent"])
-            metrics.set_gauge("state.window_bytes", by_kind["window"])
+            by_gauge = {row.bytes_gauge: 0
+                        for row in cache_views.KINDS.values()
+                        if row.bytes_gauge}
+            for st, entry in zip(self._slot_layers, self.arena.slot_state):
+                by_gauge[cache_views.KINDS[st.kind].bytes_gauge] += sum(
+                    int(a.size) * a.dtype.itemsize for a in entry)
+            for gauge, nbytes in by_gauge.items():
+                metrics.set_gauge(gauge, nbytes)
         by_ns = self.arena.bytes_by_namespace()
         metrics.set_gauge("arena.scale_bytes",
                           sum(d["scale_bytes"] for d in by_ns.values()))

@@ -102,11 +102,10 @@ Counter namespaces:
   ``ops.paged_attention.paged_latent_decode``), ``kernel.hyper_connection``
   (0/1, set for a model whose ``ServingSpec.kernels`` names it: the
   streams' update, mixers and read-out run as the Pallas kernel
-  ``ops.hyper_connection``, not as its body in the interpreter),
+  ``ops.hyper_connection``, not as its body in the interpreter) and
   ``kernel.gdn_chunk`` (0/1, likewise: a prefill's chunkwise gated delta
   rule runs as the Pallas kernel of ``ops.gated_delta``, not as its
-  ``jax.numpy`` form) and ``kernel.tuned_entries`` (tuning-store
-  records for this chip — ``ops.tuning`` / benches/TUNED_KERNELS.json)
+  ``jax.numpy`` form)
 
 * ``moe.*``        — the expert layers' load, summed by the decode step
   program over its expert layers and the lanes that hold a request, and

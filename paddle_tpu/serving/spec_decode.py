@@ -18,7 +18,7 @@ semantics are *bit-identical* to plain decode by construction:
   correctness.
 * The verify program is deliberately **unrolled into k+1 single-token
   sub-steps inside one jitted call**, each running the exact ops (same
-  shapes, same :class:`~.engine._PagedCacheView`, same
+  shapes, same :class:`~.cache_views.PagedCacheView`, same
   ``GPTForCausalLM._head_logits``) as the plain compiled decode step. A
   single ``[S, k+1]`` batched forward would be mathematically equal but
   NOT bitwise equal (shape-dependent matmul reduction order), which would
@@ -268,7 +268,7 @@ class SpecDecoder:
         from ..core import rng as prng
         from ..jit import _swap_data
         from ..models.serving_seam import forward_cached
-        from .engine import _CapturePrefillView, _scatter_blocks
+        from .cache_views import CapturePrefillView, scatter_blocks
 
         draft = self.draft
         n_layers = len(draft.serving_spec().layers)
@@ -280,12 +280,12 @@ class SpecDecoder:
             self.draft_prefill_traces[p_bucket] = \
                 self.draft_prefill_traces.get(p_bucket, 0) + 1
             compile_cache.bump("serving.prefill_compiles")
-            views = [_CapturePrefillView() for _ in range(n_layers)]
+            views = [CapturePrefillView() for _ in range(n_layers)]
             with _swap_data(self._d_objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):
                     _, chunks = forward_cached(draft, Tensor(ids), views, 0)
-            return [_scatter_blocks(entry, rows, true_len, chunk.k[0],
-                                    chunk.v[0], bs)
+            return [scatter_blocks(entry, rows, true_len, chunk.k[0],
+                                   chunk.v[0], bs)
                     for chunk, entry in zip(chunks, pools)]
 
         fn = (jax.jit(draft_prefill, donate_argnums=(3,))
@@ -310,7 +310,7 @@ class SpecDecoder:
         from ..core import rng as prng
         from ..jit import _swap_data
         from ..models.serving_seam import forward_cached
-        from .engine import _PagedCacheView
+        from .cache_views import PagedCacheView
 
         engine = self.engine
         model = engine._model
@@ -330,8 +330,8 @@ class SpecDecoder:
             with it — on a multi-device mesh the sub-steps run the
             sharded kernel per model-shard like the main decode step).
             Returns (last hidden [S, H], new pools)."""
-            views = [_PagedCacheView(entry, bt, positions, act, bs,
-                                     kernel=use_kernel, mesh=kmesh)
+            views = [PagedCacheView(entry, bt, positions, act, bs,
+                                    kernel=use_kernel, mesh=kmesh)
                      for entry in pools]
             with _swap_data(objs, list(arrays)):
                 with prng.key_guard(jax.random.key(0)):

@@ -1,5 +1,5 @@
 """known-bad twin of the paged-attention kernel dispatch pattern
-(ops.paged_attention / engine._PagedCacheView): block tables and
+(ops.paged_attention / cache_views.PagedCacheView): block tables and
 positions must ride compiled programs as runtime DATA. This one
 (1) derives the kernel's workload from the table's CONTENTS — boolean-
 mask indexing over the non-scratch entries gives a data-dependent shape

@@ -1,5 +1,5 @@
 """known-good twin of the paged-attention kernel dispatch pattern
-(ops.paged_attention / engine._PagedCacheView): the block table is
+(ops.paged_attention / cache_views.PagedCacheView): the block table is
 runtime data with a STATIC shape — every table entry is covered
 unconditionally (scratch rows are masked by position, never filtered
 out), and launch-shaping decisions come from static shapes

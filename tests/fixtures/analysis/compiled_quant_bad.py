@@ -1,5 +1,5 @@
 """known-bad twin of the quantized-serving dequant pattern
-(quantization.quantize_kv / engine._scatter_rows): a compiled dequant
+(quantization.quantize_kv / cache_views.scatter_rows): a compiled dequant
 must be all-array math. This one (1) computes its scale THROUGH a host
 cast — ``float()`` on a traced absmax is traced-cast: it forces a
 device sync per call and bakes the first batch's scale into the

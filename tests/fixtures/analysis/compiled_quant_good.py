@@ -1,5 +1,5 @@
 """known-good twin of the quantized-serving dequant pattern
-(quantization.quantize_kv / engine._scatter_rows): the scale is a traced
+(quantization.quantize_kv / cache_views.scatter_rows): the scale is a traced
 ARRAY (no host cast — it rides the program as data, one executable for
 every batch), and the dequant covers every element unconditionally with
 masking expressed as ``where`` over a static shape — no data-dependent

@@ -1,7 +1,7 @@
 """Pallas paged-attention serving kernels (ISSUE 13): interpreter-mode
 parity of :mod:`paddle_tpu.ops.paged_attention` against the XLA gather
-baseline (``engine._gather_ctx`` + ``serving_seam.masked_attention``), the shared
-kernel-tuning store (:mod:`paddle_tpu.ops.tuning`), and the engine
+baseline (``cache_views.gather_ctx`` + ``serving_seam.masked_attention``),
+the launch shapes the kernels derive for themselves, and the engine
 integration behind ``ServingConfig.paged_kernel``.
 
 Parity policy (docs/performance.md "Paged attention kernels"): the
@@ -14,15 +14,15 @@ bodies through the Pallas interpreter on the CPU mesh."""
 import json
 import math
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core import compile_cache
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle_tpu.ops import paged_attention as pk
-from paddle_tpu.ops import tuning
 from paddle_tpu.serving import ServingAPI, ServingConfig
 from paddle_tpu.serving import metrics as serving_metrics
 
@@ -36,7 +36,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from paddle_tpu.quantization import quantize_kv  # noqa: E402
-from paddle_tpu.serving.engine import _gather_ctx  # noqa: E402
+from paddle_tpu.serving.cache_views import gather_ctx  # noqa: E402
 from paddle_tpu.models.serving_seam import masked_attention  # noqa: E402
 
 
@@ -44,19 +44,19 @@ from paddle_tpu.models.serving_seam import masked_attention  # noqa: E402
 
 
 def _decode_ref(q, entry, bt, pos):
-    """The XLA gather baseline, op-for-op what _PagedCacheView does after
+    """The XLA gather baseline, op-for-op what PagedCacheView does after
     the scatter: gather the whole logical context, mask to <= pos."""
     t_len = bt.shape[1] * entry[0].shape[1]
-    k_all, v_all = _gather_ctx(entry, bt, q.dtype)
+    k_all, v_all = gather_ctx(entry, bt, q.dtype)
     mask = (jnp.arange(t_len)[None, :] <= pos[:, None])[:, None, None, :]
     return masked_attention(q[:, None], k_all, v_all, mask)[:, 0]
 
 
 def _prefill_ref(q, entry, bt_row, prefix_len):
-    """The _PrefixPrefillView baseline: one slot's suffix queries at
+    """The PrefixPrefillView baseline: one slot's suffix queries at
     global positions prefix_len + i over the gathered table."""
     t_len = bt_row.shape[0] * entry[0].shape[1]
-    k_all, v_all = _gather_ctx(entry, bt_row, q.dtype)
+    k_all, v_all = gather_ctx(entry, bt_row, q.dtype)
     gpos = prefix_len + jnp.arange(q.shape[0])
     mask = (jnp.arange(t_len)[None, :] <= gpos[:, None])[None, None]
     return masked_attention(q[None], k_all[None], v_all[None], mask)[0]
@@ -352,111 +352,102 @@ def test_kernel_runtime_data_one_trace():
     assert traces["n"] == 1
 
 
-# ------------------------------------------------------------ tuning store
+# ------------------------------ launch shapes: derived, in the kernel's file
 
 
-def test_tuning_store_roundtrip(tmp_path):
-    tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
-    try:
-        key = tuning.bucket_key(h=4, d=32, bs=16, mb=7)
-        assert tuning.lookup("paged_decode", key) is None
-        tuning.adopt("paged_decode", key, {"block_h": 2}, 12.5,
-                     baseline_us=20.0)
-        tuning.reset()  # force a re-read from disk
-        assert tuning.lookup("paged_decode", key) == {"block_h": 2}
-        assert tuning.entries() == 1
-        assert tuning.entries("paged_decode") == 1
-        assert tuning.entries("paged_prefill") == 0
-        # persisted under THIS device kind only
-        with open(tuning.store_path()) as f:
-            data = json.load(f)
-        assert list(data["records"]) == [tuning.device_kind()]
-    finally:
-        tuning.set_store_path(None)
+@pytest.mark.parametrize("kind,seq,tiles", [
+    ("TPU v5 lite", 512, None),            # under the measured range
+    ("TPU v5 lite", 1024, (1024, 1024)),   # its first row (`train-1chip`)
+    ("TPU v5 lite", 3000, (1024, 1024)),   # inside: the nearest row, 2048
+    ("TPU v5 lite", 8192, (1024, 512)),    # its last row
+    ("TPU v5 lite", 32768, (1024, 512)),   # past it: the last row still
+    ("TPU v9000", 2048, None),             # a chip nobody measured
+])
+def test_flash_tuned_blocks_come_from_the_table_alone(kind, seq, tiles,
+                                                      monkeypatch):
+    """``_tuned_blocks``: the kernel module's own table by device kind,
+    the nearest measured sequence length inside and past the measured
+    range, nothing under it and nothing for a kind that is not there."""
+    from types import SimpleNamespace
 
-
-def test_tuning_adopt_merges_fresh_disk_state(tmp_path):
-    """adopt() merges into what's on disk NOW, not the per-process
-    snapshot — a concurrent tuner's records (flash_tune racing the
-    serving bench) must survive this process's adoption."""
-    tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
-    try:
-        assert tuning.lookup("paged_decode", "k1") is None  # snapshot: {}
-        # another process adopts while our snapshot is live
-        (tmp_path / "TUNED_KERNELS.json").write_text(json.dumps(
-            {"records": {tuning.device_kind(): {"flash_fwd": {
-                "s=2048": {"params": {"blk_q": 256, "blk_k": 512},
-                           "measured_us": 1.0}}}}}))
-        assert tuning.adopt("paged_decode", "k1", {"block_h": 2}, 5.0)
-        tuning.reset()
-        assert tuning.lookup("flash_fwd", "s=2048") == {
-            "blk_q": 256, "blk_k": 512}  # the other tuner's record lives
-        assert tuning.lookup("paged_decode", "k1") == {"block_h": 2}
-    finally:
-        tuning.set_store_path(None)
-
-
-def test_tuning_adopt_reports_persist_failure(tmp_path):
-    """A failed persist (unwritable path) returns False so callers never
-    report an unpublished tune as adopted."""
-    tuning.set_store_path(str(tmp_path / "no_such_dir" / "T.json"))
-    try:
-        assert tuning.adopt("paged_decode", "k", {"block_h": 1}, 1.0) \
-            is False
-    finally:
-        tuning.set_store_path(None)
-
-
-def test_tuning_store_device_kind_gated(tmp_path):
-    """A record measured on another chip generation is never served."""
-    path = tmp_path / "TUNED_KERNELS.json"
-    key = tuning.bucket_key(h=4, d=32)
-    path.write_text(json.dumps({"records": {"TPU v9000": {
-        "paged_decode": {key: {"params": {"block_h": 1},
-                               "measured_us": 1.0}}}}}))
-    tuning.set_store_path(str(path))
-    try:
-        assert tuning.lookup("paged_decode", key) is None
-    finally:
-        tuning.set_store_path(None)
-
-
-def test_tuning_store_malformed_never_blocks(tmp_path):
-    path = tmp_path / "TUNED_KERNELS.json"
-    path.write_text("{not json")
-    tuning.set_store_path(str(path))
-    try:
-        assert tuning.lookup("paged_decode", "h=4") is None
-        assert tuning.entries() == 0
-    finally:
-        tuning.set_store_path(None)
-
-
-def test_tuning_bucket_key_buckets_like_compile_cache():
-    """Tuning keys ride the compile cache's bucket ladder: shapes that
-    share a compiled program share a tuning record."""
-    assert tuning.bucket_key(s=100) == tuning.bucket_key(s=128)
-    assert tuning.bucket_key(s=100) == f"s={compile_cache.bucket_dim(100, 1)}"
-    assert tuning.bucket_key(d=64, h=12) == "d=64,h=12"
-
-
-def test_flash_tuned_blocks_reads_shared_store(tmp_path, monkeypatch):
-    """_tuned_blocks consults the shared store first (kernel
-    "flash_fwd"), the kernel module's own table second."""
     from paddle_tpu.ops import pallas_ops
 
-    kind = tuning.device_kind()
-    monkeypatch.setattr(pallas_ops, "_TUNED_BLOCKS",
-                        {kind: {2048: (512, 512), 4096: (512, 512)}})
-    tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
-    try:
-        tuning.adopt("flash_fwd", tuning.bucket_key(s=2048),
-                     {"blk_q": 256, "blk_k": 512}, 10.0)
-        tuning.reset()
-        assert pallas_ops._tuned_blocks(2048) == (256, 512)  # the store
-        assert pallas_ops._tuned_blocks(4096) == (512, 512)  # the table
-    finally:
-        tuning.set_store_path(None)
+    monkeypatch.setattr(pallas_ops.jax, "devices",
+                        lambda *a: [SimpleNamespace(device_kind=kind)])
+    assert pallas_ops._tuned_blocks(seq) == tiles
+
+
+#: one page of a bf16 pool at block 16: K (or V) rows of ``heads`` heads of
+#: 128, or a latent pool's 8 rows of two 576-value tokens
+_KV_PAGE = lambda heads: 16 * heads * 128 * 2  # noqa: E731
+_LATENT_PAGE = 8 * (2 * 576) * 2
+
+
+@pytest.mark.parametrize("page_bytes,max_blocks,tile_bytes,pages", [
+    (_KV_PAGE(16), 2048 // 16, pk._TILE_BYTES, 16),
+    (_KV_PAGE(30), 4096 // 16, pk._TILE_BYTES, 8),
+    (_KV_PAGE(10), 16384 // 16, pk._TILE_BYTES, 16),
+    (_KV_PAGE(8), 16384 // 16, pk._TILE_BYTES, 32),
+    (_LATENT_PAGE, 10240 // 16, pk._LATENT_TILE_BYTES, 64),
+    (_LATENT_PAGE, 7168 // 16, pk._LATENT_TILE_BYTES, 64),
+    (_KV_PAGE(16), 3, pk._TILE_BYTES, 4),
+], ids=["serve-batch-long", "serve-doc-hybrid", "serve-reason-flash",
+        "serve-mixed-swa-moe", "serve-doc-latent-moe", "serve-agent-scmoe",
+        "a_table_shorter_than_a_tile"])
+def test_tile_pages_derived_at_the_cells_shapes(page_bytes, max_blocks,
+                                                tile_bytes, pages):
+    """With no ``pages`` argument a decode launch takes what fills its
+    tile's bytes, a power of two: 16 pages at GPT-1.3B's 16 heads, 8 at
+    Olmo-Hybrid's 30 (within 3% of the best of 4/8/16/32 on the chip,
+    PERF.md PR 27), 64 of a latent pool's 18 KB; never more than covers
+    the table."""
+    assert pk._tile_pages(max_blocks, page_bytes,
+                          tile_bytes=tile_bytes) == pages
+
+
+_STRAY_STORE = """
+import json, os, sys
+from types import SimpleNamespace
+import jax
+from paddle_tpu.core import compile_cache
+from paddle_tpu.ops import paged_attention as pk, pallas_ops
+
+kind = str(jax.devices()[0].device_kind or jax.devices()[0].platform)
+compile_cache.default_cache_dir = lambda: sys.argv[1]
+rec = lambda **params: {"params": params, "measured_us": 1.0}
+with open(os.path.join(sys.argv[1], "TUNED_KERNELS.json"), "w") as f:
+    json.dump({"records": {kind: {
+        "flash_fwd": {"s=2048": rec(blk_q=256, blk_k=512)},
+        "paged_decode": {"bs=4,d=16,h=4,mb=3": rec(pages=2)}}}}, f)
+pallas_ops._TUNED_BLOCKS = {kind: {2048: (512, 512)}}
+asked = []
+pk._decode_call = lambda q, entry, bt, lengths, pages: (
+    asked.append(pages), q)[1]
+import jax.numpy as jnp
+pools = (jnp.zeros((9, 4, 4, 16)),) * 2
+pk.paged_decode_attention(jnp.zeros((2, 4, 16)), pools,
+                          jnp.zeros((2, 3), jnp.int32),
+                          jnp.zeros((2,), jnp.int32))
+print(json.dumps({"flash": pallas_ops._tuned_blocks(2048),
+                  "pages": asked}))
+"""
+
+
+def test_a_stray_tuned_kernels_file_changes_no_launch(tmp_path):
+    """A ``TUNED_KERNELS.json`` in the compile cache's directory (what
+    the tuning store read until PR 47; nothing ever wrote one) changes
+    neither the flash kernel's tiles nor the decode launch's tile: which
+    program runs is in the diff, not in a checkout's cache directory. In
+    a process of its own: the store kept what it read for the life of a
+    process."""
+    out = subprocess.run(
+        [sys.executable, "-c", _STRAY_STORE, str(tmp_path)],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got == {"flash": [512, 512], "pages": [0]}
 
 
 def test_use_interpret_memoized():
@@ -480,7 +471,7 @@ def test_gather_ctx_per_block_dequant_bitwise():
     NB, bs, H, D, S, MB = 9, 4, 2, 16, 3, 3
     entry = _pools(rng, NB, bs, H, D, quantized=True)
     table = jnp.asarray(rng.integers(0, NB, (S, MB)), jnp.int32)
-    k_all, v_all = _gather_ctx(entry, table, "bfloat16")
+    k_all, v_all = gather_ctx(entry, table, "bfloat16")
     k_ref = dequantize_kv(entry[0][table], entry[2][table],
                           "bfloat16").reshape(S, MB * bs, H, D)
     v_ref = dequantize_kv(entry[1][table], entry[3][table],
@@ -584,7 +575,7 @@ def test_engine_parity_chunked_prefill(model):
 
 def test_engine_parity_spec_verify(model):
     """Speculative decoding's draft/verify sub-steps read through the
-    kernel too (the _PagedCacheView route inside _spec_step): lockstep
+    kernel too (the PagedCacheView route inside _spec_step): lockstep
     spec + kernel == plain greedy, acceptance structurally 1.0."""
     w = _workload(np.random.default_rng(3), n=4)
     off, _ = _serve(model, None, w, paged_kernel=False)
@@ -674,7 +665,6 @@ def test_engine_default_route_follows_the_device(model):
 # virtual CPU devices conftest forces.
 
 from paddle_tpu.distributed.mesh import serving_mesh  # noqa: E402
-from paddle_tpu.distributed.sharding_util import mesh_axes_key  # noqa: E402
 
 
 def _fresh():
@@ -807,73 +797,6 @@ def test_mesh_mp1_kernel_bit_identity():
     assert st["kernel.mesh"] != "kernel@single"  # keyed differently
     for a, b in zip(ref, on):
         np.testing.assert_array_equal(a, b)
-
-
-def test_tuning_mesh_key_roundtrip(tmp_path):
-    """Mesh-keyed records: adopted under the topology suffix, resolved
-    only at that topology — never off-mesh, never at another degree."""
-    tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
-    try:
-        key = tuning.bucket_key(h=2, d=32, bs=16, mb=8)
-        topo = (("data", 1), ("model", 4))
-        assert tuning.mesh_suffix(topo) == "mesh=data1.model4"
-        tuning.adopt("paged_decode", key, {"block_h": 2}, 9.0, mesh=topo)
-        tuning.reset()
-        assert tuning.lookup("paged_decode", key, mesh=topo) \
-            == {"block_h": 2}
-        assert tuning.lookup("paged_decode", key) is None
-        assert tuning.lookup("paged_decode", key,
-                             mesh=(("data", 1), ("model", 2))) is None
-    finally:
-        tuning.set_store_path(None)
-
-
-def test_tuning_mesh_legacy_migration(tmp_path):
-    """Pre-ISSUE-16 stores (no mesh suffix) keep resolving on 1-device
-    topologies; a multi-device topology never borrows a single-chip
-    tune; a suffixed 1-device record wins over the legacy fallback."""
-    tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
-    try:
-        key = tuning.bucket_key(h=4, d=32)
-        tuning.adopt("paged_decode", key, {"block_h": 4}, 7.0)  # legacy
-        tuning.reset()
-        one = (("data", 1), ("model", 1))
-        assert tuning.lookup("paged_decode", key, mesh=one) \
-            == {"block_h": 4}
-        assert tuning.lookup("paged_decode", key,
-                             mesh=(("model", 4),)) is None
-        tuning.adopt("paged_decode", key, {"block_h": 2}, 5.0, mesh=one)
-        tuning.reset()
-        assert tuning.lookup("paged_decode", key, mesh=one) \
-            == {"block_h": 2}
-    finally:
-        tuning.set_store_path(None)
-
-
-def test_sharded_tuned_pages_applies(tmp_path):
-    """A mesh-keyed tune actually reaches the sharded launch: the
-    record's tile size (looked up under the LOCAL head count, 8//4 = 2)
-    changes nothing numerically — it stays a pure launch parameter under
-    shard_map."""
-    mesh = serving_mesh(4, install=False)
-    rng = np.random.default_rng(16)
-    S, H, D, NB, bs, MB = 3, 8, 16, 11, 4, 3
-    entry = _pools(rng, NB, bs, H, D)
-    q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
-    bt = jnp.asarray(rng.integers(1, NB, (S, MB)), jnp.int32)
-    pos = jnp.asarray([2, 7, 11], jnp.int32)
-    ref = _decode_ref(q, entry, bt, pos)
-    tuning.set_store_path(str(tmp_path / "TUNED_KERNELS.json"))
-    try:
-        key = tuning.bucket_key(h=H // 4, d=D, bs=bs, mb=MB)
-        tuning.adopt("paged_decode", key, {"pages": 2}, 3.0,
-                     mesh=mesh_axes_key(mesh))
-        tuning.reset()
-        out = pk.paged_decode_attention(q, entry, bt, pos, mesh=mesh)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   **_tol("float32"))
-    finally:
-        tuning.set_store_path(None)
 
 
 @pytest.mark.chaos

@@ -1160,21 +1160,21 @@ def test_drain_completes_in_flight_within_grace(model):
 def _row_scatter_reference(entry, table_rows, true_len, kc, vc, block_size):
     """What the full prefill did before it wrote whole blocks: one
     ``(block, offset)`` pair a POSITION, the padded ones to scratch block
-    0 (``_scatter_rows``, which suffix prefills and the decode step still
-    use). Same signature as ``_scatter_blocks``: the reference here."""
+    0 (``scatter_rows``, which suffix prefills and the decode step still
+    use). Same signature as ``scatter_blocks``: the reference here."""
     import jax.numpy as jnp
 
-    from paddle_tpu.serving.engine import _scatter_rows
+    from paddle_tpu.serving.cache_views import scatter_rows
 
     p_idx = jnp.arange(kc.shape[0])
     row = jnp.where(p_idx < true_len, table_rows[p_idx // block_size], 0)
-    return _scatter_rows(entry, row, p_idx % block_size, kc, vc)
+    return scatter_rows(entry, row, p_idx % block_size, kc, vc)
 
 
 @pytest.mark.parametrize("p", [64, 44], ids=["whole_blocks", "ragged"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
 def test_scatter_blocks_matches_row_scatter(quantized, p):
-    """``_scatter_blocks`` against the row scatter on pools full of noise
+    """``scatter_blocks`` against the row scatter on pools full of noise
     (block 8, 3 heads of 4; a chunk of ``p`` positions of which 37 are
     real, so block 4 of the chunk straddles ``true_len`` and the rest is
     padding; 44 is not a whole number of blocks): every real position bit
@@ -1183,7 +1183,7 @@ def test_scatter_blocks_matches_row_scatter(quantized, p):
     the wholly padded part."""
     import jax.numpy as jnp
 
-    from paddle_tpu.serving.engine import _scatter_blocks
+    from paddle_tpu.serving.cache_views import scatter_blocks
 
     rng = np.random.default_rng(p + quantized)
     nb, bs, h, d, true_len = 24, 8, 3, 4, 37
@@ -1200,7 +1200,7 @@ def test_scatter_blocks_matches_row_scatter(quantized, p):
     # a table row for EVERY block of the chunk, the padded ones too
     table = rng.permutation(np.arange(1, nb))[:-(-p // bs)].astype(np.int32)
     args = (jnp.asarray(table), jnp.int32(true_len), kc, vc, bs)
-    got = [np.asarray(a) for a in _scatter_blocks(entry, *args)]
+    got = [np.asarray(a) for a in scatter_blocks(entry, *args)]
     ref = [np.asarray(a) for a in _row_scatter_reference(entry, *args)]
     was = [np.asarray(a) for a in entry]
     pos = np.arange(true_len)
@@ -1229,7 +1229,7 @@ def test_block_prefill_under_a_padded_bucket(model, monkeypatch, quant_kv):
     and 20 decode steps, which cross the straddling block's end and two
     more, give the tokens of the same prompt under a bucket that fits it
     exactly and under the row scatter."""
-    from paddle_tpu.serving import engine as engine_mod
+    from paddle_tpu.serving import cache_views
 
     rng = np.random.default_rng(45)
     first, prompt = _prompt(rng, 10), _prompt(rng, 37)
@@ -1264,7 +1264,7 @@ def test_block_prefill_under_a_padded_bucket(model, monkeypatch, quant_kv):
     fit = serve(37)
     assert list(fit.eng.prefill_traces) == [37] and fit.toks == got.toks
 
-    monkeypatch.setattr(engine_mod, "_scatter_blocks",
+    monkeypatch.setattr(cache_views, "scatter_blocks",
                         _row_scatter_reference)
     ref = serve(64)
     assert ref.writes == got.writes  # the engine's count, not the write's

@@ -69,7 +69,7 @@ FIXTURE_CASES = [
     ("traced-branch", "compiled_spec_verify", ()),
     # the quantized-serving dequant shape: host-cast scale and
     # data-dependent quantization support (quantization.quantize_kv /
-    # engine._scatter_rows must stay all-array math)
+    # cache_views.scatter_rows must stay all-array math)
     ("traced-cast", "compiled_quant", ()),
     ("shape-from-data", "compiled_quant", ()),
     # the ISSUE 12 per-slot sampling shape: traced branch on a per-slot

@@ -132,17 +132,17 @@ def test_paged_decode_compiles_at_the_cells_shapes(hq, h, mb, nb):
 def test_prefill_pool_write_is_in_place_at_the_cells_shapes(nb, h, p,
                                                             quantized):
     """A prefill's write of its chunk into the donated pool entry
-    (``engine._scatter_blocks``) at the four cells' pool shapes, two of
+    (``cache_views.scatter_blocks``) at the four cells' pool shapes, two of
     them head-major on the chip (30 and 10 heads): no ``copy`` or
     ``transpose`` with the pool's leading dimension (``[NB, ...]`` or its
     ``[NB*16, ...]`` view) is in the program, payload or scale pool. The
-    row scatter (``_scatter_rows``) has six a head-major entry."""
-    from paddle_tpu.serving.engine import _scatter_blocks
+    row scatter (``scatter_rows``) has six a head-major entry."""
+    from paddle_tpu.serving.cache_views import scatter_blocks
 
     entry = _entry(h, 128, quantized, nb)
     kv = _sds((p, h, 128), jnp.bfloat16)
     text = jax.jit(
-        lambda entry, rows, true_len, k, v: _scatter_blocks(
+        lambda entry, rows, true_len, k, v: scatter_blocks(
             entry, rows, true_len, k, v, BS),
         donate_argnums=(0,)).lower(
             entry, _sds((p // BS,), jnp.int32), _sds((), jnp.int32), kv,

@@ -31,7 +31,7 @@ from paddle_tpu.models.serving_seam import KVLayerState, WindowLayerState
 from paddle_tpu.ops import grouped_matmul as gm
 from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.serving import ServingConfig, ServingEngine
-from paddle_tpu.serving import engine as E
+from paddle_tpu.serving import cache_views as E
 from paddle_tpu.serving import metrics as serving_metrics
 
 from benchmark.hooks import trinity as hook
@@ -544,7 +544,7 @@ def small_tiles(monkeypatch):
 @pytest.mark.parametrize("s", [5, 8, 24, 37])
 def test_window_prefill_view_by_kernel_is_the_view_by_xla(s, group,
                                                           small_tiles):
-    """``_WindowPrefillView(kernel=True)`` against ``kernel=False`` over
+    """``WindowPrefillView(kernel=True)`` against ``kernel=False`` over
     lengths shorter than, equal to and several times the window and one no
     tile divides, at 1, 2 and 6 query heads a K/V head: the same attention
     and the SAME ring (the write is the view's own either way)."""
@@ -552,10 +552,10 @@ def test_window_prefill_view_by_kernel_is_the_view_by_xla(s, group,
     rings = tuple(jnp.full((3, 2, 8, 16), 7.0) for _ in range(2))
     out = {}
     for kernel in (False, True):
-        view = E._WindowPrefillView(rings, jnp.int32(1), jnp.int32(s - 2), 8,
-                                    kernel=kernel)
+        view = E.WindowPrefillView(rings, jnp.int32(1), jnp.int32(s - 2), 8,
+                                   kernel=kernel)
         o, nv = view.update_and_attend(q, k, v)
-        assert isinstance(nv, E._WindowPrefillView) and nv.kernel is kernel
+        assert isinstance(nv, E.WindowPrefillView) and nv.kernel is kernel
         out[kernel] = (o, nv.entry)
     assert float(jnp.max(jnp.abs(out[True][0] - out[False][0]))) < KTOL
     for a, b in zip(out[True][1], out[False][1]):
@@ -568,17 +568,17 @@ def test_window_prefill_view_by_kernel_is_the_view_by_xla(s, group,
 @pytest.mark.parametrize("s", [5, 8, 24, 37])
 def test_capture_prefill_view_by_kernel_is_the_view_by_xla(s, group,
                                                            small_tiles):
-    """``_CapturePrefillView(kernel=True)``: the same kernel with no
+    """``CapturePrefillView(kernel=True)``: the same kernel with no
     window, against the XLA form; the captured K/V are the layer's own."""
     q, k, v = (a[None] for a in _qkv(s, 2 * group, 2, seed=10 + group))
-    o_x, cap_x = E._CapturePrefillView(8).update_and_attend(q, k, v)
-    o_k, cap_k = E._CapturePrefillView(8, kernel=True).update_and_attend(
+    o_x, cap_x = E.CapturePrefillView(8).update_and_attend(q, k, v)
+    o_k, cap_k = E.CapturePrefillView(8, kernel=True).update_and_attend(
         q, k, v)
     assert float(jnp.max(jnp.abs(o_k - o_x))) < KTOL
     assert cap_k.k is k and cap_x.v is v
     # one query row alone (a prefill tail's last row) keeps the XLA form
     last = jnp.int32(s - 1)
-    one, _ = E._CapturePrefillView(8, kernel=True, last=last) \
+    one, _ = E.CapturePrefillView(8, kernel=True, last=last) \
         .update_and_attend(q[:, s - 1:], k, v)
     assert float(jnp.max(jnp.abs(one[0, 0] - o_x[0, s - 1]))) < KTOL
 

@@ -58,8 +58,8 @@ step's default route where they compile natively, every route under
 ``ServingConfig.paged_kernel=True``) add the trace-time ``kernel.decode_traces`` /
 ``kernel.prefill_traces`` / ``kernel.verify_traces`` counters (frozen
 after warmup in a healthy run — churn never re-lowers a kernel) and the
-end-of-run ``kernel.paged`` / ``kernel.tuned_entries`` gauges (the route
-the decode step was built with + tuning-store coverage for this chip, ``ops.tuning``).
+end-of-run ``kernel.paged`` gauge (the route the decode step was built
+with).
 The mesh-sharded execution core (ISSUE 14, docs/distributed.md) adds the
 ``mesh.devices`` / ``mesh.model_axis`` / ``mesh.data_axis`` topology
 gauges — a tensor-parallel run shows ``mesh.model_axis`` > 1 with the
