@@ -13,6 +13,12 @@ from .gpt import (  # noqa: F401
     gpt_base,
     gpt_tiny,
 )
+from .keye import (  # noqa: F401
+    KeyeConfig,
+    KeyeForCausalLM,
+    KeyeModel,
+    keye_tiny,
+)
 from .longcat_flash import (  # noqa: F401
     LongcatFlashConfig,
     LongcatFlashForCausalLM,

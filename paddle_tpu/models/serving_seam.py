@@ -14,7 +14,8 @@ model shares (docs/serving_model_seam.md).
   declaration per layer, in order (the KIND of per-request state that layer
   keeps and its shape: :class:`KVLayerState`, :class:`RecurrentLayerState`,
   :class:`WindowLayerState`, :class:`SharedKVLayerState`,
-  :class:`LatentKVLayerState`, :class:`StatelessLayerState`), and
+  :class:`LatentKVLayerState`, :class:`SparseKVLayerState`,
+  :class:`StatelessLayerState`), and
   ``prefill_tail``: the first layer that
   a prefill runs on each request's last valid token only (None: every layer
   runs on every token);
@@ -73,6 +74,27 @@ rows [lanes, 1, W], scale) -> (the probabilities' sums of the rows' first
 expands through the value half of its own up-projection. The view owns the
 pool's layout (rows are packed two to a pool row at W = 576), the block
 tables and the kernel.
+
+A ``"sparse"`` layer (:class:`SparseKVLayerState`) keeps K and V rows as a
+``"kv"`` layer's AND one index key of ``index_dim`` values a token, in the
+same blocks under the same table, and attends, for every query, the
+``topk`` earlier tokens of largest index score alone. The MODEL computes
+everything a token brings (norms and rotary applied, to the index queries
+and the index key too: the view never sees an angle) and drives
+``view.select_and_attend(q [b, s, heads, head_dim], k, v [b, s, kv_heads,
+head_dim], qi [b, s, index_heads, index_dim], ki [b, s, index_dim], w [b,
+s, index_heads] float32) -> (attention output [b, s, heads, head_dim],
+successor)``. The view owns the rest: it writes the three rows, scores
+``I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s])`` over the keys ``s <= t``
+(float32 sums), keeps the ``topk`` largest (all while there are no more)
+and runs the softmax over those; one kept set serves every head. In the
+decode step it reads the lane's live index keys and at most ``topk`` rows
+of K and of V through the block table; in a prefill no ``[positions,
+positions]`` array is made (:mod:`paddle_tpu.ops.sparse_attention`).
+Refused, by name: ``prefix_cache``, ``kv_tiering``, ``chunked_prefill``,
+``spec_k``, ``quant_kv``, a ``mesh`` of more than one chip and the
+disaggregated handoff (each would need the index keys carried, shared,
+quantized or sharded beside K and V).
 
 **Options a latent pool refuses** (a ``ValueError`` that names the option,
 at construction): ``prefix_cache``, ``kv_tiering``, ``chunked_prefill``
@@ -158,6 +180,27 @@ class LatentKVLayerState:
     @property
     def width(self) -> int:
         return int(self.latent_dim) + int(self.rope_dim)
+
+
+@dataclass(frozen=True)
+class SparseKVLayerState:
+    """A layer whose per-request state is keys and values, one row a token
+    as a ``"kv"`` layer's, AND one index key of ``index_dim`` values a
+    token, by which ``index_heads`` index queries choose the ``topk``
+    tokens a query attends. All three lie in the paged arena, in the same
+    blocks under the same table."""
+
+    num_heads: int
+    head_dim: int
+    num_kv_heads: int
+    index_dim: int
+    index_heads: int
+    topk: int
+    kind: str = "sparse"
+
+    @property
+    def kv_heads(self) -> int:
+        return int(self.num_kv_heads)
 
 
 @dataclass(frozen=True)

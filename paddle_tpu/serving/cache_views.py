@@ -16,6 +16,7 @@ structure, fixed when the engine is built, never a traced branch.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Callable, NamedTuple, Optional, Tuple
 
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor
 from ..models.serving_seam import SharedRef, masked_attention
 from ..ops import paged_attention as pa
+from ..ops import sparse_attention as sa
 from ..quantization import dequantize_kv, quantize_kv
 
 
@@ -589,6 +591,112 @@ def scatter_latent(entry, table_rows, true_len, rows, block_size: int):
         rows.reshape(p // pack, pack * width).astype(pool.dtype)),)
 
 
+class SparseDecodeView:
+    """One ``"sparse"`` layer's decode-step view: ``entry`` is its pool
+    entry ``(k, v, index_rows)``. The token's three rows are written at
+    the lane's (block, offset) (a lane that is not active writes scratch
+    block 0); the lane's live index keys are scored (``kernel``: the Pallas
+    kernel :func:`paddle_tpu.ops.sparse_attention.paged_index_scores`
+    through the block tables, the live pages alone; else the XLA gather of
+    the tables), the ``topk`` largest kept (``sparse_attention.
+    select_topk``: ``lax.top_k`` over the lane's scores; only the set is
+    used), and the attention reads those rows of K and of V alone, gathered
+    through the table: ``topk`` rows of each a lane whatever its context.
+    What it scored and what it read it counts itself, where it reads
+    (``counts``: the lanes' live index keys handed to the scoring and the
+    live rows handed to the gather), for the layer to add to the step's
+    counters."""
+
+    def __init__(self, entry, block_tables, positions, active,
+                 block_size: int, topk: int, kernel: bool = False,
+                 counts=None):
+        self.entry = entry
+        self.block_tables = block_tables
+        self.positions = positions
+        self.active = active
+        self.block_size = block_size
+        self.topk = int(topk)
+        self.kernel = kernel
+        #: a successor's: what the call that made it scored and read
+        self.counts = counts
+
+    def select_and_attend(self, q, k, v, qi, ki, w):
+        qa, ka, va, qia, kia, wa = _raw(q, k, v, qi, ki, w)
+        bs, pos = self.block_size, self.positions
+        blk = self.block_tables[jnp.arange(qa.shape[0]), pos // bs]
+        blk = jnp.where(self.active, blk, 0)
+        kp, vp = (pa.write_token(pool, blk, pos % bs, new[:, 0])
+                  for pool, new in zip(self.entry, (ka, va)))
+        ip = pa.write_latent_token(self.entry[2], blk, pos % bs, kia[:, 0])
+        with jax.named_scope("indexer"):
+            scores = sa.paged_index_scores(
+                qia[:, 0], wa[:, 0], ip, self.block_tables, pos,
+                active=self.active, kernel=self.kernel)
+        with jax.named_scope("select"):
+            idx, live = sa.select_topk(scores, self.topk)
+        with jax.named_scope("sparse_attn"):
+            o = sa.gathered_attention(qa, kp, vp, self.block_tables, idx,
+                                      live)
+        counts = {
+            # the keys the scoring was told are live (the kernel copies
+            # their pages alone) and the rows of K and of V the gather was
+            # handed that hold one: a view that attended every live row
+            # under a mask would count them all here
+            "sparse.index_rows_scored": jnp.sum(
+                jnp.where(self.active, pos + 1, 0), dtype=jnp.int32),
+            "sparse.rows_read": jnp.sum(live, dtype=jnp.int32)}
+        return o, SparseDecodeView((kp, vp, ip), self.block_tables, pos,
+                                   self.active, bs, self.topk, self.kernel,
+                                   counts)
+
+
+class SparsePrefillView:
+    """One ``"sparse"`` layer's prefill view: the prompt's K, V and index
+    keys are kept for the commit to write into the slot's blocks
+    (:func:`scatter_blocks`, :func:`scatter_index_blocks`), and every query
+    attends the ``topk`` keys before it of largest index score: a chunk of
+    queries at a time is scored against every key for each row's threshold
+    (its ``topk``-th largest), then ONE flash pass keeps what stands at or
+    over it (:mod:`paddle_tpu.ops.sparse_attention`; ``kernel``: its two
+    Pallas kernels, else ``jax.numpy`` on the CPU's small prompts). No
+    ``[positions, positions]`` array is made by either route."""
+
+    def __init__(self, topk: int, kernel: bool = False, kept=None):
+        self.topk = int(topk)
+        self.kernel = kernel
+        self.kept = kept  # (k, v, index keys) of the prompt, for the commit
+
+    def select_and_attend(self, q, k, v, qi, ki, w):
+        qa, ka, va, qia, kia, wa = _raw(q, k, v, qi, ki, w)
+        with jax.named_scope("select"):
+            tau = sa.index_thresholds(qia[0], kia[0], wa[0], self.topk,
+                                      kernel=self.kernel)
+        with jax.named_scope("sparse_attn"):
+            o = sa.sparse_prefill_attention(
+                qa[0], ka[0], va[0], qia[0], kia[0], wa[0], tau,
+                kernel=self.kernel)[None]
+        return o, SparsePrefillView(self.topk, self.kernel, (ka, va, kia))
+
+
+def scatter_index_blocks(pool, table_rows, true_len, rows, block_size: int):
+    """A prompt's index keys ``[p, W]`` into the slot's blocks of a packed
+    index pool, in whole blocks as :func:`scatter_blocks` writes K and V
+    (a block at or past ``true_len`` lands in scratch block 0)."""
+    n_blk = -(-rows.shape[0] // block_size)
+    blk = jnp.where(jnp.arange(n_blk) * block_size < true_len,
+                    table_rows[:n_blk], 0)
+    rows = jnp.pad(rows, ((0, n_blk * block_size - rows.shape[0]), (0, 0)))
+    return pool.at[blk].set(
+        rows.reshape((n_blk,) + pool.shape[1:]).astype(pool.dtype))
+
+
+def _commit_sparse(view, entry, rows, c):
+    k, v, ki = view.kept
+    return scatter_blocks(entry[:2], rows, c.true_len, k[0], v[0],
+                          c.block_size) + (scatter_index_blocks(
+                              entry[2], rows, c.true_len, ki[0],
+                              c.block_size),)
+
 
 # ------------------------------------------------------ the table by kind
 
@@ -621,7 +729,9 @@ class PrefillContext(NamedTuple):
     true_len: object      # scalar int32: real (unpadded) length
     block_size: int
     kernel: bool          # the prefill route (``paged_kernel`` asked for)
-    latent_kernel: bool   # ... or, failing that, the decode step's route
+    any_kernel: bool      # ... or, failing that, the decode step's route:
+    #                       for a kind whose prompt attention has no XLA
+    #                       form that fits a long prompt
     mesh: object
     last: object = None   # the last valid row: for the layer before a
     #                       ``prefill_tail`` alone
@@ -639,7 +749,8 @@ class Kind:
     #: ``(successor view, entry, table rows, PrefillContext)`` -> the entry
     #: as the prefill leaves it
     commit: Callable = lambda view, entry, rows, c: None
-    #: PAGED: ``st`` -> the arena's ``(heads, head_dim, latent_width)``, and
+    #: PAGED: ``st`` -> the arena's ``(heads, head_dim, latent_width,
+    #: index_width)`` (:class:`~.kv_arena.KVArena`'s arguments), and
     #: ``st`` -> a pool row's minor dimension (the decode kernel reads the
     #: pool where it lies if ``paged_attention.decode_in_place`` of it)
     pool_row: Optional[Callable] = None
@@ -680,7 +791,7 @@ KINDS = {
             c.block_size, kernel=c.kernel, mesh=c.mesh, last=c.last),
         commit=lambda view, entry, rows, c: scatter_blocks(
             entry, rows, c.true_len, view.k[0], view.v[0], c.block_size),
-        pool_row=lambda st: (st.kv_heads, st.head_dim, 0),
+        pool_row=lambda st: (st.kv_heads, st.head_dim, 0, 0),
         minor=lambda st: st.head_dim,
         counted_in=("arena.paged_layers", "arena.kv_readers")),
     "latent": Kind(
@@ -689,10 +800,10 @@ KINDS = {
             entry, c.block_tables, c.positions, c.active, c.block_size,
             st.latent_dim, kernel=c.kernel),
         # its prompt attention has no XLA form that fits a long prompt
-        prefill_view=lambda st, entry, c: LatentPrefillView(c.latent_kernel),
+        prefill_view=lambda st, entry, c: LatentPrefillView(c.any_kernel),
         commit=lambda view, entry, rows, c: scatter_latent(
             entry, rows, c.true_len, view.rows[0], c.block_size),
-        pool_row=lambda st: (1, 1, st.width),
+        pool_row=lambda st: (1, 1, st.width, 0),
         minor=lambda st: pa.latent_pack(st.width) * st.width,
         counted_in=("arena.paged_layers",),
         kernel_gauge="kernel.paged_latent",
@@ -703,6 +814,30 @@ KINDS = {
         called="latent-attention layers (one shared row a token in the "
                "paged pool)",
         why="it assumes per-head K and V pools"),
+    "sparse": Kind(
+        store=PAGED, block_writes=True,
+        decode_view=lambda st, entry, c: SparseDecodeView(
+            entry, c.block_tables, c.positions, c.active, c.block_size,
+            st.topk, kernel=c.kernel),
+        # as a latent layer's: no XLA form that fits a long prompt
+        prefill_view=lambda st, entry, c: SparsePrefillView(
+            st.topk, c.any_kernel),
+        commit=_commit_sparse,
+        # K and V rows of (kv_heads, head_dim) and, beside them, ONE row of
+        # index_dim values a token; read in place where both fill whole
+        # lane tiles (the index row two tokens to a pool row)
+        pool_row=lambda st: (st.kv_heads, st.head_dim, 0, st.index_dim),
+        minor=lambda st: math.gcd(
+            st.head_dim, pa.latent_pack(st.index_dim) * st.index_dim),
+        counted_in=("arena.paged_layers",),
+        kernel_gauge="kernel.paged_index",
+        # each keeps, shares, rewinds, quantizes or shards K and V blocks
+        refuses=_BLOCK_OPTIONS + ("quant_kv", "mesh (more than one chip)",
+                                  HANDOFF),
+        called="sparse-attention layers (an index key a token beside its "
+               "K and V rows)",
+        why="it knows K and V pools alone, and the index keys would have "
+            "to be carried, quantized or sharded beside them"),
     "recurrent": Kind(
         decode_view=lambda st, entry, c: SlotStateDecodeView(entry, c.active),
         prefill_view=lambda st, entry, c: SlotStatePrefillView(
@@ -747,7 +882,8 @@ def by_store(states, items):
 def pool_row(layers):
     """The ONE shape of row the arena's block pools hold for a model's
     ``layers``, as :class:`~.kv_arena.KVArena` takes it: ``(heads,
-    head_dim, latent_width)``. Raises for a model that declares two, and
+    head_dim, latent_width, index_width)``. Raises for a model that
+    declares two, and
     for a ``"shared"`` layer that names no ``"kv"`` layer before it."""
     for i, st in enumerate(layers):
         if st.kind == "shared" and not (
@@ -759,9 +895,10 @@ def pool_row(layers):
     if len(rows) > 1:
         raise ValueError(
             "the paged arena holds one shape of row: a model's kv layers "
-            "share one (heads, head_dim), its latent layers one width, "
-            f"and it has not both (declared: {sorted(rows)})")
-    return rows.pop() if rows else (1, 1, 0)
+            "share one (heads, head_dim), its latent layers one width, its "
+            "sparse layers one (heads, head_dim, index width), and it has "
+            f"layers of one of the three alone (declared: {sorted(rows)})")
+    return rows.pop() if rows else (1, 1, 0, 0)
 
 
 def refuse_options(layers, asked) -> None:

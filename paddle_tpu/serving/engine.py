@@ -375,7 +375,8 @@ class ServingEngine:
         self._layer_kinds = tuple(cache_views.KINDS[st.kind]
                                   for st in spec.layers)
         self._prefill_tail = spec.prefill_tail
-        kv_heads, kv_dim, latent_width = cache_views.pool_row(spec.layers)
+        kv_heads, kv_dim, latent_width, index_width = cache_views.pool_row(
+            spec.layers)
         paged_layers, self._slot_layers = cache_views.by_store(
             spec.layers, spec.layers)
         self.recurrent = bool(self._slot_layers)
@@ -470,12 +471,14 @@ class ServingEngine:
             "mesh (more than one chip)": self._mesh_devices > 1})
         self._arena_args = (len(paged_layers), kv_heads, kv_dim, num_blocks,
                             self.block_size, kv_dtype, self.quant_kv,
-                            # a latent row has no heads to shard
-                            None if latent_width else self.mesh,
+                            # a latent row and an index row have no
+                            # heads to shard
+                            None if latent_width or index_width
+                            else self.mesh,
                             self.num_slots,
                             tuple(cache_views.KINDS[st.kind].arrays(
                                 st, kv_dtype) for st in self._slot_layers),
-                            latent_width)
+                            latent_width, index_width)
         self.arena = KVArena(*self._arena_args)
         # pool arrays a full prefill writes in whole blocks (an admission)
         self._block_writes = sum(
@@ -812,7 +815,7 @@ class ServingEngine:
         use_kernel = self.paged_kernel
         # a kind whose prompt attention has no XLA form that fits a long
         # prompt follows the decode step's route
-        latent_kernel = self.paged_kernel or self.decode_kernel
+        any_kernel = self.paged_kernel or self.decode_kernel
         kmesh = self._kernel_mesh
 
         def prefill(arrays, ids, true_len, pools, rows, samp, rec, slot,
@@ -833,7 +836,7 @@ class ServingEngine:
             # tail runs its last layers on row `last` alone
             last = None if tail is None else true_len - 1
             ctx = cache_views.PrefillContext(slot, true_len, bs, use_kernel,
-                                             latent_kernel, kmesh)
+                                             any_kernel, kmesh)
             entries = cache_views.layer_entries(states, pools, rec)
             views = [kind.prefill_view(st, entry, ctx._replace(last=last)
                                        if i + 1 == tail else ctx)

@@ -29,7 +29,10 @@ the client's contract with its model):
   taxonomy below. ``offset=N`` resumes from token N — the exactly-once
   reattach contract: a client that saw N tokens before a disconnect (or a
   gateway crash, with the WAL on) reattaches with ``offset=N`` and
-  observes no duplicate and no gap.
+  observes no duplicate and no gap. A stream with nothing new for
+  ``api.STREAM_WAIT_S`` (its request waits in the queue, or behind a long
+  prefill) carries an SSE comment, ``: waiting``: clients skip it, and
+  the write finds a client that has left before a prefill is spent on it.
 * ``POST /v1/stream`` — submit + stream in one round trip (the streaming
   front door's main path; body as ``/v1/submit``).
 * ``POST /v1/cancel/<request_id>`` — flag the request; its slot frees at
@@ -638,12 +641,25 @@ def _make_handler(gw: Gateway):
         def _sse(self, rr: RoutedRequest, offset: int = 0) -> None:
             self._sse_headers()
             try:
-                for i, tok in enumerate(gw.pool.stream(rr)):
-                    if i < offset:
-                        continue  # resume: the client already holds these
-                    self.wfile.write(
-                        b"data: " + json.dumps({"token": int(tok)}).encode()
-                        + b"\n\n")
+                i = 0
+                for tok in gw.pool.stream(rr, idle_turns=True):
+                    if tok is None:
+                        # nothing for STREAM_WAIT_S (the request waits in
+                        # the queue, or behind a long prefill): an SSE
+                        # comment, which a client skips. Written to a
+                        # client that has left it fails like a token's
+                        # write, so the request is cancelled BEFORE its
+                        # prefill is spent; and a client that reads it
+                        # knows the stream lives
+                        self.wfile.write(b": waiting\n\n")
+                    else:
+                        i += 1
+                        if i <= offset:
+                            continue  # resume: the client holds these
+                        self.wfile.write(
+                            b"data: "
+                            + json.dumps({"token": int(tok)}).encode()
+                            + b"\n\n")
                     self.wfile.flush()
             except (ConnectionError, BrokenPipeError, OSError):
                 # the CLIENT hung up mid-stream: cancel the request so the

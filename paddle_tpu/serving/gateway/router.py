@@ -1213,30 +1213,35 @@ class ReplicaPool:
             else:
                 raise
 
-    def _wait_for(self, rr: RoutedRequest, seen: int) -> None:
+    def _wait_for(self, rr: RoutedRequest, seen: int) -> bool:
         """One turn of a consumer with nothing to read. On a foreground
         pool the consumer IS the pump: step every replica. On a
         background pool the replicas pump themselves and housekeeping has
         its own thread, so block until ``rr``'s signal fires past
         ``seen`` (a token, a terminal backend, a re-route, a finalize, a
-        cancel); the timeout is the liveness backstop."""
+        cancel); the timeout is the liveness backstop. False if the turn
+        ended by the backstop with nothing new."""
         if not self._background:
             self.pump_once()
-            return
+            return True
         fired = rr.signal.wait(seen, STREAM_WAIT_S)
         metrics.bump("gateway.stream_wakeups")
         if not fired:
             metrics.bump("gateway.stream_wait_timeouts")
+        return fired
 
     def _count_consumer(self, delta: int) -> None:
         with self._consumers_lock:
             self._consumers += delta
             metrics.set_gauge("gateway.stream_consumers", self._consumers)
 
-    def stream(self, rr: RoutedRequest):
+    def stream(self, rr: RoutedRequest, idle_turns: bool = False):
         """Yield ``rr``'s tokens as they are generated — across replica
         ejections and re-routes. Raises the request's error at the end of
-        a failed stream (mirrors ``ServingAPI.stream``)."""
+        a failed stream (mirrors ``ServingAPI.stream``). With
+        ``idle_turns`` a wait that ends by the backstop yields ``None``:
+        the gateway's stream handler writes a comment line then, the only
+        way it learns that the client of a QUEUED request has left."""
         sent = 0
         self._count_consumer(+1)
         try:
@@ -1250,7 +1255,8 @@ class ReplicaPool:
                 self._observe(rr)
                 if rr.finished:
                     continue  # flush tokens folded in by the finalize
-                self._wait_for(rr, seen)
+                if not self._wait_for(rr, seen) and idle_turns:
+                    yield None
             # drain any tokens recorded between the last read and the
             # finalize
             for tok in rr.tokens_from(sent):

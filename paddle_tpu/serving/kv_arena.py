@@ -42,7 +42,10 @@ read-only by contract; a slot that must write into one copies it first
 layers' state, which grows a row a token (a ``"latent"`` layer's likewise:
 ONE row a token for all heads, its entry the 1-tuple ``(rows,)`` of
 ``latent_width`` values a token, same blocks, same tables, same
-accounting). A ``"recurrent"`` layer's state
+accounting; a ``"sparse"`` layer's is K and V rows AND one index key of
+``index_width`` values a token: the 3-tuple ``(k, v, index_rows)``, the
+index rows packed as a latent pool's are, :meth:`_fresh_index`). A
+``"recurrent"`` layer's state
 has a fixed size whatever the context, so it lives in a second,
 **slot-indexed** store: per such layer a tuple of ``[num_slots, *shape]``
 arrays (:attr:`KVArena.slot_state`), the lane a request decodes in being its
@@ -128,13 +131,16 @@ class KVArena:
                  num_blocks: int, block_size: Optional[int] = None,
                  dtype: str = "float32", quantized: bool = False,
                  mesh=None, num_slots: int = 0, slot_state=(),
-                 latent_width: int = 0):
+                 latent_width: int = 0, index_width: int = 0):
         """``slot_state``: per recurrent layer, its
         ``((name, per-lane shape, dtype), ...)``; ``num_slots`` lanes each.
         ``latent_width`` > 0: the ``num_layers`` pools are latent ones, ONE
         row of that many values a token (``num_heads``, ``head_dim``
         unused): each entry a 1-tuple ``(rows,)``, see
-        :meth:`_fresh_latent`."""
+        :meth:`_fresh_latent`. ``index_width`` > 0: ONE row of that many
+        values a token BESIDE its K and V rows (a ``"sparse"`` layer's
+        index keys): each entry the 3-tuple ``(k, v, index_rows)``, see
+        :meth:`_fresh_index`. Not both."""
         import jax.numpy as jnp
 
         # mesh-sharded pools (ISSUE 14): every pool entry — primary and
@@ -164,13 +170,24 @@ class KVArena:
         self.dtype = dtype
         self.quantized = bool(quantized)
         self.latent_width = int(latent_width)
-        if self.latent_width and (self.quantized or mesh is not None):
-            raise ValueError("a latent pool has no int8 form (quant_kv) "
-                             "and no heads to shard over a mesh")
+        #: values of the index key a token keeps beside its K and V rows
+        self.index_width = int(index_width)
+        if self.latent_width and self.index_width:
+            raise ValueError("a pool set holds latent rows alone or index "
+                             "rows beside K and V, not both")
+        if (self.latent_width or self.index_width) and (
+                self.quantized or mesh is not None):
+            raise ValueError("a latent pool and an index pool have no int8 "
+                             "form (quant_kv) and no heads to shard over a "
+                             "mesh")
         self._pools: List[Tuple] = [
             self._fresh_latent(jnp) if self.latent_width
             else self._fresh_entry(jnp, num_heads, head_dim)
+            + self._fresh_index(jnp)
             for _ in range(num_layers)]
+        if self.index_width:
+            metrics.set_gauge("arena.index_bytes", sum(
+                int(e[2].size) * e[2].dtype.itemsize for e in self._pools))
         # LIFO: churny workloads keep re-taking the most recently freed
         # blocks (cache-friendly, and makes reuse observable)
         self._free: List[int] = list(range(1, self.num_blocks))
@@ -229,14 +246,24 @@ class KVArena:
         the same order): ``pack`` consecutive tokens share a pool row so
         that its lanes fill whole tiles on the chip
         (:func:`paddle_tpu.ops.paged_attention.latent_pack`)."""
+        return (self._packed_rows(jnp, self.latent_width),)
+
+    def _fresh_index(self, jnp) -> Tuple:
+        """A ``"sparse"`` layer's zeroed index pool, behind its ``(k, v)``:
+        ``(index_rows,)``, one key of :attr:`index_width` values a token,
+        packed as a latent pool is; ``()`` for any other layer."""
+        return ((self._packed_rows(jnp, self.index_width),)
+                if self.index_width else ())
+
+    def _packed_rows(self, jnp, width: int):
         from ..ops.paged_attention import latent_pack
 
-        pack = latent_pack(self.latent_width)
+        pack = latent_pack(width)
         if self.block_size % pack:
             raise ValueError(f"kv_block_size {self.block_size} does not "
-                             f"hold whole rows of {pack} latent tokens")
-        return (jnp.zeros((self.num_blocks, self.block_size // pack,
-                           pack * self.latent_width), self.dtype),)
+                             f"hold whole rows of {pack} tokens of {width}")
+        return jnp.zeros((self.num_blocks, self.block_size // pack,
+                          pack * width), self.dtype)
 
     @property
     def pools(self) -> List[Tuple]:
@@ -509,12 +536,15 @@ class KVArena:
             want = 4 if quantized else 2
             if name == "primary" and self.latent_width:
                 want = 1
+            if name == "primary" and self.index_width:
+                want = 3  # neither (k, v) nor (k, v, k_scale, v_scale)
             for li, entry in enumerate(pools):
                 if len(entry) != want:
                     raise RuntimeError(
                         f"invariant violated: {name} pool entry {li} has "
                         f"{len(entry)} arrays (expected {want}) — a "
-                        "quantized pool was adopted without its scales")
+                        "quantized pool was adopted without its scales, or "
+                        "a sparse layer's without its index keys")
                 if quantized and tuple(entry[2].shape) != (
                         self.num_blocks, self.block_size):
                     raise RuntimeError(
@@ -559,7 +589,7 @@ class KVArena:
                 for d in arr.shape:
                     per *= int(d)
                 b = per * arr.dtype.itemsize
-                if i < 2:
+                if i < 2 or len(entry) == 3:  # (k, v, index keys): payload
                     kv += b
                 else:
                     scale += b

@@ -238,6 +238,13 @@ DOCUMENTED_NAMESPACES = (
     # (ISSUE 45) block_writes: pool arrays a full prefill wrote in whole
     # blocks — docs/observability.md "An admission, from inside"
     "prefill",
+    # moe.* (ISSUE 33), window.* (ISSUE 44), sparse.* (ISSUE 48): what a
+    # model's layers count inside the step program (``carry["counters"]``,
+    # ``models.serving_seam.add_step_counters``) and the engine reads back
+    # behind the step's tokens: expert loads; ring rows live / read; a
+    # sparse layer's rows live / read / index rows scored
+    # (docs/serving_model_seam.md "The step carry")
+    "moe", "window", "sparse",
     "queue", "slots", "tokens_per_sec",
 )
 

@@ -328,6 +328,145 @@ def test_a_client_hangup_still_cancels_and_frees_the_lane(model):
         gw.close()
 
 
+# ------------------------------------ idle turns: a stream with nothing new
+
+
+def _hold_every_stream_idle(pool):
+    """No request is admitted while the API lock is held (the pump thread
+    needs it for every turn): every stream stands where a QUEUED one
+    stands. Returns the release."""
+    rep, = pool.replicas()
+    rep.api._lock.acquire()
+    return rep.api._lock.release
+
+
+def test_an_idle_stream_yields_none_by_the_backstop_only_when_asked(
+        model, monkeypatch):
+    """``stream(rr, idle_turns=True)`` hands its consumer a ``None`` for
+    every wait that ended by the backstop; without the argument the same
+    stream yields tokens alone, as every caller before it expects."""
+    monkeypatch.setattr(router, "STREAM_WAIT_S", 0.1)
+    pool = ReplicaPool(model, replicas=1, background=True, **POOL_KW)
+    rng = np.random.default_rng(36)
+    p = _prompt(rng, 6)
+    try:
+        release = _hold_every_stream_idle(pool)
+        try:
+            plain, asked = pool.submit(p, max_new_tokens=5), pool.submit(
+                p, max_new_tokens=5)
+            out = []
+            t = _consume(pool, plain, out)
+            turns = pool.stream(asked, idle_turns=True)
+            assert [next(turns) for _ in range(3)] == [None] * 3
+            assert out == []
+        finally:
+            release()
+        rest = [tok for tok in turns if tok is not None]
+        t.join(timeout=60)
+        assert None not in out
+        for got in (out, rest):
+            np.testing.assert_array_equal(np.concatenate([p, got]),
+                                          _ref(model, p, 5))
+    finally:
+        pool.close()
+
+
+def _open_stream(gw, body):
+    sock = socket.create_connection(("127.0.0.1", gw.port), timeout=30)
+    sock.sendall(b"POST /v1/stream HTTP/1.1\r\nHost: x\r\n"
+                 b"Content-Type: application/json\r\n"
+                 b"Content-Length: %d\r\n\r\n" % len(body) + body)
+    return sock
+
+
+def _fill_the_lanes(pool, rng, step_s):
+    """Every lane taken for 50 steps of ``step_s``: what a handler submits
+    meanwhile waits in the queue. Returns the handles, to cancel."""
+    rep, = pool.replicas()
+    _slow_decode(rep.api, step_s)
+    held = [pool.submit(_prompt(rng, 6), max_new_tokens=50)
+            for _ in range(POOL_KW["num_slots"])]
+    assert _wait_until(lambda: all(len(rr.tokens()) for rr in held))
+    return held
+
+
+def test_a_client_that_leaves_the_queue_is_found_before_its_first_token(
+        model, monkeypatch):
+    """A stream whose request waits is sent an SSE comment every backstop
+    period; written to a client that has left, it fails as a token's write
+    does: the request is cancelled with no token made, where it used to
+    hold its place until its prefill had been spent on it."""
+    monkeypatch.setattr(router, "STREAM_WAIT_S", 0.1)
+    pool = ReplicaPool(model, replicas=1, background=True, **POOL_KW)
+    gw = Gateway(pool, port=0).start()
+    try:
+        rng = np.random.default_rng(37)
+        held = _fill_the_lanes(pool, rng, 0.1)
+        d0 = _count("gateway.client_disconnects")
+        sock = _open_stream(gw, json.dumps({
+            "prompt": _prompt(rng, 6).tolist(), "max_new_tokens": 50,
+            "request_id": "left-the-queue"}).encode())
+        seen = b""
+        while seen.count(b": waiting\n\n") < 2:
+            chunk = sock.recv(4096)
+            assert chunk, seen
+            seen += chunk
+        assert b"data:" not in seen
+        sock.close()
+        assert _wait_until(
+            lambda: _count("gateway.client_disconnects") == d0 + 1)
+        rr = gw._requests["left-the-queue"]
+        assert _wait_until(lambda: rr.state == RequestState.CANCELLED)
+        assert rr.tokens() == []
+        assert not any(h.finished for h in held)  # found while it queued
+        for h in held:
+            h.cancel()
+        assert _wait_until(lambda: _count(CONSUMERS) == 0)
+        assert _wait_until(
+            lambda: pool.tenants.stats()["default"]["inflight"] == 0)
+    finally:
+        gw.close()
+
+
+def test_the_load_generator_cuts_streams_that_wait_in_the_queue(
+        model, monkeypatch):
+    """The benchmark's closed loop cuts its streams at the window's end.
+    ``http.client`` hands a ``Connection: close`` response its socket, so
+    ``cut()`` has nothing to shut and a client ends at its stream's next
+    LINE: a queued stream's only lines before its first token are these
+    comments. Without them each client hung until its request had been
+    prefilled behind every one before it (``serve-longctx-sparse-moe``:
+    32 queued prompts of 4-28k tokens, some 24 s, past the generator's
+    ten-second join: ``left 1 threads``, exit 1, one run in twelve)."""
+    from benchmark.harness import loadgen
+
+    monkeypatch.setattr(router, "STREAM_WAIT_S", 0.2)
+    pool = ReplicaPool(model, replicas=1, background=True, **POOL_KW)
+    gw = Gateway(pool, port=0).start()
+    clients, seconds = 6, 1.0
+    sched = {"loop": "closed", "clients": clients, "seconds": seconds,
+             "ramp_s": 0.5, "drain_s": 0, "seed": 38, "vocab": 1024,
+             "shared_prefix": 0,
+             "requests": [{"id": i, "due_s": None, "prompt_len": 6,
+                           "max_new_tokens": 5}
+                          for i in range(4 * clients)]}
+    try:
+        held = _fill_the_lanes(pool, np.random.default_rng(38), 0.15)
+        t0 = time.monotonic() + 1.0
+        res = loadgen.run(sched, f"http://127.0.0.1:{gw.port}", t0)
+        took = time.monotonic() - (t0 + seconds)
+        assert not any(h.finished for h in held)  # the queue never moved
+        assert res["threads_left"] == 0
+        assert took < 2.0, took  # the lanes are held for five seconds more
+        assert len(res["requests"]) == clients
+        assert all(r["error"] == "cut" and r["tokens"] == []
+                   for r in res["requests"])
+        for h in held:
+            h.cancel()
+    finally:
+        gw.close()
+
+
 # ------------------------------------------------------ ServingAPI.stream
 
 

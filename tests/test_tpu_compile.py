@@ -653,3 +653,85 @@ def test_gdn_core_holds_no_more_with_the_kernel(_gdn_route):
                               compiled.as_text())) == kernel
         temps[kernel] = compiled.memory_analysis().temp_size_in_bytes
     assert temps[True] <= temps[False], temps
+
+
+# -------------------------------- the sparse-attention-and-experts cell (Keye)
+
+
+@pytest.fixture
+def _sparse_route(monkeypatch):
+    """``ops/sparse_attention.py`` off the interpreter; its launches are
+    jitted, so what another route traced is dropped before and after."""
+    from paddle_tpu.ops import sparse_attention as sa
+
+    calls = (sa._paged_scores_call, sa._scores_call, sa._flash_call)
+    monkeypatch.setattr(sa, "_use_interpret", lambda: False)
+    for call in calls:
+        call.clear_cache()
+    yield sa
+    for call in calls:
+        call.clear_cache()
+
+
+def test_paged_index_scores_compiles_at_the_cells_shapes(_sparse_route):
+    """``serve-longctx-sparse-moe``'s decode shapes: 32 lanes, 16 index
+    heads of 64, index keys packed two to a pool row of 128 lanes, 1,920
+    blocks of 16 a lane, 32,768 blocks. The pool reaches the kernel as it
+    lies: no copy of it is in the program."""
+    sa = _sparse_route
+    text = _compile(
+        lambda qi, w, pool, bt, pos, act: sa.paged_index_scores(
+            qi, w, pool, bt, pos, act, kernel=True),
+        _sds((32, 16, 64), jnp.bfloat16), _sds((32, 16), jnp.float32),
+        _sds((32768, 8, 128), jnp.bfloat16), _sds((32, 1920), jnp.int32),
+        _sds((32,), jnp.int32), _sds((32,), jnp.bool_))
+    assert re.search(r"%paged_index_scores[.\d]* = ", text)
+    assert not re.search(r"bf16\[32768,8,128\][^\n]* (copy|transpose)\(",
+                         text)
+
+
+def test_sparse_decode_gathers_topk_rows_and_no_more(_sparse_route):
+    """The decode view's attention at the cell's shapes (4 K/V heads of
+    128 under 32 query heads): what it reads of the K and V pools is one
+    gather each of ``[32, 2048, 4, 128]``, never a lane's whole table."""
+    sa = _sparse_route
+    pool = _sds((32768, 16, 4, 128), jnp.bfloat16)
+    text = jax.jit(sa.gathered_attention).lower(
+        _sds((32, 1, 32, 128), jnp.bfloat16), pool, pool,
+        _sds((32, 1920), jnp.int32), _sds((32, 2048), jnp.int32),
+        _sds((32, 2048), jnp.bool_)).compile().as_text()
+    assert len(re.findall(r"= bf16\[32,2048,4,128\][^\n]* gather\(",
+                          text)) == 2
+    assert "bf16[32,30720,4,128]" not in text
+    assert not re.search(r"bf16\[32768,16,4,128\][^\n]* (copy|transpose)\(",
+                         text)
+
+
+@pytest.mark.parametrize("s", [4096, 30720])
+def test_sparse_prefill_kernels_compile(s, _sparse_route):
+    """A prefill's two kernels at the cell's smallest and largest bucket:
+    32 query heads over 4 K/V heads of 128, 16 index heads of 64, query
+    tiles of 128 and key tiles of 512; the thresholds a chunk of queries
+    at a time, 1,024 or as many as leave the chunk's scores in VMEM
+    between the 32 passes of the search (``S(1)`` in the compiler's text:
+    512 at 30,720 positions, where 1,024 queries' 126 MB made every pass
+    read HBM)."""
+    sa = _sparse_route
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (_sds((s, 32, 128), bf16), _sds((s, 4, 128), bf16),
+            _sds((s, 4, 128), bf16), _sds((s, 16, 64), bf16),
+            _sds((s, 64), bf16), _sds((s, 16), f32))
+
+    def prefill(q, k, v, qi, ki, w):
+        tau = sa.index_thresholds(qi, ki, w, 2048, kernel=True)
+        return sa.sparse_prefill_attention(q, k, v, qi, ki, w, tau,
+                                           kernel=True)
+
+    compiled = jax.jit(prefill).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(r"%index_scores[.\d]* = ", text)
+    assert re.search(r"%sparse_prefill_flash[.\d]* = ", text)
+    assert f"f32[{s},{s}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    rows = {4096: 1024, 30720: 512}[s]
+    assert re.search(r"s32\[%d,%d\]\{[^}]*S\(1\)\}" % (rows, s), text)
